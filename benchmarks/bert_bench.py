@@ -1,35 +1,39 @@
 """BERT-base pretraining throughput (SURVEY §6: samples/sec).
 
 Standalone wrapper over bench.py's `_bert_phase` (fused fwd+bwd+AdamW
-step, bf16 on TPU, ragged valid_length so the Pallas flash-attention
-kernel engages). Budget-guarded like bench.py: the BudgetGuard prints
-best-so-far and exits 0 if BENCH_BUDGET_S expires. bench.py also folds
-this metric into its own headline JSON as `bert_samples_per_sec`; this
-script exists for a focused, full-budget BERT run.
+step, bf16, ragged valid_length so the Pallas flash-attention kernel
+engages). Like bench.py it measures on the chip or not at all, in the
+calling process: a failure or the BENCH_BUDGET_S deadline ends it with
+a non-zero exit code. bench.py also folds this metric into its own
+headline JSON as `bert_samples_per_sec`; this script exists for a
+focused, full-budget BERT run.
 """
-import json
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
 
-from bench import (REFERENCE_BERT_SPS, _bert_phase, _best,
-                   _enable_compile_cache, _guard, acquire_backend_once)
+from bench import REFERENCE_BERT_SPS, _bert_phase, _best, _guard
 
 
 def main():
+    import jax
+
+    from mxnet_tpu import tracing
+
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise SystemExit("bert_bench.py measures on the chip: "
+                         f"jax.default_backend() is {backend!r}")
     _guard.best.update({
         "metric": "bert_base_pretrain_samples_per_sec_per_chip",
         "unit": "samples/sec",
     })
     _guard.install()
-    backend = acquire_backend_once(max_wait=min(120.0, _guard.budget_s / 3))
-    on_tpu = backend not in ("cpu",)
-    if on_tpu:  # see bench.py: TPU-only cache
-        _enable_compile_cache()
+    tracing.enable_compile_cache()
     _best.update({"backend": backend, "phase": "backend_acquired"})
-    sps = _bert_phase(on_tpu, backend)
+    sps = _bert_phase(backend)
     _best.update({
         "value": round(sps, 2),
         "vs_baseline": round(sps / REFERENCE_BERT_SPS, 3),
@@ -39,14 +43,4 @@ def main():
 
 
 if __name__ == "__main__":
-    try:
-        main()
-    except Exception as e:  # always emit a JSON line; rc stays 0
-        import traceback
-
-        traceback.print_exc()
-        print(json.dumps({
-            "metric": "bert_base_pretrain_samples_per_sec_per_chip",
-            "value": 0.0, "unit": "samples/sec", "vs_baseline": 0.0,
-            "error": f"{type(e).__name__}: {e}"[:300],
-        }))
+    main()

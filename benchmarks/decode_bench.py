@@ -2,11 +2,11 @@
 
 The inference twin of the training benches: greedy decode through the
 Llama flash-decode path, bf16 cache vs int8-quantized cache (the
-design claim is ~2x decode HBM-traffic reduction at large S — this
-bench is what turns that from UNMEASURED to MEASURED the moment a chip
-window opens). On CPU it runs a tiny config as a pipeline check and
-reports honestly (vs_baseline 0.0: no published reference decode
-number applies off-chip).
+design claim is ~2x decode HBM-traffic reduction at large S, not
+measured yet). It takes the platform JAX gives its own process: on the
+CPU it runs a tiny config as a pipeline check, and what it prints there
+are counts and host wall clocks, never device numbers (vs_baseline
+0.0).
 
 generate() now rides the persistent executable cache
 (mxnet_tpu.serving.executables), so the second call at a signature is
@@ -23,7 +23,12 @@ comparison).
 through 1 replica, then N subprocess replicas behind
 mx.serving.FleetRouter (fleet TTFT p50/p95, tokens/sec per replica vs
 single), then N replicas with one SIGKILLed mid-run — zero lost and
-zero duplicated requests is the reported robustness claim. Adding
+zero duplicated requests is the reported robustness claim. The fleet
+workers are pinned to the CPU whatever the parent runs on (the chip
+belongs to the parent process), so these legs yield COUNTS ONLY — lost,
+duplicated, shed, failed-over requests — and their latencies and
+tokens/sec are host wall clocks of a toy model, not serving numbers.
+Adding
 --slo appends two burn-rate legs: clean (the SLO alert must stay
 silent) and with `replica.stall` armed in every worker (the alert
 must fire, name the objective in health, and collect a cross-process
@@ -50,7 +55,8 @@ adapter table inside the SAME decode executable — headline
 `bench_lora_mix_vs_base_ratio` gated >= 0.8x with zero compiles added
 after the adapters hot-load.
 
-One JSON line, rc 0, BudgetGuard — same contract as every bench here.
+One JSON line under a BudgetGuard, in the calling process; a failure
+or the deadline ends the run with a non-zero exit code.
 """
 import argparse
 import json
@@ -63,8 +69,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
 
 import numpy as np
 
-from bench import BudgetGuard, _enable_compile_cache, \
-    acquire_backend_once
+from bench import BudgetGuard
 
 _guard = None
 
@@ -97,13 +102,9 @@ def _build_net(on_tpu, serve=False):
     return cfg, net
 
 
-def run_phase(on_tpu, guard, headline=True):
+def run_phase(on_tpu, guard):
     """Measure greedy decode tokens/sec for both cache dtypes into
-    guard.best. Shared by this script and bench.py's leftover-chip
-    tail. headline=False (the bench.py ride-along) writes ONLY the
-    namespaced tokens_per_sec* keys, never value/phase — the shared
-    guard's last JSON line is the ResNet headline and must stay that
-    way (autotune_kernels precedent)."""
+    guard.best."""
     import mxnet_tpu as mx
     from mxnet_tpu.models.llama_infer import generate
 
@@ -148,7 +149,7 @@ def run_phase(on_tpu, guard, headline=True):
             f"compile_s_{cache_dtype}": round(max(0.0,
                                                   dt_cold - dt_warm), 1),
         })
-        if cache_dtype == "model" and headline:
+        if cache_dtype == "model":
             guard.best.update({"value": round(tps, 2),
                                "phase": "decode",
                                "batch": batch,
@@ -479,10 +480,11 @@ def mixed_phase(on_tpu, guard, num_requests=24, seed=0):
 def _fleet_spawn(d, name, cfg_json, fault=None, max_wall_s=300,
                  extra_env=None):
     """One subprocess fleet replica over the FileKV channel. Workers
-    always run on CPU: this phase measures the ROUTER (failover,
-    shedding, fleet latency), not chip throughput — and N processes
-    cannot share one TPU anyway. `extra_env` rides into the worker
-    (the --slo legs use it to enable telemetry + flight recorder)."""
+    always run on CPU: the chip belongs to the parent process, and
+    this phase counts what the ROUTER does (failover, shedding, lost
+    and duplicated requests), not chip throughput. `extra_env` rides
+    into the worker (the --slo legs use it to enable telemetry +
+    flight recorder)."""
     import subprocess
 
     env = dict(os.environ)
@@ -1929,12 +1931,14 @@ def main():
         metric, unit = "llama_serve_tokens_per_sec", "tokens/sec"
     else:
         metric, unit = "llama_decode_tokens_per_sec", "tokens/sec"
+    import jax
+
+    from mxnet_tpu import tracing
+
     _guard = guard = BudgetGuard(metric, unit).install()
-    backend = acquire_backend_once(max_wait=min(120.0,
-                                                guard.budget_s / 3))
+    backend = jax.default_backend()
     on_tpu = backend not in ("cpu",)
-    if on_tpu:
-        _enable_compile_cache()
+    tracing.enable_compile_cache()
     guard.best.update({"backend": backend, "phase": "backend_acquired",
                        "vs_baseline": 0.0})
     guard.emit()
@@ -1987,17 +1991,4 @@ def main():
 
 
 if __name__ == "__main__":
-    try:
-        main()
-    except Exception as e:  # always emit a JSON line; rc stays 0
-        import traceback
-
-        traceback.print_exc()
-        if _guard is not None:
-            _guard.best["error"] = f"{type(e).__name__}: {e}"[:300]
-            _guard.emit()
-        else:
-            print(json.dumps({"metric": "llama_decode_tokens_per_sec",
-                              "value": 0.0, "unit": "tokens/sec",
-                              "vs_baseline": 0.0,
-                              "error": f"{type(e).__name__}: {e}"[:300]}))
+    main()

@@ -7,7 +7,7 @@ multi_lars kernels attack: the per-param loop pays one jitted dispatch
 executable per dtype group. Runs honestly on CPU — dispatch overhead is
 host-side — so this bench produces a MEASURED number every round.
 
-One JSON line, rc 0, BudgetGuard like every other benchmark here.
+One JSON line under a BudgetGuard like every other benchmark here.
 `value` is the speedup (per-param ms / fused ms); the acceptance floor
 for the multi-tensor PR is 3x.
 """

@@ -23,7 +23,7 @@ PipeDream-flush / Megatron-LM 1F1B schedule):
    faster — every "parallel" stage serializes — so latency is reported
    for the record, not gated.
 
-One JSON line, rc 0, BudgetGuard like every other benchmark here.
+One JSON line under a BudgetGuard like every other benchmark here.
 """
 import json
 import os

@@ -9,20 +9,6 @@ import numpy as _np
 
 import jax.numpy as jnp
 
-# jax moved shard_map out of experimental in 0.6; support both so the
-# collective paths (parallel/, bench) run on either side of the move
-try:
-    from jax import shard_map  # noqa: F401  (jax >= 0.6)
-except ImportError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-# jax.typeof is likewise new api; shaped_abstractify is its longstanding
-# equivalent (ShapedArray of a concrete value or tracer)
-try:
-    from jax import typeof  # noqa: F401
-except ImportError:  # pragma: no cover - version-dependent
-    from jax.api_util import shaped_abstractify as typeof  # noqa: F401
-
 # MXNet dtype names -> jnp dtypes (reference: mshadow type enum).
 _DTYPE_ALIASES = {
     "float32": jnp.float32,

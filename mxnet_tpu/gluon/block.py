@@ -21,10 +21,10 @@ import numpy as _np
 
 import jax
 import jax.numpy as jnp
+from jax import typeof as _typeof
 
 from .. import autograd
 from .. import random as _random
-from ..base import typeof as _typeof
 from ..ndarray import NDArray
 from .parameter import Parameter, ParameterDict, DeferredInitializationError
 

@@ -96,7 +96,8 @@ class SoftmaxCrossEntropyLoss(Loss):
                 and self._axis in (-1, pred.ndim - 1) and pred.ndim >= 2):
             from ..kernels import fused_ce
 
-            if fused_ce.eligible(pred.shape[-1]):
+            if fused_ce.eligible(pred.shape[-1],
+                                 pred._data.dtype.itemsize):
                 # LM hot path: one fused Pallas pass over the (N, V)
                 # logits, no materialized log-probabilities
                 from ..ndarray import invoke

@@ -36,11 +36,13 @@ flight events the stack already emits into those numbers:
   .GoodputObjective` can burn-rate-alert on efficiency collapse.
 - efficiency — :func:`note_train_step` publishes ``goodput_mfu`` /
   ``goodput_hfu`` (model / hardware FLOPs per step ÷ step time × chips
-  × per-chip peak from :data:`PEAK_FLOPS_BY_KIND`), with honest source
-  labels: ``analytic`` (6·N·D) vs ``cost_analysis`` flops, and
-  ``device_table`` vs ``nominal`` peak (there is no honest CPU peak).
+  × per-chip peak from :data:`PEAK_FLOPS_BY_KIND`), labelled by flops
+  source: ``analytic`` (6·N·D) vs ``cost_analysis``. There is no
+  honest CPU peak, so neither gauge is published on the CPU platform,
+  and an accelerator that is not in the table is an error.
   :func:`note_tokens` feeds the comparable headline gauges
-  ``goodput_{train,serve}_tokens_per_sec_per_chip``.
+  ``goodput_{train,serve}_tokens_per_sec_per_chip``; "per chip" is per
+  chip the work spans, as its caller reports it.
 - memory pressure — :func:`note_hbm_watermark` records per-executable
   HBM watermarks via ``memory_analysis()`` (``bytes_source`` label
   says whether the number is measured or an analytic fallback), and
@@ -71,6 +73,7 @@ from . import telemetry as _tm
 __all__ = [
     "CATEGORIES",
     "PEAK_FLOPS_BY_KIND",
+    "peak_flops",
     "GoodputLedger",
     "PoolForecaster",
     "enable",
@@ -127,23 +130,39 @@ _PHASE_CATEGORY = {
     "checkpoint_restore": "checkpoint_restore",
 }
 
-#: dense bf16 peak FLOPs per chip (public spec numbers); matched by
-#: device_kind prefix, longest match wins
+#: dense bf16 peak FLOPs per chip, keyed by ``jax.Device.device_kind``
+#: (Google Cloud TPU documentation; "TPU v5 lite" is what a v5e chip
+#: reports). The ONE peak table: benchmarks and chip_smoke.py read it
+#: through :func:`peak_flops`.
 PEAK_FLOPS_BY_KIND = {
     "TPU v2": 45e12,
     "TPU v3": 123e12,
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5p": 459e12,
     "TPU v5": 459e12,
     "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
 }
 
-#: there is no honest CPU peak — this keeps the MFU gauge defined on
-#: the 8-way virtual CPU mesh, labelled peak_source="nominal"
-_CPU_NOMINAL_FLOPS = 1e12
+
+def peak_flops(device=None) -> Optional[float]:
+    """Per-chip dense bf16 peak of `device` (default: the first
+    device). None on the CPU platform — there is no honest CPU peak,
+    so nothing divides by one there. An accelerator whose device_kind
+    is not in :data:`PEAK_FLOPS_BY_KIND` raises: a utilization against
+    a guessed peak is worse than none."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return None
+    try:
+        return PEAK_FLOPS_BY_KIND[device.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"device_kind {device.device_kind!r} has no entry in "
+            "goodput.PEAK_FLOPS_BY_KIND — add its published bf16 peak "
+            "(with the source) before reporting a utilization on it"
+        ) from None
 
 
 def _category_for(phase: str) -> Optional[str]:
@@ -262,7 +281,11 @@ _MODEL_FLOPS = 0.0
 _HW_FLOPS = 0.0
 _LAST_MFU: Optional[float] = None
 _LAST_HFU: Optional[float] = None
-_PEAK_CACHE: Optional[Tuple[float, str]] = None
+#: chips each kind of work spans, as its caller last reported (the
+#: train step's mesh size, the device count of the server's arrays) —
+#: never the host's device count, which a one-chip program on a
+#: four-chip host does not use
+_CHIPS: Dict[str, int] = {}
 _LAST_PUB: Dict[str, float] = {}
 _PUB_LOCK = threading.Lock()
 
@@ -294,8 +317,7 @@ def disable() -> None:
 
 def reset() -> None:
     """disable() plus drop all ledger/efficiency state (tests)."""
-    global _LEDGER, _MODEL_FLOPS, _HW_FLOPS, _LAST_MFU, _LAST_HFU, \
-        _PEAK_CACHE
+    global _LEDGER, _MODEL_FLOPS, _HW_FLOPS, _LAST_MFU, _LAST_HFU
     disable()
     _LEDGER = None
     _TOKENS.clear()
@@ -305,7 +327,7 @@ def reset() -> None:
     _HW_FLOPS = 0.0
     _LAST_MFU = None
     _LAST_HFU = None
-    _PEAK_CACHE = None
+    _CHIPS.clear()
     _PLAN_AXES.clear()
     with _PUB_LOCK:
         _LAST_PUB.clear()
@@ -361,11 +383,14 @@ def note_compile(seconds: float) -> None:
     _LEDGER.charge_span("compile", seconds)
 
 
-def note_tokens(kind: str, n: int) -> None:
-    """Accumulate train/serve tokens for the tokens/sec/chip gauges."""
+def note_tokens(kind: str, n: int, chips: int = 1) -> None:
+    """Accumulate train/serve tokens for the tokens/sec/chip gauges.
+    `chips` is how many chips that work spans — the train step's mesh
+    size, or the device count of the arrays the server computes on."""
     if not _ENABLED or n <= 0:
         return
     _TOKENS[kind] = _TOKENS.get(kind, 0) + int(n)
+    _CHIPS[kind] = max(1, int(chips))
 
 
 def note_tenant_tokens(tenant: Optional[str], n: int) -> None:
@@ -379,34 +404,8 @@ def note_tenant_tokens(tenant: Optional[str], n: int) -> None:
     _TENANT_TOKENS[t] = _TENANT_TOKENS.get(t, 0) + int(n)
 
 
-def _chips() -> int:
-    try:
-        import jax
-        return max(1, jax.local_device_count())
-    except Exception:
-        return 1
-
-
-def _peak_flops() -> Tuple[float, str]:
-    """(per-chip peak FLOPs, source) — ``device_table`` when the
-    device kind is a known TPU, else the ``nominal`` CPU stand-in."""
-    global _PEAK_CACHE
-    if _PEAK_CACHE is None:
-        try:
-            import jax
-            kind = jax.devices()[0].device_kind
-        except Exception:
-            kind = "cpu"
-        best = None
-        for k, v in PEAK_FLOPS_BY_KIND.items():
-            if kind.lower().startswith(k.lower()):
-                if best is None or len(k) > len(best[0]):
-                    best = (k, v)
-        if best is None:
-            _PEAK_CACHE = (_CPU_NOMINAL_FLOPS, "nominal")
-        else:
-            _PEAK_CACHE = (best[1], "device_table")
-    return _PEAK_CACHE
+def _chips(kind: str) -> int:
+    return _CHIPS.get(kind, 1)
 
 
 #: active ParallelPlan axis sizes — the MFU/HFU gauges carry them as
@@ -425,8 +424,10 @@ def set_plan_axes(dp: int = 1, tp: int = 1, pp: int = 1,
 
 
 def note_train_step(step_s: float, model_flops: Optional[float] = None,
-                    hw_flops: Optional[float] = None) -> None:
-    """Publish MFU/HFU for one train step.
+                    hw_flops: Optional[float] = None,
+                    chips: int = 1) -> None:
+    """Publish MFU/HFU for one train step over `chips` chips (the
+    step's mesh size). Nothing is published on the CPU platform.
 
     ``model_flops`` is the analytic 6·N·D estimate (MFU numerator);
     ``hw_flops`` is the traced ``cost_analysis()`` count, which
@@ -441,20 +442,19 @@ def note_train_step(step_s: float, model_flops: Optional[float] = None,
         _MODEL_FLOPS = float(model_flops)
     if hw_flops:
         _HW_FLOPS = float(hw_flops)
-    if step_s <= 0:
+    _CHIPS["train"] = max(1, int(chips))
+    peak = peak_flops()
+    if step_s <= 0 or peak is None:
         return
-    peak, peak_src = _peak_flops()
-    denom = step_s * _chips() * peak
+    denom = step_s * _chips("train") * peak
     if _MODEL_FLOPS > 0:
         _LAST_MFU = _MODEL_FLOPS / denom
         _tm.set_gauge("goodput_mfu", _LAST_MFU,
-                      flops_source="analytic", peak_source=peak_src,
-                      **_PLAN_AXES)
+                      flops_source="analytic", **_PLAN_AXES)
     if _HW_FLOPS > 0:
         _LAST_HFU = _HW_FLOPS / denom
         _tm.set_gauge("goodput_hfu", _LAST_HFU,
-                      flops_source="cost_analysis",
-                      peak_source=peak_src, **_PLAN_AXES)
+                      flops_source="cost_analysis", **_PLAN_AXES)
 
 
 def note_hbm_watermark(name: str, jit_fn, args) -> None:
@@ -527,19 +527,18 @@ def publish() -> None:
         return
     _tm.set_gauge("goodput_productive_fraction",
                   secs["productive"] / el)
-    chips = _chips()
     for kind in ("train", "serve"):
         tok = _TOKENS.get(kind, 0)
         if tok:
             _tm.set_gauge(f"goodput_{kind}_tokens_per_sec_per_chip",
-                          tok / (el * chips))
+                          tok / (el * _chips(kind)))
 
 
 def usage_report() -> dict:
     """Billing-grade per-tenant usage: tokens + chip-seconds.
 
     Chip-seconds distribute the ledger's SETTLED productive seconds
-    (times the local chip count) across tenants in proportion to
+    (times the chips the server spans) across tenants in proportion to
     their attributed serve tokens, so the per-tenant column plus the
     ``unattributed`` remainder always sums exactly to the ledger's
     productive chip-seconds — conservation by construction, checked in
@@ -549,7 +548,7 @@ def usage_report() -> dict:
         secs, settled_el = {c: 0.0 for c in CATEGORIES}, 0.0
     else:
         secs, settled_el = _LEDGER.settled()
-    chips = _chips()
+    chips = _chips("serve")
     prod_chip_s = secs.get("productive", 0.0) * chips
     serve_tok = _TOKENS.get("serve", 0)
     attr_tok = sum(_TENANT_TOKENS.values())
@@ -620,21 +619,19 @@ def format_summary() -> str:
             continue
         lines.append(f"  {c:<18s} {v:10.2f}s  "
                      f"{100.0 * v / max(el, 1e-9):5.1f}%")
-    chips = _chips()
     if el > 0:
         for kind in ("train", "serve"):
             tok = _TOKENS.get(kind, 0)
             if tok:
                 lines.append(f"  {kind} tokens/sec/chip: "
-                             f"{tok / (el * chips):.1f}")
-    peak, peak_src = _peak_flops()
+                             f"{tok / (el * _chips(kind)):.1f}")
     if _LAST_MFU is not None:
         lines.append(f"  MFU {100.0 * _LAST_MFU:.1f}% "
-                     f"(analytic flops / {peak_src} peak "
-                     f"{peak / 1e12:.0f} TFLOPs/chip)")
+                     f"(analytic flops / {peak_flops() / 1e12:.0f} "
+                     "TFLOPs/chip peak)")
     if _LAST_HFU is not None:
         lines.append(f"  HFU {100.0 * _LAST_HFU:.1f}% "
-                     f"(cost_analysis flops / {peak_src} peak)")
+                     "(cost_analysis flops)")
     return "\n".join(lines)
 
 

@@ -1,15 +1,18 @@
-"""Shared kernel-dispatch policy: warn-once, counted fallback with a
-strict-mode escape hatch. Every Pallas kernel family routes its
-jnp-fallback bookkeeping through one KernelFallback so a kernel
-regression is always visible (warning + counter) and can be made fatal
-(MXNET_TPU_STRICT_KERNELS=1, or the family-specific env)."""
+"""Shared kernel-dispatch policy. Every Pallas kernel family routes a
+failure of a kernel its gate admitted through one KernelFallback. On a
+TPU backend that failure RAISES — a kernel that does not trace, lower
+or compile on the chip is a bug, and a silent return to the jnp
+reference would let a chip run pass without the kernel. Off the chip
+(the Pallas interpreter under tests) it is a warn-once, counted
+fallback that MXNET_TPU_STRICT_KERNELS=1 (or the family-specific env)
+makes fatal."""
 from __future__ import annotations
 
 import os
 import warnings
 
 __all__ = ["KernelFallback", "fallback_counts", "operand_on_cpu",
-           "pick_rows", "pad_rows"]
+           "pick_rows", "pad_rows", "per_shard"]
 
 
 def operand_on_cpu(x) -> bool:
@@ -27,16 +30,57 @@ def operand_on_cpu(x) -> bool:
         return False
 
 
-#: VMEM is ~16 MiB/core; keep one fp32 block + temps well under it
-VMEM_BUDGET_BYTES = 4 << 20
+def per_shard(fn, args, in_specs, out_like=0):
+    """`fn(*args)` with every device working on its own shard; the one
+    output is laid out like argument `out_like`.
+
+    GSPMD cannot partition a Mosaic kernel: under a multi-device jit
+    the lowering refuses ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map"). This is that
+    wrap, at the one seam every kernel family dispatches through. It
+    applies while tracing under `mesh.current_mesh()` (FusedTrainStep /
+    ShardedForward bind it) when the mesh parallelizes something and no
+    enclosing shard_map has made the axes manual already — the ZeRO and
+    pipeline steps trace their kernels on per-device views and come
+    straight through, as does everything on one device.
+
+    Specs name the repo's conventional axes ("dp" splits the batch,
+    "tp" the heads). An axis is used only if the mesh has it and it
+    divides that dim of EVERY argument naming it — q heads split over
+    tp while indivisible kv heads stay whole would pair the wrong
+    heads — else it is dropped everywhere: replicated, which is
+    correct if redundant."""
+    import jax
+    from jax import shard_map
+    from jax.sharding import PartitionSpec
+
+    from ..parallel.mesh import current_mesh
+
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1 \
+            or jax.sharding.get_abstract_mesh().manual_axes \
+            or not any(isinstance(a, jax.core.Tracer) for a in args):
+        return fn(*args)
+
+    named = [(ax, dim) for sp, a in zip(in_specs, args)
+             for ax, dim in zip(sp, a.shape) if ax is not None]
+    usable = {ax for ax, _ in named if ax in mesh.axis_names
+              and all(dim % mesh.shape[ax] == 0
+                      for ax2, dim in named if ax2 == ax)}
+    ins = tuple(PartitionSpec(*[ax if ax in usable else None
+                                for ax in sp]) for sp in in_specs)
+    return shard_map(fn, mesh=mesh, in_specs=ins,
+                     out_specs=ins[out_like], check_vma=False)(*args)
 
 
-def pick_rows(n, d, want=512, budget_bytes=VMEM_BUDGET_BYTES):
-    """Rows per block for a (rows, d) fp32 VMEM-resident block: bounded
-    by the byte budget, power of two, MINIMUM 8 — Mosaic requires the
-    sublane (second-to-last) block dim be a multiple of 8 (callers pad
-    the row count up to a multiple, see pad_rows)."""
-    budget = max(8, budget_bytes // (max(d, 1) * 4))
+def pick_rows(n, row_bytes, want, budget_bytes):
+    """Rows per block for a kernel whose VMEM working set costs
+    `row_bytes` a row (its blocks double-buffered in their own dtype
+    plus its fp32 temporaries — the caller's sum): bounded by the byte
+    budget, power of two, MINIMUM 8 — Mosaic requires the sublane
+    (second-to-last) block dim be a multiple of 8 (callers pad the row
+    count up to a multiple, see pad_rows)."""
+    budget = max(8, budget_bytes // max(row_bytes, 1))
     n_cap = 8
     while n_cap < n:
         n_cap *= 2
@@ -77,10 +121,14 @@ class KernelFallback:
         _REGISTRY[kernel_name] = self
 
     def strict(self) -> bool:
-        return any(os.environ.get(e, "0") == "1" for e in self.strict_envs)
+        import jax
+
+        return jax.default_backend() == "tpu" or any(
+            os.environ.get(e, "0") == "1" for e in self.strict_envs)
 
     def note(self, e: BaseException):
-        """Record a fallback; re-raises first in strict mode."""
+        """Record a fallback; re-raises first on a TPU backend and in
+        strict mode."""
         if self.strict():
             raise e
         self.count += 1

@@ -193,6 +193,7 @@ def _pallas_forward(q, k, v, causal, scale, block_q=None, block_k=None,
             jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(lengths.astype(jnp.int32), qt, kt, vt)
     out = out.transpose(0, 2, 1, 3)       # back to (B, T, H, d)
     return (out, lse[..., 0]) if return_lse else out
@@ -332,6 +333,7 @@ def _pallas_backward(q, k, v, lse, delta, dout, causal, scale,
             out_specs=qspec),
         out_shape=jax.ShapeDtypeStruct((B, H, T, d), q.dtype),
         interpret=interpret,
+        name="flash_attention_dq",
     )(lens, qt, kt, vt, lse4, delta4, dot)
 
     kspec = pl.BlockSpec((None, None, block_k, d),
@@ -351,6 +353,7 @@ def _pallas_backward(q, k, v, lse, delta, dout, causal, scale,
             jax.ShapeDtypeStruct((B, H, T, d), q.dtype),
         ],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(lens, qt, kt, vt, lse4, delta4, dot)
     dq = dq.transpose(0, 2, 1, 3)                  # (B, T, H, d)
     # GQA: query head h reads kv head h//rep, so sum each group of rep
@@ -465,9 +468,20 @@ def flash_attention_raw(q, k, v, causal=True, scale=None,
         if operand_on_cpu(q):
             mode = None  # eager call on CPU-committed data: no Mosaic
     if mode is not None:
+        from jax.sharding import PartitionSpec as P
+
+        from .dispatch import per_shard
+
+        interp = mode == "interpret"
+        qkv = P("dp", None, "tp", None)     # batch over dp, heads over tp
+        has_len = lengths is not None
         try:
-            return _flash_pallas(q, k, v, lengths, causal, scale,
-                                 mode == "interpret")
+            return per_shard(
+                lambda q_, k_, v_, *l_: _flash_pallas(
+                    q_, k_, v_, l_[0] if l_ else None, causal, scale,
+                    interp),
+                (q, k, v) + ((lengths,) if has_len else ()),
+                (qkv, qkv, qkv) + ((P("dp"),) if has_len else ()))
         except Exception as e:
             # fail loudly: a silently-degraded flash path hides O(T^2)
             # perf regressions. MXNET_TPU_STRICT_FLASH=1 (or

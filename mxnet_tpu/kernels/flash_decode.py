@@ -149,6 +149,7 @@ def _flash_decode_pallas(q, k_cache, v_cache, valid_len, scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, rep, d), q.dtype),
         interpret=interpret,
+        name="flash_decode",
     )(valid_len.astype(jnp.int32), qr, k_cache, v_cache)
     return out.reshape(B, H, d)
 
@@ -238,11 +239,8 @@ def _paged_compiler_params(pltpu, interpret):
     order-dependent (the online-softmax carry lives in scratch)."""
     if interpret:
         return {}
-    try:
-        return {"compiler_params": pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))}
-    except Exception:           # older/newer param spellings: let the
-        return {}               # compiler default to sequential
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))}
 
 
 def _flash_decode_paged_pallas(q, k_pages, v_pages, block_tables,
@@ -310,6 +308,7 @@ def _flash_decode_paged_pallas(q, k_pages, v_pages, block_tables,
                                    quantized=False),
         out_shape=jax.ShapeDtypeStruct((B, K, rep, d), q.dtype),
         interpret=interpret,
+        name="flash_decode_paged",
         **_paged_compiler_params(pltpu, interpret),
     )(block_tables.astype(jnp.int32), valid_len.astype(jnp.int32),
       qr, k_pages, v_pages)
@@ -379,6 +378,7 @@ def _flash_decode_paged_pallas_q8(q, k8_pages, ks_pages, v8_pages,
                                    quantized=True),
         out_shape=jax.ShapeDtypeStruct((B, K, rep, d), q.dtype),
         interpret=interpret,
+        name="flash_decode_paged_q8",
         **_paged_compiler_params(pltpu, interpret),
     )(block_tables.astype(jnp.int32), valid_len.astype(jnp.int32),
       qr, k8_pages, ks_pages, v8_pages, vs_pages)
@@ -546,7 +546,10 @@ def _flash_decode_paged_window_pallas(q, k_pages, v_pages,
     positions fold into the rep axis, so one (b, h, i) grid cell
     carries (W*rep, d) query rows through the same per-block DMA sweep
     with per-ROW valid lengths (row w*rep+r masks at valid_lens[b, w])
-    instead of one per-sequence scalar."""
+    instead of one per-sequence scalar. The row lengths ride as a
+    (B, R, 1) VMEM operand — SMEM only serves scalar loads, so the
+    prefetched scalar is just each sequence's longest row, which is
+    all the block-skip predicate needs."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -557,12 +560,11 @@ def _flash_decode_paged_window_pallas(q, k_pages, v_pages,
     R = W * rep
     qr = q.reshape(B, W, K, rep, d).transpose(0, 2, 1, 3, 4) \
         .reshape(B, K, R, d)
+    vl = valid_lens.astype(jnp.int32)                    # (B, W)
 
-    def kernel(bt_ref, vl_ref, q_ref, k_ref, v_ref, o_ref,
+    def kernel(bt_ref, vmax_ref, q_ref, vlr_ref, k_ref, v_ref, o_ref,
                m_ref, l_ref, acc_ref):
         i = pl.program_id(2)
-        vlw = vl_ref[pl.program_id(0)]                   # (W,)
-        vl_rows = jnp.repeat(vlw, rep)                   # (R,)
 
         @pl.when(i == 0)
         def _init():
@@ -570,7 +572,7 @@ def _flash_decode_paged_window_pallas(q, k_pages, v_pages,
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        @pl.when(i * bs < jnp.max(vlw))
+        @pl.when(i * bs < vmax_ref[pl.program_id(0)])
         def _block():
             qblk = q_ref[...].astype(jnp.float32) * scale  # (R, d)
             kblk = k_ref[...].astype(jnp.float32)          # (bs, d)
@@ -578,7 +580,7 @@ def _flash_decode_paged_window_pallas(q, k_pages, v_pages,
             s = qblk @ kblk.T                              # (R, bs)
             pos = i * bs + jax.lax.broadcasted_iota(
                 jnp.int32, (R, bs), 1)
-            s = jnp.where(pos < vl_rows[:, None], s, -jnp.inf)
+            s = jnp.where(pos < vlr_ref[...], s, -jnp.inf)  # (R, 1) rows
             m_prev = m_ref[...][:, 0]
             l_prev = l_ref[...][:, 0]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -599,12 +601,14 @@ def _flash_decode_paged_window_pallas(q, k_pages, v_pages,
 
     q_spec = pl.BlockSpec((None, None, R, d),
                           lambda b, h, i, bt, vl: (b, h, 0, 0))
+    vlr_spec = pl.BlockSpec((None, R, 1),
+                            lambda b, h, i, bt, vl: (b, 0, 0))
     pool_spec = pl.BlockSpec((None, None, bs, d),
                              lambda b, h, i, bt, vl: (bt[b, i], h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, K, nb),
-        in_specs=[q_spec, pool_spec, pool_spec],
+        in_specs=[q_spec, vlr_spec, pool_spec, pool_spec],
         out_specs=pl.BlockSpec((None, None, R, d),
                                lambda b, h, i, bt, vl: (b, h, 0, 0)),
         scratch_shapes=[pltpu.VMEM((R, 1), jnp.float32),   # m
@@ -615,9 +619,10 @@ def _flash_decode_paged_window_pallas(q, k_pages, v_pages,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, R, d), q.dtype),
         interpret=interpret,
+        name="flash_decode_paged_window",
         **_paged_compiler_params(pltpu, interpret),
-    )(block_tables.astype(jnp.int32), valid_lens.astype(jnp.int32),
-      qr, k_pages, v_pages)
+    )(block_tables.astype(jnp.int32), jnp.max(vl, axis=1),
+      qr, jnp.repeat(vl, rep, axis=1)[:, :, None], k_pages, v_pages)
     return out.reshape(B, K, W, rep, d).transpose(0, 2, 1, 3, 4) \
         .reshape(B, W, H, d)
 
@@ -764,6 +769,7 @@ def _flash_decode_pallas_q8(q, k8, ks, v8, vs, valid_len, scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, rep, d), q.dtype),
         interpret=interpret,
+        name="flash_decode_q8",
     )(valid_len.astype(jnp.int32), qr, k8, ks, v8, vs)
     return out.reshape(B, H, d)
 

@@ -24,7 +24,10 @@ import os
 import jax
 import jax.numpy as jnp
 
-from .dispatch import KernelFallback, operand_on_cpu, pad_rows, pick_rows
+from jax.sharding import PartitionSpec as P
+
+from .dispatch import (KernelFallback, operand_on_cpu, pad_rows,
+                       per_shard, pick_rows)
 
 __all__ = ["fused_softmax_ce_raw", "reference_softmax_ce", "eligible"]
 
@@ -47,20 +50,27 @@ def _pallas_mode():
     return None
 
 
-#: one (rows, V) fp32 block must fit the VMEM budget even at the
-#: 8-row minimum — beyond this vocab the block cannot be staged
-#: (4 MiB budget / 4 bytes / 8 rows = 128k columns)
-_MAX_VOCAB = (4 << 20) // 4 // 8
+#: Mosaic's default scoped-VMEM limit is 16 MiB per kernel. The backward
+#: holds the logits block and the gradient block, each double-buffered
+#: in the input dtype, plus about two fp32 temporaries of the block's
+#: shape (compiled for v5e, a (32, 32000) fp32 block asked for 20.5 MiB),
+#: so rows are sized for that whole working set against 12 MiB.
+_VMEM_WORKING_SET_BYTES = 12 << 20
 
 
-def eligible(vocab: int) -> bool:
+def _row_bytes(vocab, itemsize):
+    return vocab * (4 * itemsize + 8)
+
+
+def eligible(vocab: int, itemsize: int = 4) -> bool:
     """The kernel only pays off once the vocab is large enough that
     the jnp path's extra HBM round trips dominate (threshold
     overridable via MXNET_TPU_CE_MIN_VOCAB, read per call so tests can
-    lower it)."""
+    lower it), and it needs the 8-row minimum block of `itemsize`-byte
+    logits to fit the working set (fp32: 64k columns, bf16: 96k)."""
     min_vocab = int(os.environ.get("MXNET_TPU_CE_MIN_VOCAB", "1024"))
-    return (_pallas_mode() is not None
-            and min_vocab <= vocab <= _MAX_VOCAB)
+    return (_pallas_mode() is not None and vocab >= min_vocab
+            and 8 * _row_bytes(vocab, itemsize) <= _VMEM_WORKING_SET_BYTES)
 
 
 def reference_softmax_ce(x2, lbl):
@@ -69,11 +79,12 @@ def reference_softmax_ce(x2, lbl):
     return -jnp.take_along_axis(lp, lbl[:, None], axis=-1)[:, 0]
 
 
-def _pick_rows(n, v):
+def _pick_rows(n, v, itemsize):
     from . import tuning
 
-    return pick_rows(n, v, want=tuning.get("fused_ce",
-                                           "row_block_want"))
+    return pick_rows(n, _row_bytes(v, itemsize),
+                     want=tuning.get("fused_ce", "row_block_want"),
+                     budget_bytes=_VMEM_WORKING_SET_BYTES)
 
 
 def _pad_cols_neg(x2, mult=128):
@@ -132,6 +143,7 @@ def _run_fwd(x2p, lbl2p, rows, interpret):
             jax.ShapeDtypeStruct((np_, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="softmax_ce_fwd",
     )(x2p, lbl2p)
 
 
@@ -143,7 +155,7 @@ def _ce_pallas(x2, lbl, interpret):
 
 def _ce_pallas_fwd(x2, lbl, interpret):
     n, v = x2.shape
-    rows = _pick_rows(n, v)
+    rows = _pick_rows(n, v, x2.dtype.itemsize)
     x2p = _pad_cols_neg(pad_rows(x2, rows))
     lbl2p = pad_rows(lbl.astype(jnp.int32)[:, None], rows)
     loss, lse = _run_fwd(x2p, lbl2p, rows, interpret)
@@ -155,7 +167,7 @@ def _ce_pallas_bwd(interpret, res, g):
 
     x2p, lbl2p, lse, n, v = res
     np_, vp = x2p.shape
-    rows = _pick_rows(np_, vp)
+    rows = _pick_rows(np_, vp, x2p.dtype.itemsize)
     g2p = pad_rows(g.astype(jnp.float32)[:, None], rows)
     grid = (np_ // rows,)
     dx = pl.pallas_call(
@@ -170,6 +182,7 @@ def _ce_pallas_bwd(interpret, res, g):
         out_specs=pl.BlockSpec((rows, vp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((np_, vp), x2p.dtype),
         interpret=interpret,
+        name="softmax_ce_bwd",
     )(x2p, lbl2p, lse, g2p)
     import numpy as _np
 
@@ -189,9 +202,13 @@ def fused_softmax_ce_raw(x2, lbl, use_fused=True):
     mode = _pallas_mode() if use_fused else None
     if mode == "compiled" and operand_on_cpu(x2):
         mode = None  # eager call on CPU-committed data: no Mosaic
-    if mode is not None and eligible(x2.shape[1]):
+    if mode is not None and eligible(x2.shape[1], x2.dtype.itemsize):
         try:
-            return _ce_pallas(x2, lbl, mode == "interpret")
+            # rows over dp; the vocab axis stays whole (the softmax
+            # reduces over it), so tp-sharded logits gather first
+            return per_shard(
+                lambda x_, l_: _ce_pallas(x_, l_, mode == "interpret"),
+                (x2, lbl), (P("dp"), P("dp")), out_like=1)
         except Exception as e:
             _fallback.note(e)
     return reference_softmax_ce(x2, lbl)

@@ -19,9 +19,15 @@ import os
 import jax
 import jax.numpy as jnp
 
-from .dispatch import KernelFallback
+from jax.sharding import PartitionSpec as P
+
+from .dispatch import KernelFallback, per_shard
 
 __all__ = ["fused_rmsnorm", "fused_layernorm"]
+
+#: under a mesh the leading (batch) dim splits over dp; the feature dim
+#: stays whole — the kernels reduce over it
+_ROWS = P("dp")
 
 #: fallback bookkeeping (FALLBACK_COUNT exposed via __getattr__ below)
 _fallback = KernelFallback("fused-norm",
@@ -50,9 +56,19 @@ from .dispatch import pad_rows as _pad_rows  # noqa: E402
 from .dispatch import pick_rows as _pick_rows_raw  # noqa: E402
 
 
-def _pick_rows(n, d):
+def _pick_rows(n, d, itemsize=4):
+    """Rows per block for (n, d) activations of `itemsize` bytes. The
+    budget (tuning: fused_norm.vmem_budget_bytes) covers the backward's
+    whole working set — x, dy and dx blocks double-buffered in their
+    own dtype plus about three fp32 temporaries — and is HALF of
+    Mosaic's 16 MiB scoped limit on purpose: inside a large step XLA
+    parks the kernel's (N, 1) statistics operand in VMEM too,
+    lane-padded 128-fold (4 MiB at 8192 rows), against the same limit.
+    (Compiled for v5e inside the Llama step, 256-row bf16 blocks of
+    width 4096 asked for 18.95 MiB.)"""
     return _pick_rows_raw(
-        n, d, want=_tuning.get("fused_norm", "row_block_want"),
+        n, d * (6 * itemsize + 12),
+        want=_tuning.get("fused_norm", "row_block_want"),
         budget_bytes=_tuning.get("fused_norm", "vmem_budget_bytes"))
 
 
@@ -84,7 +100,7 @@ def _rms_bwd_kernel(eps, x_ref, g_ref, rrms_ref, dy_ref, dx_ref):
 def _rms_pallas_fwd(x2, g, eps, interpret):
     from jax.experimental import pallas as pl
     n, d = x2.shape
-    rows = _pick_rows(n, d)
+    rows = _pick_rows(n, d, x2.dtype.itemsize)
     x2p = _pad_rows(x2, rows)
     np_ = x2p.shape[0]
     grid = (np_ // rows,)
@@ -98,6 +114,7 @@ def _rms_pallas_fwd(x2, g, eps, interpret):
         out_shape=[jax.ShapeDtypeStruct((np_, d), x2.dtype),
                    jax.ShapeDtypeStruct((np_, 1), jnp.float32)],
         interpret=interpret,
+        name="rmsnorm_fwd",
     )(x2p, g)
     return out[:n], rrms[:n, 0]
 
@@ -105,7 +122,7 @@ def _rms_pallas_fwd(x2, g, eps, interpret):
 def _rms_pallas_dx(x2, g, rrms, dy2, eps, interpret):
     from jax.experimental import pallas as pl
     n, d = x2.shape
-    rows = _pick_rows(n, d)
+    rows = _pick_rows(n, d, x2.dtype.itemsize)
     x2p = _pad_rows(x2, rows)
     rrmsp = _pad_rows(rrms[:, None], rows)
     dy2p = _pad_rows(dy2, rows)
@@ -121,6 +138,7 @@ def _rms_pallas_dx(x2, g, rrms, dy2, eps, interpret):
         out_specs=pl.BlockSpec((rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((np_, d), x2.dtype),
         interpret=interpret,
+        name="rmsnorm_bwd",
     )(x2p, g, rrmsp, dy2p)
     return dx[:n]
 
@@ -157,10 +175,13 @@ def fused_rmsnorm(x, gamma, eps=1e-6):
         if operand_on_cpu(x):
             mode = None  # eager call on CPU-committed data: no Mosaic
     if mode is not None:
+        def rms(x_, g_):
+            x2 = x_.reshape(-1, x_.shape[-1])
+            return _rms(x2, g_, eps, mode == "interpret") \
+                .reshape(x_.shape)
+
         try:
-            x2 = x.reshape(-1, x.shape[-1])
-            out = _rms(x2, gamma, eps, mode == "interpret")
-            return out.reshape(x.shape)
+            return per_shard(rms, (x, gamma), (_ROWS, P()))
         except Exception as e:
             _fallback.note(e)
     xs = x.astype(jnp.float32)
@@ -200,7 +221,7 @@ def _ln_bwd_kernel(eps, x_ref, g_ref, mu_ref, rstd_ref, dy_ref, dx_ref):
 def _ln_pallas_fwd(x2, g, b, eps, interpret):
     from jax.experimental import pallas as pl
     n, d = x2.shape
-    rows = _pick_rows(n, d)
+    rows = _pick_rows(n, d, x2.dtype.itemsize)
     x2p = _pad_rows(x2, rows)
     np_ = x2p.shape[0]
     grid = (np_ // rows,)
@@ -217,6 +238,7 @@ def _ln_pallas_fwd(x2, g, b, eps, interpret):
                    jax.ShapeDtypeStruct((np_, 1), jnp.float32),
                    jax.ShapeDtypeStruct((np_, 1), jnp.float32)],
         interpret=interpret,
+        name="layernorm_fwd",
     )(x2p, g, b)
     return out[:n], mu[:n, 0], rstd[:n, 0]
 
@@ -224,7 +246,7 @@ def _ln_pallas_fwd(x2, g, b, eps, interpret):
 def _ln_pallas_dx(x2, g, mu, rstd, dy2, eps, interpret):
     from jax.experimental import pallas as pl
     n, d = x2.shape
-    rows = _pick_rows(n, d)
+    rows = _pick_rows(n, d, x2.dtype.itemsize)
     x2p = _pad_rows(x2, rows)
     mup = _pad_rows(mu[:, None], rows)
     rstdp = _pad_rows(rstd[:, None], rows)
@@ -242,6 +264,7 @@ def _ln_pallas_dx(x2, g, mu, rstd, dy2, eps, interpret):
         out_specs=pl.BlockSpec((rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((np_, d), x2.dtype),
         interpret=interpret,
+        name="layernorm_bwd",
     )(x2p, g, mup, rstdp, dy2p)
     return dx[:n]
 
@@ -280,10 +303,13 @@ def fused_layernorm(x, gamma, beta, eps=1e-5):
         if operand_on_cpu(x):
             mode = None  # eager call on CPU-committed data: no Mosaic
     if mode is not None:
+        def ln(x_, g_, b_):
+            x2 = x_.reshape(-1, x_.shape[-1])
+            return _ln(x2, g_, b_, eps, mode == "interpret") \
+                .reshape(x_.shape)
+
         try:
-            x2 = x.reshape(-1, x.shape[-1])
-            out = _ln(x2, gamma, beta, eps, mode == "interpret")
-            return out.reshape(x.shape)
+            return per_shard(ln, (x, gamma, beta), (_ROWS, P(), P()))
         except Exception as e:
             _fallback.note(e)
     xs = x.astype(jnp.float32)

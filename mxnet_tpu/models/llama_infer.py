@@ -58,6 +58,14 @@ def _params_tree(net):
             "layers": layers}
 
 
+def _params_device(params):
+    """The device the decoder's weights live on. Serving state (page
+    pools, adapter tables, logits rows) is committed next to them, so
+    a server built on another chip of the host does not leave its
+    state on the first one."""
+    return min(params["embed"].devices(), key=lambda d: d.id)
+
+
 # the layer math itself (RMSNorm, RoPE, SwiGLU, residual wiring) is
 # single-sourced in llama_math.py — this module owns ONLY the cache
 # plumbing and the sampling/beam loops

@@ -1037,7 +1037,7 @@ class MultiTensorUpdater:
                 member_states, mp, plans, padded, shard)
 
         nbk = len(plans)
-        from .base import shard_map
+        from jax import shard_map
 
         def body(st_bks, m_or_w_bks, g_bks, seg_bks, lrs, wds, ts,
                  rescale, extras):
@@ -1067,7 +1067,7 @@ class MultiTensorUpdater:
             body, mesh=mesh,
             in_specs=(Pz, Pz, Pz, Pz, Pr, Pr, Pr, Pr, Pr),
             out_specs=(Pz, Pz, Pz) if mp else (Pz, Pz),
-            check_rep=False)
+            check_vma=False)
 
         # donate the resident sharded state, the masters (mp) or
         # resident weight buckets, and the scattered grad buckets —

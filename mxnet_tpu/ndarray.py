@@ -16,9 +16,10 @@ import numpy as _np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import typeof as _typeof
 
 from . import autograd
-from .base import resolve_dtype, dtype_name, typeof as _typeof
+from .base import resolve_dtype, dtype_name
 from .context import Context, current_context
 
 __all__ = ["NDArray", "array", "zeros", "ones", "full", "empty", "arange",

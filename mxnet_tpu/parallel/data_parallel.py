@@ -10,6 +10,7 @@ and XLA inserts the gradient AllReduce over ICI during the backward pass.
 """
 from __future__ import annotations
 
+import time as _time
 from typing import Callable, Optional, Sequence
 
 import numpy as _np
@@ -25,6 +26,7 @@ from .. import flight as _fl
 from .. import goodput as _gp
 from .. import random as _random
 from .. import telemetry as _tm
+from .. import tracing as _tracing
 from ..ndarray import NDArray
 from .mesh import current_mesh, use_mesh
 
@@ -87,6 +89,17 @@ def _unshard(v):
         return jnp.asarray(
             multihost_utils.process_allgather(v, tiled=True))
     return jnp.asarray(_np.asarray(v))  # gather sharded dims
+
+
+def _shards_nothing(spec, mesh):
+    """True when `spec` names only axes `mesh` lacks or has at size 1:
+    an annotation that shards nothing here. The Llama blocks carry
+    P("tp", ...) whatever plan they run under, and a dp-only plan must
+    not read that as tensor parallelism."""
+    from .mesh import axis_size
+    names = [a for part in spec if part is not None
+             for a in (part if isinstance(part, tuple) else (part,))]
+    return all(axis_size(mesh, a) == 1 for a in names)
 
 
 def _param_shardings(params, names, mesh):
@@ -698,12 +711,13 @@ class FusedTrainStep:
         we can quantize (psum of int codes + error feedback) instead of
         the implicit fp32 AllReduce XLA inserts in the backward. Pure
         data parallelism only — parameters must be unsharded."""
-        from ..base import shard_map
+        from jax import shard_map
         from .compression import compressed_psum_tree
         from ..gluon.contrib import SyncBatchNorm
 
         for n in tr_names:
-            if self._params[n].sharding is not None:
+            sh = self._params[n].sharding
+            if sh is not None and not _shards_nothing(sh, self.mesh):
                 raise ValueError(
                     "gradient compression supports pure data parallelism; "
                     f"parameter {n!r} carries a TP sharding")
@@ -767,14 +781,19 @@ class FusedTrainStep:
             _np.ndim(a._data if isinstance(a, NDArray) else a), 0, dp)
             for a in args)
         in_specs = (P(), P(), P(), P(), P(), P(dp), *batch_specs)
+        # check_vma=False: local_grads must yield each shard's OWN
+        # gradient for the quantized psum below. With the varying-axes
+        # checker on, differentiating w.r.t. the replicated weights
+        # psums the cotangent implicitly and the explicit collective
+        # then sums it a second time (ndp-fold gradients).
         fn = shard_map(
             fn_step, mesh=mesh, in_specs=in_specs,
-            out_specs=(P(), P(), P(), P(), P(dp)))
+            out_specs=(P(), P(), P(), P(), P(dp)), check_vma=False)
         self._compiled = jax.jit(
             fn, donate_argnums=(0, 2, 5) if self.donate else ())
         fn_loop = shard_map(
             step, mesh=mesh, in_specs=in_specs,
-            out_specs=(P(), P(), P(), P(), P(), P(dp)))
+            out_specs=(P(), P(), P(), P(), P(), P(dp)), check_vma=False)
 
         def loop_body(tr, aux, states, resid, hyper, key, batch):
             return fn_loop(tr, aux, states, hyper, key, resid, *batch)
@@ -821,7 +840,7 @@ class FusedTrainStep:
         gradient compression: codes ride the reduce-scatter, error
         feedback keeps the full local residual. Pure data parallelism
         only."""
-        from ..base import shard_map
+        from jax import shard_map
         from .. import multi_tensor as _mt
         from .compression import compressed_psum_scatter
         from ..gluon.contrib import SyncBatchNorm
@@ -836,7 +855,7 @@ class FusedTrainStep:
         ep_names = set()
         for n in tr_names:
             sh = self._params[n].sharding
-            if sh is None:
+            if sh is None or _shards_nothing(sh, self.mesh):
                 continue
             if ep_on and len(sh) >= 1 and sh[0] == self.dp_axis:
                 ep_names.add(n)
@@ -951,6 +970,7 @@ class FusedTrainStep:
         class _Grp:
             __slots__ = ("names", "plans", "padded", "segs", "treedef")
 
+        elementwise = _mt.is_elementwise_rule(self.optimizer)
         grp_list = []
         for gk in order:
             g = _Grp()
@@ -961,9 +981,13 @@ class FusedTrainStep:
             g.padded = _mt.zero1_padded_sizes(g.plans, ndp)
             # static segment ids (flat element -> group-local tensor
             # index, pad id = n) close over the body as constants; the
-            # per-shard slice is taken by rank inside the step
-            g.segs = [jnp.asarray(s) for s in _mt.bucket_segments(
-                g.plans, g.padded, len(g.names))]
+            # per-shard slice is taken by rank inside the step. Only
+            # the norm-based rules (LAMB/LARS) read them, and at four
+            # bytes a parameter they are not free: an elementwise rule
+            # builds none.
+            g.segs = None if elementwise else [
+                jnp.asarray(s) for s in _mt.bucket_segments(
+                    g.plans, g.padded, len(g.names))]
             grp_list.append(g)
 
         def _skey(gi, j):
@@ -978,6 +1002,12 @@ class FusedTrainStep:
             new_states = jax.tree_util.tree_map(
                 lambda v: _global_put(v, shard), self._states)
         else:
+            # flatten on the HOST: the per-name state sits full-size on
+            # the device the net was built on, and a second full-size
+            # flat copy beside it (10 bytes a parameter with Adam) is
+            # what runs a 16 GB chip out of memory at ~0.7B parameters.
+            # device_put then writes each device only its own shard.
+            host = jax.local_devices(backend="cpu")[0]
             new_states = {}
             for gi, g in enumerate(grp_list):
                 member = [jax.tree_util.tree_flatten(self._states[n])
@@ -986,9 +1016,11 @@ class FusedTrainStep:
                 nleaf = len(member[0][0])
                 per_leaf = []
                 for L in range(nleaf):
-                    bks = _mt.pad_buckets(_mt.flatten_buckets(
-                        [member[m][0][L] for m in range(len(g.names))],
-                        g.plans), g.plans, g.padded)
+                    with jax.default_device(host):
+                        bks = _mt.pad_buckets(_mt.flatten_buckets(
+                            [_np.asarray(member[m][0][L])
+                             for m in range(len(g.names))],
+                            g.plans), g.plans, g.padded)
                     per_leaf.append([_global_put(b, shard) for b in bks])
                 for j in range(len(g.plans)):
                     new_states[_skey(gi, j)] = \
@@ -1146,8 +1178,9 @@ class FusedTrainStep:
                     else:
                         w_sh = lax.dynamic_slice(
                             w_bks[j], (rank * ssz,), (ssz,))
-                    seg = lax.dynamic_slice(g.segs[j], (rank * ssz,),
-                                            (ssz,))
+                    seg = None if g.segs is None else \
+                        lax.dynamic_slice(g.segs[j], (rank * ssz,),
+                                          (ssz,))
                     nw, nst = _mt.zero1_update_shard(
                         opt, w_sh, red[sk], states[sk], hyper, seg,
                         len(g.names) + 1, dp)
@@ -1219,12 +1252,12 @@ class FusedTrainStep:
 
             def fn_stats(tr, aux, states, hyper, key, *batch):
                 return step(tr, aux, states, hyper, key, None, *batch)
-        # check_rep=False: all_gather'd weights ARE identical on every
+        # check_vma=False: all_gather'd weights ARE identical on every
         # replica but shard_map's static replication checker cannot
         # prove it, so P() outputs need the check off
         fn = shard_map(
             fn_step, mesh=mesh, in_specs=in_specs + batch_specs,
-            out_specs=out_specs, check_rep=False)
+            out_specs=out_specs, check_vma=False)
         if has_resid:
             donate = (0, 2, 5)
         else:
@@ -1233,7 +1266,7 @@ class FusedTrainStep:
             fn, donate_argnums=donate if self.donate else ())
         fn_loop = shard_map(
             fn_stats, mesh=mesh, in_specs=in_specs + batch_specs,
-            out_specs=loop_out_specs, check_rep=False)
+            out_specs=loop_out_specs, check_vma=False)
         if has_resid:
             def loop_body(tr, aux, states, resid, hyper, key, batch):
                 return fn_loop(tr, aux, states, hyper, key, resid,
@@ -1340,7 +1373,7 @@ class FusedTrainStep:
         Degrade matrix mirrors ZeRO's: no pp axis → _build warned and
         ran the sequential-semantics plain step; no dp axis → single
         data shard, dp collectives dropped."""
-        from ..base import shard_map
+        from jax import shard_map
         from .. import multi_tensor as _mt
         from . import pipeline as _pl
         from .compression import (compressed_psum_scatter,
@@ -1838,19 +1871,19 @@ class FusedTrainStep:
                 return body(tr, mask_l, states_l, hyper, key, None,
                             *batch)
 
-        # check_rep=False: the dead-tick lax.cond branches and the
+        # check_vma=False: the dead-tick lax.cond branches and the
         # ppermute broadcast produce values the static replication
         # checker cannot type, and the loss/weights ARE replicated
         # where the specs say so
         fn = shard_map(fn_step, mesh=mesh,
                        in_specs=in_specs + batch_specs,
-                       out_specs=out_specs, check_rep=False)
+                       out_specs=out_specs, check_vma=False)
         donate = (0, 2, 5) if scheme is not None else (0, 2)
         self._compiled = jax.jit(
             fn, donate_argnums=donate if self.donate else ())
         fn_loop = shard_map(fn_stats, mesh=mesh,
                             in_specs=in_specs + batch_specs,
-                            out_specs=loop_out_specs, check_rep=False)
+                            out_specs=loop_out_specs, check_vma=False)
         if scheme is not None:
             def loop_body(tr, mask_l, states_l, resid, hyper, key,
                           batch):
@@ -2004,12 +2037,7 @@ class FusedTrainStep:
                 _ft.timeout_point("collective.timeout")
         self._step_count += 1
         self.optimizer.num_update = self._step_count
-        hyper = {"lr": jnp.asarray(self.optimizer.learning_rate,
-                                   jnp.float32),
-                 "wd": jnp.asarray(self.optimizer.wd, jnp.float32),
-                 "t": jnp.asarray(self._step_count, jnp.int32),
-                 "rescale": jnp.asarray(self.optimizer.rescale_grad,
-                                        jnp.float32)}
+        hyper = self._hyper()
         key = _random.next_key()
         raw = [a._data if isinstance(a, NDArray) else jnp.asarray(a)
                for a in args]
@@ -2022,7 +2050,6 @@ class FusedTrainStep:
         # the synced whole-step device span (pid 1 in the chrome trace)
         timed = _tm._ENABLED
         if timed:
-            import time as _time
             t0 = _time.perf_counter()
         fl_on = _fl._ENABLED and (self._wire_gathered is not None
                                   or self._wire_permuted is not None)
@@ -2040,6 +2067,10 @@ class FusedTrainStep:
                 _fl.record("collective", "fused.ppermute",
                            key="__activations__", store="fused",
                            bytes=int(self._wire_permuted[1]))
+        # compile accounting, as serving's Program does it: the jit
+        # cache grew during the call = this call traced and compiled
+        n_exe = self._compiled._cache_size()
+        t_call = _time.perf_counter()
         with use_mesh(self.mesh if self.mesh is not None
                       else current_mesh()):
             if self._pp_mask is not None:
@@ -2060,6 +2091,12 @@ class FusedTrainStep:
             else:
                 loss, self._tr, self._aux, self._states = self._compiled(
                     self._tr, self._aux, self._states, hyper, key, *raw)
+        if self._compiled._cache_size() > n_exe:
+            _tracing.record_compile("fused_step", None)
+            _tracing.record_compile_seconds(
+                "fused_step", _time.perf_counter() - t_call)
+        else:
+            _tracing.record_hit("fused_step")
         if timed:
             # everything before this point is host work: argument prep
             # plus the async dispatch (the compiled call returns before
@@ -2107,7 +2144,7 @@ class FusedTrainStep:
                     tok = int(nb) * (int(shp[1])
                                      if len(shp) > 1 else 1)
                 if tok:
-                    _gp.note_tokens("train", tok)
+                    _gp.note_tokens("train", tok, self._n_chips())
                 if self._pp_mask is not None:
                     gargs = (self._tr, self._pp_mask, self._states,
                              hyper, key)
@@ -2118,6 +2155,40 @@ class FusedTrainStep:
                     gargs += (self._resid,)
                 self._goodput_step(dt, tok, gargs + tuple(raw))
         return NDArray(loss)
+
+    def _hyper(self):
+        """The traced hyperparameter operands of one step."""
+        opt = self.optimizer
+        return {"lr": jnp.asarray(opt.learning_rate, jnp.float32),
+                "wd": jnp.asarray(opt.wd, jnp.float32),
+                "t": jnp.asarray(self._step_count, jnp.int32),
+                "rescale": jnp.asarray(opt.rescale_grad, jnp.float32)}
+
+    def lower(self, *args):
+        """The ``jax.stages.Lowered`` of the single-step executable for
+        this batch against the step's live state (built first if this
+        is its first use; nothing runs, the RNG stream is untouched).
+        ``.as_text()`` names the Pallas kernels in the module (each
+        custom call carries its ``kernel_name``); ``.compile()
+        .as_text()`` shows the shapes each device works on after
+        partitioning."""
+        if self._params is None:
+            self._init_state(args)
+        if self._compiled is None:
+            self._build(args)
+        raw = [a._data if isinstance(a, NDArray) else jnp.asarray(a)
+               for a in args]
+        if self.mesh is not None:
+            raw = [_global_put(r, sh)
+                   for r, sh in zip(raw, self._batch_sh)]
+        cargs = (self._tr,
+                 self._aux if self._pp_mask is None else self._pp_mask,
+                 self._states, self._hyper(), jax.random.PRNGKey(0))
+        if self._resid is not None:
+            cargs += (self._resid,)
+        with use_mesh(self.mesh if self.mesh is not None
+                      else current_mesh()):
+            return self._compiled.lower(*cargs, *raw)
 
     #: goodput efficiency caches, filled by the first timed step
     _gp_nparams = None
@@ -2139,14 +2210,17 @@ class FusedTrainStep:
             try:
                 cost = self._compiled.lower(
                     *call_args).compile().cost_analysis()
-                if isinstance(cost, (list, tuple)):
-                    cost = cost[0] if cost else {}
                 self._gp_hw_flops = float((cost or {}).get("flops",
                                                            0.0))
             except Exception:
                 self._gp_hw_flops = 0.0
         _gp.note_train_step(step_s, model_flops=model,
-                            hw_flops=self._gp_hw_flops or None)
+                            hw_flops=self._gp_hw_flops or None,
+                            chips=self._n_chips())
+
+    def _n_chips(self) -> int:
+        """Devices this step's executable spans (its mesh, else one)."""
+        return 1 if self.mesh is None else int(self.mesh.devices.size)
 
     def _count_wire_bytes(self, k):
         """Feed the `comm_bytes_{gathered,permuted}` counter families
@@ -2358,9 +2432,6 @@ class FusedTrainStep:
             losses = [self(*b)._data for b in batches]
             return NDArray(jnp.stack(losses))
 
-        from .. import tracing as _tracing
-        import time as _time
-
         # double-buffer feed: if the previous dispatch staged THIS
         # window (run_steps(..., next_batches=window)) while the device
         # was busy, consume the device-resident copy instead of paying
@@ -2563,7 +2634,7 @@ class FusedTrainStep:
                     tok = int(nb) * (int(shp[1])
                                      if len(shp) > 1 else 1)
                 if tok:
-                    _gp.note_tokens("train", tok * k)
+                    _gp.note_tokens("train", tok * k, self._n_chips())
                 # no AOT re-lower of the scan executable: the fused
                 # window would recompile; MFU rides the analytic flops
                 self._goodput_step(per, tok)

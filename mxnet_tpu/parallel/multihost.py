@@ -73,11 +73,7 @@ def initialize(coordinator_address: Optional[str] = None,
     # jax.distributed.initialize.
     plats = (jax.config.jax_platforms or "")
     if "cpu" in plats.split(","):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except Exception:  # older/newer jax: name or impl missing
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id, **kwargs)
@@ -162,28 +158,17 @@ def _client():
     joined a multi-process job."""
     if not _initialized:
         return None
-    try:
-        from jax._src import distributed as _dist
-        return _dist.global_state.client
-    except Exception:
-        return None
+    from jax._src import distributed as _dist
+    return _dist.global_state.client
 
 
 def kv_set(key: str, value: str) -> bool:
     """Publish `key` -> `value` in the coordination-service KV store
-    (last write wins; older jaxlib without overwrite support falls back
-    to delete-then-set). False when there is no service to publish to."""
+    (last write wins). False when there is no service to publish to."""
     c = _client()
     if c is None:
         return False
-    try:
-        c.key_value_set(key, value, allow_overwrite=True)
-    except TypeError:  # jaxlib without allow_overwrite
-        try:
-            c.key_value_delete(key)
-        except Exception:
-            pass
-        c.key_value_set(key, value)
+    c.key_value_set(key, value, allow_overwrite=True)
     return True
 
 

@@ -30,7 +30,7 @@ import numpy as _np
 
 import jax
 import jax.numpy as jnp
-from ..base import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .mesh import current_mesh
@@ -350,11 +350,7 @@ def sequential_apply(stage_fn, stacked_params, x):
 
 def _vary(x, axis_name):
     try:
-        if hasattr(jax.lax, "pcast"):
-            return jax.lax.pcast(x, (axis_name,), to="varying")
-        if hasattr(jax.lax, "pvary"):
-            return jax.lax.pvary(x, (axis_name,))
-        return x  # older jax: no varying types, carries vary implicitly
+        return jax.lax.pcast(x, (axis_name,), to="varying")
     except ValueError:
         return x  # already varying over axis_name
 
@@ -850,7 +846,7 @@ def one_f_one_b(stage_fn, stacked_params, x, y, loss_fn,
 
     fn = shard_map(body, mesh=mesh,
                    in_specs=(param_specs, P(), P()),
-                   out_specs=(P(), param_specs), check_rep=False)
+                   out_specs=(P(), param_specs), check_vma=False)
     loss_sum, grads = fn(stacked_params, mbatches, ybatches)
     # per-microbatch cotangents were seeded unscaled; match the
     # sequential reference's mean-over-microbatches loss
@@ -894,7 +890,7 @@ def gpipe(stage_fn, stacked_params, x, num_microbatches, mesh=None,
 
     fn = shard_map(body, mesh=mesh,
                    in_specs=(param_specs, P()), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     out = fn(stacked_params, mbatches)
     return out.reshape(B, *out.shape[2:])
 
@@ -1237,8 +1233,6 @@ def _block_costs(blocks, block_params, entry, raw, cost_model):
             lambda tr, h: entry.raw_fn(tr, {}, jax.random.PRNGKey(0),
                                        h)[0]).lower(tr0, raw)
         cost = lowered.compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
         flops = float(cost.get("flops", 0.0)) if cost else 0.0
         if flops > 0:
             return [flops] * len(blocks)

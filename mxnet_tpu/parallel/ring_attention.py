@@ -24,7 +24,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from ..base import shard_map
+from jax import shard_map
 
 from ..ndarray import NDArray
 from .mesh import current_mesh
@@ -70,11 +70,7 @@ def ring_attention_local(q, k, v, axis_name, causal=True, scale=None):
     def _vary(x):
         # mark the carry as device-varying over the ring axis so the scan
         # carry type matches its (q/k/v-dependent, hence varying) outputs
-        if hasattr(jax.lax, "pcast"):
-            return jax.lax.pcast(x, (axis_name,), to="varying")
-        if hasattr(jax.lax, "pvary"):
-            return jax.lax.pvary(x, (axis_name,))
-        return x  # older jax: no varying types, carries vary implicitly
+        return jax.lax.pcast(x, (axis_name,), to="varying")
 
     o0 = _vary(jnp.zeros((B, H, Tq, D), jnp.float32))
     m0 = _vary(jnp.full((B, H, Tq), _NEG, jnp.float32))

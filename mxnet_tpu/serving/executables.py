@@ -29,9 +29,10 @@ Three program families:
   decode tick (sample + step + page write + per-row PRNG advance in
   ONE executable).
 
-Donation: page pools and caches are donated on non-CPU backends (the
+Donation: page pools and caches are donated on every backend (the
 caller always threads the returned arrays back), so serving holds one
-pool's worth of HBM, not two.
+pool's worth of HBM, not two — and a stale reference to a donated pool
+fails under the CPU tests the same way it would on the chip.
 """
 from __future__ import annotations
 
@@ -74,12 +75,7 @@ class Program:
             self.compiles += 1
             return fn(*args)
 
-        kw = {}
-        if donate_argnums and jax.default_backend() != "cpu":
-            # CPU XLA cannot honor donation; skipping avoids the
-            # per-call "donated buffers were not usable" warning
-            kw["donate_argnums"] = donate_argnums
-        self._jit = jax.jit(counted, **kw)
+        self._jit = jax.jit(counted, donate_argnums=donate_argnums)
 
     def __call__(self, *args):
         self.calls += 1

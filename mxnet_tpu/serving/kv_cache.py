@@ -64,7 +64,7 @@ class PagedKVCache:
                  head_dim: int, num_blocks: int, block_size: int,
                  batch_slots: int, max_blocks_per_seq: int,
                  dtype=jnp.float32, quantized: bool = False,
-                 prefix_cache: bool = False):
+                 prefix_cache: bool = False, device=None):
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is the "
                              "reserved scratch block)")
@@ -84,7 +84,8 @@ class PagedKVCache:
         # executables' first call would then carry a different
         # sharding signature than every later call (whose pools are
         # jit outputs) — one silent extra XLA compile per program.
-        dev = jax.devices()[0]
+        # The server passes the device its weights are on.
+        dev = jax.devices()[0] if device is None else device
         if quantized:
             self.pages = [jax.device_put(
                 {"k": jnp.zeros((N, K, bs, d), jnp.int8),

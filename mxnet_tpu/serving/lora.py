@@ -138,7 +138,7 @@ class AdapterPool:
     def __init__(self, net, *, capacity: int = 8, rank: int = 8,
                  targets: Tuple[str, ...] = ("wq", "wv"),
                  dtype=None):
-        from ..models.llama_infer import _params_tree
+        from ..models.llama_infer import _params_device, _params_tree
         capacity = int(capacity)
         rank = int(rank)
         if capacity < 2:
@@ -159,7 +159,7 @@ class AdapterPool:
         self.rank = rank
         self.targets = targets
         dt = params["embed"].dtype if dtype is None else jnp.dtype(dtype)
-        dev = jax.devices()[0]
+        dev = _params_device(params)
         tables = []
         self._dims = []                 # per-layer {t: (din, dout)}
         for lp in params["layers"]:

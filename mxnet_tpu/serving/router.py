@@ -2423,6 +2423,9 @@ def _worker_main(argv=None):
     args = ap.parse_args(argv)
 
     import mxnet_tpu as mx
+    from .. import tracing
+    # a restarted or newly provisioned worker finds its executables
+    tracing.enable_compile_cache()
     mx.random.seed(args.seed)
     if args.config:
         from ..models.llama import LlamaConfig, LlamaForCausalLM

@@ -319,13 +319,23 @@ class InferenceServer:
         if num_blocks is None:
             num_blocks = batch_slots * max_blocks + 1
         model_dtype = jnp.dtype(getattr(cfg, "dtype", "float32"))
+        from ..models.llama_infer import _params_device, _params_tree
+        params = _params_tree(net)
+        # every array the executables take (weights, page pools,
+        # logits/PRNG rows) is committed to the weights' device as a
+        # plain single-device array: weights a mesh'd train step handed
+        # back carry a NamedSharding, jit hands it on to the pools it
+        # returns, and the second call would then miss the first one's
+        # executable — one silent extra compile per program
+        self._device = dev = _params_device(params)
+        self._params = jax.device_put(params, dev)
         self.cache = PagedKVCache(
             num_layers=cfg.num_layers, num_kv_heads=cfg.num_kv_heads,
             head_dim=cfg.head_dim, num_blocks=num_blocks,
             block_size=block_size, batch_slots=batch_slots,
             max_blocks_per_seq=max_blocks, dtype=model_dtype,
             quantized=kv_cache_dtype == "int8",
-            prefix_cache=prefix_cache)
+            prefix_cache=prefix_cache, device=dev)
         self.programs = executables.paged_programs(
             net, batch_slots=batch_slots, max_blocks_per_seq=max_blocks,
             block_size=block_size, max_prompt_len=self.max_prompt_len,
@@ -373,15 +383,11 @@ class InferenceServer:
             pool_k.shape, (batch_slots, max_blocks),
             pool_k.dtype.itemsize, quantized=q8)
 
-        from ..models.llama_infer import _params_tree
-        self._params = _params_tree(net)
-
         B, V = batch_slots, cfg.vocab_size
         # device_put to an explicit device = committed: the decode
         # executable's first call must present the same sharding
         # signature as steady-state calls (where these are jit
         # outputs), or jit recompiles once
-        dev = jax.devices()[0]
         self._last_logits = jax.device_put(jnp.zeros((B, V),
                                                      model_dtype), dev)
         self._keys = jax.device_put(jnp.zeros((B, 2), jnp.uint32), dev)
@@ -463,7 +469,8 @@ class InferenceServer:
         """Re-snapshot the net's weights (after a training step /
         checkpoint load). Shapes are unchanged, so no recompile."""
         from ..models.llama_infer import _params_tree
-        self._params = _params_tree(self.net)
+        self._params = jax.device_put(_params_tree(self.net),
+                                      self._device)
 
     # -- tenants + adapters -------------------------------------------------
 
@@ -1294,7 +1301,8 @@ class InferenceServer:
             if eta is not None and eta < self.tier.spill_exhaust_s:
                 self.tier.spill_parked(self.tier.spill_batch)
         if _gp._ENABLED:
-            _gp.note_tokens("serve", net_new)
+            _gp.note_tokens("serve", net_new,
+                            len(self._last_logits.devices()))
             _gp.publish()
         if telemetry._ENABLED:
             telemetry.inc("serving_tokens_total", net_new)
@@ -1384,7 +1392,7 @@ class InferenceServer:
             dt = self._tok_window[-1][0] - t0
             if dt > 0:
                 n = sum(k for _, k in list(self._tok_window)[1:])
-                chips = max(1, jax.local_device_count())
+                chips = len(self._last_logits.devices())
                 telemetry.set_gauge("serving_tokens_per_sec_per_chip",
                                     n / dt / chips)
 

@@ -10,6 +10,7 @@ cache-hit statistics (the CachedOp hit-rate analogue), and — when
 graph's StableHLO to that directory as it is built.
 
 API:
+    enable_compile_cache() — JAX's persistent cache at a fixed place
     cache_stats() / reset_cache_stats()
     lower_text(entry)  — StableHLO of a compiled _CacheEntry
     jaxpr_text(entry)  — jaxpr of the same
@@ -23,9 +24,33 @@ from typing import Optional
 
 import jax
 
-__all__ = ["cache_stats", "reset_cache_stats", "record_hit",
+__all__ = ["enable_compile_cache", "cache_stats", "reset_cache_stats",
+           "record_hit",
            "record_compile", "record_compile_seconds", "lower_text",
            "jaxpr_text", "dump_dir", "maybe_dump"]
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and
+    no directory is set in code, so whoever runs the program decides
+    where compiled code survives. Otherwise the cache lives at
+    ``<checkout>/.jax_cache`` (git-ignored): a fixed path, because the
+    path is part of what a later process must reproduce to hit — never
+    one built from a temporary name, a pid or the time. Entry points
+    call this (chip_smoke.py, bench.py, benchmarks/*, the serving
+    worker); importing the package does not."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir",
+            os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), ".jax_cache"))
+    # cache every executable: the eager per-op programs of a cold start
+    # are small and quick to compile, and there are hundreds of them
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return jax.config.jax_compilation_cache_dir
+
 
 _lock = threading.Lock()
 _stats = {"compiles": 0, "hits": 0}

@@ -167,27 +167,41 @@ def test_disable_detaches_hooks():
 
 # -- MFU / HFU gauges -------------------------------------------------------
 
-def test_mfu_hfu_gauge_math():
+class _Dev:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_peak_table_is_keyed_by_device_kind():
+    assert goodput.peak_flops(_Dev("tpu", "TPU v5 lite")) == 197e12
+    # no honest CPU peak: nothing to divide by
+    assert goodput.peak_flops(_Dev("cpu", "cpu")) is None
+    # an accelerator that is not in the table is an error, not a default
+    with pytest.raises(KeyError, match="PEAK_FLOPS_BY_KIND"):
+        goodput.peak_flops(_Dev("tpu", "TPU v99"))
+
+
+def test_mfu_hfu_gauge_math(monkeypatch):
     telemetry.enable()
     goodput.enable()
     model_f, hw_f = 2.5e11, 5.0e11
+    # the suite runs on the CPU platform, where there is no peak and
+    # so no MFU/HFU at all
     goodput.note_train_step(1.0, model_flops=model_f, hw_flops=hw_f)
-    peak, src = goodput._peak_flops()
-    denom = 1.0 * goodput._chips() * peak
-    mfu = telemetry.read_gauge("goodput_mfu", flops_source="analytic",
-                               peak_source=src)
+    assert not [k for k in telemetry.snapshot()["gauges"]
+                if k.startswith(("goodput_mfu", "goodput_hfu"))]
+    # on a chip in the table, the denominator is step time x the chips
+    # the STEP spans x that chip's peak
+    monkeypatch.setattr(goodput, "peak_flops", lambda device=None: 197e12)
+    goodput.note_train_step(1.0, chips=4)
+    denom = 1.0 * 4 * 197e12
+    mfu = telemetry.read_gauge("goodput_mfu", flops_source="analytic")
     hfu = telemetry.read_gauge("goodput_hfu",
-                               flops_source="cost_analysis",
-                               peak_source=src)
+                               flops_source="cost_analysis")
     assert mfu is not None and math.isclose(mfu, model_f / denom,
                                             rel_tol=1e-9)
     assert hfu is not None and math.isclose(hfu, hw_f / denom,
                                             rel_tol=1e-9)
-    # CPU runs have no device-table entry — the peak must be honestly
-    # labelled nominal, never silently pretending to be a TPU
-    import jax
-    if jax.devices()[0].platform == "cpu":
-        assert src == "nominal"
 
 
 def test_tokens_per_sec_per_chip_gauge():

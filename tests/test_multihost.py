@@ -37,7 +37,7 @@ WORKER = textwrap.dedent("""
 
     # global allreduce across hosts through a psum on the global mesh
     import jax.numpy as jnp
-    from mxnet_tpu.base import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()).reshape(4), ("dp",))
     def f(x):

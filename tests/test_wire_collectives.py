@@ -23,7 +23,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import optimizer as opt_mod
 from mxnet_tpu import telemetry as _tm
 from mxnet_tpu import tracing
-from mxnet_tpu.base import shard_map
+from jax import shard_map
 from mxnet_tpu.gluon.loss import L2Loss
 from mxnet_tpu.gluon.parameter import Parameter
 from mxnet_tpu.ndarray import NDArray
@@ -84,7 +84,7 @@ def test_quantized_all_gather_exact_self():
         return quantized_all_gather(v, "dp", "int8", DEFAULT_BLOCK)
 
     out = jax.jit(shard_map(body, mesh=mesh, in_specs=P("dp"),
-                            out_specs=P("dp"), check_rep=False))(
+                            out_specs=P("dp"), check_vma=False))(
         jax.device_put(full, jax.sharding.NamedSharding(mesh, P("dp"))))
     # out is (n*n*256,) stacked per-device gathers; device i's copy of
     # slice i must be bitwise the original
@@ -118,7 +118,7 @@ def test_error_feedback_round_trip_stable():
 
     f = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("dp"), P("dp")),
                           out_specs=(P("dp"), P("dp")),
-                          check_rep=False))
+                          check_vma=False))
     ref = np.asarray(full).reshape(n, 256)
     outs = []
     for _ in range(3):
@@ -308,14 +308,14 @@ def test_wire_dtypes_in_lowered_collectives():
         lambda v: quantized_ppermute(v, "dp", perm, "fp8",
                                      DEFAULT_BLOCK),
         mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
-        check_rep=False))
+        check_vma=False))
     txt = f.lower(x).as_text()
     assert any("collective_permute" in ln and "f8E4M3FN" in ln
                for ln in txt.splitlines()), txt[:2000]
     g = jax.jit(shard_map(
         lambda v: quantized_all_gather(v, "dp", "int8", DEFAULT_BLOCK),
         mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
-        check_rep=False))
+        check_vma=False))
     txt = g.lower(x).as_text()
     assert any("all_gather" in ln and "xi8>" in ln
                for ln in txt.splitlines()), txt[:2000]
