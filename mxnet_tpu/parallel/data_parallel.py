@@ -2018,6 +2018,12 @@ class FusedTrainStep:
 
     # -- execution ------------------------------------------------------------
     def __call__(self, *args) -> NDArray:
+        # in a profiler trace: one `mx.train_step` a call, holding the
+        # `mx.data` phase and `mx.train_dispatch` (the compiled call)
+        with _tm.span("train_step"):
+            return self._step(args)
+
+    def _step(self, args) -> NDArray:
         if self._params is None:
             self._init_state(args)
         if self._compiled is None:
@@ -2071,8 +2077,9 @@ class FusedTrainStep:
         # cache grew during the call = this call traced and compiled
         n_exe = self._compiled._cache_size()
         t_call = _time.perf_counter()
-        with use_mesh(self.mesh if self.mesh is not None
-                      else current_mesh()):
+        with _tm.span("train_dispatch"), \
+                use_mesh(self.mesh if self.mesh is not None
+                         else current_mesh()):
             if self._pp_mask is not None:
                 cargs = (self._tr, self._pp_mask, self._states, hyper,
                          key)
@@ -2408,7 +2415,6 @@ class FusedTrainStep:
             self._init_state(batches[0])
         if self._compiled is None:
             self._build(batches[0])
-        opt = self.optimizer
         trainer = self._trainer
         scaler = getattr(trainer, "_amp_scaler", None) \
             if trainer is not None else None
@@ -2431,7 +2437,16 @@ class FusedTrainStep:
                 self._loop_warned = True
             losses = [self(*b)._data for b in batches]
             return NDArray(jnp.stack(losses))
+        # one `mx.train_step` a dispatched window of `k` steps,
+        # holding `mx.data` and `mx.train_dispatch`
+        with _tm.span("train_step"):
+            return self._run_window(batches, k, next_batches, unroll,
+                                    scaler, sanitizer, amp_on, skip_on)
 
+    def _run_window(self, batches, k, next_batches, unroll, scaler,
+                    sanitizer, amp_on, skip_on) -> NDArray:
+        """The scan path of `run_steps`: K steps in one dispatch."""
+        opt = self.optimizer
         # double-buffer feed: if the previous dispatch staged THIS
         # window (run_steps(..., next_batches=window)) while the device
         # was busy, consume the device-resident copy instead of paying
@@ -2518,8 +2533,9 @@ class FusedTrainStep:
                 _fl.record("collective", "fused.ppermute",
                            key="__activations__", store="fused",
                            bytes=int(self._wire_permuted[1]) * k)
-        with use_mesh(self.mesh if self.mesh is not None
-                      else current_mesh()):
+        with _tm.span("train_dispatch"), \
+                use_mesh(self.mesh if self.mesh is not None
+                         else current_mesh()):
             (losses, gnorms, skips, self._tr, aux_out, self._states,
              resid_out, carry_out) = entry["fn"](
                 self._tr, aux_in, self._states, resid_in, hyper0,

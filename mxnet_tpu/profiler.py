@@ -112,7 +112,12 @@ class Timer:
 
 
 def start_device_trace(logdir="/tmp/jax-trace"):
-    _tm.note_device_trace(logdir)  # export_chrome_trace merges it later
+    """Open a `jax.profiler` session writing `.xplane.pb` under
+    `logdir`. The program's own spans (`telemetry.phase` / `span`,
+    named `mx.*`) are TraceAnnotations, so that one file holds host
+    spans and device operations on one clock; read it with
+    `jax.profiler.ProfileData`. Nothing is merged into
+    `telemetry.export_chrome_trace`, which stays on the host clock."""
     jax.profiler.start_trace(logdir)
 
 

@@ -75,6 +75,8 @@ class Program:
             self.compiles += 1
             return fn(*args)
 
+        # the executable's name in a profiler trace: jit_counted_<name>
+        counted.__name__ = counted.__qualname__ = "counted_" + name
         self._jit = jax.jit(counted, donate_argnums=donate_argnums)
 
     def __call__(self, *args):

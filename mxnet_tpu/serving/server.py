@@ -743,7 +743,8 @@ class InferenceServer:
         ids[0, :T] = req.prompt
         bt_row = jnp.asarray(self.cache.block_tables[slot])
         t_pf = time.perf_counter()
-        with telemetry.phase("serve_prefill"):
+        with telemetry.phase("serve_prefill", tokens=T,
+                             padded=self.max_prompt_len):
             self.cache.pages, last = self.programs["prefill"](
                 self._params, self.cache.pages, bt_row,
                 jnp.asarray(ids), jnp.asarray([T], jnp.int32),
@@ -951,7 +952,7 @@ class InferenceServer:
         ids[0, :n] = req.prompt[start:start + n]
         bt_row = jnp.asarray(self.cache.block_tables[slot])
         t_pf = time.perf_counter()
-        with telemetry.phase("serve_prefill"):
+        with telemetry.phase("serve_prefill", tokens=n, padded=C):
             self.cache.pages, last = self.programs["prefill_chunk"](
                 self._params, self.cache.pages, bt_row,
                 jnp.asarray(ids), jnp.asarray([start], jnp.int32),
@@ -1153,7 +1154,17 @@ class InferenceServer:
         """Admit + one decode tick + evict. Returns tokens emitted
         (on ticks that only ran prefill chunks, the chunk tokens
         processed — drive loops must see prefill-only ticks as
-        progress, not idleness)."""
+        progress, not idleness).
+
+        In a profiler trace one call is one `mx.serve_tick` span,
+        early returns included, holding `mx.serve_admit` (with one
+        `mx.serve_prefill` a prompt), `mx.serve_blocks`,
+        `mx.serve_decode` (exactly `mx.serve_dispatch` then
+        `mx.serve_wait`) and `mx.serve_emit`."""
+        with telemetry.span("serve_tick"):
+            return self._tick()
+
+    def _tick(self) -> int:
         t_tick = time.perf_counter()
         done0 = len(self.finished)
         self._expire_deadlines()
@@ -1173,39 +1184,56 @@ class InferenceServer:
             self._note_progress(admitted + prefilled, done0)
             self._update_gauges()
             return prefilled
-        self._ensure_blocks()
         drafts = dlens = None
-        if self._spec is not None:
-            drafts, dlens = self._propose_drafts()
+        with telemetry.span("serve_blocks"):
+            self._ensure_blocks()
+            if self._spec is not None:
+                drafts, dlens = self._propose_drafts()
         with telemetry.phase("serve_decode"):
-            if drafts is not None:
-                (self.cache.pages, wtok, n_acc, self._last_logits,
-                 self._keys) = self.programs["verify"](
-                    self._params, self.cache.pages,
-                    jnp.asarray(self.cache.block_tables),
-                    jnp.asarray(self._pos), self._last_logits,
-                    self._keys, jnp.asarray(self._temps),
-                    jnp.asarray(self._top_ks),
-                    jnp.asarray(self._top_ps),
-                    jnp.asarray(self._active), jnp.asarray(drafts),
-                    jnp.asarray(dlens),
-                    *self._lora_args(self._adapter_ids))
-                wtok_np = np.asarray(wtok)   # (B, k+1) host sync
-                n_acc_np = np.asarray(n_acc)
-            else:
-                (self.cache.pages, tok, self._last_logits,
-                 self._keys) = self.programs["decode"](
-                    self._params, self.cache.pages,
-                    jnp.asarray(self.cache.block_tables),
-                    jnp.asarray(self._pos), self._last_logits,
-                    self._keys, jnp.asarray(self._temps),
-                    jnp.asarray(self._top_ks),
-                    jnp.asarray(self._top_ps),
-                    jnp.asarray(self._active),
-                    *self._lora_args(self._adapter_ids))
+            # exactly two spans: the uploads + the launch, then the
+            # host blocked on the device
+            with telemetry.span("serve_dispatch",
+                                active=int(self._active.sum())):
+                if drafts is not None:
+                    (self.cache.pages, wtok, n_acc, self._last_logits,
+                     self._keys) = self.programs["verify"](
+                        self._params, self.cache.pages,
+                        jnp.asarray(self.cache.block_tables),
+                        jnp.asarray(self._pos), self._last_logits,
+                        self._keys, jnp.asarray(self._temps),
+                        jnp.asarray(self._top_ks),
+                        jnp.asarray(self._top_ps),
+                        jnp.asarray(self._active), jnp.asarray(drafts),
+                        jnp.asarray(dlens),
+                        *self._lora_args(self._adapter_ids))
+                else:
+                    (self.cache.pages, tok, self._last_logits,
+                     self._keys) = self.programs["decode"](
+                        self._params, self.cache.pages,
+                        jnp.asarray(self.cache.block_tables),
+                        jnp.asarray(self._pos), self._last_logits,
+                        self._keys, jnp.asarray(self._temps),
+                        jnp.asarray(self._top_ks),
+                        jnp.asarray(self._top_ps),
+                        jnp.asarray(self._active),
+                        *self._lora_args(self._adapter_ids))
+            with telemetry.span("serve_wait"):
                 # host sync = honest tick time
-                wtok_np = np.asarray(tok).reshape(-1, 1)
-                n_acc_np = np.zeros(self.batch_slots, np.int32)
+                if drafts is not None:
+                    wtok_np = np.asarray(wtok)   # (B, k+1)
+                    n_acc_np = np.asarray(n_acc)
+                else:
+                    wtok_np = np.asarray(tok).reshape(-1, 1)
+                    n_acc_np = np.zeros(self.batch_slots, np.int32)
+        with telemetry.span("serve_emit"):
+            return self._emit(wtok_np, n_acc_np, dlens, t_tick,
+                              admitted, done0)
+
+    def _emit(self, wtok_np, n_acc_np, dlens, t_tick: float,
+              admitted: int, done0: int) -> int:
+        """The tick after the sync: hand each slot its tokens, finish
+        and evict, feed the forecaster, the watchdog and the gauges.
+        The device has nothing queued while this runs."""
         now = time.perf_counter()
         emitted = 0
         net_new = 0

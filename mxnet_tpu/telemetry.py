@@ -22,14 +22,21 @@ they all publish into ONE registry:
   KVStore, the DataLoader and the multi-tensor updater all mark their
   phases; `step_done(samples)` feeds a rolling `samples_per_sec`
   speedometer.
+- Spans on the profiler's clock: every `phase(name)` and every
+  `span(name, **counts)` is a `jax.profiler.TraceAnnotation` named
+  `mx.<name>`, whether telemetry is enabled or not. While a
+  `jax.profiler` session is open they land in its `.xplane.pb` on the
+  same clock as the device's operations, with the counts as the
+  event's stats; with no session open one costs well under a
+  microsecond. An open profiler session is their only switch.
 - `snapshot()` merges the registry with the pull-based providers:
   `profiler.resident_bytes()`, `kernels.dispatch.fallback_counts()`,
   and `tracing.cache_stats()` (compile counts + seconds, per block).
 - Exposition: `to_prometheus()` (text format), `dump_json(path)`,
   `breakdown_table()` (human table), and `export_chrome_trace(path)` —
-  one chrome://tracing-loadable JSON merging host phase events, host
-  profiler scopes, and any `jax.profiler` device-trace session that
-  `profiler.start_device_trace` registered.
+  one chrome://tracing-loadable JSON merging host phase events and
+  host profiler scopes (host clock). Host AND device on one clock is
+  the profiler's own trace: the `mx.*` spans above are in it.
 
 Cost contract: the WHOLE layer is disabled by default and near-zero
 cost while disabled — every instrumented hot path checks the single
@@ -39,7 +46,6 @@ Enable with `telemetry.enable()` or MXNET_TPU_TELEMETRY=1.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import math
 import os
@@ -50,6 +56,8 @@ import weakref
 from collections import OrderedDict, deque
 from typing import Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 from . import flight as _flight
 
 __all__ = ["enable", "disable", "enabled", "reset",
@@ -57,9 +65,9 @@ __all__ = ["enable", "disable", "enabled", "reset",
            "counter", "gauge", "histogram",
            "inc", "set_gauge", "observe",
            "read_gauge", "remove_series",
-           "phase", "mark_phase", "step_done",
+           "phase", "span", "mark_phase", "step_done",
            "snapshot", "to_prometheus", "dump_json", "breakdown_table",
-           "export_chrome_trace", "note_device_trace",
+           "export_chrome_trace",
            "start_metrics_server", "stop_metrics_server",
            "maybe_start_metrics_server",
            "register_health_source", "unregister_health_source", "health",
@@ -100,17 +108,12 @@ _REGISTRY: "OrderedDict[str, _Family]" = OrderedDict()
 _TRACE_CAP = 200_000
 _TRACE_EVENTS: deque = deque(maxlen=_TRACE_CAP)
 
-#: jax.profiler device-trace logdirs registered by
-#: profiler.start_device_trace (merged by export_chrome_trace)
-_DEVICE_TRACE_DIRS: List[str] = []
-
 #: rolling speedometer window: (perf_counter at step end, samples)
 _SPEED_WINDOW: deque = deque(maxlen=64)
 
 #: chrome pid layout: host phases / profiler scopes on pid 0, device
-#: spans (sync-measured or parsed jax traces) on pid >= 1; serving
-#: per-request span timelines get their own far-away pid so they can
-#: never collide with parsed device traces
+#: spans (sync-measured) on pid 1; serving per-request span timelines
+#: get their own far-away pid
 HOST_PID = 0
 DEVICE_PID = 1
 REQUEST_PID = 9000
@@ -163,7 +166,7 @@ def enabled() -> bool:
 
 def reset():
     """Clear every metric, trace event, and the speedometer window.
-    Keeps the enabled/disabled state and registered device-trace dirs."""
+    Keeps the enabled/disabled state."""
     with _lock:
         _REGISTRY.clear()
         _TRACE_EVENTS.clear()
@@ -409,19 +412,49 @@ def mark_phase(name: str, seconds: float, t0: Optional[float] = None,
         "tid": threading.get_ident() % 1_000_000})
 
 
-@contextlib.contextmanager
-def phase(name: str, device: bool = False):
-    """Lightweight phase mark: times the body and resolves it into the
-    step_time_breakdown histogram family + a chrome host event. No-op
-    (and no timestamping) while telemetry is disabled."""
-    if not _ENABLED:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        mark_phase(name, time.perf_counter() - t0, t0=t0, device=device)
+#: every span the program writes into a profiler trace starts so
+SPAN_PREFIX = "mx."
+
+
+def span(name: str, **counts):
+    """A span on the profiler's clock and nothing else:
+    `jax.profiler.TraceAnnotation("mx." + name, **counts)`. While a
+    `jax.profiler` session is open it lands in the `.xplane.pb` beside
+    the device's operations, the counts as the event's stats; with no
+    session open it costs well under a microsecond. No flag, no host
+    timestamp, no registry. For structure that has no histogram: a
+    span nested in a `phase` must be a `span`, or the goodput ledger
+    would count its seconds twice."""
+    return _Annotation(SPAN_PREFIX + name, **counts)
+
+
+class phase:
+    """Phase mark. Always a `span` of the same name (`mx.<name>`, see
+    there); while telemetry is enabled it also times the body on the
+    host clock and resolves it into the step_time_breakdown histogram
+    family + a chrome host event (`mark_phase`). Disabled, it stamps
+    no time and touches no registry."""
+
+    __slots__ = ("_name", "_device", "_ann", "_t0")
+
+    def __init__(self, name: str, device: bool = False, **counts):
+        self._name = name
+        self._device = device
+        self._ann = _Annotation(SPAN_PREFIX + name, **counts)
+        self._t0 = None
+
+    def __enter__(self):
+        self._ann.__enter__()
+        if _ENABLED:
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        if self._t0 is not None:
+            mark_phase(self._name, time.perf_counter() - self._t0,
+                       t0=self._t0, device=self._device)
+        return False
 
 
 def record_pipeline_step(num_stages: int, num_microbatches: int,
@@ -1107,46 +1140,6 @@ def breakdown_table() -> str:
 
 # -- chrome-trace export ----------------------------------------------------
 
-def note_device_trace(logdir: str):
-    """Register a jax.profiler trace session's logdir so
-    export_chrome_trace can merge its device events. Called by
-    profiler.start_device_trace; recorded even while telemetry is
-    disabled (the export decision happens later)."""
-    if logdir not in _DEVICE_TRACE_DIRS:
-        _DEVICE_TRACE_DIRS.append(logdir)
-
-
-def _device_trace_events() -> List[dict]:
-    """Parse chrome-format trace files a jax.profiler session left
-    under the registered logdirs (TensorBoard layout writes
-    `*.trace.json.gz`; xplane-only dumps yield nothing here — the
-    sync-measured device spans on DEVICE_PID still cover those runs).
-    Device pids are offset by DEVICE_PID + 1 so they can never collide
-    with the host pid."""
-    import glob
-    import gzip
-    events: List[dict] = []
-    for d in _DEVICE_TRACE_DIRS:
-        paths = []
-        for pat in ("**/*.trace.json.gz", "**/*.trace.json"):
-            paths.extend(glob.glob(os.path.join(d, pat), recursive=True))
-        for p in sorted(set(paths)):
-            try:
-                if p.endswith(".gz"):
-                    with gzip.open(p, "rt") as f:
-                        blob = json.load(f)
-                else:
-                    with open(p) as f:
-                        blob = json.load(f)
-            except Exception:
-                continue
-            for ev in blob.get("traceEvents", []):
-                ev = dict(ev)
-                ev["pid"] = DEVICE_PID + 1 + int(ev.get("pid", 0))
-                events.append(ev)
-    return events
-
-
 def _request_trace_events() -> List[dict]:
     """Convert every registered source's per-request span timelines
     into chrome events on REQUEST_PID: one tid per request, timed
@@ -1277,12 +1270,17 @@ def export_chrome_trace(path: str) -> str:
     - host phase events recorded by `phase`/`mark_phase` (pid 0),
     - host `profiler.scope` spans (pid 0),
     - device spans: sync-measured executable spans (pid 1, recorded by
-      FusedTrainStep with `device=True`) and any chrome-format trace a
-      registered `jax.profiler` session produced (pids >= 2),
+      FusedTrainStep with `device=True`),
     - per-request serving span timelines from registered
       InferenceServers (pid REQUEST_PID, one tid per request),
     - fleet-merged request timelines from registered FleetRouters
       (router spans on pid ROUTER_PID, one pid per replica).
+
+    Everything here is on the HOST clock. The device's own operations
+    are not merged in: the installed JAX's profiler writes `.xplane.pb`,
+    and since every phase is also a `TraceAnnotation` (`mx.<name>`)
+    that file already holds host spans and device operations on one
+    clock (`jax.profiler.ProfileData` reads it).
 
     Works with whatever has been recorded so far; events only exist
     for spans that ran while telemetry was enabled. The output is
@@ -1302,13 +1300,6 @@ def export_chrome_trace(path: str) -> str:
         pass
     events.extend(_request_trace_events())
     events.extend(_fleet_trace_events())
-    dev = _device_trace_events()
-    if dev:
-        pids = sorted({ev.get("pid") for ev in dev})
-        for pid in pids:
-            events.append({"ph": "M", "pid": pid, "name": "process_name",
-                           "args": {"name": "device: jax.profiler trace"}})
-        events.extend(dev)
     events = _normalize_trace_events(events)
     with open(path, "w") as f:
         json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f,

@@ -1,0 +1,16 @@
+"""Reader ``span_count_shortfall``: over the program's spans called
+``span`` that carry both counts, the share of the summed count ``of``
+that the summed count ``count`` leaves unfilled.
+Spec: ``{"span": name, "count": key, "of": key, "scale": factor}``."""
+from perfbench import mxspans
+
+
+def read(spec, ctx):
+    part, whole = spec["count"], spec["of"]
+    both = [s.counts for s in mxspans.of(ctx).named(spec["span"])
+            if part in s.counts and whole in s.counts]
+    total = sum(c[whole] for c in both)
+    if not total:
+        return None
+    return (1.0 - sum(c[part] for c in both) / total) \
+        * spec.get("scale", 1.0)

@@ -1,0 +1,355 @@
+"""The program's own spans on the profiler's clock: every
+`telemetry.phase` / `telemetry.span` is a `jax.profiler.TraceAnnotation`
+named `mx.<name>`, written into the `.xplane.pb` of whatever profiler
+session is open (the CPU backend's too), its counts as the event's
+stats. Checks the span tables of the serve tick and the train step,
+the off path (no session, telemetry disabled) and the enabled path
+(histogram, goodput hook, chrome event unchanged)."""
+import glob
+import os
+import time
+
+import numpy as np
+import pytest
+
+import jax
+
+import mxnet_tpu as mx
+from mxnet_tpu import faults, telemetry as tm
+from mxnet_tpu.parallel.data_parallel import FusedTrainStep
+from mxnet_tpu.serving import InferenceServer
+from mxnet_tpu.serving import executables as exe
+
+
+@pytest.fixture(autouse=True)
+def _telemetry_off():
+    tm.disable()
+    tm.reset()
+    yield
+    tm.disable()
+    tm.reset()
+
+
+class Span:
+    def __init__(self, ev):
+        self.name = ev.name
+        self.start = int(ev.start_ns)
+        self.end = int(ev.start_ns) + int(ev.duration_ns)
+        self.stats = dict(ev.stats)
+
+    def holds(self, other):
+        return other is not self and self.start <= other.start \
+            and other.end <= self.end
+
+
+def record(body, logdir):
+    """Run `body()` under a profiler session; the `mx.*` events of the
+    calling thread's line, in start order."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(logdir), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(
+        str(logdir), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    data = jax.profiler.ProfileData.from_file(path)
+    spans = []
+    for plane in data.planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            mine = [Span(e) for e in line.events
+                    if e.name.startswith("mx.")]
+            if mine:
+                assert not spans, "mx.* spans on two host threads"
+                spans = mine
+    return sorted(spans, key=lambda s: (s.start, -s.end))
+
+
+def named(spans, name):
+    return [s for s in spans if s.name == name]
+
+
+def children(spans, parent):
+    """Spans directly inside `parent` (no other span between)."""
+    inside = [s for s in spans if parent.holds(s)]
+    return [s for s in inside
+            if not any(o.holds(s) for o in inside)]
+
+
+# -- the serve tick ----------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def net():
+    mx.random.seed(0)
+    n = mx.models.get_model("llama_tiny")
+    n.initialize()
+    n(mx.nd.array(np.zeros((1, 4)), dtype="int32"))  # materialize
+    return n
+
+
+PROMPTS = (5, 3, 7)
+
+
+@pytest.fixture(scope="module")
+def serve_trace(net, tmp_path_factory):
+    """Three requests through a two-slot server: the third waits in
+    the queue for a slot. Also keeps what the server itself counted."""
+    rs = np.random.RandomState(7)
+    server = InferenceServer(net, batch_slots=2, max_len=32,
+                             block_size=8, max_prompt_len=8)
+    reqs = []
+    seen = []   # (queued, active) at the entry of each tick
+
+    def body():
+        for n in PROMPTS:
+            reqs.append(server.submit(
+                rs.randint(0, 256, n).astype(np.int32),
+                max_new_tokens=4))
+        while server.queue or server._active.any():
+            queued, active = len(server.queue), int(server._active.sum())
+            server.step()
+            seen.append((queued, active))
+        server.step()               # idle: nothing queued, none active
+
+    spans = record(body, tmp_path_factory.mktemp("serve"))
+    assert all(r.status == "ok" for r in reqs)
+    return spans, reqs, seen
+
+
+def test_serve_tick_holds_every_span_of_the_table(serve_trace):
+    spans, _, seen = serve_trace
+    ticks = named(spans, "mx.serve_tick")
+    assert len(ticks) == len(seen) + 1
+    busy = [t for t in ticks if named(children(spans, t),
+                                      "mx.serve_decode")]
+    assert len(busy) == len(seen)
+    for t in busy:
+        assert [s.name for s in children(spans, t)] == [
+            "mx.serve_admit", "mx.serve_blocks", "mx.serve_decode",
+            "mx.serve_emit"]
+    # every span of the tick's table lies inside some tick
+    for s in spans:
+        if s.name.startswith("mx.serve_") and s.name != "mx.serve_tick":
+            assert any(t.holds(s) for t in ticks), s.name
+
+
+def test_serve_prefill_is_inside_admit_with_its_counts(serve_trace):
+    spans, reqs, _ = serve_trace
+    prefills = named(spans, "mx.serve_prefill")
+    assert len(prefills) == len(PROMPTS)
+    admits = named(spans, "mx.serve_admit")
+    for p in prefills:
+        assert sum(a.holds(p) for a in admits) == 1
+    # the first admit takes two prompts into the two slots
+    assert len(named(children(spans, admits[0]),
+                     "mx.serve_prefill")) == 2
+    # admitted in the order submitted; no count is given that no
+    # metric reads (PERF.md section 3 names the reader of each)
+    assert [p.stats for p in prefills] == [
+        {"tokens": n, "padded": 8} for n in PROMPTS]
+
+
+def test_dispatch_and_wait_tile_decode(serve_trace):
+    spans, _, _ = serve_trace
+    decodes = named(spans, "mx.serve_decode")
+    assert decodes
+    for d in decodes:
+        kids = children(spans, d)
+        assert [k.name for k in kids] == ["mx.serve_dispatch",
+                                          "mx.serve_wait"]
+        disp, wait = kids
+        assert disp.end <= wait.start
+        # nothing of the program's runs between them: the two cover
+        # the phase but for the annotations' own entry and exit
+        covered = (disp.end - disp.start) + (wait.end - wait.start)
+        assert covered >= 0.5 * (d.end - d.start)
+
+
+def test_dispatch_counts_the_slots_the_server_batched(serve_trace):
+    spans, _, seen = serve_trace
+    # the tick itself carries no count
+    assert all(t.stats == {} for t in named(spans, "mx.serve_tick"))
+    # the dispatch counts the slots batched AFTER this tick's admits:
+    # two from the first tick on, one once only the third request runs
+    disp = [s.stats["active"] for s in named(spans, "mx.serve_dispatch")]
+    assert len(disp) == len(seen)
+    assert disp[0] == 2 and disp[-1] == 1
+    assert all(1 <= a <= 2 for a in disp)
+    # and it is the active count the NEXT tick opens with, unless a
+    # request finished in between
+    for a, (_, active) in zip(disp, seen[1:]):
+        assert active <= a
+
+
+def test_early_return_ticks_close_serve_tick(net, tmp_path):
+    server = InferenceServer(net, batch_slots=2, max_len=32,
+                             block_size=8, max_prompt_len=8,
+                             watchdog_ticks=50)
+    server.submit(np.arange(4, dtype=np.int32), max_new_tokens=2)
+
+    def body():
+        faults.inject("serving.stall")
+        try:
+            server.step()           # injected dead tick
+            server.step()
+        finally:
+            faults.clear()
+        server.run()
+        server.step()               # nothing queued, nothing active
+
+    spans = record(body, tmp_path)
+    ticks = named(spans, "mx.serve_tick")
+    kids = [[s.name for s in children(spans, t)] for t in ticks]
+    assert kids[0] == [] and kids[1] == []          # stalled
+    assert kids[-1] == ["mx.serve_admit"]           # idle
+    assert "mx.serve_decode" in kids[2]
+    # every tick closed: none holds another
+    assert not any(a.holds(b) for a in ticks for b in ticks)
+
+
+def test_chunked_prefill_spans_carry_chunk_counts(net, tmp_path):
+    server = InferenceServer(net, batch_slots=2, max_len=32,
+                             block_size=8, max_prompt_len=16,
+                             prefill_chunk_tokens=4)
+    req = server.submit(np.arange(10, dtype=np.int32), max_new_tokens=2)
+    spans = record(server.run, tmp_path)
+    assert req.status == "ok"
+    chunks = named(spans, "mx.serve_prefill")
+    assert [c.stats["tokens"] for c in chunks] == [4, 4, 2]
+    assert {c.stats["padded"] for c in chunks} == {4}
+    ticks = named(spans, "mx.serve_tick")
+    admits = named(spans, "mx.serve_admit")
+    for c in chunks:
+        assert any(t.holds(c) for t in ticks)
+        assert not any(a.holds(c) for a in admits)
+
+
+def test_executables_are_named_in_the_trace():
+    prog = exe.Program("serving_decode", lambda x: x + 1)
+    assert prog(1.0) == 2.0
+    assert "jit_counted_serving_decode" in \
+        prog._jit.lower(1.0).as_text()
+    assert prog.name == "serving_decode"      # accounting key unchanged
+
+
+# -- the train step ----------------------------------------------------------
+
+def _fused_step():
+    net = mx.gluon.nn.Dense(8, in_units=4)
+    net.initialize()
+
+    def loss_fn(pred, label):
+        return ((pred - label) ** 2).mean()
+
+    opt = mx.optimizer.SGD(learning_rate=0.1)
+    return FusedTrainStep(net, loss_fn, opt, mesh=None)
+
+
+def test_train_step_spans(tmp_path):
+    step = _fused_step()
+    x, y = mx.nd.ones((4, 4)), mx.nd.ones((4, 8))
+    step(x, y)                          # build outside the session
+
+    def body():
+        for _ in range(3):
+            step(x, y)
+
+    spans = record(body, tmp_path)
+    steps = named(spans, "mx.train_step")
+    assert len(steps) == 3
+    for s in steps:
+        assert [k.name for k in children(spans, s)] == [
+            "mx.data", "mx.train_dispatch"]
+    # telemetry is off: nothing synced, nothing recorded on the host
+    # clock
+    assert not tm._TRACE_EVENTS and not tm._REGISTRY
+
+
+def test_run_steps_is_one_train_step_span_a_window(tmp_path):
+    step = _fused_step()
+    batch = (mx.nd.ones((4, 4)), mx.nd.ones((4, 8)))
+    step.run_steps([batch] * 3)         # build outside the session
+    spans = record(lambda: step.run_steps([batch] * 3), tmp_path)
+    steps = named(spans, "mx.train_step")
+    assert len(steps) == 1
+    assert [k.name for k in children(spans, steps[0])] == [
+        "mx.data", "mx.train_dispatch"]
+    # K=1 without skip or loss-scale law is a single dispatch: one
+    # span, from __call__, and none around it
+    spans = record(lambda: step.run_steps([batch]), tmp_path / "k1")
+    assert len(named(spans, "mx.train_step")) == 1
+
+
+# -- off and on --------------------------------------------------------------
+
+def test_off_path_stamps_no_time_and_touches_no_registry(monkeypatch):
+    def forbidden(*a, **k):
+        raise AssertionError("the disabled path must not come here")
+
+    monkeypatch.setattr(tm.time, "perf_counter", forbidden)
+    monkeypatch.setattr(tm, "histogram", forbidden)
+    monkeypatch.setattr(tm, "_family", forbidden)
+    with tm.span("serve_dispatch", active=2):
+        with tm.phase("serve_decode"):
+            with tm.phase("data", device=True, rows=4):
+                pass
+    assert not tm._TRACE_EVENTS and not tm._REGISTRY
+
+
+def test_a_span_with_no_session_open_is_cheap():
+    n = 20000
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with tm.span("serve_dispatch", active=3):
+                pass
+        best = min(best, (time.perf_counter() - t0) / n)
+    # 0.5 us here (PERF.md section 6 has the chip host's reading); the
+    # limit only catches a span that grew a clock read or a lock
+    assert best < 10e-6
+
+
+def test_enabled_phase_records_what_it_always_did(monkeypatch, tmp_path):
+    noted = []
+    monkeypatch.setattr(tm, "_goodput_note",
+                        lambda name, seconds, t0: noted.append(name))
+    tm.enable()
+
+    def body():
+        with tm.phase("data"):
+            with tm.span("inner"):          # a span feeds no ledger
+                pass
+        with tm.phase("fused_step", device=True):
+            pass
+
+    spans = record(body, tmp_path)
+    assert noted == ["data", "fused_step"]
+    bd = tm.snapshot()["step_time_breakdown"]
+    assert bd["data"]["count"] == 1 and bd["fused_step"]["count"] == 1
+    assert "inner" not in bd
+    events = {e["name"]: e["pid"] for e in tm._TRACE_EVENTS}
+    assert events == {"data": tm.HOST_PID, "fused_step": tm.DEVICE_PID}
+    # and the same phases are on the profiler's clock, names unchanged
+    assert [s.name for s in spans] == ["mx.data", "mx.inner",
+                                       "mx.fused_step"]
+
+
+def test_export_chrome_trace_holds_no_device_trace_pids(tmp_path):
+    """The exporter's device half is gone: host-clock pids only."""
+    tm.enable()
+    tm.mark_phase("forward", 0.001)
+    tm.mark_phase("fused_step", 0.002, device=True)
+    import json
+    blob = json.loads(open(tm.export_chrome_trace(
+        str(tmp_path / "t.json"))).read())
+    pids = {e["pid"] for e in blob["traceEvents"]}
+    # (a server of this module that is still alive adds its request
+    # lanes under REQUEST_PID)
+    assert {tm.HOST_PID, tm.DEVICE_PID} <= pids \
+        <= {tm.HOST_PID, tm.DEVICE_PID, tm.REQUEST_PID}
+    assert not hasattr(tm, "note_device_trace")

@@ -28,7 +28,6 @@ def _clean_telemetry():
     yield
     tm.disable()
     tm.reset()
-    tm._DEVICE_TRACE_DIRS.clear()
 
 
 # -- metric model ------------------------------------------------------------
@@ -305,22 +304,6 @@ def test_export_chrome_trace_host_and_device_pids(tmp_path):
     assert tm.DEVICE_PID in xpids   # sync-measured device span
     names = {e["name"] for e in evs if e.get("ph") == "X"}
     assert "fused_step" in names and "data" in names
-
-
-def test_export_merges_registered_device_trace_dir(tmp_path):
-    tm.enable()
-    tm.mark_phase("forward", 0.001)
-    d = tmp_path / "jaxtrace" / "plugins" / "profile" / "run1"
-    d.mkdir(parents=True)
-    (d / "host.trace.json").write_text(json.dumps({"traceEvents": [
-        {"name": "XlaModule", "ph": "X", "ts": 1, "dur": 2, "pid": 0,
-         "tid": 0}]}))
-    tm.note_device_trace(str(tmp_path / "jaxtrace"))
-    p = tmp_path / "merged.json"
-    tm.export_chrome_trace(str(p))
-    evs = json.loads(p.read_text())["traceEvents"]
-    xla = [e for e in evs if e.get("name") == "XlaModule"]
-    assert xla and xla[0]["pid"] >= tm.DEVICE_PID + 1
 
 
 def test_phase_events_per_step():
