@@ -342,17 +342,19 @@ def sweep_decode(on_tpu, interpret):
 
 def sweep_paged(on_tpu, interpret):
     """In-kernel paged decode vs the gather fallback across pool
-    block sizes. Winners set the paged kernel's VMEM gate
-    (flash_decode_paged.vmem_budget_bytes — raise only over cell sizes
-    the in-kernel path won at) and the block size serving caches
-    should prefer (preferred_block_size)."""
+    block sizes. The winner sets the block size serving caches should
+    prefer (preferred_block_size). The kernel's VMEM budget
+    (flash_decode_paged.vmem_budget_bytes) is how many pages one step
+    of its sweep holds and is not this sweep's to shrink: on a v5e the
+    kernel's time fell with every page up to it (PERF.md, PR 25)."""
     import jax
     import jax.numpy as jnp
 
     from mxnet_tpu.kernels import flash_decode as fd
 
     if on_tpu:
-        B, K, H, d, dtype = 8, 8, 16, 64, jnp.bfloat16
+        # head_dim 128: the compiled sweep needs whole 128-lane rows
+        B, K, H, d, dtype = 8, 8, 32, 128, jnp.bfloat16
         S = 2048
         cands = [16, 32, 64, 128]
         lo, hi = 4, 12
@@ -363,7 +365,7 @@ def sweep_paged(on_tpu, interpret):
         lo, hi = 1, 2
     scale = 1.0 / (d ** 0.5)
     rows_out = []
-    best = None          # (ms, block_size, cell_bytes) of the winner
+    best = None          # (ms, block_size) of the winner
     for bs in cands:
         if _remaining() < 25.0:
             break
@@ -377,7 +379,8 @@ def sweep_paged(on_tpu, interpret):
         vl = jnp.full((B,), S, jnp.int32)
         itemsize = jnp.dtype(dtype).itemsize
         row = {"block_size": bs,
-               "cell_bytes": 4 * bs * d * itemsize}
+               "pages_per_step": fd._paged_sweep_pages(
+                   kp.shape, itemsize, nb)}
 
         def timed_call(fun):
             f = jax.jit(fun)
@@ -408,17 +411,13 @@ def sweep_paged(on_tpu, interpret):
                     scale=scale)) * 1e3, 3)
             if row["inkernel_ms"] < row["gather_ms"] \
                     and (best is None or row["inkernel_ms"] < best[0]):
-                best = (row["inkernel_ms"], bs, row["cell_bytes"])
+                best = (row["inkernel_ms"], bs)
         except Exception as e:
             row["error"] = f"{type(e).__name__}"[:60]
         rows_out.append(row)
     win = None
     if on_tpu and best is not None:
-        # budget covers the winner's double-buffered working set with
-        # one power-of-two of headroom, capped under VMEM
-        win = {"preferred_block_size": best[1],
-               "vmem_budget_bytes": min(max(best[2] * 2, 1 << 20),
-                                        14 << 20)}
+        win = {"preferred_block_size": best[1]}
     return {"shape": [B, K, H, d, S], "rows": rows_out}, win
 
 

@@ -72,6 +72,9 @@ class Size:
     decode_len: int
     block: int
     window: int
+    #: the paged decode kernel once more at a table shape that serves:
+    #: (rows, pages a row, pool blocks, shortest and longest context)
+    serve_table: tuple
     # -- serve
     slots: int
     max_len: int
@@ -105,6 +108,8 @@ FULL = Size(
     batch=4, seq=2048, steps=5,
     bert_heads=12, bert_head_dim=64, bert_seq=512, ln_width=768,
     vocab2=30522, decode_len=4096, block=16, window=4,
+    # mistral_7b.reason (BENCHMARK.json): 20 slots of max_len 8448
+    serve_table=(20, 528, 5633, 1478, 6118),
     slots=8, max_len=2048, max_prompt=1024,
     waves=(((700, 4, 0.0), (24, 24, 0.0), (1000, 8, 0.8), (57, 32, 0.0),
             (311, 16, 0.7), (990, 12, 0.0), (128, 20, 0.9)),
@@ -470,6 +475,22 @@ def phase_kernels(size):
     kp, vp = to_pool(kc), to_pool(vc)
     run("flash_decode paged", fd.flash_decode_paged, paged_ref,
         (qd, kp, vp, bt, vl), ("flash_decode_paged",), (TOL_DECODE,))
+
+    # ... and at the table shape that serves, so that a first-light
+    # run compiles the kernel at that size: ragged rows, the pages
+    # past each row's length left at the sink, scattered over the pool
+    Bs, nbs, Ns, lo, hi = size.serve_table
+    rs = np.random.RandomState(3)
+    vls = rs.permutation(np.linspace(lo, hi, Bs).astype(np.int32))
+    free = iter(rs.permutation(Ns - 1) + 1)
+    bts = np.zeros((Bs, nbs), np.int32)
+    for row, n in enumerate(-(-vls // bs)):
+        bts[row, :n] = [next(free) for _ in range(n)]
+    run("flash_decode paged, serving table", fd.flash_decode_paged,
+        paged_ref, (randn((Bs, H, d)), randn((Ns, K, bs, d)),
+                    randn((Ns, K, bs, d)), jnp.asarray(bts),
+                    jnp.asarray(vls)),
+        ("flash_decode_paged",), (TOL_DECODE,))
 
     pools8 = [to_pool(c) for c in (k8, ks, v8, vs)]
     run("flash_decode paged int8", fd.flash_decode_paged_quantized,

@@ -176,13 +176,17 @@ def flash_decode(q, k_cache, v_cache, valid_len, scale=None,
 # fixed-size blocks shared by all sequences; a per-sequence block table
 # maps logical block index -> physical block id. Two read paths:
 #
-# - IN-KERNEL (the serving hot path): the block table rides in
-#   scalar-prefetch memory and the Pallas pipeline DMAs each logical
-#   block's k/v straight from the (N, K, bs, d) pool per
-#   (batch, kv-head, block) grid cell — the index map resolves
-#   `bt[b, i]` before the cell runs, so no contiguous (B, K, S, d)
-#   view is ever materialized and decode HBM bytes return to ≈ the
-#   contiguous flash-decode's (vLLM / tpu-inference recipe).
+# - IN-KERNEL (the serving hot path): the block table and the lengths
+#   ride in scalar-prefetch memory, the (N, K, bs, d) pools stay in
+#   HBM, and the kernel copies each sequence's pages itself, many a
+#   step and only those that hold a token (a page of all kv heads is
+#   one contiguous run), double-buffered under the arithmetic — so no
+#   contiguous (B, K, S, d) view is ever materialized and decode HBM
+#   bytes are the cache's own (the recipe of the paged_attention that
+#   ships with JAX). A page a grid cell, fetched by a BlockSpec whose
+#   index map is `bt[b, i]`, paid Pallas's fixed cost a step ~10^6
+#   times a tick and ran at 1.4-1.7% of the HBM roofline (PERF.md,
+#   PR 25); the int8 and the window twins below still do.
 # - GATHER (fallback): `gather_kv_pages` materializes the contiguous
 #   view with jnp.take, then the contiguous flash sweep runs on it.
 #   Correct everywhere (interpret off, odd shapes, use_flash=False)
@@ -204,29 +208,25 @@ def gather_kv_pages(pages, block_tables):
     return g.reshape((B, K, nb * bs) + g.shape[4:])
 
 
-def _paged_grid_spec(pl, pltpu, B, K, nb, rep, bs, d, quantized):
-    """Shared PrefetchScalarGridSpec for both paged kernels: the block
-    table (B, nb) and valid_len (B,) are scalar-prefetched, and the
-    pool specs' index maps resolve `bt[b, i] -> physical block` BEFORE
-    each grid cell runs — Pallas's pipeline emitter turns that into
-    the per-block HBM->VMEM DMA (double-buffered across cells), which
-    is the whole point: no gathered contiguous view exists anywhere."""
+def _paged_grid_spec(pl, pltpu, B, K, nb, rep, bs, d):
+    """PrefetchScalarGridSpec of the int8 twin's page-a-cell schedule
+    (the window twin builds the same by hand; the bf16 kernel left it
+    for _flash_decode_paged_pallas's sweep): the block table (B, nb)
+    and valid_len (B,) are scalar-prefetched, and the pool specs'
+    index maps resolve `bt[b, i] -> physical block` BEFORE each grid
+    cell runs — Pallas's pipeline emitter turns that into the
+    per-block HBM->VMEM DMA (double-buffered across cells). Correct,
+    and slow: one (bs, d) block of one kv head a grid step."""
     q_spec = pl.BlockSpec((None, None, rep, d),
                           lambda b, h, i, bt, vl: (b, h, 0, 0))
     pool_spec = pl.BlockSpec((None, None, bs, d),
                              lambda b, h, i, bt, vl: (bt[b, i], h, 0, 0))
-    if quantized:
-        scale_spec = pl.BlockSpec(
-            (None, None, bs, 1), lambda b, h, i, bt, vl: (bt[b, i], h,
-                                                          0, 0))
-        in_specs = [q_spec, pool_spec, scale_spec, pool_spec,
-                    scale_spec]
-    else:
-        in_specs = [q_spec, pool_spec, pool_spec]
+    scale_spec = pl.BlockSpec(
+        (None, None, bs, 1), lambda b, h, i, bt, vl: (bt[b, i], h, 0, 0))
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, K, nb),
-        in_specs=in_specs,
+        in_specs=[q_spec, pool_spec, scale_spec, pool_spec, scale_spec],
         out_specs=pl.BlockSpec((None, None, rep, d),
                                lambda b, h, i, bt, vl: (b, h, 0, 0)),
         scratch_shapes=[pltpu.VMEM((rep, 1), jnp.float32),   # m
@@ -235,24 +235,77 @@ def _paged_grid_spec(pl, pltpu, B, K, nb, rep, bs, d, quantized):
 
 
 def _paged_compiler_params(pltpu, interpret):
-    """(batch, kv-head) cells are independent; only the block sweep is
-    order-dependent (the online-softmax carry lives in scratch)."""
+    """Page-a-cell schedule (int8 and window twins): (batch, kv-head)
+    cells are independent; only the block sweep is order-dependent
+    (the online-softmax carry lives in scratch)."""
     if interpret:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))}
 
 
+def _paged_sweep_pages(pool_shape, itemsize, nb=None):
+    """Pages one step of the paged sweep moves: as many as the tuned
+    VMEM budget holds, from static shapes alone; 0 when not even one
+    fits, in which case the gate below says "gather".
+
+    A page is every kv head's (bs, d) block of k or of v — one
+    contiguous run of the (N, K, bs, d) pool, so ONE copy. The kernel
+    holds two steps of k and of v (one computing, the next landing)
+    and widens one head's k and v of a step to fp32 when the MXU
+    cannot take them as stored; beside them the q and o blocks the
+    pipeline double-buffers and the fp32 m / l / acc scratch, reckoned
+    for a group of up to 16 query heads a kv head (one bf16 tile of
+    rows). On a v5e the kernel's time falls with every page added up
+    to the budget (tuned.json has the sweep)."""
+    from . import tuning
+
+    _, K, bs, d = pool_shape
+    page = K * bs * d * itemsize
+    fixed = 4 * K * 16 * d * itemsize + 3 * K * 16 * max(d, 128) * 4
+    fit = (tuning.get("flash_decode_paged", "vmem_budget_bytes")
+           - fixed) // (4 * page + 2 * bs * d * 4)
+    if nb is not None:
+        fit = min(fit, nb)
+    lane = max(1, 128 // bs)     # whole 128-lane tiles of scores a step
+    return int(max(0, fit - fit % lane if fit > lane else fit))
+
+
 def _flash_decode_paged_pallas(q, k_pages, v_pages, block_tables,
                                valid_len, scale, interpret):
-    """In-kernel paged decode: grid (B, K, nb) where cell (b, h, i)
-    owns logical block i of sequence b for kv head h. The online
-    softmax (m, l, acc) carries across the innermost block sweep in
-    VMEM scratch — initialized at i == 0, normalized into o_ref at
-    i == nb - 1 (the same walk as _flash_decode_pallas's fori_loop,
-    unrolled onto the grid so each block can be DMA'd by table
-    lookup). valid_len masks the ragged tail AND every block the
-    table left pointing at the scratch sink 0."""
+    """In-kernel paged decode: grid (B,), one cell a sequence, which
+    sweeps that sequence's pages P at a time (_paged_sweep_pages) in a
+    loop as long as the sequence, not as the block table. The pools
+    stay in HBM; a step's P pages (all kv heads of a page are one
+    contiguous run: one copy for k, one for v) land in one half of a
+    VMEM scratch while the arithmetic runs on the other, and the last
+    step of a sequence starts the first step of the next, so the
+    copies never drain. Pages past valid_len are neither fetched nor
+    stepped; the ragged tail of the last step is masked (its v rows
+    are what an earlier step left there: finite, under an exact 0).
+
+    The online softmax (m, l, acc per kv head and query row) lives in
+    VMEM scratch across the steps, fp32 throughout. bf16 q, k and v
+    feed the MXU as stored (bf16 x bf16 accumulated in fp32 is exact)
+    and the scale multiplies the fp32 scores; the fp32 probabilities
+    go in as three bf16 terms that sum to them exactly, stacked on the
+    rows of ONE product with v — Mosaic's default fp32 x fp32 dot is a
+    single bf16 pass (2.5e-3 off on a v5e), which would round them."""
+    pages = _paged_sweep_pages(k_pages.shape, k_pages.dtype.itemsize,
+                               block_tables.shape[1])
+    return _paged_sweep(q, k_pages, v_pages, block_tables, valid_len,
+                        scale=float(scale), pages=pages,
+                        interpret=bool(interpret))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "pages", "interpret"))
+def _paged_sweep(q, k_pages, v_pages, block_tables, valid_len, *,
+                 scale, pages, interpret):
+    """_flash_decode_paged_pallas's kernel at `pages` a step. A jit of
+    its own, so that the layers of a decode program share one trace
+    and one Mosaic lowering of the body (its kv heads are unrolled:
+    traced a layer it cost a 16-layer program 2 s of every start)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -260,58 +313,137 @@ def _flash_decode_paged_pallas(q, k_pages, v_pages, block_tables,
     K, bs = k_pages.shape[1], k_pages.shape[2]
     nb = block_tables.shape[1]
     rep = H // K
+    P, T = pages, pages * bs
     qr = q.reshape(B, K, rep, d)
+    native = q.dtype == k_pages.dtype == jnp.bfloat16
+    nt = (((1,), (1,)), ((), ()))                  # (r, d) x (t, d)
 
-    def kernel(bt_ref, vl_ref, q_ref, k_ref, v_ref, o_ref,
-               m_ref, l_ref, acc_ref):
-        i = pl.program_id(2)
-        vl = vl_ref[pl.program_id(0)]
+    def kernel(bt_ref, vl_ref, q_ref, k_hbm, v_hbm, o_ref,
+               kbuf, vbuf, sem, slot_ref, m_ref, l_ref, acc_ref):
+        b = pl.program_id(0)
+        vl = vl_ref[b]
 
-        @pl.when(i == 0)
-        def _init():
-            m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+        def copy_step(row, i, slot, act):
+            """Start (or wait for) the pages of `row`'s step `i` that
+            hold a token, into (out of) half `slot`."""
+            def one(j, _):
+                page = bt_ref[row * nb + i * P + j]
+                act(pltpu.make_async_copy(
+                    k_hbm.at[page], kbuf.at[slot, j], sem.at[0, slot]))
+                act(pltpu.make_async_copy(
+                    v_hbm.at[page], vbuf.at[slot, j], sem.at[1, slot]))
+            held = (vl_ref[row] + bs - 1) // bs
+            jax.lax.fori_loop(0, jnp.minimum(P, held - i * P), one,
+                              None)
 
-        @pl.when(i * bs < vl)
-        def _block():
-            qblk = q_ref[...].astype(jnp.float32) * scale    # (rep, d)
-            kblk = k_ref[...].astype(jnp.float32)            # (bs, d)
-            vblk = v_ref[...].astype(jnp.float32)
-            s = qblk @ kblk.T                                # (rep, bs)
-            pos = i * bs + jax.lax.broadcasted_iota(
-                jnp.int32, (rep, bs), 1)
-            s = jnp.where(pos < vl, s, -jnp.inf)
-            m_prev = m_ref[...][:, 0]
-            l_prev = l_ref[...][:, 0]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-            p = jnp.exp(s - m_new[:, None])
-            # comparison instead of jnp.isfinite: Mosaic has no
-            # is_finite lowering (same trick as the contiguous sweep)
-            p = jnp.where((m_new > -jnp.inf)[:, None], p, 0.0)
-            corr = jnp.where(m_prev > -jnp.inf,
-                             jnp.exp(m_prev - m_new), 0.0)
-            m_ref[...] = m_new[:, None]
-            l_ref[...] = (corr * l_prev + jnp.sum(p, axis=-1))[:, None]
-            acc_ref[...] = corr[:, None] * acc_ref[...] + p @ vblk
+        def start(row, i, slot):
+            copy_step(row, i, slot, lambda c: c.start())
 
-        @pl.when(i == nb - 1)
-        def _finish():
-            l = l_ref[...][:, 0]
-            safe_l = jnp.where(l > 0, l, 1.0)
-            o_ref[...] = (acc_ref[...] / safe_l[:, None]) \
-                .astype(o_ref.dtype)
+        @pl.when(b == 0)
+        def _first():
+            # a v row no copy ever wrote must be finite under its 0
+            vbuf[...] = jnp.zeros_like(vbuf)
+            slot_ref[0] = 0
+            start(0, 0, 0)
 
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        # a sequence takes at least one step, so that the hand-over of
+        # the halves below never skips a row (valid_len 0: all masked)
+        n = jnp.maximum((vl + T - 1) // T, 1)
+        slot0 = slot_ref[0]
+
+        def step(i, _):
+            slot = jax.lax.rem(slot0 + i, 2)
+
+            @pl.when(i + 1 < n)
+            def _next():
+                start(b, i + 1, 1 - slot)
+
+            @pl.when(jnp.logical_and(i + 1 == n, b + 1 < B))
+            def _next_row():
+                start(b + 1, 0, 1 - slot)
+
+            copy_step(b, i, slot, lambda c: c.wait())
+            live = i * T + jax.lax.broadcasted_iota(
+                jnp.int32, (rep, T), 1) < vl
+            for h in range(K):
+                qh = q_ref[h]                                # (rep, d)
+                kh = kbuf[slot, :, h].reshape(T, d)
+                vh = vbuf[slot, :, h].reshape(T, d)
+                if native:
+                    s = jax.lax.dot_general(
+                        qh, kh, nt,
+                        preferred_element_type=jnp.float32) * scale
+                else:
+                    s = jax.lax.dot_general(
+                        qh.astype(jnp.float32) * scale,
+                        kh.astype(jnp.float32), nt,
+                        preferred_element_type=jnp.float32)
+                s = jnp.where(live, s, -jnp.inf)             # (rep, T)
+                m_prev = m_ref[h]                            # (rep, 1)
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(s, axis=-1, keepdims=True))
+                # `live`, a comparison, instead of jnp.isfinite:
+                # Mosaic has no is_finite lowering
+                p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+                corr = jnp.where(m_prev > -jnp.inf,
+                                 jnp.exp(m_prev - m_new), 0.0)
+                m_ref[h] = m_new
+                l_ref[h] = corr * l_ref[h] \
+                    + jnp.sum(p, axis=-1, keepdims=True)
+                if native:
+                    hi = p.astype(jnp.bfloat16)
+                    rest = p - hi.astype(jnp.float32)
+                    mid = rest.astype(jnp.bfloat16)
+                    lo = (rest - mid.astype(jnp.float32)) \
+                        .astype(jnp.bfloat16)
+                    pv = jnp.dot(
+                        jnp.concatenate([hi, mid, lo], axis=0), vh,
+                        preferred_element_type=jnp.float32)
+                    pv = pv[:rep] + pv[rep:2 * rep] + pv[2 * rep:]
+                else:
+                    pv = jnp.dot(p, vh.astype(jnp.float32),
+                                 preferred_element_type=jnp.float32)
+                acc_ref[h] = corr * acc_ref[h] + pv
+
+        jax.lax.fori_loop(0, n, step, None)
+        slot_ref[0] = jax.lax.rem(slot0 + n, 2)
+        l = l_ref[...]
+        o_ref[...] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)) \
+            .astype(o_ref.dtype)
+
+    row_spec = pl.BlockSpec((None, K, rep, d),
+                            lambda b, bt, vl: (b, 0, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[row_spec, pool_spec, pool_spec],
+        out_specs=row_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, P, K, bs, d), k_pages.dtype),
+            pltpu.VMEM((2, P, K, bs, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),         # (k | v, half)
+            pltpu.SMEM((1,), jnp.int32),             # half of step 0
+            pltpu.VMEM((K, rep, 1), jnp.float32),    # m
+            pltpu.VMEM((K, rep, 1), jnp.float32),    # l
+            pltpu.VMEM((K, rep, d), jnp.float32)])   # acc
+    # the sequences hand the scratch's halves and the copy in flight
+    # from one to the next: in order, on one core
+    params = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))}
     out = pl.pallas_call(
         kernel,
-        grid_spec=_paged_grid_spec(pl, pltpu, B, K, nb, rep, bs, d,
-                                   quantized=False),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, rep, d), q.dtype),
         interpret=interpret,
         name="flash_decode_paged",
-        **_paged_compiler_params(pltpu, interpret),
-    )(block_tables.astype(jnp.int32), valid_len.astype(jnp.int32),
-      qr, k_pages, v_pages)
+        **params,
+    )(block_tables.astype(jnp.int32).reshape(-1),
+      valid_len.astype(jnp.int32), qr, k_pages, v_pages)
     return out.reshape(B, H, d)
 
 
@@ -374,8 +506,7 @@ def _flash_decode_paged_pallas_q8(q, k8_pages, ks_pages, v8_pages,
 
     out = pl.pallas_call(
         kernel,
-        grid_spec=_paged_grid_spec(pl, pltpu, B, K, nb, rep, bs, d,
-                                   quantized=True),
+        grid_spec=_paged_grid_spec(pl, pltpu, B, K, nb, rep, bs, d),
         out_shape=jax.ShapeDtypeStruct((B, K, rep, d), q.dtype),
         interpret=interpret,
         name="flash_decode_paged_q8",
@@ -390,30 +521,39 @@ def paged_kernel_mode(pool_operand, quantized=False):
     the gather fallback". Shared by flash_decode_paged(_quantized) at
     trace time and by the serving layer's host-side probe (the
     `serving_gather_bytes_avoided_total` accounting must agree with
-    what the executable actually traced).
+    what the executable actually traced), so it answers from the
+    pool's static shape alone.
 
     Constraints: Mosaic wants the block's sublane dim (block_size) a
-    multiple of 8; the per-cell working set (double-buffered k+v
-    blocks + q + fp32 scratch) must fit the tuned VMEM budget
-    (kernels/tuning.py: flash_decode_paged.vmem_budget_bytes)."""
+    multiple of 8, and the working set must fit the tuned VMEM budget
+    (kernels/tuning.py: flash_decode_paged.vmem_budget_bytes): for the
+    bf16/fp32 sweep two steps of at least one page of all kv heads
+    (_paged_sweep_pages), for the int8 twin its page-a-cell blocks.
+    Compiled, the sweep also needs head_dim in whole 128-lane rows:
+    Mosaic cannot slice a page out of a pool of narrower ones."""
     N, K, bs, d = pool_operand.shape
     if bs % 8 != 0:
         return None
-    from . import tuning
+    if quantized:
+        from . import tuning
 
-    per_block = bs * d * pool_operand.dtype.itemsize \
-        + (bs * 4 if quantized else 0)
-    # 2 operands (k, v) x 2 pipeline buffers + q block + scratch
-    cell_bytes = 4 * per_block + 2 * d * 4 + (d + 2) * 4 * 8
-    if cell_bytes > tuning.get("flash_decode_paged",
-                               "vmem_budget_bytes"):
+        per_block = bs * d * pool_operand.dtype.itemsize + bs * 4
+        # 2 operands (k, v) x 2 pipeline buffers + q block + scratch
+        cell_bytes = 4 * per_block + 2 * d * 4 + (d + 2) * 4 * 8
+        if cell_bytes > tuning.get("flash_decode_paged",
+                                   "vmem_budget_bytes"):
+            return None
+    elif _paged_sweep_pages(pool_operand.shape,
+                            pool_operand.dtype.itemsize) < 1:
         return None
     if os.environ.get("MXNET_TPU_FLASH_INTERPRET", "0") == "1":
         return "interpret"
     if jax.default_backend() not in ("cpu",):
         from .dispatch import operand_on_cpu
 
-        return None if operand_on_cpu(pool_operand) else "compiled"
+        if operand_on_cpu(pool_operand) or (not quantized and d % 128):
+            return None
+        return "compiled"
     return None
 
 

@@ -13,8 +13,8 @@ backend is available and (with --write) commits the winners to
     DEFAULTS[family][key]
 
 The committed defaults below are hand-chosen values: they compile and
-give right answers on a v5e (PR 21), and none has been timed there
-(PERF.md tracks which).
+give right answers on a v5e (PR 21). Timed there so far: the paged
+decode's VMEM budget (PR 25); PERF.md tracks the rest.
 """
 from __future__ import annotations
 
@@ -33,9 +33,12 @@ DEFAULTS = {
                    "vmem_budget_bytes": 8 << 20},
     "fused_ce": {"row_block_want": 256},
     "flash_decode": {"vmem_cache_budget_bytes": 10 << 20},
-    # in-kernel paged decode: per-grid-cell working set ceiling (the
-    # pipeline double-buffers one (bs, d) k block + one v block per
-    # cell) and the pool block size the serving cache should prefer so
+    # in-kernel paged decode: what one step of the sweep may hold in
+    # VMEM — two steps of P pages of k and of v (a page is every kv
+    # head's (bs, d) block: 32 KB at 8 x 16 x 128 bf16, so P = 56 and
+    # 3.5 MB of pool a step), q / o / scratch beside them; the kernel
+    # takes as many pages as fit (measured on a v5e, PERF.md PR 25) —
+    # and the pool block size the serving cache should prefer so
     # blocks land on Mosaic's (8, 128) tiling
     "flash_decode_paged": {"vmem_budget_bytes": 8 << 20,
                            "preferred_block_size": 16},

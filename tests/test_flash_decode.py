@@ -239,6 +239,117 @@ def test_paged_inkernel_valid_len_edges(vl_val):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.fixture
+def pages_per_step():
+    """Hold the sweep to P pages a step through the tuning table's
+    VMEM budget (the one number the step chooser reads), so a small
+    pool takes many steps; the table is restored afterwards."""
+    from mxnet_tpu.kernels import flash_decode as fd, tuning
+
+    def hold(P, pool):
+        for budget in range(0, 64 << 20, 256):
+            tuning.set_runtime("flash_decode_paged",
+                               "vmem_budget_bytes", budget)
+            if fd._paged_sweep_pages(pool.shape,
+                                     pool.dtype.itemsize) == P:
+                return
+        raise AssertionError(f"no budget gives {P} pages a step")
+
+    yield hold
+    tuning.clear_runtime()
+
+
+# what the many-pages-a-step schedule can get wrong, each against the
+# reference on the GATHERED view: (P, data kwargs, valid lengths,
+# rows whose table is all sink). S=128 at bs=8 is nb=16 pages.
+_SWEEP_CASES = {
+    # nb=16 is not a multiple of P=3: five full steps and a ragged one
+    "nb_not_multiple_vl_1": (3, {}, [1], ()),
+    "page_edge": (3, {}, [8], ()),
+    "page_edge_plus_1": (3, {}, [9], ()),
+    "step_edge": (3, {}, [24], ()),
+    "step_edge_plus_1": (3, {}, [25], ()),
+    "last_full_step_edge": (3, {}, [120], ()),
+    "whole_table": (3, {}, [128], ()),
+    "one_page_a_step": (1, {}, [77], ()),
+    "table_in_one_step": (16, {}, [77], ()),
+    # sequences of 1, 2, 3 and 6 steps take turns on the two halves
+    "mixed_lengths": (3, {"B": 5}, [128, 1, 24, 61, 47], ()),
+    "inactive_rows": (3, {"B": 4}, [90, 1, 1, 33], (1, 2)),
+    "inactive_first_and_last": (2, {"B": 3}, [1, 100, 1], (0, 2)),
+    "gqa_32_8_128_bs16": (3, {"B": 3, "S": 160, "H": 32, "K": 8,
+                              "d": 128, "bs": 16}, [160, 49, 97], ()),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SWEEP_CASES))
+def test_paged_sweep_schedule(case, pages_per_step):
+    from mxnet_tpu.kernels.flash_decode import (
+        _flash_decode_paged_pallas, gather_kv_pages)
+    P, kw, vls, sink_rows = _SWEEP_CASES[case]
+    q, _, _, kp, vp, bt, vl = _paged_data(seed=23, vl=vls,
+                                          **{"B": 1, **kw})
+    for row in sink_rows:      # an idle slot: length 1, table all sink
+        bt = bt.at[row].set(0)
+    pages_per_step(P, kp)
+    out = _flash_decode_paged_pallas(q, kp, vp, bt, vl, 0.25,
+                                     interpret=True)
+    ref = reference_decode_attention(q, gather_kv_pages(kp, bt),
+                                     gather_kv_pages(vp, bt), vl, 0.25)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_paged_sweep_bf16_splits_probabilities_exactly(pages_per_step):
+    # bf16 q/k/v go to the MXU as stored and the fp32 probabilities as
+    # three bf16 terms: the result must sit as close to the fp32
+    # reference as the bf16 OUTPUT allows, not a bf16 product's 3e-2
+    from mxnet_tpu.kernels.flash_decode import (
+        _flash_decode_paged_pallas, gather_kv_pages)
+    q, _, _, kp, vp, bt, vl = _paged_data(B=3, seed=24,
+                                          vl=[128, 50, 9])
+    qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
+    pages_per_step(3, kb)
+    out = _flash_decode_paged_pallas(qb, kb, vb, bt, vl, 0.25,
+                                     interpret=True)
+    f32 = jnp.float32
+    ref = reference_decode_attention(
+        qb.astype(f32), gather_kv_pages(kb, bt).astype(f32),
+        gather_kv_pages(vb, bt).astype(f32), vl, 0.25)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref), rtol=8e-3, atol=8e-3)
+
+
+class _Pool:
+    """A pool's static face: all the step chooser and the gate read."""
+
+    def __init__(self, shape, dtype):
+        self.shape, self.dtype = shape, np.dtype(dtype)
+
+
+@pytest.mark.parametrize("B,nb,N", [(32, 160, 5121), (20, 528, 5633)])
+def test_paged_step_chooser_at_the_serving_cells(B, nb, N, monkeypatch):
+    # pure Python: mistral_7b.chat / .reason's table shapes, bf16
+    from mxnet_tpu.kernels import flash_decode as fd, tuning
+    K, bs, d = 8, 16, 128
+    pool = _Pool((N, K, bs, d), jnp.bfloat16)
+    P = fd._paged_sweep_pages(pool.shape, 2, nb)
+    page = K * bs * d * 2
+    assert 1 <= P <= nb
+    assert 2 * P * page >= 256 << 10            # k + v a step
+    assert (P * bs) % 128 == 0                  # whole lanes of scores
+    budget = tuning.get("flash_decode_paged", "vmem_budget_bytes")
+    # two steps of k and of v, one head widened, q / o / m / l / acc
+    assert 4 * P * page + 2 * P * bs * d * 4 \
+        + 4 * K * 16 * d * 2 + 3 * K * 16 * d * 4 <= budget
+    assert fd._paged_sweep_pages(pool.shape, 2, 5) == 5   # short table
+    monkeypatch.setenv("MXNET_TPU_FLASH_INTERPRET", "1")
+    assert fd.paged_kernel_mode(pool) == "interpret"      # not gather
+    assert fd.paged_kernel_mode(_Pool((N, K, 12, d),
+                                      jnp.bfloat16)) is None
+
+
 def test_paged_inkernel_quantized_matches_gather():
     # quantize the POOL (per-token scales, same axis the serving cache
     # uses) and demand the in-kernel int8 path agree with the gathered

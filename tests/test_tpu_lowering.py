@@ -78,6 +78,48 @@ def test_flash_decode_lowers():
         a, b, c, d, 0.0884, False), q, kc, kc, vl)
 
 
+def _paged_args(B, nb, N, K=8, bs=16, d=128, H=32, sharding=None):
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=sharding)
+    return (sds((B, H, d), jnp.bfloat16),
+            sds((N, K, bs, d), jnp.bfloat16),
+            sds((N, K, bs, d), jnp.bfloat16),
+            sds((B, nb), jnp.int32), sds((B,), jnp.int32))
+
+
+def _paged(q, kp, vp, bt, vl):
+    from mxnet_tpu.kernels.flash_decode import _flash_decode_paged_pallas
+    return _flash_decode_paged_pallas(q, kp, vp, bt, vl,
+                                      q.shape[-1] ** -0.5, False)
+
+
+def test_flash_decode_paged_lowers_at_the_serving_cells():
+    # mistral_7b.chat / .reason's table shapes (BENCHMARK.json)
+    _lowers(_paged, *_paged_args(32, 160, 5121))
+    _lowers(_paged, *_paged_args(20, 528, 5633))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip: libtpu's own compiler, so
+    Mosaic's VMEM limit and slice alignment apply. Only inside a
+    fixture: one process at a time may load libtpu."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("B,nb,N", [(32, 160, 5121), (20, 528, 5633)])
+def test_flash_decode_paged_compiles_for_v5e(B, nb, N, one_chip):
+    text = jax.jit(_paged).lower(
+        *_paged_args(B, nb, N, sharding=one_chip)).compile().as_text()
+    assert "flash_decode_paged" in text
+
+
 def test_full_llama_step_lowers_with_kernels():
     """The flagship model's jitted forward lowers for TPU with the
     fused-norm kernels actually inside (the _ops_nn dispatch routes
