@@ -75,6 +75,14 @@ class Size:
     #: the paged decode kernel once more at a table shape that serves:
     #: (rows, pages a row, pool blocks, shortest and longest context)
     serve_table: tuple
+    #: a sliding-window layer's window, for the windowed variants of
+    #: flash_attention_fwd and flash_decode_paged (the serving table is
+    #: swept once more at `serve_window`), and an expert layer's
+    #: (published experts, held here, expert width, experts a token)
+    #: for the grouped matmul
+    sliding_window: int
+    serve_window: int
+    experts: tuple
     # -- serve
     slots: int
     max_len: int
@@ -110,6 +118,9 @@ FULL = Size(
     vocab2=30522, decode_len=4096, block=16, window=4,
     # mistral_7b.reason (BENCHMARK.json): 20 slots of max_len 8448
     serve_table=(20, 528, 5633, 1478, 6118),
+    # trinity_large (BENCHMARK.json): window 4096; 8 of 256 experts of
+    # width 3072 held here, 4 a token
+    sliding_window=1024, serve_window=4096, experts=(256, 8, 3072, 4),
     slots=8, max_len=2048, max_prompt=1024,
     waves=(((700, 4, 0.0), (24, 24, 0.0), (1000, 8, 0.8), (57, 32, 0.0),
             (311, 16, 0.7), (990, 12, 0.0), (128, 20, 0.9)),
@@ -296,7 +307,9 @@ def phase_kernels(size):
 
     from mxnet_tpu.kernels import (dispatch, flash_attention as fa,
                                    flash_decode as fd, fused_ce as ce,
-                                   fused_norm as fnorm)
+                                   fused_norm as fnorm,
+                                   grouped_matmul as _gmm)  # noqa: F401
+    from mxnet_tpu.parallel import moe
 
     fallbacks0 = dispatch.fallback_counts()
     dt = jnp.dtype(size.dtype)
@@ -511,6 +524,59 @@ def phase_kernels(size):
             fd.gather_kv_pages(vp, bt).astype(f32), n),
         (qw, kp, vp, bt, vlw), ("flash_decode_paged_window",),
         (TOL_DECODE,))
+
+    # the windowed variants a sliding-window layer runs: the forward
+    # kernel masks and skips the key blocks behind the window (prefill;
+    # its backward kernels refuse a window), the paged sweep starts at
+    # the first page inside it (decode)
+    win = size.sliding_window
+    run("flash_attention sliding window fwd",
+        lambda q, k, v: fa.flash_attention_raw(q, k, v, window=win),
+        lambda q, k, v: fa.reference_attention(*up(q, k, v), window=win),
+        (q, k, v), ("flash_attention_fwd",), (TOL_ATTN,))
+
+    def paged_window(w):
+        return (lambda q, kp, vp, bt, n: fd.flash_decode_paged(
+                    q, kp, vp, bt, n, window=w),
+                lambda q, kp, vp, bt, n: fd.reference_decode_attention(
+                    q.astype(f32), fd.gather_kv_pages(kp, bt).astype(f32),
+                    fd.gather_kv_pages(vp, bt).astype(f32), n, window=w))
+
+    run("flash_decode paged, sliding window", *paged_window(win),
+        (qd, kp, vp, bt, vl), ("flash_decode_paged",), (TOL_DECODE,))
+    run("flash_decode paged, sliding window, serving table",
+        *paged_window(size.serve_window),
+        (randn((Bs, H, d)), randn((Ns, K, bs, d)), randn((Ns, K, bs, d)),
+         jnp.asarray(bts), jnp.asarray(vls)),
+        ("flash_decode_paged",), (TOL_DECODE,))
+
+    # the held experts' grouped matmul: a decode tick's few rows (most
+    # held experts empty) and a prefill's many, routed a chunk at a time
+    E, n, width, top_k = size.experts
+    rw, rb = randn((E, size.hidden), scale=INIT_STD), \
+        randn((E,), f32, scale=0.02)
+    eg, eu = (randn((n, size.hidden, width), scale=INIT_STD)
+              for _ in range(2))
+    ed = randn((n, width, size.hidden), scale=INIT_STD)
+
+    def experts_ref(x, rw, rb, eg, eu, ed):
+        """Every held expert on every row, masked: the definition."""
+        sel, wt = moe.route_top_k(x, rw, rb, top_k, 2.448)
+        x, eg, eu, ed = up(x, eg, eu, ed)
+        y = jnp.einsum("tni,nid->tnd",
+                       jax.nn.silu(jnp.einsum("td,ndi->tni", x, eg))
+                       * jnp.einsum("td,ndi->tni", x, eu), ed)
+        on = jnp.sum(jnp.where(sel[:, :, None] == jnp.arange(n),
+                               wt[:, :, None], 0.0), axis=1)   # (T, n)
+        return jnp.einsum("tn,tnd->td", on, y)
+
+    for rows_, label in ((size.slots, "decode rows"),
+                         (2 * size.seq, "prefill rows")):
+        run(f"moe_grouped_matmul held experts, {label}",
+            lambda x, *w_: moe.held_expert_ffn(
+                x, *w_, lo=0, top_k=top_k, route_scale=2.448)[0],
+            experts_ref, (randn((rows_, size.hidden)), rw, rb, eg, eu, ed),
+            ("moe_grouped_matmul",), (TOL_ATTN,))
 
     check_no_fallbacks("kernels", fallbacks0)
 

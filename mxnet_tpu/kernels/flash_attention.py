@@ -34,10 +34,10 @@ def __getattr__(name):
 
 
 def reference_attention(q, k, v, causal=True, scale=None,
-                        lengths=None):
+                        lengths=None, window=None):
     """jnp reference: XLA fuses this into a few kernels; exact softmax.
     lengths (B,) masks key positions >= lengths[b] (BERT-style key
-    padding)."""
+    padding). `window` (with causal) keeps keys j > i - window."""
     B, T, H, d = q.shape
     K = k.shape[2]
     if scale is None:
@@ -50,6 +50,8 @@ def reference_attention(q, k, v, causal=True, scale=None,
                    kf.astype(jnp.float32)) * scale
     if causal:
         mask = jnp.tril(jnp.ones((T, T), bool))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((T, T), bool), -int(window))
         s = jnp.where(mask[None, None], s, -jnp.inf)
     if lengths is not None:
         keep = jnp.arange(T)[None, :] < lengths[:, None]   # (B, S)
@@ -87,8 +89,19 @@ def _mask_lengths(s, ki, block_k, len_b):
     return jnp.where(kpos < len_b, s, -jnp.inf)
 
 
+def _mask_window(s, qi, ki, block_q, block_k, window):
+    """-inf for keys at or beyond `window` positions behind the query
+    in score block (qi, ki)."""
+    qpos = qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+    kpos = ki * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    return jnp.where(qpos - kpos < window, s, -jnp.inf)
+
+
 def _pallas_forward(q, k, v, causal, scale, block_q=None, block_k=None,
-                    interpret=False, return_lse=False, lengths=None):
+                    interpret=False, return_lse=False, lengths=None,
+                    window=None):
     has_len = lengths is not None
     plat = "cpu" if interpret else "tpu"
     if block_q is None:
@@ -131,6 +144,8 @@ def _pallas_forward(q, k, v, causal, scale, block_q=None, block_k=None,
             s = qblk @ kblk.T  # (block_q, block_k)
             if causal:
                 s = _mask_causal(s, qi, ki, block_q, block_k)
+            if window is not None:
+                s = _mask_window(s, qi, ki, block_q, block_k, window)
             if has_len:
                 s = _mask_lengths(s, ki, block_k, len_b)
             m_new = jnp.maximum(m_, jnp.max(s, axis=-1))
@@ -148,10 +163,14 @@ def _pallas_forward(q, k, v, causal, scale, block_q=None, block_k=None,
                 n_k, ((qi + 1) * block_q + block_k - 1) // block_k)
         else:
             upper = n_k
+        # key blocks wholly behind the window of the block's first
+        # query row are skipped, like those above the diagonal
+        lower = 0 if window is None else jnp.maximum(
+            0, (qi * block_q - window + 1) // block_k)
         if has_len:
             # key blocks past lengths[b] are fully masked: skip them
             upper = jnp.minimum(upper, (len_b + block_k - 1) // block_k)
-        m, l, acc = jax.lax.fori_loop(0, upper, body, (m, l, acc))
+        m, l, acc = jax.lax.fori_loop(lower, upper, body, (m, l, acc))
         safe_l = jnp.where(l > 0, l, 1.0)
         o_ref[...] = (acc / safe_l[:, None]).astype(o_ref.dtype)
         # rows with no unmasked keys get lse=+inf so exp(s - lse) == 0
@@ -369,17 +388,19 @@ def _pallas_backward(q, k, v, lse, delta, dout, causal, scale,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash_pallas(q, k, v, lengths, causal, scale, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_pallas(q, k, v, lengths, causal, scale, interpret,
+                  window=None):
     out, _ = _flash_pallas_fwd(q, k, v, lengths, causal, scale,
-                               interpret)
+                               interpret, window)
     return out
 
 
-def _flash_pallas_fwd(q, k, v, lengths, causal, scale, interpret):
+def _flash_pallas_fwd(q, k, v, lengths, causal, scale, interpret,
+                      window=None):
     out, lse = _pallas_forward(q, k, v, causal, scale,
                                interpret=interpret, return_lse=True,
-                               lengths=lengths)
+                               lengths=lengths, window=window)
     return out, (q, k, v, lengths, out, lse)
 
 
@@ -392,7 +413,12 @@ def _len_cotangent(lengths):
     return _np.zeros(lengths.shape, jax.dtypes.float0)
 
 
-def _flash_pallas_bwd(causal, scale, interpret, res, g):
+def _flash_pallas_bwd(causal, scale, interpret, window, res, g):
+    if window is not None:
+        raise NotImplementedError(
+            "flash attention with a sliding window has no backward "
+            "kernels (dq / dkv know no window mask): the windowed "
+            "forward serves prefill only")
     q, k, v, lengths, out, lse = res
     # delta_i = rowsum(dO_i * O_i): the softmax-jacobian correction term
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
@@ -417,23 +443,24 @@ def _flash_pallas_bwd(causal, scale, interpret, res, g):
 _flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _flash_ref(q, k, v, lengths, causal, scale):
-    return reference_attention(q, k, v, causal, scale, lengths)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_ref(q, k, v, lengths, causal, scale, window=None):
+    return reference_attention(q, k, v, causal, scale, lengths, window)
 
 
-def _flash_ref_fwd(q, k, v, lengths, causal, scale):
+def _flash_ref_fwd(q, k, v, lengths, causal, scale, window=None):
     # save only q/k/v; recompute the softmax in the backward instead of
     # storing the (B, H, T, T) probability matrix
-    return (reference_attention(q, k, v, causal, scale, lengths),
+    return (reference_attention(q, k, v, causal, scale, lengths,
+                                window),
             (q, k, v, lengths))
 
 
-def _flash_ref_bwd(causal, scale, res, g):
+def _flash_ref_bwd(causal, scale, window, res, g):
     q, k, v, lengths = res
     _, vjp = jax.vjp(lambda q_, k_, v_:
                      reference_attention(q_, k_, v_, causal, scale,
-                                         lengths),
+                                         lengths, window),
                      q, k, v)
     return vjp(g) + (_len_cotangent(lengths),)
 
@@ -454,9 +481,16 @@ def _pallas_mode(T):
 
 
 def flash_attention_raw(q, k, v, causal=True, scale=None,
-                        use_flash=True, lengths=None):
+                        use_flash=True, lengths=None, window=None):
     """lengths (B,) optionally masks key positions >= lengths[b]
-    (BERT-style key padding); composes with causal."""
+    (BERT-style key padding); composes with causal. `window` (causal
+    only) is a sliding window: query i sees keys i - window < j <= i;
+    the forward kernel masks and skips the key blocks behind it, the
+    backward kernels refuse it (the jnp path differentiates)."""
+    if window is not None:
+        if not causal:
+            raise ValueError("a sliding window needs causal=True")
+        window = int(window)
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if lengths is not None:
@@ -479,7 +513,7 @@ def flash_attention_raw(q, k, v, causal=True, scale=None,
             return per_shard(
                 lambda q_, k_, v_, *l_: _flash_pallas(
                     q_, k_, v_, l_[0] if l_ else None, causal, scale,
-                    interp),
+                    interp, window),
                 (q, k, v) + ((lengths,) if has_len else ()),
                 (qkv, qkv, qkv) + ((P("dp"),) if has_len else ()))
         except Exception as e:
@@ -488,4 +522,4 @@ def flash_attention_raw(q, k, v, causal=True, scale=None,
             # MXNET_TPU_STRICT_KERNELS=1) turns the fallback into an
             # error; otherwise warn once and count.
             _fallback.note(e)
-    return _flash_ref(q, k, v, lengths, causal, scale)
+    return _flash_ref(q, k, v, lengths, causal, scale, window)

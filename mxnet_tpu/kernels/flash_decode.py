@@ -53,10 +53,10 @@ def __getattr__(name):
 
 
 def reference_decode_attention(q, k_cache, v_cache, valid_len,
-                               scale=None):
+                               scale=None, window=None):
     """jnp reference on (B, K, S, d) caches. GQA WITHOUT jnp.repeat:
     fold the rep axis into the einsum so XLA reads the cache once per
-    kv head."""
+    kv head. `window` keeps the last `window` valid positions only."""
     B, H, d = q.shape
     K, S = k_cache.shape[1], k_cache.shape[2]
     rep = H // K
@@ -67,6 +67,8 @@ def reference_decode_attention(q, k_cache, v_cache, valid_len,
     vf = v_cache.astype(jnp.float32)
     s = jnp.einsum("bkrd,bksd->bkrs", qr, kf) * scale
     mask = jnp.arange(S)[None, :] < valid_len[:, None]        # (B, S)
+    if window is not None:
+        mask &= jnp.arange(S)[None, :] >= valid_len[:, None] - window
     s = jnp.where(mask[:, None, None, :], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkrs,bksd->bkrd", p, vf)
@@ -272,7 +274,8 @@ def _paged_sweep_pages(pool_shape, itemsize, nb=None):
 
 
 def _flash_decode_paged_pallas(q, k_pages, v_pages, block_tables,
-                               valid_len, scale, interpret):
+                               valid_len, scale, interpret,
+                               window=None):
     """In-kernel paged decode: grid (B,), one cell a sequence, which
     sweeps that sequence's pages P at a time (_paged_sweep_pages) in a
     loop as long as the sequence, not as the block table. The pools
@@ -290,18 +293,24 @@ def _flash_decode_paged_pallas(q, k_pages, v_pages, block_tables,
     and the scale multiplies the fp32 scores; the fp32 probabilities
     go in as three bf16 terms that sum to them exactly, stacked on the
     rows of ONE product with v — Mosaic's default fp32 x fp32 dot is a
-    single bf16 pass (2.5e-3 off on a v5e), which would round them."""
+    single bf16 pass (2.5e-3 off on a v5e), which would round them.
+
+    With `window` the sweep starts at the first page that holds one of
+    the last `window` positions and masks the ragged head of that page:
+    a sliding-window layer reads min(valid_len, window) positions, and
+    the table entries before that page are never looked at."""
     pages = _paged_sweep_pages(k_pages.shape, k_pages.dtype.itemsize,
                                block_tables.shape[1])
     return _paged_sweep(q, k_pages, v_pages, block_tables, valid_len,
                         scale=float(scale), pages=pages,
-                        interpret=bool(interpret))
+                        interpret=bool(interpret),
+                        window=None if window is None else int(window))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "pages", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "pages",
+                                             "interpret", "window"))
 def _paged_sweep(q, k_pages, v_pages, block_tables, valid_len, *,
-                 scale, pages, interpret):
+                 scale, pages, interpret, window=None):
     """_flash_decode_paged_pallas's kernel at `pages` a step. A jit of
     its own, so that the layers of a decode program share one trace
     and one Mosaic lowering of the body (its kv heads are unrolled:
@@ -323,16 +332,22 @@ def _paged_sweep(q, k_pages, v_pages, block_tables, valid_len, *,
         b = pl.program_id(0)
         vl = vl_ref[b]
 
+        def first(row):
+            """The first page of `row` the sweep looks at."""
+            if window is None:
+                return 0
+            return jnp.maximum(vl_ref[row] - window, 0) // bs
+
         def copy_step(row, i, slot, act):
             """Start (or wait for) the pages of `row`'s step `i` that
             hold a token, into (out of) half `slot`."""
             def one(j, _):
-                page = bt_ref[row * nb + i * P + j]
+                page = bt_ref[row * nb + first(row) + i * P + j]
                 act(pltpu.make_async_copy(
                     k_hbm.at[page], kbuf.at[slot, j], sem.at[0, slot]))
                 act(pltpu.make_async_copy(
                     v_hbm.at[page], vbuf.at[slot, j], sem.at[1, slot]))
-            held = (vl_ref[row] + bs - 1) // bs
+            held = (vl_ref[row] + bs - 1) // bs - first(row)
             jax.lax.fori_loop(0, jnp.minimum(P, held - i * P), one,
                               None)
 
@@ -351,7 +366,11 @@ def _paged_sweep(q, k_pages, v_pages, block_tables, valid_len, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         # a sequence takes at least one step, so that the hand-over of
         # the halves below never skips a row (valid_len 0: all masked)
-        n = jnp.maximum((vl + T - 1) // T, 1)
+        if window is None:
+            n = jnp.maximum((vl + T - 1) // T, 1)
+        else:
+            n = jnp.maximum(
+                ((vl + bs - 1) // bs - first(b) + P - 1) // P, 1)
         slot0 = slot_ref[0]
 
         def step(i, _):
@@ -366,8 +385,13 @@ def _paged_sweep(q, k_pages, v_pages, block_tables, valid_len, *,
                 start(b + 1, 0, 1 - slot)
 
             copy_step(b, i, slot, lambda c: c.wait())
-            live = i * T + jax.lax.broadcasted_iota(
-                jnp.int32, (rep, T), 1) < vl
+            if window is None:
+                live = i * T + jax.lax.broadcasted_iota(
+                    jnp.int32, (rep, T), 1) < vl
+            else:
+                at = first(b) * bs + i * T + jax.lax.broadcasted_iota(
+                    jnp.int32, (rep, T), 1)
+                live = jnp.logical_and(at < vl, at >= vl - window)
             for h in range(K):
                 qh = q_ref[h]                                # (rep, d)
                 kh = kbuf[slot, :, h].reshape(T, d)
@@ -572,11 +596,13 @@ def paged_gather_bytes(pool_shape, table_shape, itemsize,
 
 
 def flash_decode_paged(q, k_pages, v_pages, block_tables, valid_len,
-                       scale=None, use_flash=True):
+                       scale=None, use_flash=True, window=None):
     """Block-table decode attention straight off the page pool: the
     in-kernel Pallas path when the gate admits it, else gather the
     contiguous view and run the standard flash sweep. Both paths are
-    value-identical at every position < valid_len."""
+    value-identical at every position < valid_len. `window` (a
+    sliding-window layer) attends the last `window` of them only; the
+    table's entries before the window may be stale or zero."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     mode = paged_kernel_mode(k_pages) if use_flash else None
@@ -584,11 +610,14 @@ def flash_decode_paged(q, k_pages, v_pages, block_tables, valid_len,
         try:
             return _flash_decode_paged_pallas(
                 q, k_pages, v_pages, block_tables, valid_len, scale,
-                mode == "interpret")
+                mode == "interpret", window)
         except Exception as e:
             _paged_fallback.note(e)
     k = gather_kv_pages(k_pages, block_tables)
     v = gather_kv_pages(v_pages, block_tables)
+    if window is not None:
+        return reference_decode_attention(q, k, v, valid_len, scale,
+                                          window)
     return flash_decode(q, k, v, valid_len, scale=scale,
                         use_flash=use_flash)
 

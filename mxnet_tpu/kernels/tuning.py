@@ -42,6 +42,13 @@ DEFAULTS = {
     # blocks land on Mosaic's (8, 128) tiling
     "flash_decode_paged": {"vmem_budget_bytes": 8 << 20,
                            "preferred_block_size": 16},
+    # the expert layer's grouped matmul: a (block_k, block_n) tile of
+    # an expert's matrix a step (1 MB in bf16, two of them when gate
+    # and up share a pass), and how many tokens of a long prefill the
+    # layer routes at a time (its row buffers are sized for the worst
+    # case, every pair on a held expert). Hand-chosen.
+    "moe_grouped_matmul": {"block_k": 512, "block_n": 1024,
+                           "chunk_tokens": 2048},
 }
 
 _cache: Optional[dict] = None

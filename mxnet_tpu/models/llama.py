@@ -172,6 +172,12 @@ class LlamaForCausalLM(HybridBlock):
         h = self.model(input_ids)
         return self.lm_head(h)
 
+    def decoder(self):
+        """The serving executables' description of this net
+        (models/decoder.py)."""
+        from .llama_infer import LlamaDecoder
+        return LlamaDecoder(self.model.cfg)
+
 
 @register_model("llama_tiny")
 def llama_tiny(**kw):

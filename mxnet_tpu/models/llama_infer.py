@@ -30,6 +30,7 @@ from jax import lax
 
 from ..ndarray import NDArray
 from . import llama_math
+from .decoder import FULL, DecoderDescription
 
 __all__ = ["generate", "generate_beam", "build_decoder"]
 
@@ -56,6 +57,40 @@ def _params_tree(net):
             "norm": ps["model.norm.gamma"],
             "head": ps["lm_head.weight"],
             "layers": layers}
+
+
+class LlamaDecoder(DecoderDescription):
+    """The Llama block (RMSNorm, RoPE, GQA, SwiGLU) for the serving
+    executables: every layer FULL, the functions of `llama_math`."""
+
+    supports = frozenset({"prefill_chunk", "speculative", "lora",
+                          "int8", "prefix_cache", "kv_tier"})
+
+    def __init__(self, cfg):
+        super().__init__(cfg)
+        self.layer_kinds = (FULL,) * cfg.num_layers
+        self._shape = (cfg.rms_eps, cfg.rope_base, cfg.num_heads,
+                       cfg.num_kv_heads, cfg.head_dim)
+
+    def params_tree(self, net):
+        return _params_tree(net)
+
+    def embed(self, params, ids):
+        return params["embed"][ids]
+
+    def prefill_layer(self, li, lp, x, positions, lengths, lora=None):
+        return llama_math.decoder_layer(
+            lp, x, positions, *self._shape, lengths=lengths,
+            return_kv=True, lora=lora) + (None,)
+
+    def layer_qkv(self, li, lp, x, positions, lora=None):
+        return llama_math.layer_qkv(lp, x, positions, *self._shape,
+                                    lora=lora) + (None,)
+
+    def layer_finish(self, li, lp, x, att, carry, lora=None,
+                     valid=None):
+        return llama_math.layer_finish(lp, x, att, self.cfg.rms_eps,
+                                       lora=lora), None
 
 
 def _params_device(params):

@@ -26,7 +26,7 @@ from ..gluon.parameter import Parameter
 from .mesh import current_manual_axes
 from .tensor_parallel import sharding_constraint
 
-__all__ = ["MoEMLP"]
+__all__ = ["MoEMLP", "held_expert_ffn", "route_top_k"]
 
 
 class MoEMLP(HybridBlock):
@@ -145,3 +145,108 @@ class MoEMLP(HybridBlock):
             a = NDArray(aux) if isinstance(x, NDArray) else aux
             return res, a
         return res
+
+
+# -- a dropless token-choice layer over the experts held here ---------------
+
+def route_top_k(x, router_w, bias, top_k, route_scale):
+    """Sigmoid scores in float32 over ALL experts, top-k of score +
+    bias (the bias picks, it does not weigh), weights normalised over
+    the picked and scaled. x (T, D), router_w (E, D), bias (E,).
+    Returns (sel (T, k) int32, w (T, k) float32)."""
+    s = jax.nn.sigmoid(x.astype(jnp.float32)
+                       @ router_w.astype(jnp.float32).T)
+    _, sel = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(s, sel, axis=1)
+    w = route_scale * picked / jnp.sum(picked, axis=1, keepdims=True)
+    return sel.astype(jnp.int32), w
+
+
+def _held_rows(x, g, w, ex_gate, ex_up, ex_down, use_kernel):
+    """The held experts' weighted sum for the tokens of `x`; g (T, k)
+    names each pair's held expert (0 .. n - 1), or n where the pair
+    falls on no expert held here. The held pairs are sorted by expert,
+    each expert's rows padded to whole tiles, a grouped SwiGLU runs
+    over them and the rows go back to their tokens weighted. Every
+    shape is the worst case's: all T * k pairs held."""
+    from ..kernels.grouped_matmul import grouped_matmul
+
+    T, k = g.shape
+    n = ex_gate.shape[0]
+    P_ = T * k
+    tm = 16 if P_ <= 1024 else 128
+    m_pad = -(-P_ // tm) * tm + n * tm
+    held = g < n
+    g = g.reshape(-1)
+    counts = jnp.zeros((n + 1,), jnp.int32).at[g].add(1)
+    padded = -(-counts[:n] // tm) * tm
+    pend = jnp.cumsum(padded)
+    pstart = jnp.concatenate([pend - padded, jnp.full((1,), m_pad)])
+    ustart = jnp.cumsum(counts) - counts
+    order = jnp.argsort(g, stable=True)
+    gs = g[order]
+    row = jnp.where(gs < n, pstart[gs] + jnp.arange(P_) - ustart[gs],
+                    m_pad)                                 # m_pad: drop
+    row_token = jnp.zeros((m_pad,), jnp.int32).at[row].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    pair_row = jnp.zeros((P_,), jnp.int32).at[order].set(
+        jnp.minimum(row, m_pad - 1).astype(jnp.int32))
+    tiles = m_pad // tm
+    tile_group = jnp.minimum(
+        jnp.searchsorted(pend, jnp.arange(tiles) * tm, side="right"),
+        n - 1).astype(jnp.int32)
+    n_tiles = pend[-1] // tm
+    rows = x[row_token]
+    h = grouped_matmul(rows, ex_gate, tile_group, n_tiles, tm,
+                       rhs2=ex_up, use_kernel=use_kernel)
+    y = grouped_matmul(h, ex_down, tile_group, n_tiles, tm,
+                       use_kernel=use_kernel)
+    # a row no tile wrote is whatever the buffer held: where(), not a
+    # product with a zero weight
+    y = jnp.where(held.reshape(-1, 1), y[pair_row].astype(jnp.float32),
+                  0.0) * w.reshape(-1, 1)
+    return y.reshape(T, k, -1).sum(axis=1)
+
+
+def held_expert_ffn(x, router_w, bias, ex_gate, ex_up, ex_down, *, lo,
+                    top_k, route_scale, valid=None, use_kernel=True):
+    """One chip's share of a token-choice expert layer, dropless.
+
+    x (T, D); router_w (E, D) and bias (E,) at the PUBLISHED expert
+    count; ex_gate, ex_up (n, D, I) and ex_down (n, I, D): the stacked
+    SwiGLU weights of the experts [lo, lo + n) held here, input-major.
+    Every token is scored and its top-k picked over all E experts; the
+    layer returns the sum over picked AND held experts of
+    w_e * SwiGLU_e(x) in float32 — what the absent experts would add
+    lives on the chips that hold them, and on one chip the layer runs
+    without its exchange. `valid` (T,) bool keeps padding rows and idle
+    batch slots out of the experts. No capacity, nothing dropped:
+    shapes are the worst case's (T * k rows).
+
+    Returns (y (T, D) float32, pairs, touched): the pairs that fell on
+    held experts and the held experts with at least one row, int32.
+
+    A long prefill is routed `moe_grouped_matmul.chunk_tokens` tokens
+    at a time, so the row buffers stay a chunk's worst case."""
+    from ..kernels import tuning
+
+    sel, w = route_top_k(x, router_w, bias, top_k, route_scale)
+    n = ex_gate.shape[0]
+    held = (sel >= lo) & (sel < lo + n)
+    if valid is not None:
+        held = held & valid[:, None]
+    T = x.shape[0]
+    g = jnp.where(held, sel - lo, n)        # n: on no expert held here
+    hits = jnp.zeros((n + 1,), jnp.int32).at[g.reshape(-1)].add(1)[:n]
+    pairs, touched = jnp.sum(hits), jnp.sum(hits > 0)
+    chunk = tuning.get("moe_grouped_matmul", "chunk_tokens")
+    if T <= chunk or T % chunk:
+        return _held_rows(x, g, w, ex_gate, ex_up, ex_down,
+                          use_kernel), pairs, touched
+
+    def one(c):
+        return _held_rows(*c, ex_gate, ex_up, ex_down, use_kernel)
+
+    split = lambda a: a.reshape((T // chunk, chunk) + a.shape[1:])
+    out = jax.lax.map(one, (split(x), split(g), split(w)))
+    return out.reshape(T, -1), pairs, touched

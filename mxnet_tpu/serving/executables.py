@@ -279,15 +279,37 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
     if ent is not None:
         return ent
 
-    from ..models import llama_math
+    from ..models.decoder import SLIDING
+    from ..models.llama_math import final_logits, rms
     from ..kernels.flash_decode import (
         flash_decode_paged, flash_decode_paged_quantized,
         flash_decode_paged_window, flash_decode_paged_window_quantized)
     from .sampling import sample_tokens
 
-    cfg = net.model.cfg
-    H, K, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    # the net's own description of its decoder (models/decoder.py):
+    # layer kinds, the layer's functions, the counts it hands back
+    dec = net.decoder()
+    cfg = dec.cfg
+    K, d = cfg.num_kv_heads, cfg.head_dim
     q8 = kv_cache_dtype == "int8"
+    for feature, wanted in (("int8", q8), ("lora", lora),
+                            ("prefill_chunk", prefill_chunk),
+                            ("speculative", spec_k)):
+        if wanted:
+            dec.require(feature, f"paged_programs({feature})")
+
+    def kind_of(tables, li):
+        """A layer's block table(s): with two kinds of layer the
+        programs take the pair (full, sliding), else the one array."""
+        if not dec.mixed:
+            return tables
+        return tables[dec.layer_kinds[li] == SLIDING]
+
+    def add_counts(total, counts):
+        if counts is None:
+            return total
+        return counts if total is None else total + counts
+
     bs = block_size
     nb = max_blocks_per_seq
 
@@ -336,25 +358,29 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
                 *lo):
         B, T = ids.shape                       # B == 1
         la = gather_lora(lo)
-        x = params["embed"][ids]
+        x = dec.embed(params, ids)
         positions = jnp.arange(T)
         t = jnp.arange(T)
         # padding tokens (t >= valid) AND already-cached shared-prefix
         # tokens (t < shared) sink into scratch block 0; the forward
         # still runs over the whole prompt (causal attention is
-        # self-contained), only the cache writes are masked
-        blk = jnp.where((t >= shared_len[0]) & (t < valid_len[0]),
-                        bt_row[t // bs], 0)
+        # self-contained), only the cache writes are masked. A sliding
+        # layer's table reads 0 before the window: those rows sink too
+        keep = (t >= shared_len[0]) & (t < valid_len[0])
         offs = t % bs
         new_pages = []
+        counts = None
         for li, (lp, pg) in enumerate(zip(params["layers"], pages)):
-            x, k, v = llama_math.decoder_layer(
-                lp, x, positions, cfg.rms_eps, cfg.rope_base, H, K, d,
-                lengths=valid_len, return_kv=True, lora=la[li])
+            x, k, v, c = dec.prefill_layer(li, lp, x, positions,
+                                           valid_len, lora=la[li])
+            counts = add_counts(counts, c)
+            blk = jnp.where(keep, kind_of(bt_row, li)[t // bs], 0)
             new_pages.append(write_rows(pg, blk, offs, k[0], v[0]))
-        x = llama_math.rms(x, params["norm"], cfg.rms_eps)
+        x = rms(x, params["norm"], cfg.rms_eps)
         idx = jnp.maximum(valid_len - 1, 0)
         last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
+        if dec.counts:
+            return new_pages, last @ params["head"].T, counts
         return new_pages, last @ params["head"].T
 
     def decode(params, pages, block_tables, pos, last_logits, keys,
@@ -365,27 +391,33 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
         tok = sample_tokens(last_logits, keys_sample, temps, top_ks,
                             top_ps)
         rows = jnp.arange(batch_slots)
-        blk = jnp.where(active, block_tables[rows, pos // bs], 0)
         offs = jnp.where(active, pos % bs, 0)
         vl = jnp.where(active, pos + 1, 1)
-        x = params["embed"][tok][:, None, :]
+        x = dec.embed(params, tok)[:, None, :]
         new_pages = []
+        counts = None
         for li, (lp, pg) in enumerate(zip(params["layers"], pages)):
-            q, k, v = llama_math.layer_qkv(lp, x, pos[:, None],
-                                           cfg.rms_eps, cfg.rope_base,
-                                           H, K, d, lora=la[li])
+            q, k, v, carry = dec.layer_qkv(li, lp, x, pos[:, None],
+                                           lora=la[li])
+            bt = kind_of(block_tables, li)
+            blk = jnp.where(active, bt[rows, pos // bs], 0)
             npg = write_rows(pg, blk, offs, k[:, 0], v[:, 0])
             if q8:
                 att = flash_decode_paged_quantized(
                     q[:, 0], npg["k"], npg["ks"], npg["v"], npg["vs"],
-                    block_tables, vl)[:, None]
+                    bt, vl)[:, None]
             else:
-                att = flash_decode_paged(q[:, 0], npg["k"], npg["v"],
-                                         block_tables, vl)[:, None]
-            x = llama_math.layer_finish(lp, x, att, cfg.rms_eps,
-                                        lora=la[li])
+                att = flash_decode_paged(
+                    q[:, 0], npg["k"], npg["v"], bt, vl,
+                    window=dec.layer_window(li))[:, None]
+            x, c = dec.layer_finish(li, lp, x, att, carry,
+                                    lora=la[li], valid=active[:, None])
+            counts = add_counts(counts, c)
             new_pages.append(npg)
-        logits = llama_math.final_logits(params, x, cfg.rms_eps)[:, 0]
+        logits = final_logits(params, x, cfg.rms_eps)[:, 0]
+        if dec.counts:
+            # a few int32 the scheduler reads at its one sync a tick
+            return new_pages, tok, logits, keys_next, counts
         return new_pages, tok, logits, keys_next
 
     def make_prefill_chunk(C):
@@ -401,21 +433,20 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
                             bt_row[jnp.clip(gpos // bs, 0, nb - 1)], 0)
             offs = jnp.where(valid, gpos % bs, 0)
             vl = jnp.where(valid, gpos + 1, 1)[None, :]  # (1, C)
-            x = params["embed"][ids]
+            x = dec.embed(params, ids)
             positions = gpos[None, :]
             bt2 = bt_row[None, :]
             new_pages = []
             for li, (lp, pg) in enumerate(zip(params["layers"],
                                               pages)):
-                qh, k, v = llama_math.layer_qkv(
-                    lp, x, positions, cfg.rms_eps, cfg.rope_base,
-                    H, K, d, lora=la[li])
+                qh, k, v, carry = dec.layer_qkv(li, lp, x, positions,
+                                                lora=la[li])
                 npg = write_rows(pg, blk, offs, k[0], v[0])
                 att = window_attention(qh, npg, bt2, vl)
-                x = llama_math.layer_finish(lp, x, att, cfg.rms_eps,
-                                            lora=la[li])
+                x, _ = dec.layer_finish(li, lp, x, att, carry,
+                                        lora=la[li])
                 new_pages.append(npg)
-            x = llama_math.rms(x, params["norm"], cfg.rms_eps)
+            x = rms(x, params["norm"], cfg.rms_eps)
             idx = jnp.maximum(chunk_len - 1, 0)
             last = jnp.take_along_axis(x, idx[:, None, None],
                                        axis=1)[:, 0]
@@ -446,21 +477,20 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
                              jnp.clip(P // bs, 0, nb - 1)], 0)
             offs = jnp.where(valid, P % bs, 0)
             vl = jnp.where(valid, P + 1, 1)                # (B, W)
-            x = params["embed"][w]                         # (B, W, D)
+            x = dec.embed(params, w)                       # (B, W, D)
             fb, fo = blk.reshape(-1), offs.reshape(-1)
             new_pages = []
             for li, (lp, pg) in enumerate(zip(params["layers"],
                                               pages)):
-                qh, k, v = llama_math.layer_qkv(
-                    lp, x, P, cfg.rms_eps, cfg.rope_base, H, K, d,
-                    lora=la[li])
+                qh, k, v, carry = dec.layer_qkv(li, lp, x, P,
+                                                lora=la[li])
                 npg = write_rows(pg, fb, fo, k.reshape(-1, K, d),
                                  v.reshape(-1, K, d))
                 att = window_attention(qh, npg, block_tables, vl)
-                x = llama_math.layer_finish(lp, x, att, cfg.rms_eps,
-                                            lora=la[li])
+                x, _ = dec.layer_finish(li, lp, x, att, carry,
+                                        lora=la[li])
                 new_pages.append(npg)
-            logits = llama_math.final_logits(params, x, cfg.rms_eps)
+            logits = final_logits(params, x, cfg.rms_eps)
             # greedy accept: candidate j survives iff every candidate
             # <= j matched the model's argmax at its position
             pred = jnp.argmax(logits[:, :-1, :], axis=-1) \

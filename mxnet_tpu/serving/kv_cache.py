@@ -37,6 +37,22 @@ causal (k/v at position t depend only on tokens <= t), so identical
 prefixes produce identical cache content. Writes into a shared block
 go through copy-on-write (prepare_write); the scratch block 0 is never
 registered or shared.
+
+Two kinds of layer (layer_kinds with "sliding" entries, window=W): a
+FULL layer caches every position, as above; a SLIDING layer attends the
+last W positions only, so its layers share a second pool with its own
+free list and per-sequence table (`window_tables`, the same
+position-indexed shape as `block_tables`). There a sequence owns only
+the blocks that hold one of its last W positions: `alloc` takes the
+tail of a prompt, `ensure` RELEASES the leading blocks that fell out of
+the window before it takes the block of the next position, so a
+sequence never owns more than ceil(W / block_size) + 1 of them
+(`window_blocks_per_seq`). Released entries of the table read 0; the
+windowed sweep never looks at them (kernels/flash_decode.py). Prefill
+writes for positions outside the window sink into scratch block 0 by
+the same zero entries. Admission, `ensure`, `free_slot` and `check()`
+count both kinds; prefix sharing, rewind and tiering know one kind
+only, and the server refuses them for such a net.
 """
 from __future__ import annotations
 
@@ -64,10 +80,32 @@ class PagedKVCache:
                  head_dim: int, num_blocks: int, block_size: int,
                  batch_slots: int, max_blocks_per_seq: int,
                  dtype=jnp.float32, quantized: bool = False,
-                 prefix_cache: bool = False, device=None):
+                 prefix_cache: bool = False, device=None,
+                 layer_kinds=None, window: Optional[int] = None,
+                 window_num_blocks: Optional[int] = None):
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is the "
                              "reserved scratch block)")
+        kinds = tuple(layer_kinds) if layer_kinds is not None \
+            else ("full",) * num_layers
+        if len(kinds) != num_layers:
+            raise ValueError(f"layer_kinds names {len(kinds)} layers, "
+                             f"num_layers={num_layers}")
+        self.layer_kinds = kinds
+        self.window = int(window) if "sliding" in kinds else None
+        if self.window is not None and (quantized or prefix_cache):
+            raise NotImplementedError(
+                "a cache with sliding-window layers has no int8 pool "
+                "and no prefix sharing")
+        #: most blocks one sequence owns in the sliding layers' pool
+        self.window_blocks_per_seq = 0 if self.window is None else \
+            min(max_blocks_per_seq,
+                -(-self.window // block_size) + 1)
+        if self.window is not None and window_num_blocks is None:
+            window_num_blocks = \
+                batch_slots * self.window_blocks_per_seq + 1
+        self.window_num_blocks = window_num_blocks \
+            if self.window is not None else 0
         self.num_layers = num_layers
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
@@ -97,9 +135,11 @@ class PagedKVCache:
                           for _ in range(num_layers)]
         else:
             self.pages = [jax.device_put(
-                {"k": jnp.zeros((N, K, bs, d), dtype),
-                 "v": jnp.zeros((N, K, bs, d), dtype)}, dev)
-                          for _ in range(num_layers)]
+                {"k": jnp.zeros((n, K, bs, d), dtype),
+                 "v": jnp.zeros((n, K, bs, d), dtype)}, dev)
+                          for n in (self.window_num_blocks
+                                    if kind == "sliding" else N
+                                    for kind in kinds)]
 
         # host-side allocator state. Free list is LIFO (hot blocks get
         # reused first); block 0 never enters it.
@@ -115,6 +155,17 @@ class PagedKVCache:
         self._slot_len = np.zeros(batch_slots, np.int64)
         self.alloc_count = 0
         self.free_count = 0
+        # the sliding layers' pool: its own free list and table; a
+        # slot owns the logical blocks [first, first + len(blocks))
+        self.window_tables = None
+        if self.window is not None:
+            self._wfree: List[int] = list(
+                range(self.window_num_blocks - 1, 0, -1))
+            self.window_tables = np.zeros(
+                (batch_slots, max_blocks_per_seq), np.int32)
+            self._wslot_blocks: List[List[int]] = [
+                [] for _ in range(batch_slots)]
+            self._wslot_first = np.zeros(batch_slots, np.int64)
 
         # -- prefix-cache sharing state (refcounts are ALWAYS
         # maintained so check() can enforce them; the content index
@@ -149,10 +200,49 @@ class PagedKVCache:
         # excludes the reserved scratch block
         return (self.num_blocks - 1) - len(self._free)
 
+    # both kinds, by name: the full layers' pool is the one the
+    # unqualified counters above have always meant
+    @property
+    def global_blocks_used(self) -> int:
+        return self.num_used_blocks
+
+    @property
+    def global_blocks_capacity(self) -> int:
+        return self.num_blocks - 1
+
+    @property
+    def window_blocks_used(self) -> int:
+        if self.window is None:
+            return 0
+        return (self.window_num_blocks - 1) - len(self._wfree)
+
+    @property
+    def window_blocks_capacity(self) -> int:
+        return max(0, self.window_num_blocks - 1)
+
     def blocks_for(self, num_tokens: int) -> int:
         return max(1, math.ceil(num_tokens / self.block_size))
 
+    def _window_span(self, num_tokens: int):
+        """(first, count) of the logical blocks a sliding layer keeps
+        for a sequence of `num_tokens` cached positions whose next
+        token attends the last `window` of them, itself included."""
+        first = max(0, num_tokens - self.window + 1) // self.block_size
+        last = max(num_tokens - 1, 0) // self.block_size
+        return first, last - first + 1
+
+    def window_blocks_for(self, num_tokens: int) -> int:
+        """Most blocks of the sliding pool a sequence that grows to
+        `num_tokens` owns at one time (0 without sliding layers)."""
+        if self.window is None:
+            return 0
+        return min(self.blocks_for(num_tokens),
+                   self.window_blocks_per_seq)
+
     def can_alloc(self, num_tokens: int) -> bool:
+        if self.window is not None and \
+                len(self._wfree) < self._window_span(num_tokens)[1]:
+            return False
         return len(self._free) >= self.blocks_for(num_tokens)
 
     def fragmentation(self) -> float:
@@ -214,6 +304,12 @@ class PagedKVCache:
                "cow_copies": self.cow_count,
                "fragmentation": self.fragmentation(),
                "parked_blocks": self.parked_blocks()}
+        if self.window is not None:
+            out.update(
+                window_blocks_used=self.window_blocks_used,
+                window_blocks_capacity=self.window_blocks_capacity,
+                global_blocks_used=self.global_blocks_used,
+                global_blocks_capacity=self.global_blocks_capacity)
         if self.tier is not None:
             out.update(self.tier.stats())
         return out
@@ -265,13 +361,43 @@ class PagedKVCache:
             raise ValueError(
                 f"sequence of {num_tokens} tokens needs {need} blocks "
                 f"> max_blocks_per_seq={self.max_blocks_per_seq}")
-        if len(self._free) < need:
+        if not self.can_alloc(num_tokens):
             return False
         blocks = [self._pop_free() for _ in range(need)]
         self._slot_blocks[slot] = blocks
         self.block_tables[slot, :need] = blocks
         self._slot_len[slot] = num_tokens
         self.alloc_count += need
+        if self.window is not None:
+            first, count = self._window_span(num_tokens)
+            wblocks = [self._wfree.pop() for _ in range(count)]
+            self._wslot_blocks[slot] = wblocks
+            self._wslot_first[slot] = first
+            self.window_tables[slot, first:first + count] = wblocks
+        return True
+
+    def _window_ensure(self, slot: int, pos: int) -> bool:
+        """The sliding pool's half of `ensure`: give back the leading
+        blocks no position >= pos - window + 1 lies in, then take the
+        block of `pos` if the slot lacks it (the block just given back
+        is the one taken: a long sequence cycles through its own)."""
+        held = self._wslot_blocks[slot]
+        first = int(self._wslot_first[slot])
+        keep = max(0, pos - self.window + 1) // self.block_size
+        while held and first < keep:
+            self.window_tables[slot, first] = 0
+            self._wfree.append(held.pop(0))
+            first += 1
+        if not held:
+            first = pos // self.block_size
+        self._wslot_first[slot] = first
+        if pos // self.block_size < first + len(held):
+            return True
+        if not self._wfree:
+            return False
+        blk = self._wfree.pop()
+        self.window_tables[slot, first + len(held)] = blk
+        held.append(blk)
         return True
 
     def ensure(self, slot: int, pos: int) -> bool:
@@ -280,6 +406,9 @@ class PagedKVCache:
         slot's next write position). Allocates at most one block.
         Returns False if the pool is exhausted — the scheduler then
         preempts another sequence and retries."""
+        if self.window is not None \
+                and not self._window_ensure(slot, pos):
+            return False
         need = pos // self.block_size + 1
         held = len(self._slot_blocks[slot])
         if need <= held:
@@ -353,6 +482,11 @@ class PagedKVCache:
         self._slot_blocks[slot] = []
         self.block_tables[slot, :] = 0
         self._slot_len[slot] = 0
+        if self.window is not None:
+            self._wfree.extend(reversed(self._wslot_blocks[slot]))
+            self._wslot_blocks[slot] = []
+            self._wslot_first[slot] = 0
+            self.window_tables[slot, :] = 0
 
     # -- prefix-cache sharing -----------------------------------------------
 
@@ -589,3 +723,23 @@ class PagedKVCache:
             # tier invariants: one tier per content key, conservation
             # across spill/restore/adopt (KVTierManager.check)
             self.tier.check()
+        if self.window is not None:
+            wowned = [b for blks in self._wslot_blocks for b in blks]
+            assert 0 not in wowned and 0 not in self._wfree, \
+                "sliding pool's scratch block handed out"
+            assert len(set(wowned)) == len(wowned), \
+                "sliding block owned twice"
+            assert not (set(wowned) & set(self._wfree)), \
+                "sliding block both owned and free"
+            assert len(wowned) + len(self._wfree) \
+                == self.window_num_blocks - 1, "sliding block leak"
+            for slot, blks in enumerate(self._wslot_blocks):
+                assert len(blks) <= self.window_blocks_per_seq, \
+                    f"slot {slot} owns {len(blks)} sliding blocks > " \
+                    f"{self.window_blocks_per_seq}"
+                first = int(self._wslot_first[slot])
+                row = self.window_tables[slot]
+                assert list(row[first:first + len(blks)]) == blks \
+                    and not row[:first].any() \
+                    and not row[first + len(blks):].any(), \
+                    f"slot {slot}: sliding table out of sync"

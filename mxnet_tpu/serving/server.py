@@ -255,6 +255,7 @@ class InferenceServer:
                  max_prompt_len: Optional[int] = None,
                  kv_cache_dtype: str = "model",
                  num_blocks: Optional[int] = None,
+                 window_num_blocks: Optional[int] = None,
                  max_preemptions: Optional[int] = 3,
                  watchdog_ticks: int = 256,
                  prefix_cache: bool = False,
@@ -272,7 +273,10 @@ class InferenceServer:
                  lora=None, tenants=None):
         if max_len % block_size:
             raise ValueError("max_len must be a multiple of block_size")
-        cfg = net.model.cfg
+        # the net describes its own decoder (models/decoder.py): the
+        # server names no model
+        self.decoder = dec = net.decoder()
+        cfg = dec.cfg
         self.net = net
         self.cfg = cfg
         self.batch_slots = batch_slots
@@ -294,6 +298,18 @@ class InferenceServer:
         self.prefill_chunk_tokens = prefill_chunk_tokens
         from .speculative import as_proposer
         self._spec = as_proposer(speculative)
+        # a description says which of these its layer functions and
+        # cache kinds implement; the rest raise here, by name
+        for feature, wanted in (
+                ("prefill_chunk", prefill_chunk_tokens is not None),
+                ("speculative", self._spec is not None),
+                ("lora", lora is not None),
+                ("int8", kv_cache_dtype == "int8"),
+                ("prefix_cache", prefix_cache and not kv_tiering
+                 and prefix_store_dir is None),
+                ("kv_tier", kv_tiering or prefix_store_dir is not None)):
+            if wanted:
+                dec.require(feature, f"InferenceServer({feature})")
         # batched multi-LoRA: a fixed-capacity device-resident adapter
         # table; per-slot table INDICES are traced executable operands,
         # so every adapter mix / hot-load / eviction shares the one
@@ -319,8 +335,8 @@ class InferenceServer:
         if num_blocks is None:
             num_blocks = batch_slots * max_blocks + 1
         model_dtype = jnp.dtype(getattr(cfg, "dtype", "float32"))
-        from ..models.llama_infer import _params_device, _params_tree
-        params = _params_tree(net)
+        from ..models.llama_infer import _params_device
+        params = dec.params_tree(net)
         # every array the executables take (weights, page pools,
         # logits/PRNG rows) is committed to the weights' device as a
         # plain single-device array: weights a mesh'd train step handed
@@ -335,7 +351,18 @@ class InferenceServer:
             block_size=block_size, batch_slots=batch_slots,
             max_blocks_per_seq=max_blocks, dtype=model_dtype,
             quantized=kv_cache_dtype == "int8",
-            prefix_cache=prefix_cache, device=dev)
+            prefix_cache=prefix_cache, device=dev,
+            **({"layer_kinds": dec.layer_kinds, "window": dec.window,
+                "window_num_blocks": window_num_blocks}
+               if dec.mixed else {}))
+        #: cached positions the decode ticks attended, summed over
+        #: active slots and ticks: every one (`context_tokens`) and the
+        #: last `window` of them (`window_context_tokens`, what a
+        #: sliding layer's sweep reads); and the decoder's own counts
+        self.context_tokens = 0
+        self.window_context_tokens = 0
+        self.decoder_counts = {n: 0 for n in dec.counts}
+        self._prefill_counts = []
         self.programs = executables.paged_programs(
             net, batch_slots=batch_slots, max_blocks_per_seq=max_blocks,
             block_size=block_size, max_prompt_len=self.max_prompt_len,
@@ -468,9 +495,19 @@ class InferenceServer:
     def refresh_params(self):
         """Re-snapshot the net's weights (after a training step /
         checkpoint load). Shapes are unchanged, so no recompile."""
-        from ..models.llama_infer import _params_tree
-        self._params = jax.device_put(_params_tree(self.net),
-                                      self._device)
+        self._params = jax.device_put(
+            self.decoder.params_tree(self.net), self._device)
+
+    def _tables(self, slot=None):
+        """The block table(s) as the executables take them: the one
+        array, or with two kinds of layer the pair (full, sliding);
+        `slot` picks that sequence's row."""
+        c = self.cache
+        tabs = (c.block_tables,) if c.window_tables is None \
+            else (c.block_tables, c.window_tables)
+        out = tuple(jnp.asarray(t if slot is None else t[slot])
+                    for t in tabs)
+        return out[0] if len(out) == 1 else out
 
     # -- tenants + adapters -------------------------------------------------
 
@@ -602,6 +639,14 @@ class InferenceServer:
                 f"(prompt {prompt.size} + {max_new_tokens} new tokens, "
                 f"block_size={self.block_size}) but the pool only has "
                 f"{capacity} — raise num_blocks or shrink the request")
+        wneed = self.cache.window_blocks_for(prompt.size
+                                             + max_new_tokens)
+        if wneed > self.cache.window_blocks_capacity:
+            raise ValueError(
+                f"request needs {wneed} blocks of the sliding-window "
+                f"layers' pool, which only has "
+                f"{self.cache.window_blocks_capacity} — raise "
+                "window_num_blocks")
         spec = None
         if tenant is not None:
             tenant = str(tenant)
@@ -741,15 +786,18 @@ class InferenceServer:
 
         ids = np.zeros((1, self.max_prompt_len), np.int32)
         ids[0, :T] = req.prompt
-        bt_row = jnp.asarray(self.cache.block_tables[slot])
+        bt_row = self._tables(slot)
         t_pf = time.perf_counter()
         with telemetry.phase("serve_prefill", tokens=T,
                              padded=self.max_prompt_len):
-            self.cache.pages, last = self.programs["prefill"](
+            self.cache.pages, last, *counts = self.programs["prefill"](
                 self._params, self.cache.pages, bt_row,
                 jnp.asarray(ids), jnp.asarray([T], jnp.int32),
                 jnp.asarray([shared_len], jnp.int32),
                 *self._lora_args([req.adapter_idx]))
+        # the decoder's counts of this prefill stay on the device
+        # until the tick's one sync reads them
+        self._prefill_counts.extend(counts)
         self._charge(req, T - shared_len)
         req._tev("prefill", t=t_pf,
                  dur_s=time.perf_counter() - t_pf, tokens=T)
@@ -1185,6 +1233,7 @@ class InferenceServer:
             self._update_gauges()
             return prefilled
         drafts = dlens = None
+        tick_counts = {}
         with telemetry.span("serve_blocks"):
             self._ensure_blocks()
             if self._spec is not None:
@@ -1193,7 +1242,8 @@ class InferenceServer:
             # exactly two spans: the uploads + the launch, then the
             # host blocked on the device
             with telemetry.span("serve_dispatch",
-                                active=int(self._active.sum())):
+                                active=int(self._active.sum()),
+                                **self._note_context()):
                 if drafts is not None:
                     (self.cache.pages, wtok, n_acc, self._last_logits,
                      self._keys) = self.programs["verify"](
@@ -1208,9 +1258,9 @@ class InferenceServer:
                         *self._lora_args(self._adapter_ids))
                 else:
                     (self.cache.pages, tok, self._last_logits,
-                     self._keys) = self.programs["decode"](
+                     self._keys, *counts) = self.programs["decode"](
                         self._params, self.cache.pages,
-                        jnp.asarray(self.cache.block_tables),
+                        self._tables(),
                         jnp.asarray(self._pos), self._last_logits,
                         self._keys, jnp.asarray(self._temps),
                         jnp.asarray(self._top_ks),
@@ -1225,9 +1275,40 @@ class InferenceServer:
                 else:
                     wtok_np = np.asarray(tok).reshape(-1, 1)
                     n_acc_np = np.zeros(self.batch_slots, np.int32)
-        with telemetry.span("serve_emit"):
+                    # the decoder's counts of this tick (an expert
+                    # layer's pairs and touched experts), read at the
+                    # same sync as the tokens
+                    tick_counts = dict(zip(
+                        self.decoder.counts,
+                        (int(c) for c in np.asarray(counts[0]))
+                        if counts else ()))
+                    if self._prefill_counts:
+                        done = np.sum([np.asarray(c) for c in
+                                       self._prefill_counts], axis=0)
+                        self._prefill_counts = []
+                        tick_counts.update(
+                            ("prefill_" + n, int(c)) for n, c in
+                            zip(self.decoder.counts, done))
+        for name, n in tick_counts.items():
+            self.decoder_counts[name] = \
+                self.decoder_counts.get(name, 0) + n
+        with telemetry.span("serve_emit", **tick_counts):
             return self._emit(wtok_np, n_acc_np, dlens, t_tick,
                               admitted, done0)
+
+    def _note_context(self) -> dict:
+        """Count the cached positions this tick's decode attends. With
+        sliding-window layers the two sums also go on
+        `mx.serve_dispatch` (`ctx`, `window_ctx`): what the full and
+        the sliding layers' sweeps read, for their roofline."""
+        vl = self._pos[self._active].astype(np.int64) + 1
+        ctx = int(vl.sum())
+        self.context_tokens += ctx
+        if self.decoder.window is None:
+            return {}
+        wctx = int(np.minimum(vl, self.decoder.window).sum())
+        self.window_context_tokens += wctx
+        return {"ctx": ctx, "window_ctx": wctx}
 
     def _emit(self, wtok_np, n_acc_np, dlens, t_tick: float,
               admitted: int, done0: int) -> int:
@@ -1732,6 +1813,16 @@ class InferenceServer:
         out = {"prefill_compiles": p.compiles, "prefill_calls": p.calls,
                "decode_compiles": d.compiles, "decode_calls": d.calls,
                "copy_compiles": c.compiles, "copy_calls": c.calls}
+        if self.decoder.window is not None:
+            kv = self.cache
+            out.update(
+                window_blocks_used=kv.window_blocks_used,
+                window_blocks_capacity=kv.window_blocks_capacity,
+                global_blocks_used=kv.global_blocks_used,
+                global_blocks_capacity=kv.global_blocks_capacity,
+                context_tokens=self.context_tokens,
+                window_context_tokens=self.window_context_tokens)
+        out.update(self.decoder_counts)
         v = self.programs.get("verify")
         if v is not None:
             out["verify_compiles"] = v.compiles

@@ -1,0 +1,443 @@
+"""Plain reference of an afmoe decoder (Arcee Trinity family): one full
+forward in float32 ``jax.numpy`` at ``default_matmul_precision
+("highest")``, no kernels, no cache, no batching, no import from the
+program under test. It computes ONE CHIP'S SHARE of the stated
+deployment, as the configuration file cuts it: every token is scored
+and its top-k picked over all ``num_experts_published`` experts, and the
+routed sum runs over the picked experts that are among the
+``num_experts`` held here (experts ``held_experts_lo`` onward); what the
+absent experts would add is left out, here as in the program.
+
+The layer, from the published config and the family's description::
+
+    h0 = E[ids] * sqrt(hidden)                            (mup_enabled)
+    u  = RMSNorm(x; g_in);  q, k, v = u Wq^T, u Wk^T, u Wv^T
+    q, k = RMSNorm over each head's 128 (g_q, g_k)
+    sliding layer: rotate q, k (theta, position); full layer: no rotation
+    a  = softmax(q k^T / sqrt(d) + mask) v     mask: j <= i; sliding also j > i - window
+    a  = a * sigmoid(u Wg^T)
+    x  = x + RMSNorm(a Wo^T; g_post_attn)
+    m  = RMSNorm(x; g_pre_mlp)
+    dense: f = SwiGLU(m)      sparse: s = sigmoid(m Wr^T) in float32
+           sel = top-k(s + b);  w = route_scale * s[sel] / sum(s[sel])
+           f = SwiGLU_shared(m) + sum over sel of w_e * SwiGLU_e(m)
+    x  = x + RMSNorm(f; g_post_mlp);   logits = RMSNorm(x_L; g_f) W_head^T
+
+Possible departures from the released modeling code, which was not at
+hand (each is listed under ``assumed`` in the configuration file): the
+embedding scale, the per-head q/k norm, no rotation on full layers, the
+bias used for selection only, the gate taken from the normed input, the
+depth-scaled start of the closing norms' gains.
+
+Computed in blocks: attention a block of query rows at a time (a
+sliding layer against the ``window + block`` keys it can see), an
+expert over the rows routed to it, gathered into a block of fixed size
+(a layer that overflows it runs again with a larger block: nothing is
+dropped). It runs after the
+program's state is freed, layer by layer, each layer's weights made
+again from the seed by the function that made the served ones.
+"""
+import math
+
+import numpy as np
+
+_ATTN = ("ln_in", "wq", "wk", "wv", "wg", "wo", "q_norm", "k_norm",
+         "ln_post_attn", "ln_pre_mlp", "ln_post_mlp")
+DENSE_LEAVES = _ATTN + ("gate", "up", "down")
+MOE_LEAVES = _ATTN + ("router", "bias", "sh_gate", "sh_up", "sh_down",
+                      "ex_gate", "ex_up", "ex_down")
+CONTROLS = ("fp8", "top3", "no_window", "no_bias")
+
+
+def is_moe(cfg, l):
+    return l >= cfg["num_dense_layers"]
+
+
+def is_sliding(cfg, l):
+    return cfg["layer_types"][l].startswith("sliding")
+
+
+def _shapes(cfg):
+    h, v, d = cfg["hidden_size"], cfg["vocab_size"], cfg["head_dim"]
+    q = cfg["num_attention_heads"] * d
+    kv = cfg["num_key_value_heads"] * d
+    i, m = cfg["intermediate_size"], cfg["moe_intermediate_size"]
+    n, e = cfg["num_experts"], cfg["num_experts_published"]
+    return {"ln_in": (h,), "wq": (q, h), "wk": (kv, h), "wv": (kv, h),
+            "wg": (q, h), "wo": (h, q), "q_norm": (d,), "k_norm": (d,),
+            "ln_post_attn": (h,), "ln_pre_mlp": (h,),
+            "ln_post_mlp": (h,), "gate": (i, h), "up": (i, h),
+            "down": (h, i), "router": (e, h), "bias": (e,),
+            "sh_gate": (m, h), "sh_up": (m, h), "sh_down": (h, m),
+            # the held experts stacked, input-major: x @ W
+            "ex_gate": (n, h, m), "ex_up": (n, h, m),
+            "ex_down": (n, m, h),
+            "embed": (v, h), "norm": (h,), "head": (v, h)}
+
+
+class Weights:
+    """The seeded weights, made on the device in the served type:
+    N(0, initializer_range) matrices, unit norm scales, a selection
+    bias N(0, router_bias_std) (small and non-zero, so it changes some
+    selections); the two norms that close a branch start at the
+    depth-scaled gain post_norm_gain ("depth-scaled sandwich norm"), so
+    the residual stream stays the token's own and the router is not
+    driven by what every token shares. One jitted call a layer (one executable for the dense
+    layers, one for the sparse), one for embedding, final norm and
+    head; the served copy and the reference's layer-by-layer copy come
+    from the same calls with the same keys."""
+
+    def __init__(self, cfg, seed, device=None):
+        import jax
+        import jax.numpy as jnp
+
+        shapes, std = _shapes(cfg), cfg["initializer_range"]
+        bias_std = cfg["router_bias_std"]
+        dt = jnp.dtype(cfg["torch_dtype"])
+
+        def leaf(k, name, i):
+            if name == "bias":
+                return (jax.random.normal(jax.random.fold_in(k, i),
+                                          shapes[name], jnp.float32)
+                        * bias_std).astype(dt)
+            if name in ("ln_post_attn", "ln_post_mlp"):
+                return jnp.full(shapes[name], cfg["post_norm_gain"], dt)
+            if len(shapes[name]) == 1:
+                return jnp.ones(shapes[name], dt)
+            return (jax.random.normal(jax.random.fold_in(k, i),
+                                      shapes[name], jnp.float32)
+                    * std).astype(dt)
+
+        def maker(names):
+            return jax.jit(lambda k: {n: leaf(k, n, i)
+                                      for i, n in enumerate(names)})
+
+        self._dense = maker(DENSE_LEAVES)
+        self._moe = maker(MOE_LEAVES)
+        self._ends = maker(("embed", "norm", "head"))
+        root = jax.random.PRNGKey(seed % (2 ** 31 - 1))
+        self._root = jax.device_put(root, device) \
+            if device is not None else root
+        self._fold = jax.random.fold_in
+        self.cfg = cfg
+        self.num_layers = cfg["num_hidden_layers"]
+
+    def layer(self, l):
+        make = self._moe if is_moe(self.cfg, l) else self._dense
+        return make(self._fold(self._root, l + 1))
+
+    def ends(self):
+        return self._ends(self._fold(self._root, 0))
+
+    def all(self):
+        out = dict(self.ends())
+        out["layers"] = [self.layer(l) for l in range(self.num_layers)]
+        return out
+
+
+def make_weights(cfg, seed, device=None):
+    return Weights(cfg, seed, device).all()
+
+
+# -- the model ---------------------------------------------------------------
+
+def _rms(x, g, eps):
+    import jax.numpy as jnp
+    return x / jnp.sqrt(jnp.mean(jnp.square(x), -1, keepdims=True)
+                        + eps) * g
+
+
+def _rope(x, pos, base):
+    """Rotate-half rotary embedding on (T, H, d) at positions (T,)."""
+    import jax.numpy as jnp
+    half = x.shape[-1] // 2
+    inv = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    ang = pos.astype(jnp.float32)[:, None] * inv
+    sin, cos = jnp.sin(ang)[:, None, :], jnp.cos(ang)[:, None, :]
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def fp8_round(x):
+    """Round to float8 e4m3 with one scale a tensor: the nearest
+    precision below bfloat16."""
+    import jax.numpy as jnp
+    s = 448.0 / jnp.maximum(jnp.max(jnp.abs(x)), 1e-30)
+    return (x * s).astype(jnp.float8_e4m3fn).astype(jnp.float32) / s
+
+
+def bf16_round(x):
+    """Round to bfloat16: the program's own arithmetic."""
+    import jax.numpy as jnp
+    return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+ROUNDERS = {"fp8": fp8_round, "bf16": bf16_round}
+
+
+def route(cfg, m, router, bias, top_k=None, use_bias=True):
+    """(sel (T, k), w (T, k)): sigmoid scores in float32 over every
+    published expert, top-k of score + bias, weights over the picked."""
+    import jax
+    import jax.numpy as jnp
+    s = jax.nn.sigmoid(m @ router.T)
+    k = top_k or cfg["num_experts_per_tok"]
+    _, sel = jax.lax.top_k(s + bias if use_bias else s, k)
+    picked = jnp.take_along_axis(s, sel, 1)
+    return sel, cfg["route_scale"] * picked / picked.sum(-1, keepdims=True)
+
+
+def _swiglu(r, h, g, u, dn):
+    """Dense convention: y = x @ W.T."""
+    import jax
+    return r(jax.nn.silu(h @ g.T) * (h @ u.T)) @ dn.T
+
+
+def _routed(cfg, control, w, m, cap=None):
+    """The held experts' part of the routed sum for rows m (T, D):
+    (sum over picked AND held experts of w_e * SwiGLU_e(m), overflow,
+    sel). An expert's rows are gathered into a block of `cap` rows
+    (every row when None); `overflow` counts the rows past it."""
+    import jax
+    import jax.numpy as jnp
+
+    r = ROUNDERS.get(control, lambda a: a)
+    lo, n = cfg.get("held_experts_lo", 0), cfg["num_experts"]
+    T = m.shape[0]
+    k = 3 if control == "top3" else None
+    sel, wt = route(cfg, m, w["router"], w["bias"], k,
+                    control != "no_bias")
+    cap = T if cap is None else min(cap, T)
+    mz = jnp.concatenate([r(m), jnp.zeros((1, m.shape[1]))], 0)
+
+    def expert(e, carry):
+        out, over = carry
+        hit = sel == lo + e                               # (T, k)
+        we = jnp.sum(jnp.where(hit, wt, 0.0), -1)
+        took = hit.any(-1)
+        idx = jnp.nonzero(took, size=cap, fill_value=T)[0]
+        rows = mz[idx]
+        y = r(jax.nn.silu(rows @ w["ex_gate"][e])
+              * (rows @ w["ex_up"][e])) @ w["ex_down"][e]
+        scale = jnp.concatenate([we, jnp.zeros((1,))])[idx]
+        out = out.at[idx].add(y * scale[:, None], mode="drop")
+        return out, over + jnp.maximum(took.sum() - cap, 0)
+
+    out, over = jax.lax.fori_loop(
+        0, n, expert, (jnp.zeros_like(m), jnp.zeros((), jnp.int32)))
+    return out, over, sel
+
+
+def ffn_parts(cfg, lp, m):
+    """(shared, routed) of one sparse layer's feed-forward on rows m
+    (T, D) in float32: the shared expert's output, and the held
+    experts' part of the routed sum. The shares of a deployment add up:
+    the routed parts of all shares plus the shared part once are the
+    uncut layer's feed-forward."""
+    import jax
+    import jax.numpy as jnp
+
+    w = jax.tree_util.tree_map(lambda a: jnp.asarray(a, jnp.float32), lp)
+    with jax.default_matmul_precision("highest"):
+        part, _, _ = _routed(cfg, None, w, m)
+        return _swiglu(lambda a: a, m, w["sh_gate"], w["sh_up"],
+                       w["sh_down"]), part
+
+
+def _layer(cfg, sliding, moe, q_block, control=None, cap=None):
+    """Jitted (layer weights, x (T, D)) -> (x (T, D), overflow, sel):
+    float32; T a multiple of ``q_block``. ``control`` alters the
+    mathematics the way one of CONTROLS says; ``overflow`` counts rows
+    an expert's block of ``cap`` rows could not hold (the caller runs
+    the layer again with a larger one); ``sel`` is the picked experts
+    (T, k), or None for a dense layer."""
+    import jax
+    import jax.numpy as jnp
+
+    H, K, d = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+               cfg["head_dim"])
+    eps, base = cfg["rms_norm_eps"], cfg["rope_theta"]
+    W = cfg["sliding_window"]
+    r = ROUNDERS.get(control, lambda a: a)
+    windowed = sliding and control != "no_window"
+
+    def attention(q, k, v):
+        T = q.shape[0]
+        qg = q.reshape(T // q_block, q_block, K, H // K, d)
+        # a sliding block sees `span` keys ending at its last row
+        span = min(T, W - 1 + q_block) if windowed else T
+        pad = span - q_block
+        kp = jnp.pad(k, ((pad, 0), (0, 0), (0, 0)))
+        vp = jnp.pad(v, ((pad, 0), (0, 0), (0, 0)))
+
+        def block(args):
+            b, qb = args
+            s0 = b * q_block                   # first query position
+            kb = jax.lax.dynamic_slice_in_dim(kp, s0, span, 0)
+            vb = jax.lax.dynamic_slice_in_dim(vp, s0, span, 0)
+            qpos = s0 + jnp.arange(q_block)
+            kpos = s0 - pad + jnp.arange(span)
+            ok = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] >= 0)
+            if windowed:
+                ok &= kpos[None, :] > qpos[:, None] - W
+            sc = jnp.einsum("tkrd,skd->krts", qb, kb) / math.sqrt(d)
+            p = r(jax.nn.softmax(jnp.where(ok[None, None], sc, -1e30),
+                                 axis=-1))
+            return jnp.einsum("krts,skd->tkrd", p, vb)
+
+        out = jax.lax.map(block, (jnp.arange(T // q_block), qg))
+        return out.reshape(T, H * d)
+
+    def f(lp, x):
+        lp = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), lp)
+        w = {name: r(a) if a.ndim >= 2 and name != "router" else a
+             for name, a in lp.items()}
+        T = x.shape[0]
+        pos = jnp.arange(T)
+        u = r(_rms(x, w["ln_in"], eps))
+        q = _rms((u @ w["wq"].T).reshape(T, H, d), w["q_norm"], eps)
+        k = _rms((u @ w["wk"].T).reshape(T, K, d), w["k_norm"], eps)
+        v = (u @ w["wv"].T).reshape(T, K, d)
+        if sliding:
+            q, k = _rope(q, pos, base), _rope(k, pos, base)
+        a = attention(r(q), r(k), r(v)) * jax.nn.sigmoid(u @ w["wg"].T)
+        x = x + _rms(r(a) @ w["wo"].T, w["ln_post_attn"], eps)
+        m = _rms(x, w["ln_pre_mlp"], eps)
+        over, sel = jnp.zeros((), jnp.int32), None
+        if moe:
+            part, over, sel = _routed(cfg, control, w, m, cap)
+            ff = _swiglu(r, r(m), w["sh_gate"], w["sh_up"],
+                         w["sh_down"]) + part
+        else:
+            ff = _swiglu(r, r(m), w["gate"], w["up"], w["down"])
+        return x + _rms(ff, w["ln_post_mlp"], eps), over, sel
+
+    return jax.jit(f)
+
+
+def forward(cfg, seed, ids_list, device=None, q_block=512, control=None,
+            weights=None):
+    """The hidden state after the last layer, (T_pad, D) float32, for
+    each id sequence (each padded to the longest's multiple of
+    ``q_block``: one shape, so each kind of layer compiles once), and
+    per sequence the picked experts of every sparse layer."""
+    import jax.numpy as jnp
+
+    weights = weights or Weights(cfg, seed, device)
+    embed = weights.ends()["embed"]
+    t_pad = max(len(i) for i in ids_list)
+    t_pad += -t_pad % q_block
+    xs = [embed[jnp.asarray(np.pad(np.asarray(i, np.int32),
+                                   (0, t_pad - len(i))))]
+          .astype(jnp.float32) * math.sqrt(cfg["hidden_size"])
+          for i in ids_list]
+    fns, sels = {}, [[] for _ in xs]
+
+    def layer_fn(l, cap):
+        key = (is_sliding(cfg, l), is_moe(cfg, l), cap)
+        if key not in fns:
+            fns[key] = _layer(cfg, key[0], key[1], q_block, control, cap)
+        return fns[key]
+
+    # an expert's block holds 4x its even share of a long sequence's
+    # rows; a layer that routes more to one expert runs again with a
+    # block twice the size, so nothing is ever dropped
+    cap = t_pad if t_pad <= 4096 else max(
+        512, t_pad * cfg["num_experts_per_tok"] * 4
+        // cfg["num_experts_published"])
+    for l in range(cfg["num_hidden_layers"]):
+        lp = weights.layer(l)
+        for j, x in enumerate(xs):
+            y, over, sel = layer_fn(l, cap)(lp, x)
+            while int(over):
+                cap = min(2 * cap, t_pad)
+                y, over, sel = layer_fn(l, cap)(lp, x)
+            xs[j] = y
+            if sel is not None:
+                sels[j].append(np.asarray(sel))
+        del lp
+    return xs, sels
+
+
+def served_token_gaps(cfg, seed, sequences, device=None, q_block=512,
+                      control=False):
+    """For each ``(prompt ids, served ids)``: at every served position,
+    how far the served token's reference logit lies below the
+    reference's best (0 where the reference would have served the same
+    token). One teacher-forced pass over prompt + served tokens.
+    Returns a list of float32 arrays, one per sequence.
+
+    ``control`` (one of CONTROLS; True is "fp8") puts the reference,
+    computed that way, in the program's place: at each position of the
+    same prompts and tokens it reads the gap of the token the control
+    pass puts first."""
+    import jax
+    import jax.numpy as jnp
+
+    control = "fp8" if control is True else control or None
+    if control is not None and control not in CONTROLS:
+        raise ValueError(f"unknown control {control!r}: {CONTROLS}")
+    weights = Weights(cfg, seed, device)
+    ends = weights.ends()
+    ids = [np.concatenate([np.asarray(p, np.int32),
+                           np.asarray(s, np.int32)])[:-1]
+           for p, s in sequences]
+    with jax.default_matmul_precision("highest"):
+        xs, _ = forward(cfg, seed, ids, device, q_block, None, weights)
+        ys = forward(cfg, seed, ids, device, q_block, control,
+                     weights)[0] if control else None
+        low = ROUNDERS.get(control, lambda a: a)
+
+        def logits_of(x, norm, head, r=lambda a: a):
+            return r(_rms(x, norm.astype(jnp.float32),
+                          cfg["rms_norm_eps"])) @ r(head.astype(
+                              jnp.float32)).T
+
+        @jax.jit
+        def gaps(x, norm, head, nxt):
+            logits = logits_of(x, norm, head)
+            got = jnp.take_along_axis(logits, nxt[:, None], 1)[:, 0]
+            return jnp.max(logits, axis=-1) - got
+
+        @jax.jit
+        def first_of_control(y, norm, head):
+            return jnp.argmax(logits_of(y, norm, head, low), -1)
+
+        out = []
+        for j, (x, (prompt, served)) in enumerate(zip(xs, sequences)):
+            nxt = np.zeros(x.shape[0], np.int32)
+            both = np.concatenate([np.asarray(prompt, np.int32),
+                                   np.asarray(served, np.int32)])
+            nxt[:len(both) - 1] = both[1:]
+            nxt = jnp.asarray(nxt)
+            if control:
+                nxt = first_of_control(ys[j], ends["norm"],
+                                       ends["head"]).astype(jnp.int32)
+            g = np.asarray(gaps(x, ends["norm"], ends["head"], nxt))
+            out.append(g[len(prompt) - 1:len(both) - 1])
+    return out
+
+
+def selection_flip_share(cfg, seed, sequences, device=None, q_block=512):
+    """The share of (served position, sparse layer) at which the set of
+    picked experts differs between the float32 reference and the same
+    reference with every matmul operand rounded to bfloat16 (the
+    program's arithmetic): near-ties of the router flip under rounding,
+    and the limits of the output check have to hold with those flips
+    in."""
+    import jax
+
+    ids = [np.concatenate([np.asarray(p, np.int32),
+                           np.asarray(s, np.int32)])[:-1]
+           for p, s in sequences]
+    with jax.default_matmul_precision("highest"):
+        _, exact = forward(cfg, seed, ids, device, q_block)
+        _, rounded = forward(cfg, seed, ids, device, q_block, "bf16")
+    flips = total = 0
+    for (prompt, served), a, b in zip(sequences, exact, rounded):
+        rows = slice(len(prompt) - 1, len(prompt) + len(served) - 1)
+        for sa, sb in zip(a, b):
+            differ = np.sort(sa[rows], -1) != np.sort(sb[rows], -1)
+            flips += int(differ.any(-1).sum())
+            total += differ.shape[0]
+    return flips / max(total, 1)

@@ -35,6 +35,7 @@ TINY = chip_smoke.Size(
     bert_heads=2, bert_head_dim=32, bert_seq=128, ln_width=96,
     vocab2=1100, decode_len=128, block=16, window=2,
     serve_table=(3, 12, 30, 20, 150),
+    sliding_window=48, serve_window=64, experts=(16, 4, 32, 2),
     slots=4, max_len=128, max_prompt=64,
     waves=(((40, 2, 0.0), (5, 4, 0.0), (64, 3, 0.8), (17, 5, 0.0),
             (33, 3, 0.7), (60, 2, 0.0)),
@@ -47,7 +48,7 @@ TINY = chip_smoke.Size(
 def interpret(monkeypatch):
     """The kernels' CPU switch: trace the real Pallas kernels and run
     them under the interpreter."""
-    for fam in ("FLASH", "NORM", "CE"):
+    for fam in ("FLASH", "NORM", "CE", "MOE"):
         monkeypatch.setenv(f"MXNET_TPU_{fam}_INTERPRET", "1")
 
 

@@ -185,6 +185,50 @@ def test_dispatch_counts_the_slots_the_server_batched(serve_trace):
         assert active <= a
 
 
+def test_a_decoder_with_counts_puts_them_on_its_spans(tmp_path):
+    """afmoe: `ctx` / `window_ctx` on mx.serve_dispatch (the positions
+    the full and the sliding layers' sweeps read), the expert layers'
+    `pairs` / `touched` on mx.serve_emit, a prefill's on the emit of the
+    tick that admitted it; they add up to what the server counted. The
+    Llama block's spans carry none of them."""
+    moe = mx.models.get_model("afmoe_tiny", held_experts=(0, 8))
+    moe.initialize()
+    server = InferenceServer(moe, batch_slots=2, max_len=64,
+                             block_size=8, max_prompt_len=48)
+    rs = np.random.RandomState(3)
+
+    def body():
+        for n in (40, 6, 9):
+            server.submit(rs.randint(0, 256, n).astype(np.int32),
+                          max_new_tokens=5)
+        server.run()
+
+    spans = record(body, tmp_path)
+    disp = named(spans, "mx.serve_dispatch")
+    emit = named(spans, "mx.serve_emit")
+    assert len(disp) == len(emit) == server.ticks
+    total = lambda ss, k: sum(s.stats.get(k, 0) for s in ss)  # noqa: E731
+    stats = server.compile_stats()
+    assert total(disp, "ctx") == stats["context_tokens"] > 0
+    assert total(disp, "window_ctx") == stats["window_context_tokens"]
+    assert stats["window_context_tokens"] < stats["context_tokens"]
+    assert all(0 < s.stats["window_ctx"] <= 32 * s.stats["active"]
+               for s in disp)
+    for key in ("pairs", "touched", "prefill_pairs", "prefill_touched"):
+        assert total(emit, key) == stats[key] > 0, key
+    assert all({"pairs", "touched"} <= set(s.stats) for s in emit)
+    # three prefills, each counted on the emit of its own tick
+    assert sum("prefill_pairs" in s.stats for s in emit) == 2
+    assert all(s.stats["touched"] <= 4 * 8 for s in emit)
+
+
+def test_the_llama_block_adds_no_count(serve_trace):
+    spans, _, _ = serve_trace
+    assert all(set(s.stats) == {"active"}
+               for s in named(spans, "mx.serve_dispatch"))
+    assert all(s.stats == {} for s in named(spans, "mx.serve_emit"))
+
+
 def test_early_return_ticks_close_serve_tick(net, tmp_path):
     server = InferenceServer(net, batch_slots=2, max_len=32,
                              block_size=8, max_prompt_len=8,

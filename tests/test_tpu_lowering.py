@@ -120,6 +120,72 @@ def test_flash_decode_paged_compiles_for_v5e(B, nb, N, one_chip):
     assert "flash_decode_paged" in text
 
 
+# -- the afmoe cell's kernels at its shapes (trinity_large.longctx:
+# 48 slots of max_len 22,528, window 4,096, 48 / 8 heads of 128, 32 held
+# experts of 3,072 x 3,072, prompts up to 14,336) -------------------------
+
+def _paged_windowed(q, kp, vp, bt, vl):
+    from mxnet_tpu.kernels.flash_decode import _flash_decode_paged_pallas
+    return _flash_decode_paged_pallas(q, kp, vp, bt, vl,
+                                      q.shape[-1] ** -0.5, False, 4096)
+
+
+def _grouped(fused, tm):
+    from mxnet_tpu.kernels.grouped_matmul import _grouped_matmul_pallas
+
+    def f(x, a, b, tg, nt):
+        return _grouped_matmul_pallas(x, a, b if fused else None, tg, nt,
+                                      tm=tm, interpret=False)
+    return f
+
+
+def _grouped_args(M, tm, sharding=None):
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=sharding)
+    w = sds((32, 3072, 3072), jnp.bfloat16)
+    return (sds((M, 3072), jnp.bfloat16), w, w,
+            sds((M // tm,), jnp.int32), sds((), jnp.int32))
+
+
+def _windowed_prefill(q, k, v, n):
+    from mxnet_tpu.kernels.flash_attention import _flash_pallas
+    return _flash_pallas(q, k, v, n, True, 128 ** -0.5, False, 4096)
+
+
+def test_afmoe_kernels_lower_at_the_cells_shapes():
+    _lowers(_paged_windowed, *_paged_args(48, 1408, 12337, H=48))
+    _lowers(_grouped(True, 16), *_grouped_args(704, 16))
+    _lowers(_grouped(False, 128), *_grouped_args(12288, 128))
+
+
+@pytest.mark.parametrize("what", ["paged sweep, window", "paged sweep, "
+                                  "full layer", "grouped matmul, decode",
+                                  "grouped matmul, prefill chunk",
+                                  "flash forward, window"])
+def test_afmoe_kernels_compile_for_v5e(what, one_chip):
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    if what.startswith("paged sweep"):
+        # the full layer's table is 48 x 1,408 int32 = 270 KB of scalar
+        # prefetch: it has to fit SMEM beside the sweep's scratch
+        fn = _paged_windowed if "window" in what else _paged
+        N = 12337 if "window" in what else 32001
+        args, name = _paged_args(48, 1408, N, H=48, sharding=one_chip), \
+            "flash_decode_paged"
+    elif what.startswith("grouped"):
+        M, tm = (704, 16) if "decode" in what else (12288, 128)
+        fn, args, name = _grouped("decode" in what, tm), \
+            _grouped_args(M, tm, one_chip), "moe_grouped_matmul"
+    else:
+        fn, name = _windowed_prefill, "flash_attention_fwd"
+        args = (sds((1, 14336, 48, 128), jnp.bfloat16),
+                sds((1, 14336, 8, 128), jnp.bfloat16),
+                sds((1, 14336, 8, 128), jnp.bfloat16),
+                sds((1,), jnp.int32))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert name in text
+
+
 def test_full_llama_step_lowers_with_kernels():
     """The flagship model's jitted forward lowers for TPU with the
     fused-norm kernels actually inside (the _ops_nn dispatch routes
