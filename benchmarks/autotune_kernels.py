@@ -20,7 +20,7 @@ the kernel modules at trace time. Sweep space:
 On CPU the kernels run under the Pallas interpreter, so the timings
 validate the harness (and the sweep plumbing) but are NOT advisory for
 TPU constants — winners are still recorded, under the "cpu" platform
-section, which TPU runs never read. Timing discipline follows bench.py:
+section, which TPU runs never read. Timing discipline:
 chained/accumulated dispatch, host fetch of a chain-dependent scalar,
 difference timing so the dispatch and fetch overheads cancel.
 
@@ -33,13 +33,62 @@ import argparse
 import functools
 import json
 import os
+import signal
 import sys
+import threading
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
 
-from bench import BudgetGuard
+BUDGET_S = float(os.environ.get("BENCH_BUDGET_S", "540"))
+
+
+class BudgetGuard:
+    """Self-defended deadline of the sweep.
+
+    Holds the best-measurement-so-far dict. When the budget expires it
+    prints that dict as a JSON line and ends the process with exit code
+    1 — a run that did not finish is a failed run — via a daemon
+    THREAD, not signal.alarm: Python signal handlers only run between
+    bytecodes on the main thread, so a main thread blocked in a C call
+    (XLA compile, block_until_ready) never sees SIGALRM/SIGTERM. The
+    timer thread's os._exit always fires."""
+
+    def __init__(self, metric, unit, budget_s=None):
+        self.budget_s = BUDGET_S if budget_s is None else budget_s
+        self.t0 = time.monotonic()
+        self.best = {"metric": metric, "value": 0.0, "unit": unit,
+                     "vs_baseline": 0.0, "phase": "startup"}
+
+    def remaining(self):
+        return self.budget_s - (time.monotonic() - self.t0)
+
+    def emit(self):
+        sys.stdout.write(json.dumps(self.best) + "\n")
+        sys.stdout.flush()
+
+    def _deadline(self, signum=None, frame=None):
+        # never let this thread die before os._exit: snapshot the dict
+        # (the main thread may be mutating it) and exit even if
+        # emission fails
+        try:
+            snap = dict(self.best)
+            snap["error"] = "budget expired; best-so-far emitted"
+            sys.stdout.write(json.dumps(snap) + "\n")
+            sys.stdout.flush()
+        finally:
+            os._exit(1)
+
+    def install(self):
+        t = threading.Timer(max(5.0, self.budget_s), self._deadline)
+        t.daemon = True
+        t.start()
+        # best-effort: if the main thread IS interruptible, end on the
+        # driver's TERM the same way
+        signal.signal(signal.SIGTERM, self._deadline)
+        return self
+
 
 _guard = None
 
@@ -49,7 +98,8 @@ def _remaining():
 
 
 def _diff_time(run_chain, lo, hi):
-    """Seconds per iteration via difference timing (see bench.py)."""
+    """Seconds per iteration via difference timing (the module
+    docstring says why)."""
     dt_lo = run_chain(lo)
     dt_hi = run_chain(hi)
     dd = dt_hi - dt_lo

@@ -23,6 +23,7 @@ Usage: python examples/llama_serve.py [--cpu] [--steps 200]
 import argparse
 import os
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
@@ -235,9 +236,10 @@ def main():
     spans = ", ".join(f"{e['name']}@{e['src']}" for e in tr["events"])
     print(f"fleet trace {fr0.token}: decision="
           f"{tr['attempts'][0]['decision']} [{spans}]")
-    telemetry.export_chrome_trace("llama_serve_fleet_trace.json")
-    print("chrome trace (router + replica pids): "
-          "llama_serve_fleet_trace.json")
+    trace_path = os.path.join(tempfile.gettempdir(),
+                              "llama_serve_fleet_trace.json")
+    telemetry.export_chrome_trace(trace_path)
+    print("chrome trace (router + replica pids):", trace_path)
 
     # -- goodput + memory pressure: where did the wall clock go, and
     # how much KV headroom is left? ------------------------------------

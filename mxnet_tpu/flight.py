@@ -28,8 +28,8 @@ Cost contract: identical to telemetry — the whole layer is off by
 default and every instrumented call site guards on the module-level
 ``_ENABLED`` flag (one attribute load + branch), so the disabled path
 never builds a payload dict or touches the ring
-(``tests/test_telemetry_lint.py`` enforces the gate pattern;
-``benchmarks/optimizer_bench.py --telemetry-overhead`` measures it).
+(``tests/test_telemetry_lint.py`` enforces the gate pattern; its cost
+on the chip: no cell measures this, ROADMAP D7).
 
 Env: ``MXNET_TPU_FLIGHT=1`` enables at import, ``MXNET_TPU_FLIGHT_DIR``
 picks the dump directory (default: cwd), ``MXNET_TPU_FLIGHT_EVENTS``

@@ -35,8 +35,8 @@ class _DownloadedDataset(Dataset):
     def __getitem__(self, idx):
         # samples are host numpy: the transform chain mirrors the
         # type, so the whole pipeline stays on the host and the
-        # DataLoader device-puts once per BATCH (9-11x throughput vs
-        # per-sample NDArray round trips on this host). The .copy()
+        # DataLoader device-puts once per BATCH (one transfer a batch
+        # where per-sample NDArrays cost one a sample). The .copy()
         # isolates the shared dataset buffer from in-place transforms
         # (a mutating transform must not corrupt later epochs).
         d = self._data[idx].copy()
@@ -229,10 +229,9 @@ def _as_np(x):
 def _like(out, ref):
     """Mirror the input container type: NDArray in -> NDArray out
     (upstream-compatible for direct callers); numpy in -> numpy out,
-    which is what makes the DataLoader pipeline fast — samples stay on
-    the host through the whole transform chain and the batchify does
-    ONE device put per batch instead of two transfers per sample
-    (measured 9-11x input-pipeline throughput on this host)."""
+    which keeps the DataLoader pipeline on the host — samples stay
+    there through the whole transform chain and the batchify does ONE
+    device put per batch instead of two transfers per sample."""
     return array(out) if isinstance(ref, NDArray) else out
 
 

@@ -51,16 +51,11 @@ flight events the stack already emits into those numbers:
   health source and feeds ``FleetRouter`` admission so a replica
   forecast to exhaust within its drain window stops taking long-prompt
   work *before* it preempts.
-- ``python -m mxnet_tpu.goodput check`` — regression sentinel over the
-  ``BENCH_*.json`` trajectory: exits nonzero when the newest record
-  regresses any shared metric by more than ``--tolerance`` (10%
-  default), making the benches CI-enforceable.
 
 Everything here is off by default (``MXNET_TPU_GOODPUT=1`` or
 :func:`enable` opts in) and rides — never replaces — the existing
 telemetry registry.
 """
-import json
 import os
 import threading
 import time
@@ -93,10 +88,6 @@ __all__ = [
     "state_dict",
     "restore_state",
     "format_summary",
-    "load_bench_history",
-    "check_metrics",
-    "check_against_history",
-    "main",
 ]
 
 #: every second of wall clock lands in exactly one of these
@@ -132,7 +123,7 @@ _PHASE_CATEGORY = {
 
 #: dense bf16 peak FLOPs per chip, keyed by ``jax.Device.device_kind``
 #: (Google Cloud TPU documentation; "TPU v5 lite" is what a v5e chip
-#: reports). The ONE peak table: benchmarks and chip_smoke.py read it
+#: reports). The program's ONE peak table: chip_smoke.py reads it
 #: through :func:`peak_flops`.
 PEAK_FLOPS_BY_KIND = {
     "TPU v2": 45e12,
@@ -408,8 +399,7 @@ def _chips(kind: str) -> int:
     return _CHIPS.get(kind, 1)
 
 
-#: active ParallelPlan axis sizes — the MFU/HFU gauges carry them as
-#: labels so plan choices are comparable across BENCH rounds
+#: active ParallelPlan axis sizes — the MFU/HFU gauges carry them as labels
 _PLAN_AXES: Dict[str, str] = {}
 
 
@@ -463,8 +453,7 @@ def note_hbm_watermark(name: str, jit_fn, args) -> None:
     *args* is a tree of ``ShapeDtypeStruct`` avals (what the serving
     ``Program`` already builds for compile-cache tracing). Falls back
     to the summed aval footprint, honestly labelled
-    ``bytes_source="analytic"`` — same idiom as the paged-kernel
-    bench.
+    ``bytes_source="analytic"``.
     """
     if not _ENABLED:
         return
@@ -734,198 +723,7 @@ class PoolForecaster:
                 "exhaust_in_s": self.exhaust_in_s()}
 
 
-# -- bench regression sentinel ----------------------------------------
-#: metric-name suffixes where smaller is the good direction
-_LOWER_BETTER_SUFFIXES = ("_ms", "_s", "_seconds", "_bytes", "_ratio",
-                          "_pct", "_overhead", "_failures", "_errors")
-
-#: throughput-flavoured names where bigger stays the good direction
-#: even when the name ends in a latency-like suffix (`tok_per_s`)
-_HIGHER_BETTER_MARKERS = ("per_s", "per_sec", "throughput", "speedup",
-                          "tok_s", "tokens_s", "mfu", "hfu", "goodput")
-
-#: explicit per-metric direction pins (checked before the heuristics)
-#: for bench metrics whose names the suffix rules would misread.
-#: True = lower is better. bench_lora_mix_vs_base_ratio is a
-#: THROUGHPUT ratio (mixed-adapter tokens/sec over base — the `_ratio`
-#: suffix would flip it); the tenant-QoS leg's SLO attainment and shed
-#: counters carry no latency suffix at all.
-_DIRECTION_OVERRIDES = {
-    "bench_lora_mix_vs_base_ratio": False,        # higher is better
-    "bench_lora_extra_compiles": True,            # 0 is the contract
-    "bench_tenant_victim_slo_attainment": False,  # fraction inside SLO
-    "bench_tenant_victim_shed_total": True,       # victim sheds = harm
-    "bench_canary_pass": False,                   # 1 = acceptance held
-    "bench_canary_rollbacks": False,  # degrade leg MUST roll back (>=1)
-    "bench_canary_clean_alerts": True,            # clean leg: 0 alerts
-    "bench_canary_clean_rollbacks": True,         # clean leg: 0
-    "bench_canary_bundle_sources": False,         # >=2 sources required
-    # autoscale leg: chip-seconds are the currency being minimized;
-    # attainment / scale-event counts must not be misread as latency
-    "bench_autoscale_chip_seconds": True,         # the bill itself
-    "bench_autoscale_chip_savings_frac": False,   # saved vs best static
-    "bench_autoscale_slo_attainment": False,      # interactive holds 1.0
-    "bench_autoscale_scale_outs": False,          # >=1 required
-    "bench_autoscale_scale_ins": False,           # >=1 required
-    "bench_autoscale_lost": True,                 # zero-loss contract
-    "bench_autoscale_clean_alerts": True,         # clean leg: 0 alerts
-}
-
-
-def _lower_is_better(metric: str) -> bool:
-    if metric in _DIRECTION_OVERRIDES:
-        return _DIRECTION_OVERRIDES[metric]
-    m = metric.lower()
-    if any(k in m for k in _HIGHER_BETTER_MARKERS):
-        return False
-    return metric.endswith(_LOWER_BETTER_SUFFIXES)
-
-
-def _metrics_from_record(rec: dict) -> Dict[str, float]:
-    """Pull {metric: value} out of one BENCH record — its ``parsed``
-    dict plus any ``{"metric": ..., "value": ...}`` JSON lines the
-    bench printed into ``tail``."""
-    out: Dict[str, float] = {}
-
-    def _take(d):
-        if isinstance(d, dict) and "metric" in d and "value" in d:
-            try:
-                out[str(d["metric"])] = float(d["value"])
-            except (TypeError, ValueError):
-                pass
-
-    _take(rec.get("parsed"))
-    for line in str(rec.get("tail", "")).splitlines():
-        line = line.strip()
-        if not (line.startswith("{") and '"metric"' in line):
-            continue
-        try:
-            _take(json.loads(line))
-        except ValueError:
-            continue
-    return out
-
-
-def load_bench_history(directory: str = ".") \
-        -> List[Tuple[int, str, Dict[str, float]]]:
-    """BENCH_*.json records as (n, filename, metrics), oldest first."""
-    recs = []
-    try:
-        names = sorted(os.listdir(directory))
-    except OSError:
-        return []
-    for fn in names:
-        if not (fn.startswith("BENCH") and fn.endswith(".json")):
-            continue
-        try:
-            with open(os.path.join(directory, fn)) as f:
-                rec = json.load(f)
-        except (OSError, ValueError):
-            continue
-        if isinstance(rec, dict):
-            recs.append((int(rec.get("n") or 0), fn,
-                         _metrics_from_record(rec)))
-    recs.sort(key=lambda r: (r[0], r[1]))
-    return recs
-
-
-def check_metrics(current: Dict[str, float],
-                  history: Dict[str, List[float]],
-                  tolerance: float = 0.10) -> dict:
-    """Compare a run's metrics against their historical best.
-
-    Direction is inferred from the metric name (latency/size/ratio
-    suffixes → lower is better, else higher). A metric regresses when
-    it is worse than the best historical value by more than
-    *tolerance* (relative).
-    """
-    regressions = []
-    compared = 0
-    for metric in sorted(current):
-        past = history.get(metric) or []
-        if not past:
-            continue
-        compared += 1
-        value = float(current[metric])
-        lower = _lower_is_better(metric)
-        baseline = min(past) if lower else max(past)
-        if baseline == 0:
-            continue
-        delta = (value - baseline) / abs(baseline)
-        regressed = delta > tolerance if lower else delta < -tolerance
-        if regressed:
-            regressions.append({
-                "metric": metric,
-                "value": value,
-                "baseline": baseline,
-                "delta_pct": round(100.0 * delta, 2),
-                "direction": ("lower_is_better" if lower
-                              else "higher_is_better"),
-            })
-    return {"ok": not regressions, "compared": compared,
-            "tolerance": tolerance, "regressions": regressions}
-
-
-def check_against_history(current: Dict[str, float],
-                          directory: str = ".",
-                          tolerance: float = 0.10) -> dict:
-    """Sentinel entry point for the benches: verdict for *current*
-    metrics vs the whole BENCH_*.json trajectory in *directory*."""
-    hist: Dict[str, List[float]] = {}
-    for _n, _fn, metrics in load_bench_history(directory):
-        for m, v in metrics.items():
-            hist.setdefault(m, []).append(v)
-    return check_metrics(dict(current), hist, tolerance)
-
-
-def main(argv: Optional[List[str]] = None) -> int:
-    import argparse
-    ap = argparse.ArgumentParser(
-        prog="python -m mxnet_tpu.goodput",
-        description="goodput tooling (bench regression sentinel)")
-    sub = ap.add_subparsers(dest="cmd", required=True)
-    ck = sub.add_parser(
-        "check",
-        help="compare the newest BENCH_*.json (or --current) against "
-             "the trajectory; exit 1 on >tolerance regression")
-    ck.add_argument("--dir", default=".",
-                    help="directory holding BENCH_*.json (default .)")
-    ck.add_argument("--tolerance", type=float, default=0.10,
-                    help="relative regression tolerance (default 0.10)")
-    ck.add_argument("--current", default=None,
-                    help="JSON file of {metric: value} (or one bench "
-                         "emit line) to check instead of the newest "
-                         "BENCH record")
-    args = ap.parse_args(argv)
-
-    recs = load_bench_history(args.dir)
-    if args.current:
-        with open(args.current) as f:
-            cur = json.load(f)
-        if isinstance(cur, dict) and "metric" in cur and "value" in cur:
-            cur = {str(cur["metric"]): float(cur["value"])}
-        hist_recs = recs
-    else:
-        if len(recs) < 2:
-            print(f"goodput check: {len(recs)} BENCH_*.json record(s) "
-                  f"in {args.dir!r} — nothing to compare")
-            return 0
-        cur = recs[-1][2]
-        hist_recs = recs[:-1]
-    hist: Dict[str, List[float]] = {}
-    for _n, _fn, metrics in hist_recs:
-        for m, v in metrics.items():
-            hist.setdefault(m, []).append(v)
-    verdict = check_metrics(cur, hist, args.tolerance)
-    print(json.dumps(verdict, indent=2, sort_keys=True))
-    return 0 if verdict["ok"] else 1
-
-
 if os.environ.get("MXNET_TPU_GOODPUT", "").lower() in ("1", "true",
                                                        "yes"):
     enable()
 
-
-if __name__ == "__main__":
-    import sys
-    raise SystemExit(main(sys.argv[1:]))

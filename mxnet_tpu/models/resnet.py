@@ -2,8 +2,7 @@
 ptrendx fork's headline benchmark model).
 
 TPU-first: default layout NHWC (XLA-native conv layout on TPU; the
-reference uses NCHW+cuDNN). BatchNorm axis follows the layout. bench.py
-trains resnet50_v1 in bf16 — convs hit the MXU at full tile occupancy.
+reference uses NCHW+cuDNN). BatchNorm axis follows the layout.
 """
 from __future__ import annotations
 

@@ -202,7 +202,7 @@ class ShardedForward:
 class FusedTrainStep:
     """Compile net+loss+optimizer into one XLA executable.
 
-    Usage (bench.py / examples):
+    Usage:
         step = FusedTrainStep(net, loss_fn, trainer, mesh=mesh)
         loss = step(x, y)          # one fused device step
         step.sync_to_params()      # write weights back for checkpointing

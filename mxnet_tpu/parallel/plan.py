@@ -232,7 +232,7 @@ class ParallelPlan:
         return step
 
     def describe(self) -> str:
-        """Human-readable one-plan summary (bench/REPL helper)."""
+        """Human-readable one-plan summary."""
         parts = [f"dp={self.dp}", f"tp={self.tp}", f"pp={self.pp}",
                  f"ep={self.ep}", f"zero={self.zero}"]
         if self.pp > 1:

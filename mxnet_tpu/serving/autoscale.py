@@ -50,9 +50,9 @@ WITH its input signals (burn, queue-age p95, backlog tokens, tps/chip)
 so a post-mortem shows *why* the fleet moved.
 
 Chip-seconds are the ledger: the autoscaler meters every replica's
-alive span (``chips_per_replica x seconds``) into ``usage()`` — the
-number `decode_bench --autoscale` shows beating both static N=min and
-static N=max fleets over the same diurnal curve.
+alive span (``chips_per_replica x seconds``) into ``usage()``. Whether
+that beats a static fleet over a diurnal curve: no cell measures this
+(ROADMAP D7).
 
 Cost contract: the tick itself is control-plane (it must run even with
 telemetry disabled — it drives real capacity), but every metric /
@@ -79,7 +79,7 @@ _TPS_GAUGE = "goodput_serve_tokens_per_sec_per_chip"
 
 class AutoscalePolicy:
     """Knobs for the control loop. Everything has a production-shaped
-    default; the bench and tests tighten the windows.
+    default; the tests tighten the windows.
 
     - ``min_replicas`` / ``max_replicas``: target clamp. ``min=0``
       enables scale-to-zero (the router tolerates an empty fleet while
@@ -152,7 +152,7 @@ class ReplicaProvisioner:
     ProcReplica — anything the router speaks) and an optional ``reap``
     called after the handle leaves the fleet (kill the subprocess,
     release the chips). Subprocess provisioning stays out of this
-    module: the bench/tests pass their own spawn/reap closures."""
+    module: the tests pass their own spawn/reap closures."""
 
     def __init__(self, spawn: Callable, reap: Optional[Callable] = None):
         self._spawn = spawn

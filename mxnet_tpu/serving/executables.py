@@ -2,8 +2,7 @@
 
 Before this module, every `generate()` call rebuilt `build_decoder`'s
 closures and wrapped them in FRESH `jax.jit` objects — a full retrace
-+ XLA compile per call (benchmarks/decode_bench.py had to
-difference-time around it). Here every executable is a `Program`: a
++ XLA compile per call. Here every executable is a `Program`: a
 named, compile-counting `jax.jit` wrapper cached on the net object by
 its build signature. Callers get back the SAME jit object for the
 same signature, so jit's own shape-keyed cache makes repeat calls

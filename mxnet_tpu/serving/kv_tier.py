@@ -316,8 +316,7 @@ class KVTierManager:
         self.admits = 0
         self.hits = {"device": 0, "host": 0, "disk": 0}
 
-    # -- telemetry hooks (also the --telemetry-overhead B-side
-    # no-op targets in benchmarks/optimizer_bench.py) -------------------
+    # -- telemetry hooks ------------------------------------------------
 
     def _note_spill(self, nbytes: int, dur: float):
         if _tm._ENABLED:

@@ -31,9 +31,7 @@ zero-recompile serving workload:
 
 Telemetry rides the bounded ``tenant=`` label through the module-level
 ``_note_*`` hooks below — they gate on ``telemetry._ENABLED`` (the
-observability cost contract, enforced by tests/test_telemetry_lint.py)
-and double as the ``optimizer_bench --telemetry-overhead`` B-side
-no-op targets.
+observability cost contract, enforced by tests/test_telemetry_lint.py).
 """
 from __future__ import annotations
 
@@ -65,8 +63,7 @@ def priority_rank(priority: Optional[str]) -> int:
 
 
 # -- telemetry hooks ---------------------------------------------------------
-# Module-level so `optimizer_bench --telemetry-overhead` can no-op them
-# on the B side; each gates on the module flag per the cost contract.
+# Each gates on the module flag per the cost contract.
 
 def _note_adapter(event: str, name: str):
     """Adapter lifecycle counter: event in {load, evict, update}."""

@@ -45,8 +45,8 @@ scan), with two backends:
   a member dies, so this backend suits drain/rolling-restart flows,
   not SIGKILL failover.
 - `FileKV` — the same semantics over a shared directory with
-  atomic-rename writes: kill-tolerant, so the SIGKILL fleet tests and
-  `decode_bench --fleet` ride it.
+  atomic-rename writes: kill-tolerant, so the SIGKILL fleet tests
+  ride it.
 
 Fault sites (armed via `MXNET_TPU_FAULTS`, see `mxnet_tpu.faults`):
 ``replica.kill`` (worker dies after a productive tick — in-process,
@@ -59,8 +59,7 @@ discarded, exercising retry + idempotency).
 
 Worker side: `run_fleet_worker(channel, name, ...)` drives one server
 against the channel protocol; ``python -m mxnet_tpu.serving.router
---dir D --name r0`` is the subprocess entry the tests and the fleet
-bench spawn.
+--dir D --name r0`` is the subprocess entry the tests spawn.
 
 Fleet observability (telemetry-gated end to end):
 
@@ -137,8 +136,8 @@ class FileKV:
     value), `get` polls for the key up to `timeout_ms`, `dir` is a
     non-blocking prefix scan. Keys are slash-separated paths. Unlike
     the coordination service, a SIGKILLed participant takes nothing
-    else down — this is the kill-tolerant backend the fleet tests and
-    bench use."""
+    else down — this is the kill-tolerant backend the fleet tests
+    use."""
 
     def __init__(self, root: str):
         self.root = os.path.abspath(root)
@@ -2394,7 +2393,7 @@ def _worker_main(argv=None):
     Builds the model deterministically (seeded), then serves over a
     `FileKV` channel rooted at ``--dir`` until a ``stop`` command.
     ``--config`` takes LlamaConfig kwargs as JSON instead of a model
-    zoo name (the bench uses this to match its serve config)."""
+    zoo name."""
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--dir", required=True)

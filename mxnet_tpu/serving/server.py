@@ -866,14 +866,15 @@ class InferenceServer:
             admitted += 1
         return admitted
 
-    def _preempt_youngest(self, protect: int) -> bool:
-        """Free the most recently admitted running request (except
-        `protect`) back to the queue head. Returns False if there is
-        nothing to preempt."""
+    def _preempt_youngest(self, asker: int) -> bool:
+        """Free the most recently admitted running request back to the
+        queue head: `asker` itself when it is the youngest, so no
+        request is ever evicted for a younger one, the oldest always
+        runs to its end, and two requests cannot evict each other for
+        ever. Returns False if nothing but `asker` is running."""
         running = [i for i in range(self.batch_slots)
-                   if (self._active[i] or self._prefilling[i])
-                   and i != protect]
-        if not running:
+                   if self._active[i] or self._prefilling[i]]
+        if all(i == asker for i in running):
             return False
         victim = max(running, key=lambda i: self._slot_admit[i])
         req = self._slot_req[victim]
@@ -919,19 +920,19 @@ class InferenceServer:
                         if self._active[i]),
                        key=lambda i: self._slot_admit[i])
         for slot in order:
-            if not self._active[slot]:
-                # preempted by an older slot earlier in this pass —
-                # calling ensure() on it would allocate a block to an
-                # empty slot and poison its next admission
-                continue
-            while not self.cache.ensure(slot, int(self._pos[slot])):
+            # a slot evicted in this pass — by an older slot, or by
+            # itself as the youngest — asks for nothing more: ensure()
+            # on it would allocate a block to an empty slot and poison
+            # its next admission
+            while self._active[slot] and not self.cache.ensure(
+                    slot, int(self._pos[slot])):
                 if not self._preempt_youngest(slot):
                     raise RuntimeError(
                         "KV pool too small for a single sequence — "
                         "raise num_blocks or lower max_len")
             # copy-on-write: this tick's token lands in a block some
             # other slot still references
-            while True:
+            while self._active[slot]:
                 pw = self.cache.prepare_write(slot,
                                               int(self._pos[slot]))
                 if pw is False:

@@ -7,9 +7,8 @@ verify cost. The built-in proposer is self-drafting n-gram lookup
 (prompt-lookup decoding): find the most recent earlier occurrence of
 the sequence's trailing n-gram and propose the tokens that followed
 it — free, model-less, and strong on repetitive continuations
-(code, templated text, and the retrieval-heavy traffic the serving
-benchmarks model). A tiny draft MODEL plugs into the same interface:
-anything with `.k` and `.propose(tokens) -> array` works.
+(code, templated text, retrieval-heavy traffic). A tiny draft MODEL
+plugs into the same interface: anything with `.k` and `.propose(tokens) -> array` works.
 
 Contract: proposals are CANDIDATES only. The verify executable scores
 them against the real model and keeps the longest accepted prefix, so

@@ -41,7 +41,8 @@ they all publish into ONE registry:
 Cost contract: the WHOLE layer is disabled by default and near-zero
 cost while disabled — every instrumented hot path checks the single
 module-level `_ENABLED` flag before doing any dict or string work
-(benchmarks/optimizer_bench.py --telemetry-overhead asserts <= 2%).
+(tests/test_telemetry_lint.py enforces the gate; what the disabled
+layer costs a step on the chip: no cell measures this, ROADMAP D7).
 Enable with `telemetry.enable()` or MXNET_TPU_TELEMETRY=1.
 """
 from __future__ import annotations

@@ -38,8 +38,8 @@ def enable_compile_cache() -> str:
     ``<checkout>/.jax_cache`` (git-ignored): a fixed path, because the
     path is part of what a later process must reproduce to hit — never
     one built from a temporary name, a pid or the time. Entry points
-    call this (chip_smoke.py, bench.py, benchmarks/*, the serving
-    worker); importing the package does not."""
+    call this (chip_smoke.py, perfbench/run.py, the kernel tuner, the
+    serving worker); importing the package does not."""
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update(
             "jax_compilation_cache_dir",

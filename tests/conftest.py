@@ -14,6 +14,7 @@ if "--xla_force_host_platform_device_count" not in os.environ["XLA_FLAGS"]:
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -22,57 +23,22 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "slow: multi-process spawns, example smoke runs, heavy model "
-        "tests — the fast tier is `pytest -m 'not slow'` (<8 min); "
-        "the FULL suite remains the snapshot gate")
+        "tests — tier-1 is `pytest -m 'not slow'`; the FULL suite "
+        "remains the snapshot gate")
 
 
-#: Tier-1 runs inside a wall-clock box (ROADMAP "Tier-1 verify": 870 s).
-#: On the installed JAX the suite needs about 1200 s of one 8-core host,
-#: a third of it in the tests below: multi-process kill/restart gangs,
-#: subprocess fleets, the pipeline / plan / ZeRO parity grids that run
-#: again since PR 21 instead of raising at their first line, and the
-#: chip_smoke rehearsal (serial seconds measured by PR 21, each >= 6).
-#: They run LAST, in their usual relative order, so a boxed run reports
-#: on the thousand cheap tests first and spends what is left on these.
-#: An unboxed run executes exactly the same tests.
-_HEAVY_LAST = {
-    "tests/test_anomaly.py::test_subprocess_canary_rollback_on_degraded_worker",
-    "tests/test_checkpoint.py::test_kill_restart_sgd_bitexact",
-    "tests/test_checkpoint.py::test_kill_restart_zero2_elastic_shards",
-    "tests/test_chip_smoke.py::test_compile_cache_can_be_placed_from_outside",
-    "tests/test_chip_smoke.py::test_kernels_phase_tiny",
-    "tests/test_chip_smoke.py::test_train_serve_multichip_phases_tiny",
-    "tests/test_models.py::test_llama_backward_grads_flow_every_param",
-    "tests/test_multi_tensor.py::test_compressed_psum_tree_bucketed_matches_leafwise_2bit",
-    "tests/test_pipeline.py::test_1f1b_bf16_keeps_loss_and_cotangent_dtype",
-    "tests/test_pipeline.py::test_fuzz_1f1b_equals_sequential",
-    "tests/test_pipeline.py::test_fuzz_gpipe_equals_sequential",
-    "tests/test_pipeline.py::test_gpipe_grad_matches",
-    "tests/test_plan.py::test_plan_grid_core",
-    "tests/test_quantization.py::test_quantize_net_on_hybridized_net",
-    "tests/test_rnn_op.py::test_rnn_shapes_and_grad",
-    "tests/test_router.py::test_fleet_local_token_parity_both_replicas",
-    "tests/test_router.py::test_fleet_subprocess_failover_trace_and_metrics",
-    "tests/test_serving.py::test_fleet_subprocess_kill_failover_zero_lost",
-    "tests/test_tp_sp.py::test_ring_attention_exact",
-    "tests/test_tp_sp.py::test_ulysses_attention_exact",
-    "tests/test_tpu_lowering.py::test_bert_forward_with_flash_lengths_lowers",
-    "tests/test_train_loop.py::test_parity_matrix",
-    "tests/test_train_loop.py::test_sigkill_resume_on_k_boundary",
-    "tests/test_wire_collectives.py::test_eager_weight_gather_parity",
-    "tests/test_wire_collectives.py::test_fused_weight_gather_parity",
-    "tests/test_zero23.py::test_fused_zero23_composes_with_compression",
-    "tests/test_zero23.py::test_fused_zero23_matches_unsharded",
-    "tests/test_zero23.py::test_zero_resident_bytes_shrink",
-}
-
-
-def pytest_collection_modifyitems(config, items):
-    heavy = [it for it in items
-             if it.nodeid.split("[")[0] in _HEAVY_LAST]
-    if heavy:
-        ids = {id(it) for it in heavy}
-        items[:] = [it for it in items if id(it) not in ids] + heavy
+@pytest.fixture(scope="session", autouse=True)
+def _flight_bundles_out_of_the_checkout(tmp_path_factory):
+    """Flight dumps and fleet bundles default to the working directory
+    (`MXNET_TPU_FLIGHT_DIR` is the deployment's setting). A test that
+    sets neither a directory nor the variable would leave them in the
+    checkout, so the session points the variable at a temp directory;
+    a test's own `monkeypatch.setenv` still wins."""
+    with pytest.MonkeyPatch.context() as mp:
+        if "MXNET_TPU_FLIGHT_DIR" not in os.environ:
+            mp.setenv("MXNET_TPU_FLIGHT_DIR",
+                      str(tmp_path_factory.mktemp("flight")))
+        yield
 
 
 # tier-1 regression floor: a FULL-suite run (anything that collected at

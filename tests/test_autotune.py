@@ -56,10 +56,9 @@ def test_norm_kernel_consults_tuning():
 
 def test_sweeps_run_on_cpu_interpret():
     import autotune_kernels as at
-    from bench import BudgetGuard
 
-    at._guard = BudgetGuard("autotune_kernels", "families",
-                            budget_s=600.0)
+    at._guard = at.BudgetGuard("autotune_kernels", "families",
+                               budget_s=600.0)
     res, win = at.sweep_norm(False, True)
     assert win is not None and "row_block_want" in win
     assert all("ms" in r for r in res["rows"])

@@ -5,7 +5,6 @@ TPU, the compile cache can be placed from outside, the paged kernels
 pass the installed Pallas's compiler params, `mx.tpu()` does not
 quietly mean the host, a kernel failure on a TPU backend raises, and a
 Pallas call under a multi-device jit runs per shard."""
-import json
 import os
 import subprocess
 import sys
@@ -200,21 +199,3 @@ def test_pallas_under_multi_device_jit_runs_per_shard(interpret, tp, H, K,
         fa._pallas_forward = real
     assert seen == [(B, T, H, d), (B // 2, T, local_heads, d)]
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.slow
-def test_decode_bench_runs_in_the_calling_process():
-    """decode_bench takes the platform JAX gives its own process (here
-    the CPU, so its numbers are pipeline checks only) and emits one
-    well-formed JSON line with both cache variants."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_BUDGET_S="240")
-    out = subprocess.run(
-        [sys.executable,
-         os.path.join(_REPO, "benchmarks", "decode_bench.py")],
-        capture_output=True, text=True, timeout=300, env=env)
-    lines = [l for l in out.stdout.splitlines() if l.startswith("{")]
-    assert lines, out.stderr[-2000:]
-    d = json.loads(lines[-1])
-    assert d["metric"] == "llama_decode_tokens_per_sec"
-    assert d["value"] > 0, d
-    assert d["tokens_per_sec_int8_cache"] > 0, d

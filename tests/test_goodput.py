@@ -430,95 +430,6 @@ def test_parked_blocks_counts_registered_free_blocks():
     c.check()
 
 
-# -- regression sentinel ----------------------------------------------------
-
-def test_check_metrics_directions():
-    # lower-is-better metric regressing
-    v = goodput.check_metrics({"step_ms": 12.0}, {"step_ms": [10.0]})
-    assert not v["ok"] and v["regressions"][0]["metric"] == "step_ms"
-    assert v["regressions"][0]["direction"] == "lower_is_better"
-    # higher-is-better metric regressing
-    v = goodput.check_metrics({"speedup": 1.0}, {"speedup": [2.0]})
-    assert not v["ok"]
-    # within tolerance
-    v = goodput.check_metrics({"step_ms": 10.5}, {"step_ms": [10.0]})
-    assert v["ok"] and v["compared"] == 1
-    # no history for the metric: skipped, not failed
-    v = goodput.check_metrics({"brand_new": 1.0}, {})
-    assert v["ok"] and v["compared"] == 0
-
-
-def test_check_metrics_interleaved_bench_directions():
-    """The interleaved-pipeline bench gauges must be sentinel-correct:
-    the headline contains 'speedup' (higher-better) and the bubble
-    keys end in '_ratio' (lower-better), so a regression in either
-    direction gates `goodput check` over BENCH_*.json history."""
-    v = goodput.check_metrics(
-        {"pipeline_interleaved_bubble_speedup": 1.0},
-        {"pipeline_interleaved_bubble_speedup": [1.7]})
-    assert not v["ok"]
-    assert v["regressions"][0]["direction"] == "higher_is_better"
-    v = goodput.check_metrics(
-        {"interleaved_bubble_ratio": 0.27, "baseline_bubble_ratio": 0.27},
-        {"interleaved_bubble_ratio": [0.158],
-         "baseline_bubble_ratio": [0.273]})
-    assert not v["ok"] and len(v["regressions"]) == 1
-    assert v["regressions"][0]["metric"] == "interleaved_bubble_ratio"
-    assert v["regressions"][0]["direction"] == "lower_is_better"
-    # at-history values pass both directions
-    v = goodput.check_metrics(
-        {"pipeline_interleaved_bubble_speedup": 1.72,
-         "interleaved_bubble_ratio": 0.158},
-        {"pipeline_interleaved_bubble_speedup": [1.7],
-         "interleaved_bubble_ratio": [0.158]})
-    assert v["ok"] and v["compared"] == 2
-
-
-def _bench_record(n, metric, value):
-    return {"n": n, "cmd": "python bench.py", "rc": 0,
-            "tail": "", "parsed": {"metric": metric, "value": value,
-                                   "unit": "ms"}}
-
-
-def test_sentinel_cli_over_bench_trajectory(tmp_path, capsys):
-    d = tmp_path
-    (d / "BENCH_r01.json").write_text(
-        json.dumps(_bench_record(1, "decode_step_ms", 10.0)))
-    (d / "BENCH_r02.json").write_text(
-        json.dumps(_bench_record(2, "decode_step_ms", 10.5)))
-    assert goodput.main(["check", "--dir", str(d)]) == 0
-    # a >10% regression in the newest record gates
-    (d / "BENCH_r03.json").write_text(
-        json.dumps(_bench_record(3, "decode_step_ms", 15.0)))
-    assert goodput.main(["check", "--dir", str(d)]) == 1
-    # a looser tolerance waves it through
-    assert goodput.main(["check", "--dir", str(d),
-                         "--tolerance", "0.6"]) == 0
-    capsys.readouterr()
-
-
-def test_sentinel_cli_too_little_history_is_not_an_error(tmp_path,
-                                                         capsys):
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps(_bench_record(1, "x_ms", 1.0)))
-    assert goodput.main(["check", "--dir", str(tmp_path)]) == 0
-    assert "nothing to compare" in capsys.readouterr().out
-
-
-def test_sentinel_parses_tail_metric_lines(tmp_path):
-    rec = {"n": 1, "cmd": "c", "rc": 0, "parsed": None,
-           "tail": 'noise\n{"metric": "tok_per_s", "value": 100.0}\n'}
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(rec))
-    hist = goodput.load_bench_history(str(tmp_path))
-    assert hist[0][2] == {"tok_per_s": 100.0}
-    v = goodput.check_against_history({"tok_per_s": 120.0},
-                                      str(tmp_path))
-    assert v["ok"] and v["compared"] == 1
-    v = goodput.check_against_history({"tok_per_s": 50.0},
-                                      str(tmp_path))
-    assert not v["ok"]
-
-
 # -- SIGKILL + restart: badput attribution survives the process -------------
 
 GOODPUT_WORKER = textwrap.dedent("""

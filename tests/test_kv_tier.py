@@ -372,7 +372,7 @@ def test_tier_transition_fuzz_token_identical(net, tmp_path):
         if op == 0:
             s.tier.spill_parked(int(rs.randint(1, 6)))
         elif op == 1:
-            s._preempt_youngest(protect=-1)  # spill-preempt path
+            s._preempt_youngest(-1)  # spill-preempt path
         elif op == 2:                      # simulated SIGKILL restart
             s.persist_prefixes()
             s = mk()

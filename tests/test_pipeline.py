@@ -262,6 +262,29 @@ def test_bubble_math_helpers():
     assert stash_slots(1) == 1
 
 
+def test_1f1b_stash_is_bounded_by_stages_not_microbatches(pp_mesh):
+    """The stash claim as a count: the compiled 1F1B step keeps 2n-1
+    stage inputs where GPipe under plain reverse-mode AD keeps all M,
+    so at n=4, M=16 its temporaries (`memory_analysis()`, bytes) are
+    under half of GPipe's; the slot count alone says 16/7."""
+    n, M, mb, d = 4, 16, 8, 64
+    params = _make_params(n, d, d)
+    x = jax.ShapeDtypeStruct((M * mb, d), jnp.float32)
+
+    def f1b(p, x_, y_):
+        return one_f_one_b(_stage_fn, p, x_, y_, _mse_loss, M,
+                           mesh=pp_mesh)
+
+    def gpipe_ad(p, x_, y_):
+        return jax.grad(lambda q: _mse_loss(
+            gpipe(_stage_fn, q, x_, M, mesh=pp_mesh), y_))(p)
+
+    temp = [jax.jit(f).lower(params, x, x).compile()
+            .memory_analysis().temp_size_in_bytes
+            for f in (f1b, gpipe_ad)]
+    assert 0 < 2 * temp[0] <= temp[1], temp
+
+
 # -- auto-staging a HybridSequential ----------------------------------------
 
 def _dense_chain(n_blocks, d=8, seed=0):

@@ -243,10 +243,9 @@ def test_flash_decode_quantized_lowers():
 
 
 def test_bert_forward_with_flash_lengths_lowers():
-    """The on-chip bench's BERT phase feeds ragged valid_length so the
+    """The benchmark's BERT cells feed ragged valid_length so the
     flash kernel's key-padding path engages — prove THAT exact forward
-    lowers for TPU before chip time is spent on it (bench.py
-    _bert_phase)."""
+    lowers for TPU before chip time is spent on it."""
     import mxnet_tpu as mx
     from mxnet_tpu.models.bert import BERTForPretraining
 
