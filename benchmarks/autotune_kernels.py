@@ -7,7 +7,8 @@ available and emits a JSON table; with --write the winners land in
 `mxnet_tpu/kernels/tuned.json`, which `kernels/tuning.py` serves to
 the kernel modules at trace time. Sweep space:
 
-- flash attention fwd + bwd: block_q x block_k in {128, 256, 512}
+- flash attention fwd + merged bwd: block_q x block_k in {128, 256,
+  512, 1024} at the train cell's shape and at a prefill's
 - fused RMSNorm: row_block_want in {128, 256, 512, 1024}
 - fused softmax-CE: row_block_want in {64, 128, 256, 512}
 - flash decode: Pallas-vs-reference speedup across cache sizes S;
@@ -109,100 +110,92 @@ def _diff_time(run_chain, lo, hi):
 
 
 def sweep_flash_attention(on_tpu, interpret):
+    """block_q x block_k of the forward and of the merged backward at
+    the two shapes the benchmark's cells run: the train step's (BERT:
+    64 x 512, 12 heads of 64, not causal, lengths 256-512; forward and
+    backward) and a prefill's (2,048 positions, 32 / 8 heads of 128,
+    causal; forward only). The winner has the least sum of times, each
+    over the best of its row, so neither shape outweighs the other.
+    Heads a grid step are not swept: beyond the two that fill the
+    lanes they changed nothing on a v5e (tuned.json's note, PR 31)."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from mxnet_tpu.kernels import flash_attention as fa
 
     if on_tpu:
-        B, H, T, d, dtype = 4, 16, 2048, 64, jnp.bfloat16
-        lo, hi = 3, 9
-        cands = [128, 256, 512]
+        shapes = {"train": (64, 512, 12, 12, 64, False, True),
+                  "prefill": (1, 2048, 32, 8, 128, True, False)}
+        dtype, lo, hi = jnp.bfloat16, 3, 9
+        cands = [128, 256, 512, 1024]
     else:
-        B, H, T, d, dtype = 1, 2, 256, 32, jnp.float32
-        lo, hi = 1, 2
+        shapes = {"train": (2, 256, 2, 2, 64, False, True),
+                  "prefill": (1, 256, 4, 2, 128, True, False)}
+        dtype, lo, hi = jnp.float32, 1, 2
         cands = [128, 256]
-    key = jax.random.PRNGKey(0)
-    kq, kk, kv = jax.random.split(key, 3)
-    q = (jax.random.normal(kq, (B, T, H, d)) * 0.1).astype(dtype)
-    k = (jax.random.normal(kk, (B, T, H, d)) * 0.1).astype(dtype)
-    v = (jax.random.normal(kv, (B, T, H, d)) * 0.1).astype(dtype)
-    scale = 1.0 / (d ** 0.5)
 
-    fwd_rows, bwd_rows = [], []
-    # center-out order: the incumbent default first, so a budget cutoff
-    # still records a line for the committed configuration
-    combos = sorted(((bq, bk) for bq in cands for bk in cands),
-                    key=lambda c: (c != (256, 256), c))
-    for bq, bk in combos:
-        if _remaining() < 30.0:
-            break
-        f = jax.jit(functools.partial(
-            fa._pallas_forward, causal=True, scale=scale, block_q=bq,
-            block_k=bk, interpret=interpret))
-
+    def timed(fn, args):
         def chain(iters):
             t0 = time.perf_counter()
-            c = q
+            acc = None
             for _ in range(iters):
-                c = f(c, k, v)  # out shape == q shape: true chain
-            float(jnp.sum(c.astype(jnp.float32)))
+                s = jnp.sum(fn(*args)[0].astype(jnp.float32))
+                acc = s if acc is None else acc + s
+            float(acc)
             return time.perf_counter() - t0
 
-        try:
-            chain(1)  # compile
-            s_it = _diff_time(chain, lo, hi)
-            fwd_rows.append({"block_q": bq, "block_k": bk,
-                             "ms": round(s_it * 1e3, 3)})
-        except Exception as e:
-            fwd_rows.append({"block_q": bq, "block_k": bk,
-                             "error": f"{type(e).__name__}"[:60]})
+        chain(1)  # compile
+        return round(_diff_time(chain, lo, hi) * 1e3, 3)
 
-    # backward: reuse one forward's lse/delta, accumulate dq checksums
-    try:
-        out, lse = fa._pallas_forward(q, k, v, True, scale,
+    res, score = {}, {}
+    for label, (B, T, H, K, d, causal, bwd) in shapes.items():
+        kq, kk, kv, kg = jax.random.split(jax.random.PRNGKey(0), 4)
+        q = (jax.random.normal(kq, (B, T, H, d)) * 0.5).astype(dtype)
+        k = (jax.random.normal(kk, (B, T, K, d)) * 0.5).astype(dtype)
+        v = (jax.random.normal(kv, (B, T, K, d)) * 0.5).astype(dtype)
+        g = (jax.random.normal(kg, (B, T, H, d)) * 0.5).astype(dtype)
+        lengths = jnp.asarray(np.linspace(T // 2, T, B).astype(np.int32))
+        scale = 1.0 / (d ** 0.5)
+        out, lse = fa._pallas_forward(q, k, v, causal, scale,
                                       interpret=interpret,
-                                      return_lse=True)
-        dout = jnp.ones_like(out)
-        delta = jnp.sum(dout.astype(jnp.float32)
-                        * out.astype(jnp.float32),
-                        axis=-1).transpose(0, 2, 1)  # (B, H, T)
+                                      return_lse=True, lengths=lengths)
+        delta = fa._rowsum_per_head(g, out)
+        rows = {"fwd": [], "bwd": []} if bwd else {"fwd": []}
+        # the committed blocks first, so a budget cutoff still records
+        # a line for them
+        combos = sorted(((bq, bk) for bq in cands for bk in cands
+                         if bq <= T and bk <= T),
+                        key=lambda c: (c != (512, 512), c))
         for bq, bk in combos:
             if _remaining() < 30.0:
                 break
-            fb = jax.jit(functools.partial(
-                fa._pallas_backward, causal=True, scale=scale,
-                block_q=bq, block_k=bk, interpret=interpret))
-
-            def chain_b(iters):
-                t0 = time.perf_counter()
-                acc = None
-                for _ in range(iters):
-                    dq, dk, dv = fb(q, k, v, lse, delta, dout)
-                    s = jnp.sum(dq.astype(jnp.float32))
-                    acc = s if acc is None else acc + s
-                float(acc)
-                return time.perf_counter() - t0
-
-            try:
-                chain_b(1)
-                s_it = _diff_time(chain_b, lo, hi)
-                bwd_rows.append({"block_q": bq, "block_k": bk,
-                                 "ms": round(s_it * 1e3, 3)})
-            except Exception as e:
-                bwd_rows.append({"block_q": bq, "block_k": bk,
-                                 "error": f"{type(e).__name__}"[:60]})
-    except Exception as e:
-        bwd_rows.append({"error": f"{type(e).__name__}: {e}"[:120]})
-
-    timed = [r for r in fwd_rows if "ms" in r]
-    winner = min(timed, key=lambda r: r["ms"]) if timed else None
-    # fwd sets the tuned block (bwd shares the constants); a combined
-    # score would double-count the fwd-heavy inference path
-    res = {"shape": [B, T, H, d], "fwd": fwd_rows, "bwd": bwd_rows}
-    win = ({"block_q": winner["block_q"], "block_k": winner["block_k"]}
-           if winner else None)
-    return res, win
+            kw = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
+                      interpret=interpret, lengths=lengths)
+            calls = {"fwd": (functools.partial(
+                fa._pallas_forward, return_lse=True, **kw), (q, k, v)),
+                "bwd": (functools.partial(fa._pallas_backward, **kw),
+                        (q, k, v, lse, delta, g))}
+            for kind in rows:
+                row = {"block_q": bq, "block_k": bk}
+                try:
+                    row["ms"] = timed(*calls[kind])
+                except Exception as e:
+                    row["error"] = f"{type(e).__name__}"[:60]
+                rows[kind].append(row)
+        res[label] = {"shape": [B, T, H, K, d], "causal": causal, **rows}
+        for kind, rs in rows.items():
+            ok = [r for r in rs if "ms" in r]
+            best = min((r["ms"] for r in ok), default=None)
+            for r in ok:
+                key = (r["block_q"], r["block_k"])
+                score.setdefault(key, []).append(r["ms"] / best)
+    full = max((len(v) for v in score.values()), default=0)
+    done = {c: sum(v) for c, v in score.items() if len(v) == full}
+    if not done:
+        return res, None
+    bq, bk = min(done, key=done.get)
+    return res, {"block_q": bq, "block_k": bk}
 
 
 def sweep_norm(on_tpu, interpret):

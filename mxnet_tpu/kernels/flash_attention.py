@@ -1,12 +1,18 @@
 """Causal (flash) attention.
 
 Reference analogue: the fork's fused multi-head attention CUDA kernels
-(interleaved_matmul_selfatt*, fmha). TPU-first: a Pallas kernel tiles
-Q/K/V blocks through VMEM with an online-softmax accumulator; the jnp
-reference path is used for backward (recompute) and on CPU.
+(interleaved_matmul_selfatt*, fmha). TPU-first: Pallas kernels tile
+Q/K/V blocks through VMEM with an online-softmax accumulator, forward
+(`flash_attention_fwd`) and backward (`flash_attention_dkv`: dQ, dK and
+dV of one recomputation against the saved log-sum-exp). The jnp
+reference is the CPU path, the tests' yardstick and the differentiable
+path of a sliding window.
 
 Layout convention: (B, T, H, d) for q, (B, T, K, d) for k/v with GQA
-(H % K == 0). Output (B, T, H, d).
+(H % K == 0). Output (B, T, H, d). The kernels read and write the
+free reshape (B, T, H * d) of it — where the projections leave the
+activations — in column blocks of whole lane tiles (two heads of 64,
+one of 128), so nothing is transposed or lane-padded round a call.
 """
 from __future__ import annotations
 
@@ -73,91 +79,141 @@ def _pick_block(T, want):
     return b
 
 
-def _mask_causal(s, qi, ki, block_q, block_k):
-    """-inf upper-triangle mask for score block (qi, ki)."""
-    qpos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    kpos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    return jnp.where(qpos >= kpos, s, -jnp.inf)
+def _tuned_block(T, key, want, interpret):
+    """`want` rows (the tuning table's for the platform when None) cut
+    to a divisor of T."""
+    if want is None:
+        want = tuning.get("flash_attention", key,
+                          "cpu" if interpret else "tpu")
+    return _pick_block(T, want)
 
 
-def _mask_lengths(s, ki, block_k, len_b):
-    """-inf for key positions >= len_b in score block column ki."""
-    kpos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)
-    return jnp.where(kpos < len_b, s, -jnp.inf)
+def _heads_per_step(H, K, d):
+    """(G, Gk): how many query heads and kv heads one grid step holds.
+
+    The kernels block the activations as the model keeps them,
+    (B, T, H * d), in column blocks of G heads. Mosaic wants a block's
+    lane width a multiple of 128 or the whole array's: G is the fewest
+    heads that fill whole lane tiles (2 heads of 64, 1 of 128) and
+    whose kv heads do too, else every head (a width the array spans).
+    Query head j of a step reads kv head j // rep of the step's kv
+    block when the step holds whole GQA groups, else the block's one
+    head."""
+    rep = H // K
+    for G in range(1, H):
+        if H % G or (G * d) % 128:
+            continue
+        if G % rep == 0:
+            Gk = G // rep
+        elif rep % G == 0:
+            Gk = 1
+        else:
+            continue
+        if (Gk * d) % 128 == 0:
+            return G, Gk
+    return H, K
 
 
-def _mask_window(s, qi, ki, block_q, block_k, window):
-    """-inf for keys at or beyond `window` positions behind the query
-    in score block (qi, ki)."""
-    qpos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    kpos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    return jnp.where(qpos - kpos < window, s, -jnp.inf)
+def _head_cols(j, d, G, rep):
+    """Lane slices of query head j of a step in its q / o block and of
+    its kv head in the step's k / v block (`_heads_per_step`)."""
+    kv = j // rep if G % rep == 0 else 0
+    return slice(j * d, (j + 1) * d), slice(kv * d, (kv + 1) * d)
 
 
+# dot_general dimension numbers on 2-D operands
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _dot(a, b, dims=_NN):
+    """MXU product of the operands AS STORED (bf16 in, no upcast: the
+    MXU rounds float32 operands to one bf16 pass anyway, PERF.md PR 25)
+    accumulated in float32."""
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _keep_mask(qi, ki, block_q, block_k, causal, window, len_b):
+    """Which scores of block (key block ki, query block qi) stay, in
+    the kernels' layout: keys down the sublanes, queries along the
+    lanes. None when nothing is masked."""
+    if not causal and len_b is None:
+        return None
+    shape = (block_k, block_q)
+    kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    keep = None
+    if causal:
+        qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        keep = qpos >= kpos
+        if window is not None:
+            keep = jnp.logical_and(keep, qpos - kpos < window)
+    if len_b is not None:
+        valid = kpos < len_b
+        keep = valid if keep is None else jnp.logical_and(keep, valid)
+    return keep
+
+
+def _rows(i, block):
+    """Slice of block i; tells Mosaic a traced start is aligned."""
+    from jax.experimental import pallas as pl
+
+    start = i * block
+    if not isinstance(start, int):
+        start = pl.multiple_of(start, block)
+    return pl.ds(start, block)
+
+
+def _compiler_params(interpret, resident_bytes):
+    """The grid's last axis carries state (resident blocks, the dq
+    accumulator); VMEM is asked for by what a step holds — its blocks
+    double-buffered plus the float32 score tiles — not left at the
+    16 MiB default a whole-sequence K/V block already fills."""
+    if interpret:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+    limit = min(max(32 << 20, resident_bytes * 5 // 4), 100 << 20)
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=limit)}
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret", "return_lse",
+    "window"))
 def _pallas_forward(q, k, v, causal, scale, block_q=None, block_k=None,
                     interpret=False, return_lse=False, lengths=None,
                     window=None):
-    has_len = lengths is not None
-    plat = "cpu" if interpret else "tpu"
-    if block_q is None:
-        block_q = tuning.get("flash_attention", "block_q", plat)
-    if block_k is None:
-        block_k = tuning.get("flash_attention", "block_k", plat)
     """Online-softmax flash forward in Pallas (TPU; interpret=True runs
     the same kernel under the Pallas interpreter for CPU testing).
 
-    Internally the kernel works on (B, H, T, d) — Mosaic requires the
-    LAST TWO block dims be (8k, 128k) or span the array, which the
-    public (B, T, H, d) layout cannot satisfy when blocking one head.
-    Per-row log-sum-exp travels as (B, H, T, 1) for the same reason and
-    is returned squeezed to (B, H, T) when return_lse=True."""
+    The call reads q / k / v and writes o where the model keeps them:
+    (B, T, H * d), a free reshape of (B, T, H, d), in column blocks of
+    `_heads_per_step` heads — no transpose round the call and no lane
+    padding at heads of 64. Inside, scores are held TRANSPOSED (keys
+    down the sublanes, queries along the lanes), so the running max,
+    the sum and the log-sum-exp are lane-dense rows: lse leaves as
+    (B, H // G, G, T) and is returned as (B, H, T) when
+    return_lse=True. A jit of its own: a program's layers share one
+    trace of the unrolled head loop."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
+    has_len = lengths is not None
     B, T, H, d = q.shape
     Kh = k.shape[2]
     rep = H // Kh
-    block_q = _pick_block(T, block_q)
-    block_k = _pick_block(T, block_k)
-    n_q = T // block_q
+    G, Gk = _heads_per_step(H, Kh, d)
+    block_q = _tuned_block(T, "block_q", block_q, interpret)
+    block_k = _tuned_block(T, "block_k", block_k, interpret)
+    n_q, n_k = T // block_q, T // block_k
 
     def kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref):
-        # grid: (B, H, n_q). Block of Q rows vs full K/V sweep.
+        # grid: (B, H // G, n_q). G heads of a block of Q rows, each
+        # against a sweep of its kv head's resident K/V.
         qi = pl.program_id(2)
-        len_b = lens_ref[pl.program_id(0)]
-        qblk = q_ref[...].astype(jnp.float32) * scale  # (block_q, d)
-        m = jnp.full((block_q,), -jnp.inf, jnp.float32)
-        l = jnp.zeros((block_q,), jnp.float32)
-        acc = jnp.zeros((block_q, d), jnp.float32)
-        n_k = T // block_k
-
-        def body(ki, carry):
-            m_, l_, acc_ = carry
-            kblk = k_ref[pl.dslice(ki * block_k, block_k), :] \
-                .astype(jnp.float32)
-            vblk = v_ref[pl.dslice(ki * block_k, block_k), :] \
-                .astype(jnp.float32)
-            s = qblk @ kblk.T  # (block_q, block_k)
-            if causal:
-                s = _mask_causal(s, qi, ki, block_q, block_k)
-            if window is not None:
-                s = _mask_window(s, qi, ki, block_q, block_k, window)
-            if has_len:
-                s = _mask_lengths(s, ki, block_k, len_b)
-            m_new = jnp.maximum(m_, jnp.max(s, axis=-1))
-            p = jnp.exp(s - m_new[:, None])
-            # "row has seen a valid key" == running max left -inf; spelled
-            # as a comparison because Mosaic has no is_finite lowering
-            p = jnp.where((m_new > -jnp.inf)[:, None], p, 0.0)
-            corr = jnp.where(m_ > -jnp.inf, jnp.exp(m_ - m_new), 0.0)
-            l_new = corr * l_ + jnp.sum(p, axis=-1)
-            acc_new = corr[:, None] * acc_ + p @ vblk
-            return m_new, l_new, acc_new
-
+        len_b = lens_ref[pl.program_id(0)] if has_len else None
         if causal:
             upper = jnp.minimum(
                 n_k, ((qi + 1) * block_q + block_k - 1) // block_k)
@@ -170,221 +226,211 @@ def _pallas_forward(q, k, v, causal, scale, block_q=None, block_k=None,
         if has_len:
             # key blocks past lengths[b] are fully masked: skip them
             upper = jnp.minimum(upper, (len_b + block_k - 1) // block_k)
-        m, l, acc = jax.lax.fori_loop(lower, upper, body, (m, l, acc))
-        safe_l = jnp.where(l > 0, l, 1.0)
-        o_ref[...] = (acc / safe_l[:, None]).astype(o_ref.dtype)
-        # rows with no unmasked keys get lse=+inf so exp(s - lse) == 0
-        # in the backward (cannot happen for full causal blocks, but
-        # keeps the kernel total for arbitrary masks)
-        lse_ref[...] = jnp.where(l > 0, m + jnp.log(safe_l),
-                                 jnp.inf)[:, None]
 
-    from jax.experimental.pallas import tpu as pltpu
+        for j in range(G):
+            cols, kcols = _head_cols(j, d, G, rep)
+            # the scale folds into q once (float32 product, one
+            # rounding to the stored type: what the MXU sees)
+            qh = (q_ref[:, cols].astype(jnp.float32) * scale) \
+                .astype(q_ref.dtype)                     # (block_q, d)
 
-    qt = q.transpose(0, 2, 1, 3)          # (B, H, T, d)
-    kt = k.transpose(0, 2, 1, 3)          # (B, Kh, T, d)
-    vt = v.transpose(0, 2, 1, 3)
+            def body(ki, carry, qh=qh, kcols=kcols):
+                m_, l_, acc_ = carry       # (1, bq), (1, bq), (d, bq)
+                kblk = k_ref[_rows(ki, block_k), kcols]
+                vblk = v_ref[_rows(ki, block_k), kcols]
+                s = _dot(kblk, qh, _NT)                  # (block_k, bq)
+                keep = _keep_mask(qi, ki, block_q, block_k, causal,
+                                  window, len_b)
+                if keep is not None:
+                    s = jnp.where(keep, s, -jnp.inf)
+                m_new = jnp.maximum(m_, jnp.max(s, axis=0, keepdims=True))
+                # a query that has seen no valid key keeps max -inf:
+                # subtracting 0 there leaves exp(-inf) = 0, no NaN
+                # (a comparison: Mosaic has no is_finite lowering)
+                m_safe = jnp.where(m_new > -jnp.inf, m_new, 0.0)
+                p = jnp.exp(s - m_safe)
+                corr = jnp.exp(m_ - m_safe)
+                l_new = corr * l_ + jnp.sum(p, axis=0, keepdims=True)
+                acc_new = corr * acc_ + _dot(vblk, p.astype(vblk.dtype),
+                                             _TN)       # (d, bq)
+                return m_new, l_new, acc_new
+
+            init = (jnp.full((1, block_q), -jnp.inf, jnp.float32),
+                    jnp.zeros((1, block_q), jnp.float32),
+                    jnp.zeros((d, block_q), jnp.float32))
+            if n_k == 1:    # a whole-sequence key block: no loop
+                m, l, acc = body(0, init)
+            else:
+                m, l, acc = jax.lax.fori_loop(lower, upper, body, init)
+            safe_l = jnp.where(l > 0, l, 1.0)
+            o_ref[:, cols] = (acc * (1.0 / safe_l)).T.astype(o_ref.dtype)
+            # rows with no unmasked keys get lse=+inf so exp(s - lse)
+            # == 0 in the backward (cannot happen for full causal
+            # blocks, but keeps the kernel total for arbitrary masks)
+            lse_ref[j:j + 1, :] = jnp.where(l > 0, m + jnp.log(safe_l),
+                                            jnp.inf)
+
     if lengths is None:  # static no-padding case: kernels skip the
         lengths = jnp.full((B,), T, jnp.int32)  # mask entirely
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, H, n_q),
-        in_specs=[
-            pl.BlockSpec((None, None, block_q, d),
-                         lambda b, h, i, lens: (b, h, i, 0)),
-            pl.BlockSpec((None, None, T, d),
-                         lambda b, h, i, lens: (b, h // rep, 0, 0)),
-            pl.BlockSpec((None, None, T, d),
-                         lambda b, h, i, lens: (b, h // rep, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, None, block_q, d),
-                         lambda b, h, i, lens: (b, h, i, 0)),
-            pl.BlockSpec((None, None, block_q, 1),
-                         lambda b, h, i, lens: (b, h, i, 0)),
-        ],
-    )
+    qspec = pl.BlockSpec((None, block_q, G * d),
+                         lambda b, g, i, lens: (b, i, g))
+    kvspec = pl.BlockSpec((None, T, Gk * d),
+                          lambda b, g, i, lens: (b, 0, g * G // rep // Gk))
+    item = q.dtype.itemsize
+    resident = 2 * item * (2 * block_q * G * d + 2 * T * Gk * d) \
+        + 6 * 4 * block_q * block_k
     out, lse = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H // G, n_q),
+            in_specs=[qspec, kvspec, kvspec],
+            out_specs=[qspec,
+                       pl.BlockSpec((None, None, G, block_q),
+                                    lambda b, g, i, lens: (b, g, 0, i))]),
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, T, d), q.dtype),
-            jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, T, H * d), q.dtype),
+            jax.ShapeDtypeStruct((B, H // G, G, T), jnp.float32),
         ],
         interpret=interpret,
         name="flash_attention_fwd",
-    )(lengths.astype(jnp.int32), qt, kt, vt)
-    out = out.transpose(0, 2, 1, 3)       # back to (B, T, H, d)
-    return (out, lse[..., 0]) if return_lse else out
+        **_compiler_params(interpret, resident),
+    )(lengths.astype(jnp.int32), q.reshape(B, T, H * d),
+      k.reshape(B, T, Kh * d), v.reshape(B, T, Kh * d))
+    out = out.reshape(B, T, H, d)
+    return (out, lse.reshape(B, H, T)) if return_lse else out
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret"))
 def _pallas_backward(q, k, v, lse, delta, dout, causal, scale,
                      block_q=None, block_k=None, interpret=False,
                      lengths=None):
-    has_len = lengths is not None
-    plat = "cpu" if interpret else "tpu"
-    if block_q is None:
-        block_q = tuning.get("flash_attention", "block_q", plat)
-    if block_k is None:
-        block_k = tuning.get("flash_attention", "block_k", plat)
     """O(T)-memory flash backward: dQ/dK/dV via block recomputation
     against the saved log-sum-exp — no (T, T) score matrix is ever
-    materialized. delta is rowsum(dO * O), shape (B, H, T).
+    materialized. lse and delta = rowsum(dO * O) are (B, H, T).
 
-    dq kernel: one Q block vs a K/V sweep (same walk as forward).
-    dkv kernel: one K block vs a Q sweep, per *query* head; the GQA
-    group-sum over the rep query heads per kv head happens outside."""
+    ONE call (`flash_attention_dkv`) on the forward's layout: a grid
+    step holds a K/V block of G heads and sweeps the Q blocks, deriving
+    the transposed scores and dP once for dV, dK and dQ (5 matmuls
+    where a dq and a dkv call made 7). dK/dV come per *query* head; the
+    GQA group-sum over the rep query heads per kv head happens
+    outside. dQ of a query block gathers over the key blocks (the
+    grid's last axis) in a float32 scratch."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
+    has_len = lengths is not None
     B, T, H, d = q.shape
     Kh = k.shape[2]
     rep = H // Kh
-    block_q = _pick_block(T, block_q)
-    block_k = _pick_block(T, block_k)
-    n_q = T // block_q
-    n_k = T // block_k
+    G, Gk = _heads_per_step(H, Kh, d)
+    block_q = _tuned_block(T, "block_q", block_q, interpret)
+    block_k = _tuned_block(T, "block_k", block_k, interpret)
+    n_q, n_k = T // block_q, T // block_k
 
-    def dq_kernel(lens_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref,
-                  do_ref, dq_ref):
-        qi = pl.program_id(2)
-        len_b = lens_ref[pl.program_id(0)]
-        qblk = q_ref[...].astype(jnp.float32)          # (block_q, d)
-        doblk = do_ref[...].astype(jnp.float32)
-        lseb = lse_ref[...].astype(jnp.float32)        # (block_q, 1)
-        deltb = delta_ref[...].astype(jnp.float32)
-
-        def body(ki, acc_):
-            kblk = k_ref[pl.dslice(ki * block_k, block_k), :] \
-                .astype(jnp.float32)
-            vblk = v_ref[pl.dslice(ki * block_k, block_k), :] \
-                .astype(jnp.float32)
-            s = (qblk @ kblk.T) * scale
-            if causal:
-                s = _mask_causal(s, qi, ki, block_q, block_k)
-            if has_len:
-                s = _mask_lengths(s, ki, block_k, len_b)
-            p = jnp.exp(s - lseb)                      # 0 where masked
-            dp = doblk @ vblk.T
-            ds = p * (dp - deltb)
-            return acc_ + ds @ kblk
-
-        if causal:
-            upper = jnp.minimum(
-                n_k, ((qi + 1) * block_q + block_k - 1) // block_k)
-        else:
-            upper = n_k
-        if has_len:
-            upper = jnp.minimum(upper, (len_b + block_k - 1) // block_k)
-        acc = jax.lax.fori_loop(
-            0, upper, body, jnp.zeros((block_q, d), jnp.float32))
-        dq_ref[...] = (acc * scale).astype(dq_ref.dtype)
-
-    def dkv_kernel(lens_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref,
-                   do_ref, dk_ref, dv_ref):
+    def kernel(lens_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
+               dq_ref, dk_ref, dv_ref, *scratch):
+        # grid: (B, H // G, n_k). G heads of a block of K/V rows, each
+        # against a sweep of its resident Q / dO; dq_acc gathers dQ
+        # over the key blocks when there is more than one.
+        dq_acc = scratch[0] if scratch else None
         ki = pl.program_id(2)
-        len_b = lens_ref[pl.program_id(0)]
-        kblk = k_ref[...].astype(jnp.float32)          # (block_k, d)
-        vblk = v_ref[...].astype(jnp.float32)
-
-        def body(qi, carry):
-            dk_, dv_ = carry
-            qblk = q_ref[pl.dslice(qi * block_q, block_q), :] \
-                .astype(jnp.float32)
-            doblk = do_ref[pl.dslice(qi * block_q, block_q), :] \
-                .astype(jnp.float32)
-            lseb = lse_ref[pl.dslice(qi * block_q, block_q), :] \
-                .astype(jnp.float32)                   # (block_q, 1)
-            deltb = delta_ref[pl.dslice(qi * block_q, block_q), :] \
-                .astype(jnp.float32)
-            s = (qblk @ kblk.T) * scale                # (block_q, block_k)
-            if causal:
-                s = _mask_causal(s, qi, ki, block_q, block_k)
-            if has_len:
-                # NOTE: the q-block sweep is NOT truncated — query rows
-                # beyond lengths still attend valid keys (only KEYS are
-                # padded), so their cotangents legitimately reach dk/dv
-                s = _mask_lengths(s, ki, block_k, len_b)
-            p = jnp.exp(s - lseb)
-            dv_ = dv_ + p.T @ doblk
-            dp = doblk @ vblk.T
-            ds = p * (dp - deltb)
-            dk_ = dk_ + ds.T @ qblk
-            return dk_, dv_
-
+        len_b = lens_ref[pl.program_id(0)] if has_len else None
         lower = (ki * block_k) // block_q if causal else 0
-        zeros = jnp.zeros((block_k, d), jnp.float32)
-        dk, dv = jax.lax.fori_loop(lower, n_q, body, (zeros, zeros))
-        dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
-        dv_ref[...] = dv.astype(dv_ref.dtype)
+        # NOTE: the q-block sweep is NOT truncated by lengths — query
+        # rows beyond lengths still attend valid keys (only KEYS are
+        # padded), so their cotangents legitimately reach dk/dv; a key
+        # block wholly past lengths[b] is all zeros and sweeps nothing
+        upper = jnp.where(ki * block_k < len_b, n_q, 0) \
+            if has_len and n_k > 1 else n_q
 
-    # (B, H, T, d) internal layout (see _pallas_forward); lse/delta as
-    # (B, H, T, 1)
-    from jax.experimental.pallas import tpu as pltpu
+        if n_k > 1:
+            @pl.when(ki == 0)
+            def _():
+                dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    dot = dout.transpose(0, 2, 1, 3)
-    lse4 = lse[..., None]
-    delta4 = delta[..., None]
+        for j in range(G):
+            cols, kcols = _head_cols(j, d, G, rep)
+            kblk = k_ref[:, kcols]                       # (block_k, d)
+            vblk = v_ref[:, kcols]
+
+            def body(qi, carry, j=j, cols=cols, kblk=kblk, vblk=vblk):
+                dk_, dv_ = carry
+                r = _rows(qi, block_q)
+                qh = (q_ref[r, cols].astype(jnp.float32) * scale) \
+                    .astype(q_ref.dtype)                 # (block_q, d)
+                doblk = do_ref[r, cols]
+                s = _dot(kblk, qh, _NT)                  # (block_k, bq)
+                keep = _keep_mask(qi, ki, block_q, block_k, causal,
+                                  None, len_b)
+                if keep is not None:
+                    s = jnp.where(keep, s, -jnp.inf)
+                p = jnp.exp(s - lse_ref[j:j + 1, r])     # 0 where masked
+                dv_ = dv_ + _dot(p.astype(doblk.dtype), doblk)
+                dp = _dot(vblk, doblk, _NT)
+                ds = (p * (dp - delta_ref[j:j + 1, r])).astype(qh.dtype)
+                dk_ = dk_ + _dot(ds, qh)                 # scale is in qh
+                dq = _dot(ds, kblk, _TN) * scale         # (block_q, d)
+                if n_k == 1:
+                    dq_ref[r, cols] = dq.astype(dq_ref.dtype)
+                else:
+                    dq_acc[r, cols] += dq
+                return dk_, dv_
+
+            zeros = jnp.zeros((block_k, d), jnp.float32)
+            if n_q == 1:    # a whole-sequence query block: no loop
+                dk, dv = body(0, (zeros, zeros))
+            else:
+                dk, dv = jax.lax.fori_loop(lower, upper, body,
+                                           (zeros, zeros))
+            dk_ref[:, cols] = dk.astype(dk_ref.dtype)
+            dv_ref[:, cols] = dv.astype(dv_ref.dtype)
+
+        if n_k > 1:
+            @pl.when(ki == n_k - 1)
+            def _():
+                dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
     if lengths is None:
         lengths = jnp.full((B,), T, jnp.int32)
-    lens = lengths.astype(jnp.int32)
-
-    qspec = pl.BlockSpec((None, None, block_q, d),
-                         lambda b, h, i, lens: (b, h, i, 0))
-    full_q = pl.BlockSpec((None, None, T, d),
-                          lambda b, h, i, lens: (b, h, 0, 0))
-    full_kv = pl.BlockSpec((None, None, T, d),
-                           lambda b, h, i, lens: (b, h // rep, 0, 0))
-    row_blk = pl.BlockSpec((None, None, block_q, 1),
-                           lambda b, h, i, lens: (b, h, i, 0))
-    row_full = pl.BlockSpec((None, None, T, 1),
-                            lambda b, h, i, lens: (b, h, 0, 0))
-
-    dq = pl.pallas_call(
-        dq_kernel,
+    full_q = pl.BlockSpec((None, T, G * d),
+                          lambda b, g, i, lens: (b, 0, g))
+    kspec = pl.BlockSpec((None, block_k, Gk * d),
+                         lambda b, g, i, lens: (b, i, g * G // rep // Gk))
+    dkv_out = pl.BlockSpec((None, block_k, G * d),
+                           lambda b, g, i, lens: (b, i, g))
+    row_full = pl.BlockSpec((None, None, G, T),
+                            lambda b, g, i, lens: (b, g, 0, 0))
+    item = q.dtype.itemsize
+    resident = 2 * item * (3 * T * G * d + 2 * block_k * (Gk + G) * d) \
+        + (n_k > 1) * 4 * T * G * d + 8 * 4 * block_q * block_k
+    dq, dk_h, dv_h = pl.pallas_call(
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, H, n_q),
-            in_specs=[qspec, full_kv, full_kv, row_blk, row_blk,
-                      qspec],
-            out_specs=qspec),
-        out_shape=jax.ShapeDtypeStruct((B, H, T, d), q.dtype),
-        interpret=interpret,
-        name="flash_attention_dq",
-    )(lens, qt, kt, vt, lse4, delta4, dot)
-
-    kspec = pl.BlockSpec((None, None, block_k, d),
-                         lambda b, h, i, lens: (b, h // rep, i, 0))
-    dkv_out = pl.BlockSpec((None, None, block_k, d),
-                           lambda b, h, i, lens: (b, h, i, 0))
-    dk_h, dv_h = pl.pallas_call(
-        dkv_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, H, n_k),
-            in_specs=[full_q, kspec, kspec, row_full, row_full,
-                      full_q],
-            out_specs=[dkv_out, dkv_out]),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, T, d), q.dtype),
-            jax.ShapeDtypeStruct((B, H, T, d), q.dtype),
-        ],
+            grid=(B, H // G, n_k),
+            in_specs=[full_q, kspec, kspec, row_full, row_full, full_q],
+            out_specs=[full_q, dkv_out, dkv_out],
+            scratch_shapes=[pltpu.VMEM((T, G * d), jnp.float32)]
+            if n_k > 1 else []),
+        out_shape=[jax.ShapeDtypeStruct((B, T, H * d), q.dtype)] * 3,
         interpret=interpret,
         name="flash_attention_dkv",
-    )(lens, qt, kt, vt, lse4, delta4, dot)
-    dq = dq.transpose(0, 2, 1, 3)                  # (B, T, H, d)
+        **_compiler_params(interpret, resident),
+    )(lengths.astype(jnp.int32), q.reshape(B, T, H * d),
+      k.reshape(B, T, Kh * d), v.reshape(B, T, Kh * d),
+      lse.reshape(B, H // G, G, T), delta.reshape(B, H // G, G, T),
+      dout.reshape(B, T, H * d))
+    dq = dq.reshape(B, T, H, d)
     # GQA: query head h reads kv head h//rep, so sum each group of rep
     # consecutive query heads back into its kv head
     if rep > 1:
-        dk = dk_h.reshape(B, Kh, rep, T, d).sum(axis=2) \
-            .transpose(0, 2, 1, 3).astype(k.dtype)
-        dv = dv_h.reshape(B, Kh, rep, T, d).sum(axis=2) \
-            .transpose(0, 2, 1, 3).astype(v.dtype)
+        dk = dk_h.reshape(B, T, Kh, rep, d).sum(axis=3).astype(k.dtype)
+        dv = dv_h.reshape(B, T, Kh, rep, d).sum(axis=3).astype(v.dtype)
     else:
-        dk = dk_h.transpose(0, 2, 1, 3)
-        dv = dv_h.transpose(0, 2, 1, 3)
+        dk = dk_h.reshape(B, T, Kh, d)
+        dv = dv_h.reshape(B, T, Kh, d)
     return dq, dk, dv
 
 
@@ -404,6 +450,21 @@ def _flash_pallas_fwd(q, k, v, lengths, causal, scale, interpret,
     return out, (q, k, v, lengths, out, lse)
 
 
+def _rowsum_per_head(g, out):
+    """delta_i = rowsum(dO_i * O_i), the softmax-jacobian correction
+    term, as (B, H, T) float32. Summed on the (B, T, H * d) layout by a
+    product with the heads' 0/1 selector: a reduce over the d of
+    (B, T, H, d) would first re-lay the float32 product out with
+    (H, d) minor — a copy of twice the activations' bytes."""
+    B, T, H, d = g.shape
+    prod = (g.astype(jnp.float32) * out.astype(jnp.float32)) \
+        .reshape(B, T, H * d)
+    sel = (jnp.arange(H * d)[:, None] // d
+           == jnp.arange(H)[None, :]).astype(jnp.float32)
+    return jnp.einsum("btc,ch->bht", prod, sel,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
 def _len_cotangent(lengths):
     # integer primal -> float0 cotangent (jax's convention); None stays
     # None (the static no-padding case)
@@ -417,12 +478,10 @@ def _flash_pallas_bwd(causal, scale, interpret, window, res, g):
     if window is not None:
         raise NotImplementedError(
             "flash attention with a sliding window has no backward "
-            "kernels (dq / dkv know no window mask): the windowed "
+            "kernel (dkv knows no window mask): the windowed "
             "forward serves prefill only")
     q, k, v, lengths, out, lse = res
-    # delta_i = rowsum(dO_i * O_i): the softmax-jacobian correction term
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).transpose(0, 2, 1)  # (B, H, T)
+    delta = _rowsum_per_head(g, out)                 # (B, H, T)
     try:
         dq, dk, dv = _pallas_backward(q, k, v, lse, delta,
                                       g.astype(q.dtype), causal, scale,

@@ -66,6 +66,19 @@ def test_sweeps_run_on_cpu_interpret():
     assert win is not None and "row_block_want" in win
 
 
+def test_flash_sweep_times_both_shapes_on_cpu_interpret():
+    import autotune_kernels as at
+
+    at._guard = at.BudgetGuard("autotune_kernels", "families",
+                               budget_s=600.0)
+    res, win = at.sweep_flash_attention(False, True)
+    assert set(res) == {"train", "prefill"}
+    assert all("ms" in r for r in res["train"]["fwd"] + res["train"]["bwd"]
+               + res["prefill"]["fwd"])
+    assert "bwd" not in res["prefill"]
+    assert set(win) == {"block_q", "block_k"}
+
+
 def test_write_tuned_merges_and_reloads():
     import autotune_kernels as at
 

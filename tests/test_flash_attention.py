@@ -187,6 +187,113 @@ def test_lengths_backward_matches_reference_vjp(monkeypatch):
                                    rtol=3e-4, atol=3e-5)
 
 
+# the shapes the packed tiling has to serve: (H, K, d, causal, window,
+# ragged lengths) -> heads a grid step
+_PACKED = {
+    "bert_12x64_two_heads_a_step": (12, 12, 64, False, None, True),
+    "gqa_rep4_128_causal": (8, 2, 128, True, None, False),
+    "gqa_rep4_128_causal_ragged": (8, 2, 128, True, None, True),
+    "gqa_rep4_128_window": (8, 2, 128, True, 48, True),
+    "odd_3x64_whole_width": (3, 3, 64, False, None, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PACKED))
+def test_packed_heads_match_reference(case, monkeypatch):
+    """Forward and custom_vjp gradients of the lane-dense kernels
+    against reference_attention and its jax.vjp, through the dispatch
+    seam (the windowed forward has no backward: forward only)."""
+    from mxnet_tpu.kernels import flash_attention as fa
+    monkeypatch.setenv("MXNET_TPU_FLASH_INTERPRET", "1")
+    H, K, d, causal, window, ragged = _PACKED[case]
+    G, Gk = fa._heads_per_step(H, K, d)
+    assert (G * d) % 128 == 0 or G == H
+    if case.startswith("bert"):
+        assert (G, Gk) == (2, 2)
+    q, k, v = _qkv(B=2, T=128, H=H, K=K, d=d, seed=21)
+    lengths = jnp.asarray([128, 45], jnp.int32) if ragged else None
+    kw = dict(causal=causal, lengths=lengths, window=window)
+    before = fa.FALLBACK_COUNT
+    out = fa.flash_attention_raw(q, k, v, **kw)
+    ref = reference_attention(q, k, v, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-5)
+    if window is None:
+        g = jnp.asarray(np.random.RandomState(22)
+                        .randn(*q.shape).astype(np.float32) * 0.2)
+        gk = jax.grad(lambda *a: (fa.flash_attention_raw(*a, **kw)
+                                  * g).sum(), argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(lambda *a: (reference_attention(*a, **kw)
+                                  * g).sum(), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gk, gr):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=3e-4, atol=3e-5)
+    assert fa.FALLBACK_COUNT == before, "the kernels fell back"
+
+
+def test_row_with_every_key_masked(monkeypatch):
+    """lengths[b] == 0: every key of the row is masked. The output is
+    0, lse is +inf, and nothing but zeros flows back — no NaN from
+    exp(-inf - -inf)."""
+    from mxnet_tpu.kernels import flash_attention as fa
+    monkeypatch.setenv("MXNET_TPU_FLASH_INTERPRET", "1")
+    q, k, v = _qkv(B=2, T=128, H=4, K=4, d=64, seed=23)
+    lengths = jnp.asarray([0, 77], jnp.int32)
+    out, lse = fa._pallas_forward(q, k, v, causal=False, scale=0.125,
+                                  interpret=True, return_lse=True,
+                                  lengths=lengths)
+    assert lse.shape == (2, 4, 128)
+    assert np.all(np.asarray(out[0]) == 0)
+    assert np.all(np.isposinf(np.asarray(lse[0])))
+    assert np.all(np.isfinite(np.asarray(lse[1])))
+    grads = jax.grad(lambda *a: (fa.flash_attention_raw(
+        *a, causal=False, lengths=lengths) ** 2).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+    live = jax.grad(lambda *a: (reference_attention(
+        *a, causal=False, lengths=lengths[1:]) ** 2).sum(),
+        argnums=(0, 1, 2))(q[1:], k[1:], v[1:])
+    for a, b in zip(grads, live):
+        assert np.all(np.asarray(a[0]) == 0)
+        np.testing.assert_allclose(np.asarray(a[1:]), np.asarray(b),
+                                   rtol=3e-4, atol=3e-5)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_calls_read_the_models_layout(monkeypatch):
+    """The three calls read q / k / v / do and write o / dq / dk / dv
+    on (B, T, H * d): no transpose of an activation surrounds them, and
+    no operand of a pallas_call has a minor dimension of 1 (the row
+    statistics travel with T along the lanes)."""
+    from mxnet_tpu.kernels import flash_attention as fa
+    monkeypatch.setenv("MXNET_TPU_FLASH_INTERPRET", "1")
+    q, k, v = _qkv(B=2, T=128, H=12, K=12, d=64, seed=25)
+    lengths = jnp.asarray([128, 45], jnp.int32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: (fa.flash_attention_raw(
+            *a, causal=False, lengths=lengths) ** 2).sum(),
+        argnums=(0, 1, 2)))(q, k, v)
+    calls = []
+    for eqn in _eqns(jaxpr.jaxpr):
+        if eqn.primitive.name == "transpose":
+            assert eqn.invars[0].aval.size < q.size, eqn
+        if eqn.primitive.name == "pallas_call":
+            calls.append(eqn)
+            for var in list(eqn.invars) + list(eqn.outvars):
+                shape = var.aval.shape
+                assert len(shape) < 2 or shape[-1] > 1, (eqn, shape)
+                if len(shape) == 3:
+                    assert shape == (2, 128, 12 * 64), shape
+    names = sorted(e.params["name"] for e in calls)
+    assert names == ["flash_attention_dkv", "flash_attention_fwd"], names
+
+
 def test_bert_valid_length_flash_vs_mask(monkeypatch):
     """BERT's key-padding now rides the kernel's lengths support; the
     kernel-on and fallback paths must agree, and padding tokens must

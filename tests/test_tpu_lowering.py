@@ -6,6 +6,8 @@ are the proof that the 'compiled' kernel paths are actually viable on
 hardware, which interpret-mode tests cannot give (the interpreter
 ignores tiling constraints; round 2 shipped kernels that passed
 interpret tests but could never have compiled on-chip)."""
+import re
+
 import numpy as np
 import pytest
 
@@ -184,6 +186,39 @@ def test_afmoe_kernels_compile_for_v5e(what, one_chip):
                 sds((1,), jnp.int32))
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert name in text
+
+
+@pytest.mark.parametrize("what", ["train cell, forward",
+                                  "train cell, backward",
+                                  "prefill of 2,048", "prefill of 6,144"])
+def test_flash_attention_compiles_for_v5e_at_the_cells_shapes(
+        what, one_chip):
+    """The lane-dense kernels at the shapes the benchmark's cells run,
+    with the blocks tuned.json's tpu section gives: heads of 64 two a
+    step on (64, 512, 768), heads of 128 with GQA on a prefill."""
+    from mxnet_tpu.kernels import flash_attention as fa
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    if what.startswith("train"):
+        B, T, H, K, d, causal = 64, 512, 12, 12, 64, False
+    else:
+        B, T, H, K, d, causal = 1, 2048 if "2,048" in what else 6144, \
+            32, 8, 128, True
+    q, kv, n = sds((B, T, H, d)), sds((B, T, K, d)), sds((B,), jnp.int32)
+    if what.endswith("backward"):
+        row = sds((B, H, T), jnp.float32)
+        fn, args, name = (lambda q, k, v, lse, dl, g, n: fa._pallas_backward(
+            q, k, v, lse, dl, g, causal, d ** -0.5, lengths=n)), \
+            (q, kv, kv, row, row, q, n), "flash_attention_dkv"
+    else:
+        fn, args, name = (lambda q, k, v, n: fa._pallas_forward(
+            q, k, v, causal, d ** -0.5, return_lse=True, lengths=n)), \
+            (q, kv, kv, n), "flash_attention_fwd"
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert name in text
+    # the row statistics leave and enter with T along the lanes
+    assert re.search(r"f32\[%d,%d,%d,%d\]" % (
+        (B, H // 2, 2, T) if d == 64 else (B, H, 1, T)), text), what
 
 
 def test_full_llama_step_lowers_with_kernels():
