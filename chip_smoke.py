@@ -761,13 +761,14 @@ def phase_serve(size, net, meter):
                                     seed=i))
                 for i, (n, new, temp) in enumerate(wave)]
 
-    # wave 1. Its first request is the probe for the logits check: admit
-    # it alone (the prefill), read the row, run one tick (the first
-    # decode step), read the row again. Logits, not tokens: with random
-    # weights the argmax is a coin toss.
+    # the probe for the logits check, ahead of wave 1: a request of ONE
+    # token, so that the server launches one decode tick for it and no
+    # second behind it. Admit it alone (the prefill), read the row, run
+    # the tick (the first decode step), read the row again. Logits, not
+    # tokens: with random weights the argmax is a coin toss.
     mark = meter.mark()
-    (n0, new0, _), rest = size.waves[0][0], size.waves[0][1:]
-    probe = server.submit(rs.randint(0, size.vocab, n0), new0)
+    n0 = size.waves[0][0][0]
+    probe = server.submit(rs.randint(0, size.vocab, n0), 1)
     pool0 = server.cache.pages[0]["k"]
     server._admit()
     slot = server._slot_req.index(probe)
@@ -778,7 +779,7 @@ def phase_serve(size, net, meter):
     decode_row = np.asarray(server._last_logits[slot], np.float32)
     t0 = probe.output_tokens[0]
 
-    wave1 = [(new0, probe)] + submit(rest)
+    wave1 = [(1, probe)] + submit(size.waves[0])
     server.run()
     built1 = meter.since(mark)
     cs1 = server.compile_stats()

@@ -6,22 +6,26 @@ TTFT / tokens-per-sec-per-chip bar this engine is instrumented for):
 
 - `submit()` enqueues a request (prompt + per-request sampling params
   + max_new_tokens). FIFO by submission.
-- every `step()` (one decode tick):
+- every `step()` (one tick; ONE decode tick is kept queued on the
+  device ahead of the one whose tokens are handed over, see `step`):
     1. ADMIT: while a batch slot and enough KV blocks are free, pop
        the queue head, allocate its blocks, run the persistent prefill
        executable (batch 1, padded to `max_prompt_len` — so 16
        mixed-length prompts are ONE compile), and seed the slot's
        logits/PRNG rows.
-    2. ENSURE: lazily allocate each running slot's next block when its
-       write position crosses a block boundary. Pool exhausted →
-       preempt the youngest running request (free its blocks, re-queue
-       it at the front; greedy requests regenerate identically).
-    3. DECODE: one shared decode-tick executable for ALL slots —
-       per-row sampling of the previous logits, one flash-decode step
-       through the paged cache, per-row PRNG advance. Compiled once,
-       reused for the lifetime of the server.
-    4. EVICT: finished rows (eos hit or max_new_tokens reached) free
-       their blocks and slots at the SAME tick, so the next step()
+    2. ENSURE: lazily allocate the next block of each slot with a row
+       in the tick about to be launched, when its write position
+       crosses a block boundary. Pool exhausted → preempt the
+       youngest running request (free its blocks, re-queue it at the
+       front; greedy requests regenerate identically).
+    3. LAUNCH decode tick n+1: one shared decode-tick executable for
+       ALL slots — per-row sampling of the previous logits, one
+       flash-decode step through the paged cache, per-row PRNG
+       advance. Compiled once, reused for the lifetime of the server.
+    4. HAND OVER tick n, launched by the step() before: block on its
+       tokens and append them to their requests.
+    5. EVICT: finished rows (eos hit or max_new_tokens reached) free
+       their blocks and slots in the SAME step(), so the next step()
        admits from the queue immediately.
 
 Telemetry (PR-4 registry, enabled via telemetry.enable()):
@@ -127,6 +131,31 @@ _QUEUED, _RUNNING, _FINISHED = "queued", "running", "finished"
 #: terminal statuses — set exactly once when a request leaves the system
 _OK, _TIMED_OUT, _PREEMPTED, _REJECTED, _CANCELLED = \
     "ok", "timed_out", "preempted", "rejected", "cancelled"
+
+
+def _upload(a):
+    """A host array the scheduler goes on changing, as an executable's
+    operand: a copy, never a view. A launch may not have run yet when
+    the host writes the next tick's values (on the CPU backend
+    `jnp.asarray` of an aligned numpy array can share its memory)."""
+    return jnp.asarray(np.array(a))
+
+
+class _Flight:
+    """One decode tick launched and not yet read: its outputs still on
+    the device, and what the host knew of each row when it launched."""
+
+    __slots__ = ("tok", "n_acc", "counts", "prefill_counts", "admit",
+                 "warm", "dlens")
+
+    def __init__(self, tok, n_acc, counts, prefill_counts, admit, warm,
+                 dlens):
+        self.tok, self.n_acc = tok, n_acc
+        self.counts, self.prefill_counts = counts, prefill_counts
+        #: admission stamp of each row's request, -2 where the tick
+        #: has no row: a row counts only while its slot's stamp is this
+        self.admit = admit
+        self.warm, self.dlens = warm, dlens
 
 
 class ServerStalledError(RuntimeError):
@@ -428,8 +457,17 @@ class InferenceServer:
         # re-key the executables
         self._adapter_ids = np.zeros(B, np.int32)
         self._slot_req: List[Optional[Request]] = [None] * B
-        self._admit_seq = 0                 # admission order stamp
-        self._slot_admit = np.zeros(B, np.int64)
+        # admission order stamp; -1 on an empty slot, so a stamp also
+        # says whether a row computed for a slot still has its owner
+        self._admit_seq = 0
+        self._slot_admit = np.full(B, -1, np.int64)
+        # one decode tick is kept queued on the device ahead of the
+        # one whose tokens step() hands over: `_flights` holds the
+        # ticks launched and not yet read (oldest first), `_left` the
+        # tokens each slot has still to ask the device for
+        self._flights: deque = deque()
+        self._left = np.zeros(B, np.int32)
+        self.ticks_ahead = 0
         # chunked-prefill / speculative per-slot state: a prefilling
         # slot holds blocks + request but isn't decode-active yet; a
         # warm slot's next tick re-feeds the last prompt token (full
@@ -505,7 +543,7 @@ class InferenceServer:
         c = self.cache
         tabs = (c.block_tables,) if c.window_tables is None \
             else (c.block_tables, c.window_tables)
-        out = tuple(jnp.asarray(t if slot is None else t[slot])
+        out = tuple(_upload(t if slot is None else t[slot])
                     for t in tabs)
         return out[0] if len(out) == 1 else out
 
@@ -565,7 +603,7 @@ class InferenceServer:
         LoRA-less build."""
         if self.lora is None:
             return ()
-        return (self.lora.tables, jnp.asarray(aids, jnp.int32))
+        return (self.lora.tables, _upload(np.asarray(aids, np.int32)))
 
     def _prefix_root(self, req: "Request"):
         """Prefix-cache chain root for a request: adapter requests get
@@ -722,11 +760,16 @@ class InferenceServer:
                 telemetry.inc("serving_prefix_tokens_shared_total",
                               shared_len)
 
-    def _seed_slot(self, slot: int, req: Request):
-        """Decode activation: PRNG row + per-row sampling params."""
+    def _seed_key(self, slot: int, req: Request):
+        """(Re)start the slot's PRNG row from the request's seed."""
         self._keys = self._keys.at[slot].set(
             jnp.asarray(jax.random.PRNGKey(req.seed), jnp.uint32))
+
+    def _seed_slot(self, slot: int, req: Request):
+        """Decode activation: PRNG row + per-row sampling params."""
+        self._seed_key(slot, req)
         self._active[slot] = True
+        self._left[slot] = req.max_new_tokens - len(req.output_tokens)
         self._temps[slot] = req.temperature
         self._top_ks[slot] = req.top_k
         self._top_ps[slot] = req.top_p
@@ -913,11 +956,10 @@ class InferenceServer:
         self.queue.appendleft(req)
         return True
 
-    def _ensure_blocks(self):
-        """Every running slot needs the block holding its next write
-        position before the tick."""
-        order = sorted((i for i in range(self.batch_slots)
-                        if self._active[i]),
+    def _ensure_blocks(self, send):
+        """Every slot with a row in the tick about to be launched
+        (`send`) needs the block holding its next write position."""
+        order = sorted(np.flatnonzero(send),
                        key=lambda i: self._slot_admit[i])
         for slot in order:
             # a slot evicted in this pass — by an older slot, or by
@@ -999,7 +1041,7 @@ class InferenceServer:
         n = min(T - start, budget, C)
         ids = np.zeros((1, C), np.int32)
         ids[0, :n] = req.prompt[start:start + n]
-        bt_row = jnp.asarray(self.cache.block_tables[slot])
+        bt_row = _upload(self.cache.block_tables[slot])
         t_pf = time.perf_counter()
         with telemetry.phase("serve_prefill", tokens=n, padded=C):
             self.cache.pages, last = self.programs["prefill_chunk"](
@@ -1105,6 +1147,13 @@ class InferenceServer:
         self._warm[slot] = False
         self._adapter_ids[slot] = 0
         self._slot_req[slot] = None
+        self._slot_admit[slot] = -1
+        self._left[slot] = 0
+        # a tick in flight that held rows of this slot alone has
+        # nothing left to hand over
+        while self._flights and not (
+                self._flights[0].admit == self._slot_admit).any():
+            self._flights.popleft()
 
     def _finish(self, slot: int, reason: str, status: str = _OK):
         req = self._slot_req[slot]
@@ -1200,16 +1249,50 @@ class InferenceServer:
     # -- the tick -----------------------------------------------------------
 
     def step(self) -> int:
-        """Admit + one decode tick + evict. Returns tokens emitted
-        (on ticks that only ran prefill chunks, the chunk tokens
-        processed — drive loops must see prefill-only ticks as
-        progress, not idleness).
+        """One tick: admit, queue the next decode tick on the device,
+        hand over the tokens of the one before it, evict. Returns the
+        tokens handed over (on ticks that only ran prefill chunks, the
+        chunk tokens processed — drive loops must see prefill-only
+        ticks as progress, not idleness).
+
+        The server keeps ONE decode tick queued ahead: while tick n
+        runs, `step()` admits, allocates blocks, uploads and launches
+        tick n+1, and only then blocks on tick n's tokens, so the
+        device never waits for the host between ticks. Everything one
+        tick hands the next (page pools, last logits, PRNG rows) is a
+        device array chained from output to input, and tick n's token
+        is sampled inside tick n's program; what the host uploads for
+        n+1 (tables, positions, sampling rows, the mask of rows)
+        depends on n's tokens only through an `eos_id` finish. So:
+
+        - a slot whose last token by `max_new_tokens` is already in
+          flight is left out of the next launch; it stays `_active`
+          until that token has been handed over;
+        - a request that an `eos_id`, `cancel()`, a deadline or a
+          preemption ends while a later row of it is in flight has
+          that row DROPPED: computed, never appended to
+          `output_tokens`, never counted. `output_tokens` grows only
+          by tokens that count;
+        - a prompt admitted beside running requests has its prefill
+          queued behind the tick in flight and joins the launch of
+          the same `step()`; its first token comes out of the next
+          `step()`, at the same place on the device's queue;
+        - from an idle server the first `step()` launches two ticks
+          and hands over the first;
+        - with `speculative=` the drafts are proposed from the tokens
+          just handed over, so there the server reads before it
+          launches and nothing is queued ahead.
 
         In a profiler trace one call is one `mx.serve_tick` span,
         early returns included, holding `mx.serve_admit` (with one
         `mx.serve_prefill` a prompt), `mx.serve_blocks`,
-        `mx.serve_decode` (exactly `mx.serve_dispatch` then
-        `mx.serve_wait`) and `mx.serve_emit`."""
+        `mx.serve_decode` (`mx.serve_dispatch`: the uploads and launch
+        of the tick being queued, count `ahead` 1 when another was in
+        flight; then `mx.serve_wait`: the read of the tick handed
+        over) and `mx.serve_emit` (the counts of the tick read). From
+        an idle server a first `mx.serve_blocks` + `mx.serve_dispatch`
+        precede those; a `step()` with nothing left to launch has no
+        `mx.serve_blocks` and no `mx.serve_dispatch`."""
         with telemetry.span("serve_tick"):
             return self._tick()
 
@@ -1229,80 +1312,114 @@ class InferenceServer:
         if self.prefill_chunk_tokens is not None \
                 and self._prefilling.any():
             prefilled = self._prefill_tick()
-        if not self._active.any():
+        plan = self._prepare()
+        if plan is not None and not self._flights \
+                and self._spec is None:
+            # nothing in flight (an idle server): the tick to hand
+            # over goes first, the one queued behind it second.
+            # Speculation needs a tick's tokens to build the next, so
+            # there nothing is queued ahead
+            self._dispatch(*plan)
+            plan = self._prepare()
+        if plan is None and not self._flights:
             self._note_progress(admitted + prefilled, done0)
             self._update_gauges()
             return prefilled
-        drafts = dlens = None
         tick_counts = {}
-        with telemetry.span("serve_blocks"):
-            self._ensure_blocks()
-            if self._spec is not None:
-                drafts, dlens = self._propose_drafts()
         with telemetry.phase("serve_decode"):
-            # exactly two spans: the uploads + the launch, then the
-            # host blocked on the device
-            with telemetry.span("serve_dispatch",
-                                active=int(self._active.sum()),
-                                **self._note_context()):
-                if drafts is not None:
-                    (self.cache.pages, wtok, n_acc, self._last_logits,
-                     self._keys) = self.programs["verify"](
-                        self._params, self.cache.pages,
-                        jnp.asarray(self.cache.block_tables),
-                        jnp.asarray(self._pos), self._last_logits,
-                        self._keys, jnp.asarray(self._temps),
-                        jnp.asarray(self._top_ks),
-                        jnp.asarray(self._top_ps),
-                        jnp.asarray(self._active), jnp.asarray(drafts),
-                        jnp.asarray(dlens),
-                        *self._lora_args(self._adapter_ids))
-                else:
-                    (self.cache.pages, tok, self._last_logits,
-                     self._keys, *counts) = self.programs["decode"](
-                        self._params, self.cache.pages,
-                        self._tables(),
-                        jnp.asarray(self._pos), self._last_logits,
-                        self._keys, jnp.asarray(self._temps),
-                        jnp.asarray(self._top_ks),
-                        jnp.asarray(self._top_ps),
-                        jnp.asarray(self._active),
-                        *self._lora_args(self._adapter_ids))
+            # the uploads + the launch of the tick being queued, then
+            # the host blocked on the tick before it
+            if plan is not None:
+                self._dispatch(*plan)
             with telemetry.span("serve_wait"):
-                # host sync = honest tick time
-                if drafts is not None:
-                    wtok_np = np.asarray(wtok)   # (B, k+1)
-                    n_acc_np = np.asarray(n_acc)
+                flight = self._flights.popleft()
+                if flight.n_acc is not None:
+                    wtok_np = np.asarray(flight.tok)   # (B, k+1)
+                    n_acc_np = np.asarray(flight.n_acc)
                 else:
-                    wtok_np = np.asarray(tok).reshape(-1, 1)
+                    wtok_np = np.asarray(flight.tok).reshape(-1, 1)
                     n_acc_np = np.zeros(self.batch_slots, np.int32)
-                    # the decoder's counts of this tick (an expert
-                    # layer's pairs and touched experts), read at the
-                    # same sync as the tokens
+                # the decoder's counts of this tick (an expert layer's
+                # pairs and touched experts), read at the same sync as
+                # the tokens; those of the prefills queued before it
+                # are already past
+                if flight.counts:
                     tick_counts = dict(zip(
                         self.decoder.counts,
-                        (int(c) for c in np.asarray(counts[0]))
-                        if counts else ()))
-                    if self._prefill_counts:
-                        done = np.sum([np.asarray(c) for c in
-                                       self._prefill_counts], axis=0)
-                        self._prefill_counts = []
-                        tick_counts.update(
-                            ("prefill_" + n, int(c)) for n, c in
-                            zip(self.decoder.counts, done))
+                        (int(c) for c in np.asarray(flight.counts[0]))))
+                if flight.prefill_counts:
+                    done = np.sum([np.asarray(c) for c in
+                                   flight.prefill_counts], axis=0)
+                    tick_counts.update(
+                        ("prefill_" + n, int(c)) for n, c in
+                        zip(self.decoder.counts, done))
         for name, n in tick_counts.items():
             self.decoder_counts[name] = \
                 self.decoder_counts.get(name, 0) + n
         with telemetry.span("serve_emit", **tick_counts):
-            return self._emit(wtok_np, n_acc_np, dlens, t_tick,
+            return self._emit(flight, wtok_np, n_acc_np, t_tick,
                               admitted, done0)
 
-    def _note_context(self) -> dict:
-        """Count the cached positions this tick's decode attends. With
-        sliding-window layers the two sums also go on
+    def _prepare(self):
+        """The rows of the next tick to launch, with their blocks (and
+        drafts): `(send, drafts, dlens)`, or None when no slot has a
+        token left to ask the device for."""
+        send = self._active & (self._left > 0)
+        if not send.any():
+            return None
+        drafts = dlens = None
+        with telemetry.span("serve_blocks"):
+            self._ensure_blocks(send)
+            if self._spec is not None:
+                drafts, dlens = self._propose_drafts()
+        send &= self._active        # less the slots a preemption emptied
+        return (send, drafts, dlens) if send.any() else None
+
+    def _dispatch(self, send, drafts, dlens):
+        """Upload and launch one decode (or verify) tick for the rows
+        `send` and put it on `_flights`. Nothing here waits for the
+        device: the positions advance now, not when the tokens are
+        read."""
+        ahead = int(bool(self._flights))
+        self.ticks_ahead += ahead
+        counts = ()
+        n_acc = None
+        with telemetry.span("serve_dispatch", active=int(send.sum()),
+                            ahead=ahead, **self._note_context(send)):
+            args = (self._params, self.cache.pages, self._tables(),
+                    _upload(self._pos), self._last_logits, self._keys,
+                    _upload(self._temps), _upload(self._top_ks),
+                    _upload(self._top_ps), jnp.asarray(send))
+            if drafts is not None:
+                (self.cache.pages, tok, n_acc, self._last_logits,
+                 self._keys) = self.programs["verify"](
+                    *args, jnp.asarray(drafts), jnp.asarray(dlens),
+                    *self._lora_args(self._adapter_ids))
+            else:
+                (self.cache.pages, tok, self._last_logits,
+                 self._keys, *counts) = self.programs["decode"](
+                    *args, *self._lora_args(self._adapter_ids))
+            warm = send & self._warm
+            self._flights.append(_Flight(
+                tok, n_acc, counts, self._prefill_counts,
+                np.where(send, self._slot_admit, -2), warm, dlens))
+            self._prefill_counts = []
+            self._pos[send] += 1
+            self._left[send & ~warm] -= 1
+            for slot in np.flatnonzero(warm):
+                # the warm tick's one sample is the re-fed prompt
+                # token, not output, and consumed one PRNG split:
+                # re-seed behind it so the sampled stream matches the
+                # cold (real-prefill) path tick for tick
+                self._seed_key(slot, self._slot_req[slot])
+            self._warm[warm] = False
+
+    def _note_context(self, send) -> dict:
+        """Count the cached positions the rows `send` of a decode tick
+        attend. With sliding-window layers the two sums also go on
         `mx.serve_dispatch` (`ctx`, `window_ctx`): what the full and
         the sliding layers' sweeps read, for their roofline."""
-        vl = self._pos[self._active].astype(np.int64) + 1
+        vl = self._pos[send].astype(np.int64) + 1
         ctx = int(vl.sum())
         self.context_tokens += ctx
         if self.decoder.window is None:
@@ -1311,31 +1428,31 @@ class InferenceServer:
         self.window_context_tokens += wctx
         return {"ctx": ctx, "window_ctx": wctx}
 
-    def _emit(self, wtok_np, n_acc_np, dlens, t_tick: float,
+    def _emit(self, flight, wtok_np, n_acc_np, t_tick: float,
               admitted: int, done0: int) -> int:
-        """The tick after the sync: hand each slot its tokens, finish
+        """Hand over the tick just read: each slot its tokens, finish
         and evict, feed the forecaster, the watchdog and the gauges.
-        The device has nothing queued while this runs."""
+        The next tick is already running on the device. A row whose
+        slot has changed hands since the launch (an `eos_id` finish, a
+        cancel, a deadline, a preemption) is dropped here."""
         now = time.perf_counter()
         emitted = 0
         net_new = 0
+        dlens = flight.dlens
         tenant_tokens = {} if self._wfs is not None else None
-        for slot in range(self.batch_slots):
-            if not self._active[slot]:
-                continue
+        for slot in np.flatnonzero(flight.admit == self._slot_admit):
             req = self._slot_req[slot]
-            warm = bool(self._warm[slot])
-            run = 1 + int(n_acc_np[slot])
+            warm = bool(flight.warm[slot])
+            acc = int(n_acc_np[slot])
             proposed = int(dlens[slot]) if dlens is not None else 0
             finished = None
-            for j in range(run):
-                t = int(wtok_np[slot, j])
-                self._pos[slot] += 1
+            for j in range(1 + acc):
                 if warm and j == 0:
                     # warm re-feed of the last prompt token: its k/v
                     # write is the whole point; the token itself is
                     # NOT output
                     continue
+                t = int(wtok_np[slot, j])
                 req.output_tokens.append(t)
                 emitted += 1
                 if tenant_tokens is not None:
@@ -1366,7 +1483,6 @@ class InferenceServer:
                     finished = "length"
                     break
             if proposed:
-                acc = int(n_acc_np[slot])
                 self.spec_tokens_accepted += acc
                 self.spec_tokens_rejected += proposed - acc
                 self._spec_window.append((acc, proposed))
@@ -1375,24 +1491,18 @@ class InferenceServer:
                                   acc)
                     telemetry.inc("serving_spec_tokens_rejected_total",
                                   proposed - acc)
-            if warm:
-                self._warm[slot] = False
             if finished is not None:
                 self._finish(slot, finished)
                 continue
             if proposed:
-                # rejected-suffix rewind: pos simply didn't advance
-                # over the rejected window positions — return the
-                # blocks the unconsumed tail had grabbed (stale rows
-                # are masked by valid lengths and overwritten later)
+                # the launch advanced over the sampled token alone:
+                # the accepted drafts follow; the rejected suffix is
+                # rewound by NOT advancing over it — return the blocks
+                # the unconsumed tail had grabbed (stale rows are
+                # masked by valid lengths and overwritten later)
+                self._pos[slot] += acc
+                self._left[slot] -= acc
                 self.cache.rewind(slot, int(self._pos[slot]))
-            if warm:
-                # the warm tick consumed one PRNG split on a discarded
-                # sample; re-seed so the sampled stream matches the
-                # cold (real-prefill) path tick-for-tick
-                self._keys = self._keys.at[slot].set(
-                    jnp.asarray(jax.random.PRNGKey(req.seed),
-                                jnp.uint32))
         if tenant_tokens:
             # decode tokens are weighted-fair service too: a tenant
             # hogging slots pays in admission priority next round
@@ -1857,6 +1967,7 @@ class InferenceServer:
         if self._wfs is not None:
             extra["tenant_passes"] = self._wfs.snapshot()
         return {"ticks": self.ticks,
+                "ticks_ahead": self.ticks_ahead,
                 **extra,
                 "queue_age_p50_s": age_p50,
                 "queue_age_p95_s": age_p95,

@@ -74,19 +74,23 @@ def test_served_logits_match_the_reference_across_the_window(
     reqs = [served.submit(rng.integers(0, cfg["vocab_size"], n), 24,
                           sampling if i % 2 else None, seed=i)
             for i, n in enumerate((6, 12, 40, 23))]
-    seen = {id(r): [] for r in reqs}       # logits that chose token j
+    # The logits that chose each token. The server keeps one tick
+    # queued ahead: step 1, from idle, launches ticks 1 and 2 (their
+    # tokens are sampled from the prefill's logits and from tick 1's,
+    # which the host never holds between two steps), and every later
+    # step k launches tick k + 1, sampled from the row read before
+    # that step, and hands over tick k.
+    before = []                             # rows read before step k
     while served.busy():
-        logits = np.asarray(srv._last_logits)
-        admitted = {id(r) for r in srv._slot_req if r is not None}
+        before.append(np.asarray(srv._last_logits))
         srv.step()
         srv.cache.check()
-        for s, r in enumerate(srv._slot_req):
-            if r is None or id(r) not in seen:
-                continue
-            if id(r) not in admitted:       # admitted in this step:
-                seen[id(r)].append(None)    # its prefill's logits went
-                continue                    # straight into the sample
-            seen[id(r)].append(logits[s])
+        if len(before) == 1:
+            slot_of = {id(r): srv._slot_req.index(r) for r in reqs}
+    assert len(before) == 24 and not srv._flights
+    seen = {id(r): [None, None]
+            + [before[j - 2][slot_of[id(r)]] for j in range(3, 25)]
+            for r in reqs}
     assert all(served.ok(r) for r in reqs)
     assert srv.compile_stats()["prefill_compiles"] == 1
     assert srv.compile_stats()["decode_compiles"] == 1
@@ -380,6 +384,65 @@ def test_preemption_and_readmission_are_identical_under_greedy():
     assert n0 == 0 and n1 > 0 and n2 > 0
     assert status == wstatus == ["ok"] * 3
     assert tight == roomy and wtight == roomy
+
+
+#: (prompt tokens, new tokens, sampled) and the `step()`s between the
+#: arrivals; contexts end below, across and beyond the window of 16
+AHEAD_MIX = [(6, 20, False), (12, 14, True), (30, 10, False),
+             (9, 18, True), (20, 12, False)]
+AHEAD_ARRIVALS = [(2, 2), (2, 3), (1, 0)]      # (submit, steps)
+#: what commit 268f475 (the serial tick) served for AHEAD_MIX, seed 17
+AHEAD_PINNED = [
+    [197, 204, 102, 198, 223, 123, 169, 71, 125, 186, 147, 209, 157, 36,
+     176, 161, 254, 210, 255, 3],
+    [52, 211, 39, 174, 116, 238, 194, 173, 38, 190, 48, 248, 80, 113],
+    [210, 252, 151, 222, 46, 158, 190, 160, 187, 247],
+    [163, 97, 147, 236, 222, 143, 3, 54, 174, 1, 210, 26, 52, 70, 57,
+     191, 223, 205],
+    [93, 85, 178, 197, 105, 122, 7, 145, 223, 150, 16, 100]]
+
+
+@pytest.mark.parametrize("eos_at", [None, 4])
+def test_a_tick_queued_ahead_serves_the_serial_orders_tokens(eos_at):
+    """Two kinds of layer under the tick that is launched before the
+    one ahead of it is read: greedy and sampled requests arriving
+    between ticks hold, one by one, the tokens the serial order served;
+    one ended by `eos_id` holds them up to that token and not the row
+    that was already queued behind it; both pools drain to empty."""
+    cfg = tiny_cfg()
+    served = build_server(cfg, 17, batch_slots=3)
+    srv = served.server
+    rng = np.random.default_rng(9)
+    sampling = {"temperature": 0.7, "top_k": 20, "top_p": 0.9}
+    todo = list(enumerate(AHEAD_MIX))
+    reqs = []
+    for n_submit, n_steps in AHEAD_ARRIVALS:
+        for _ in range(n_submit):
+            i, (n, new, sampled) = todo.pop(0)
+            reqs.append(served.submit(
+                rng.integers(0, cfg["vocab_size"], n), new,
+                sampling if sampled else None, seed=i))
+        if eos_at is not None:
+            reqs[0].eos_id = AHEAD_PINNED[0][eos_at]
+        for _ in range(n_steps):
+            srv.step()
+    while served.busy():
+        srv.step()
+        srv.cache.check()
+    want = [list(t) for t in AHEAD_PINNED]
+    if eos_at is not None:
+        want[0] = want[0][:eos_at + 1]
+        assert reqs[0].finish_reason == "eos"
+    assert [list(r.output_tokens) for r in reqs] == want
+    st = srv.stats()
+    assert st["tokens_generated"] == sum(len(t) for t in want)
+    assert 0 < st["ticks_ahead"] < st["ticks"]
+    # the tick with the dropped row held other requests' rows too, so
+    # it was handed over like any other
+    assert st["decode_calls"] == st["ticks"]
+    assert not srv._flights
+    kv = srv.cache
+    assert kv.window_blocks_used == 0 and kv.num_used_blocks == 0
 
 
 # -- (6) what the server refuses ---------------------------------------------

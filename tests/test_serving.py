@@ -1787,3 +1787,315 @@ def test_chunked_prefill_health_backlog_signal(net):
     assert d["speculative"] is False
     server.run()
     assert server.health_detail()["prefill_backlog_tokens"] == 0
+
+
+# -- one decode tick queued ahead ---------------------------------------------
+# The server launches tick n+1 before it reads tick n's tokens. Every
+# request must still hold the tokens of the order that reads before it
+# launches (the parent's), and a row computed for a request that has
+# ended in the meantime must reach nobody.
+
+class _NoDrafts:
+    """A proposer that never proposes. `speculative=` makes the server
+    read a tick before it launches the next, so this is the serial
+    order on the plain decode program: the in-process control."""
+    k = 2
+
+    def propose(self, tokens):
+        return np.zeros(0, np.int32)
+
+
+#: (prompt tokens, new tokens, temperature) and the `step()`s between
+#: the arrivals; greedy and sampled rows share every tick
+AHEAD_MIX = [(5, 9, 0.0), (11, 6, 0.8), (3, 12, 0.0), (16, 7, 1.1),
+             (8, 10, 0.0), (2, 5, 0.7), (13, 8, 0.0)]
+AHEAD_ARRIVALS = [(2, 2), (2, 3), (1, 1), (2, 0)]   # (submit, steps)
+#: what commit 268f475 (the serial tick) served for AHEAD_MIX
+AHEAD_PINNED = [
+    [223, 223, 223, 223, 223, 223, 223, 223, 223],
+    [245, 183, 160, 252, 153, 8],
+    [169, 169, 54, 119, 125, 6, 67, 58, 202, 119, 119, 119],
+    [5, 129, 31, 119, 58, 112, 81],
+    [119, 82, 82, 82, 98, 98, 98, 9, 195, 195],
+    [67, 80, 166, 150, 202],
+    [92, 102, 102, 158, 149, 149, 149, 133]]
+
+
+def _staggered(net, **kw):
+    """AHEAD_MIX through a three-slot server, arriving in four groups
+    with ticks between them. Returns (server, requests)."""
+    rs = np.random.RandomState(21)
+    kw = dict(dict(batch_slots=3, max_len=48, block_size=8,
+                   max_prompt_len=16), **kw)
+    server = InferenceServer(net, **kw)
+    todo = list(enumerate(AHEAD_MIX))
+    reqs = []
+    for n_submit, n_steps in AHEAD_ARRIVALS:
+        for _ in range(n_submit):
+            i, (n, new, temp) = todo.pop(0)
+            reqs.append(server.submit(
+                rs.randint(0, 256, n).astype(np.int32),
+                max_new_tokens=new, temperature=temp,
+                top_k=20 if temp else 0, top_p=0.9 if temp else 0.0,
+                seed=100 + i))
+        for _ in range(n_steps):
+            server.step()
+    server.run()
+    return server, reqs
+
+
+def test_ahead_serves_the_serial_orders_tokens_pinned(net):
+    # the programs are cached on the net by shape: count from here
+    calls0 = InferenceServer(net, batch_slots=3, max_len=48, block_size=8,
+                             max_prompt_len=16).stats()["decode_calls"]
+    server, reqs = _staggered(net)
+    free0 = server.cache.num_blocks - 1
+    assert [r.output_tokens for r in reqs] == AHEAD_PINNED
+    assert all(r.status == "ok" for r in reqs)
+    st = server.stats()
+    assert 0 < st["ticks_ahead"] < st["ticks"]
+    assert st["tokens_generated"] == sum(n for _, n, _ in AHEAD_MIX)
+    # nothing in flight, and the pool is back where it started
+    assert not server._flights
+    assert server.cache.num_free_blocks == free0
+    assert st["decode_calls"] - calls0 == st["ticks"]   # none dropped
+    server.cache.check()
+
+
+@pytest.mark.parametrize("kw", [
+    {}, {"prefix_cache": True}, {"prefill_chunk_tokens": 4},
+    {"kv_cache_dtype": "int8"},
+    {"num_blocks": 6, "max_preemptions": None},
+    {"prefix_cache": True, "prefill_chunk_tokens": 8, "num_blocks": 7,
+     "max_preemptions": None}],
+    ids=["plain", "prefix", "chunked", "int8", "starved",
+         "prefix-chunked-starved"])
+def test_ahead_equals_read_before_launch(net, kw):
+    """Request by request the tokens of the serial order, whatever
+    else the server does in the tick; the serial control never has a
+    tick queued ahead."""
+    ahead, got = _staggered(net, **kw)
+    serial, want = _staggered(net, speculative=_NoDrafts(), **kw)
+    assert serial.stats()["ticks_ahead"] == 0
+    assert ahead.stats()["ticks_ahead"] > 0
+    for g, w in zip(got, want):
+        assert g.status == w.status == "ok"
+        assert g.output_tokens == w.output_tokens
+    for s in (ahead, serial):
+        assert not s._flights
+        assert s.cache.num_used_blocks == 0
+        s.cache.check()
+    if "num_blocks" in kw:
+        assert ahead.stats()["preemptions"] > 0
+
+
+def test_step_from_idle_hands_over_the_first_tick(net):
+    server = InferenceServer(net, batch_slots=2, max_len=32,
+                             block_size=8, max_prompt_len=8)
+    p = np.arange(1, 6, dtype=np.int32)
+    want = generate(net, p[None, :], max_new_tokens=3, max_len=32)[0, 5:]
+    r = server.submit(p, max_new_tokens=3)
+    calls0 = server.compile_stats()["decode_calls"]
+    calls = lambda: (server.compile_stats()["decode_calls"]  # noqa: E731
+                     - calls0)
+    # launch tick 1, launch tick 2, hand over tick 1
+    assert server.step() == 1
+    assert r.output_tokens == [int(want[0])]
+    assert calls() == 2 and len(server._flights) == 1
+    assert server.stats()["ticks_ahead"] == 1
+    # `_active` holds until the LAST token has been handed over, so a
+    # drive loop keeps stepping while a tick is in flight
+    assert server.step() == 1 and calls() == 3
+    assert server._active.any() and len(server._flights) == 1
+    # the third token is in flight: nothing left to launch
+    assert server.step() == 1 and calls() == 3
+    assert r.status == "ok" and r.output_tokens == list(want)
+    assert not server._active.any() and not server._flights
+    assert server.step() == 0 and calls() == 3
+
+
+def _first_new_token(tokens, at_least=2):
+    """Index of the first token from `at_least` on that none before it
+    equals: as `eos_id` it ends the request exactly there."""
+    return next(k for k in range(at_least, len(tokens))
+                if tokens[k] not in tokens[:k])
+
+
+def test_eos_row_behind_the_last_token_is_dropped(net):
+    rs = np.random.RandomState(61)
+    pa = rs.randint(0, 256, 6).astype(np.int32)
+    pb = rs.randint(0, 256, 4).astype(np.int32)
+    solo_a = [int(t) for t in generate(
+        net, pa[None, :], max_new_tokens=12, max_len=32)[0, 6:]]
+    solo_b = [int(t) for t in generate(
+        net, pb[None, :], max_new_tokens=10, max_len=32)[0, 4:]]
+    k = _first_new_token(solo_a)
+    server = InferenceServer(net, batch_slots=2, max_len=32,
+                             block_size=8, max_prompt_len=8)
+    free0 = server.cache.num_free_blocks
+    ra = server.submit(pa, max_new_tokens=12, eos_id=solo_a[k])
+    rb = server.submit(pb, max_new_tokens=10)
+    while ra.status is None:
+        server.step()
+        assert len(ra.output_tokens) <= k + 1
+    assert ra.finish_reason == "eos"
+    assert ra.output_tokens == solo_a[:k + 1]
+    # the tick behind the eos was already queued with a row of ra's:
+    # it reaches nobody, and is not counted
+    server.run()
+    assert ra.output_tokens == solo_a[:k + 1]
+    assert rb.output_tokens == solo_b
+    assert server.tokens_generated == k + 1 + 10
+    assert not server._flights
+    assert server.cache.num_free_blocks == free0
+    server.cache.check()
+
+
+def test_eos_of_the_only_request_leaves_nothing_in_flight(net):
+    p = np.arange(3, 9, dtype=np.int32)
+    solo = [int(t) for t in generate(
+        net, p[None, :], max_new_tokens=12, max_len=32)[0, 6:]]
+    k = _first_new_token(solo)
+    server = InferenceServer(net, batch_slots=2, max_len=32,
+                             block_size=8, max_prompt_len=8)
+    r = server.submit(p, max_new_tokens=12, eos_id=solo[k])
+    calls0 = server.compile_stats()["decode_calls"]
+    steps = 0
+    while server.queue or server._active.any():     # a caller's loop
+        server.step()
+        steps += 1
+    assert steps == k + 1 and r.output_tokens == solo[:k + 1]
+    # k + 2 ticks ran; the last held a dropped row alone and was let go
+    assert server.compile_stats()["decode_calls"] - calls0 == k + 2
+    assert server.ticks == k + 1
+    assert not server._flights
+    assert server.cache.num_used_blocks == 0
+
+
+def test_cancel_drops_the_row_in_flight_and_the_slot_is_reused(net):
+    rs = np.random.RandomState(62)
+    prompts = [rs.randint(0, 256, n).astype(np.int32) for n in (5, 7, 4)]
+    solo = [[int(t) for t in generate(
+        net, p[None, :], max_new_tokens=9, max_len=32)[0, len(p):]]
+        for p in prompts]
+    server = InferenceServer(net, batch_slots=2, max_len=32,
+                             block_size=8, max_prompt_len=8)
+    r0 = server.submit(prompts[0], max_new_tokens=9)
+    r1 = server.submit(prompts[1], max_new_tokens=9)
+    for _ in range(3):
+        server.step()
+    assert len(server._flights) == 1       # with a row of r0's in it
+    assert server.cancel(r0.id)
+    # the newcomer takes r0's slot while that row is still in flight
+    r2 = server.submit(prompts[2], max_new_tokens=9)
+    server.step()
+    assert server._slot_req.index(r2) == 0
+    server.run()
+    assert r0.status == "cancelled" and r0.output_tokens == solo[0][:3]
+    assert r1.output_tokens == solo[1]
+    assert r2.output_tokens == solo[2]     # none of r0's tokens
+    assert server.tokens_generated == 3 + 9 + 9
+    assert not server._flights and server.cache.num_used_blocks == 0
+    server.cache.check()
+
+
+def test_deadline_drops_the_row_in_flight(net):
+    import time as _t
+    p = np.arange(2, 8, dtype=np.int32)
+    solo = [int(t) for t in generate(
+        net, p[None, :], max_new_tokens=20, max_len=32)[0, 6:]]
+    server = InferenceServer(net, batch_slots=1, max_len=32,
+                             block_size=8, max_prompt_len=8)
+    server.warmup()                        # no compile inside the deadline
+    r = server.submit(p, max_new_tokens=20, deadline_s=0.5)
+    server.step()
+    server.step()
+    assert r.output_tokens == solo[:2] and len(server._flights) == 1
+    _t.sleep(0.55)
+    assert server.step() == 0              # expired at the tick's top
+    assert r.status == "timed_out" and r.output_tokens == solo[:2]
+    assert not server._flights
+    assert server.cache.num_used_blocks == 0
+    server.cache.check()
+
+
+def test_preempted_requests_row_in_flight_is_dropped(net):
+    """A starved pool: whenever a step has preempted a request, that
+    request holds no token (its row of the tick in flight went with
+    it), and in the end each holds the serial order's tokens."""
+    rs = np.random.RandomState(18)
+    server = InferenceServer(net, batch_slots=2, max_len=32,
+                             block_size=8, max_prompt_len=12,
+                             num_blocks=6)
+    prompts = [rs.randint(0, 256, 10).astype(np.int32) for _ in range(2)]
+    reqs = [server.submit(p, max_new_tokens=12) for p in prompts]
+    seen = 0
+    while server.queue or server._active.any():
+        server.step()
+        for r in server.queue:
+            assert r.preemptions and r.output_tokens == []
+            seen += 1
+    assert seen and sum(r.preemptions for r in reqs) >= 1
+    for p, r in zip(prompts, reqs):
+        one = generate(net, p[None, :], max_new_tokens=12, max_len=32)
+        assert r.output_tokens == [int(t) for t in one[0, 10:]]
+    assert server.tokens_generated == 24
+    assert not server._flights and server.cache.num_used_blocks == 0
+    server.cache.check()
+
+
+@pytest.mark.parametrize("how", ["run", "drain", "shutdown",
+                                 "shutdown-now"])
+def test_teardown_leaves_no_tick_in_flight(net, how):
+    rs = np.random.RandomState(63)
+    server = InferenceServer(net, batch_slots=2, max_len=32,
+                             block_size=8, max_prompt_len=8)
+    free0 = server.cache.num_free_blocks
+    reqs = [server.submit(rs.randint(0, 256, 5).astype(np.int32),
+                          max_new_tokens=n) for n in (6, 9, 4)]
+    server.step()
+    server.step()
+    assert len(server._flights) == 1
+    if how == "run":
+        server.run()
+    elif how == "drain":
+        server.drain()
+    else:
+        server.shutdown(drain=how == "shutdown")
+    assert not server._flights
+    assert not server._active.any() and not server.queue
+    assert server.cache.num_free_blocks == free0
+    if how == "shutdown-now":
+        assert [r.status for r in reqs] == ["rejected"] * 3
+        assert all(len(r.output_tokens) == 2 for r in reqs[:2])
+    else:
+        assert [len(r.output_tokens) for r in reqs] == [6, 9, 4]
+    server.cache.check()
+
+
+def test_speculation_reads_before_it_launches(net):
+    """Drafts are proposed from the tokens just handed over, so with
+    `speculative=` no tick is ever queued ahead."""
+    rs = np.random.RandomState(64)
+    server = InferenceServer(net, batch_slots=2, max_len=48,
+                             block_size=8, max_prompt_len=8,
+                             speculative=2)
+    prompts = [rs.randint(0, 256, 6).astype(np.int32) for _ in range(3)]
+    reqs = [server.submit(p, max_new_tokens=14) for p in prompts]
+    while server.queue or server._active.any():
+        server.step()
+        assert not server._flights
+    assert server.stats()["ticks_ahead"] == 0
+    assert server.stats()["spec_tokens_accepted"] > 0
+    for p, r in zip(prompts, reqs):
+        one = generate(net, p[None, :], max_new_tokens=14, max_len=48)
+        assert r.output_tokens == [int(t) for t in one[0, 6:]]
+
+
+def test_uploads_are_copies_not_views():
+    from mxnet_tpu.serving.server import _upload
+    host = np.zeros((64, 64), np.int32)
+    dev = _upload(host)
+    row = _upload(host[3])
+    host[:] = 7
+    assert int(dev.sum()) == 0 and int(row.sum()) == 0

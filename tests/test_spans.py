@@ -117,20 +117,41 @@ def serve_trace(net, tmp_path_factory):
 
     spans = record(body, tmp_path_factory.mktemp("serve"))
     assert all(r.status == "ok" for r in reqs)
-    return spans, reqs, seen
+    assert not server._flights
+    return spans, reqs, seen, server.stats()
+
+
+#: what a tick holds between its admit and its decode: the blocks of
+#: the tick it queues; from an idle server also the blocks and the
+#: launch of the tick it hands over; nothing when every slot's last
+#: token is already in flight
+BEFORE_DECODE = {
+    "queues one": ["mx.serve_blocks"],
+    "from idle": ["mx.serve_blocks", "mx.serve_dispatch",
+                  "mx.serve_blocks"],
+    "queues none": [],
+}
 
 
 def test_serve_tick_holds_every_span_of_the_table(serve_trace):
-    spans, _, seen = serve_trace
+    spans, _, seen, _ = serve_trace
     ticks = named(spans, "mx.serve_tick")
     assert len(ticks) == len(seen) + 1
     busy = [t for t in ticks if named(children(spans, t),
                                       "mx.serve_decode")]
     assert len(busy) == len(seen)
+    shapes = []
     for t in busy:
-        assert [s.name for s in children(spans, t)] == [
-            "mx.serve_admit", "mx.serve_blocks", "mx.serve_decode",
-            "mx.serve_emit"]
+        kids = [s.name for s in children(spans, t)]
+        assert kids[0] == "mx.serve_admit"
+        assert kids[-2:] == ["mx.serve_decode", "mx.serve_emit"]
+        shapes.append(next(k for k, v in BEFORE_DECODE.items()
+                           if v == kids[1:-2]))
+    # two requests of four tokens fill the two slots, the third waits
+    # for one: twice from idle, two more launches, then the hand-over
+    # of the last tick with nothing left to queue
+    assert shapes == ["from idle", "queues one", "queues one",
+                      "queues none"] * 2
     # every span of the tick's table lies inside some tick
     for s in spans:
         if s.name.startswith("mx.serve_") and s.name != "mx.serve_tick":
@@ -138,7 +159,7 @@ def test_serve_tick_holds_every_span_of_the_table(serve_trace):
 
 
 def test_serve_prefill_is_inside_admit_with_its_counts(serve_trace):
-    spans, reqs, _ = serve_trace
+    spans, reqs, _, _ = serve_trace
     prefills = named(spans, "mx.serve_prefill")
     assert len(prefills) == len(PROMPTS)
     admits = named(spans, "mx.serve_admit")
@@ -154,35 +175,47 @@ def test_serve_prefill_is_inside_admit_with_its_counts(serve_trace):
 
 
 def test_dispatch_and_wait_tile_decode(serve_trace):
-    spans, _, _ = serve_trace
+    """The launch of the tick being queued, then the read of the tick
+    before it; the launch is missing where nothing is left to queue."""
+    spans, _, _, _ = serve_trace
     decodes = named(spans, "mx.serve_decode")
     assert decodes
+    both = 0
     for d in decodes:
         kids = children(spans, d)
-        assert [k.name for k in kids] == ["mx.serve_dispatch",
-                                          "mx.serve_wait"]
+        assert [k.name for k in kids] in (
+            ["mx.serve_dispatch", "mx.serve_wait"], ["mx.serve_wait"])
+        if len(kids) == 1:
+            continue
+        both += 1
         disp, wait = kids
+        assert disp.stats["ahead"] == 1
         assert disp.end <= wait.start
         # nothing of the program's runs between them: the two cover
         # the phase but for the annotations' own entry and exit
         covered = (disp.end - disp.start) + (wait.end - wait.start)
         assert covered >= 0.5 * (d.end - d.start)
+    assert both == 6
 
 
 def test_dispatch_counts_the_slots_the_server_batched(serve_trace):
-    spans, _, seen = serve_trace
+    spans, _, seen, stats = serve_trace
     # the tick itself carries no count
     assert all(t.stats == {} for t in named(spans, "mx.serve_tick"))
-    # the dispatch counts the slots batched AFTER this tick's admits:
-    # two from the first tick on, one once only the third request runs
-    disp = [s.stats["active"] for s in named(spans, "mx.serve_dispatch")]
-    assert len(disp) == len(seen)
-    assert disp[0] == 2 and disp[-1] == 1
-    assert all(1 <= a <= 2 for a in disp)
-    # and it is the active count the NEXT tick opens with, unless a
-    # request finished in between
-    for a, (_, active) in zip(disp, seen[1:]):
-        assert active <= a
+    # every tick is launched once and handed over once, a step() each
+    disp = named(spans, "mx.serve_dispatch")
+    assert len(disp) == len(seen) == stats["ticks"]
+    # the dispatch counts the rows of the tick it queues, AFTER this
+    # step's admits: two while the first two requests run, one once
+    # only the third does; a slot whose last token is in flight has
+    # no row, though the step still opens with it active
+    assert [s.stats["active"] for s in disp] == [2] * 4 + [1] * 4
+    assert [active for _, active in seen] == [0, 2, 2, 2, 0, 1, 1, 1]
+    # `ahead`: whether a tick was in flight at the launch: not for the
+    # first of the two an idle server launches
+    ahead = [s.stats["ahead"] for s in disp]
+    assert ahead == [0, 1, 1, 1] * 2
+    assert sum(ahead) == stats["ticks_ahead"]
 
 
 def test_a_decoder_with_counts_puts_them_on_its_spans(tmp_path):
@@ -223,8 +256,8 @@ def test_a_decoder_with_counts_puts_them_on_its_spans(tmp_path):
 
 
 def test_the_llama_block_adds_no_count(serve_trace):
-    spans, _, _ = serve_trace
-    assert all(set(s.stats) == {"active"}
+    spans, _, _, _ = serve_trace
+    assert all(set(s.stats) == {"active", "ahead"}
                for s in named(spans, "mx.serve_dispatch"))
     assert all(s.stats == {} for s in named(spans, "mx.serve_emit"))
 
