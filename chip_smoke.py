@@ -142,6 +142,7 @@ TOL_ATTN_GRAD = 2e-2
 #: the int8 cache is compared with the reference ON THE DEQUANTIZED
 #: cache, so the kernel's error is the same as the bf16 one
 TOL_DECODE = 1e-2
+TOL_SCAN = 1e-3      # float32 in and out; exp and the order of a 16-term sum differ
 #: norm kernels compute in fp32 and round once to the activation dtype
 TOL_NORM = 1e-2
 #: the per-row loss is fp32 from the same upcast logits as the
@@ -308,7 +309,8 @@ def phase_kernels(size):
     from mxnet_tpu.kernels import (dispatch, flash_attention as fa,
                                    flash_decode as fd, fused_ce as ce,
                                    fused_norm as fnorm,
-                                   grouped_matmul as _gmm)  # noqa: F401
+                                   grouped_matmul as _gmm,  # noqa: F401
+                                   selective_scan as ssm)
     from mxnet_tpu.parallel import moe
 
     fallbacks0 = dispatch.fallback_counts()
@@ -576,6 +578,42 @@ def phase_kernels(size):
                 x, *w_, lo=0, top_k=top_k, route_scale=2.448)[0],
             experts_ref, (randn((rows_, size.hidden)), rw, rb, eg, eu, ed),
             ("moe_grouped_matmul",), (TOL_ATTN,))
+
+    # the state-space kernels: the scan over a prompt from a nonzero
+    # state (three time chunks, dt = 0 on a padded tail) and one decode
+    # step of every row, two rows idle
+    Dn, Ns_, Ts = 2 * size.hidden, 16, 3 * 256 - 40
+    a_log = jnp.log(jnp.broadcast_to(
+        jnp.arange(1, Ns_ + 1, dtype=f32)[:, None], (Ns_, Dn)))
+    xs, bs_, cs = randn((1, Ts, Dn), f32), randn((1, Ts, Ns_), f32), \
+        randn((1, Ts, Ns_), f32)
+    dts = jnp.where(jnp.arange(Ts)[None, :, None] < Ts - 9,
+                    jax.nn.softplus(randn((1, Ts, Dn), f32) - 3.0), 0.0)
+    h0 = randn((1,) + ssm.state_shape(Ns_, Dn), f32)
+    run("selective_scan prompt", ssm.selective_scan,
+        ssm.selective_scan_ref, (xs, dts, a_log, bs_, cs, h0),
+        ("selective_scan_fwd",), (TOL_SCAN, TOL_SCAN))
+    hr = randn((B,) + ssm.state_shape(Ns_, Dn), f32)
+    live = jnp.arange(B) % 4 != 1
+    run("ssm_state_update decode rows",
+        lambda *a: ssm.ssm_state_update(*a, live),
+        lambda *a: ssm.ssm_state_update_ref(*a, live),
+        (hr, xs[0, :B], dts[0, :B], a_log, bs_[0, :B], cs[0, :B]),
+        ("ssm_state_update",), (TOL_SCAN, TOL_SCAN))
+
+    # decode and prefill attention at 20 query heads on ONE kv head: a
+    # group that is no multiple of 8 sublanes, pages of 4 KB
+    q20 = randn((Bs, 20, d))
+    run("flash_decode paged, 20 heads on 1 kv head, serving table",
+        fd.flash_decode_paged, paged_ref,
+        (q20, randn((Ns, 1, bs, d)), randn((Ns, 1, bs, d)),
+         jnp.asarray(bts), jnp.asarray(vls)),
+        ("flash_decode_paged",), (TOL_DECODE,))
+    run("flash_attention causal, 20 heads on 1 kv head, fwd",
+        lambda q, k, v: fa.flash_attention_raw(q, k, v, causal=True),
+        lambda q, k, v: fa.reference_attention(*up(q, k, v), causal=True),
+        (randn((1, T, 20, d)), randn((1, T, 1, d)), randn((1, T, 1, d))),
+        ("flash_attention_fwd",), (TOL_ATTN,))
 
     check_no_fallbacks("kernels", fallbacks0)
 

@@ -246,7 +246,7 @@ def _paged_compiler_params(pltpu, interpret):
         dimension_semantics=("parallel", "parallel", "arbitrary"))}
 
 
-def _paged_sweep_pages(pool_shape, itemsize, nb=None):
+def _paged_sweep_pages(pool_shape, itemsize, nb=None, group=1):
     """Pages one step of the paged sweep moves: as many as the tuned
     VMEM budget holds, from static shapes alone; 0 when not even one
     fits, in which case the gate below says "gather".
@@ -257,14 +257,16 @@ def _paged_sweep_pages(pool_shape, itemsize, nb=None):
     and widens one head's k and v of a step to fp32 when the MXU
     cannot take them as stored; beside them the q and o blocks the
     pipeline double-buffers and the fp32 m / l / acc scratch, reckoned
-    for a group of up to 16 query heads a kv head (one bf16 tile of
-    rows). On a v5e the kernel's time falls with every page added up
-    to the budget (tuned.json has the sweep)."""
+    for the `group` of query heads a kv head in whole bf16 tiles of 16
+    rows (a group of 20 takes two). On a v5e the kernel's time falls
+    with every page added up to the budget (tuned.json has the
+    sweep)."""
     from . import tuning
 
     _, K, bs, d = pool_shape
     page = K * bs * d * itemsize
-    fixed = 4 * K * 16 * d * itemsize + 3 * K * 16 * max(d, 128) * 4
+    rows = 16 * -(-group // 16)
+    fixed = 4 * K * rows * d * itemsize + 3 * K * rows * max(d, 128) * 4
     fit = (tuning.get("flash_decode_paged", "vmem_budget_bytes")
            - fixed) // (4 * page + 2 * bs * d * 4)
     if nb is not None:
@@ -300,7 +302,8 @@ def _flash_decode_paged_pallas(q, k_pages, v_pages, block_tables,
     a sliding-window layer reads min(valid_len, window) positions, and
     the table entries before that page are never looked at."""
     pages = _paged_sweep_pages(k_pages.shape, k_pages.dtype.itemsize,
-                               block_tables.shape[1])
+                               block_tables.shape[1],
+                               q.shape[1] // k_pages.shape[1])
     return _paged_sweep(q, k_pages, v_pages, block_tables, valid_len,
                         scale=float(scale), pages=pages,
                         interpret=bool(interpret),

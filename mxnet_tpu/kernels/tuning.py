@@ -49,6 +49,13 @@ DEFAULTS = {
     # case, every pair on a held expert). Hand-chosen.
     "moe_grouped_matmul": {"block_k": 512, "block_n": 1024,
                            "chunk_tokens": 2048},
+    # the state-space kernels: positions a grid step of the prompt scan
+    # holds in VMEM (x, dt and y blocks of 8 x 128 channels, fp32,
+    # double-buffered: 6 MB at 256), and rows of the state pool a grid
+    # step of the decode update moves in and out (2.6 MB each way at
+    # 8 rows of 16 x 5120 fp32). Hand-chosen.
+    "selective_scan": {"time_chunk": 256},
+    "ssm_state_update": {"rows": 8},
 }
 
 _cache: Optional[dict] = None

@@ -4,21 +4,25 @@
 model: they ask the net for its `DecoderDescription` (`net.decoder()`)
 and build one prefill and one decode body from it. A description gives
 the layer kinds (a FULL layer caches every position, a SLIDING one the
-last `window`), the parameter tree, and the layer in the three forms
-the programs use: whole (prefill), and split around the paged attention
-call (decode). The Llama block is the first description
-(`llama_infer.LlamaDecoder`), afmoe the second (`afmoe.AfmoeDecoder`).
+last `window`, a RECURRENT one a fixed-size state a sequence and
+nothing a token), the parameter tree, and the layer in the forms the
+programs use: an attention layer whole (prefill) and split around the
+paged attention call (decode); a recurrent layer whole, handing back
+the state at each row's length (prefill), and for one token of every
+row, taking and returning the rows' states (decode). The Llama block is
+the first description (`llama_infer.LlamaDecoder`), afmoe the second
+(`afmoe.AfmoeDecoder`), Jamba the third (`jamba.JambaDecoder`).
 """
 from __future__ import annotations
 
-FULL, SLIDING = "full", "sliding"
+FULL, SLIDING, RECURRENT = "full", "sliding", "recurrent"
 
 
 class DecoderDescription:
     """Base of the descriptions. `cfg` carries num_layers, num_heads,
     num_kv_heads, head_dim, vocab_size, rms_eps, dtype.
 
-    layer_kinds  one of FULL / SLIDING a layer
+    layer_kinds  one of FULL / SLIDING / RECURRENT a layer
     window       positions a SLIDING layer attends, else None
     counts       names of the int32 counts `layer_finish` /
                  `prefill_layer` return a layer (summed over layers
@@ -26,20 +30,30 @@ class DecoderDescription:
     supports     the server features the description's layer functions
                  implement, of: prefill_chunk, speculative, lora, int8,
                  prefix_cache, kv_tier
+    decode_compiler_options
+                 options the decode program is compiled with on a TPU,
+                 None for the compiler's defaults
     """
 
     layer_kinds = ()
     window = None
     counts = ()
     supports = frozenset()
+    decode_compiler_options = None
 
     def __init__(self, cfg):
         self.cfg = cfg
 
     @property
     def mixed(self):
-        """True where the cache has to hold two kinds of layer."""
+        """True where the cache has to hold two kinds of attention
+        layer, each with a pool and a table of its own."""
         return SLIDING in self.layer_kinds
+
+    @property
+    def recurrent(self):
+        """True where some layer keeps a state a sequence."""
+        return RECURRENT in self.layer_kinds
 
     def layer_window(self, li):
         return self.window if self.layer_kinds[li] == SLIDING else None
@@ -48,8 +62,11 @@ class DecoderDescription:
         if feature not in self.supports:
             raise NotImplementedError(
                 f"{what} is not implemented for {type(self).__name__} "
-                f"(layer kinds {sorted(set(self.layer_kinds))}, counts "
-                f"{list(self.counts)}): serve this net without it")
+                f"(layer kinds {sorted(set(self.layer_kinds))} of "
+                f"{[FULL, SLIDING, RECURRENT]}, counts "
+                f"{list(self.counts)}; it implements "
+                f"{sorted(self.supports) or 'plain prefill and decode'}"
+                f"): serve this net without it")
 
     # -- what a description implements ------------------------------------
     def params_tree(self, net):
@@ -61,6 +78,25 @@ class DecoderDescription:
 
     def prefill_layer(self, li, lp, x, positions, lengths, lora=None):
         """The whole layer on (B, T, D) -> (x, k, v, counts | None)."""
+        raise NotImplementedError
+
+    # -- a RECURRENT layer's forms ------------------------------------------
+    def state_shapes(self):
+        """{name: (shape a sequence, dtype)} of a RECURRENT layer's
+        state: what the cache keeps a slot a layer."""
+        raise NotImplementedError
+
+    def prefill_recurrent(self, li, lp, x, lengths):
+        """The whole layer on (B, T, D) from a zero state -> (x, state,
+        counts | None): `state` {name: (B,) + shape} as it stands after
+        each row's `lengths` positions (right padding must not advance
+        it)."""
+        raise NotImplementedError
+
+    def decode_recurrent(self, li, lp, x, state, active):
+        """One token of every row, x (B, 1, D), `state` the rows'
+        states -> (x, state, counts | None). A row whose `active` is
+        False hands its state back untouched."""
         raise NotImplementedError
 
     def layer_qkv(self, li, lp, x, positions, lora=None):

@@ -63,7 +63,7 @@ class Program:
     accounting into tracing.cache_stats() (and, through it, the
     telemetry compile counters)."""
 
-    def __init__(self, name, fn, donate_argnums=()):
+    def __init__(self, name, fn, donate_argnums=(), compiler_options=None):
         self.name = name
         self.compiles = 0
         self.calls = 0
@@ -76,7 +76,12 @@ class Program:
 
         # the executable's name in a profiler trace: jit_counted_<name>
         counted.__name__ = counted.__qualname__ = "counted_" + name
-        self._jit = jax.jit(counted, donate_argnums=donate_argnums)
+        # a description's options are the TPU compiler's: no other
+        # backend knows them
+        options = {"compiler_options": dict(compiler_options)} \
+            if compiler_options and jax.default_backend() == "tpu" else {}
+        self._jit = jax.jit(counted, donate_argnums=donate_argnums,
+                            **options)
 
     def __call__(self, *args):
         self.calls += 1
@@ -207,14 +212,19 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
                    lora=None):
     """Serving executables over a paged pool:
 
-    prefill(params, pages, bt_row, ids, valid_len, shared_len)
+    prefill(params, pages, bt_row, ids, valid_len, shared_len[, slot])
         -> (pages, last_logits):  ONE request (batch 1, right-padded
         to max_prompt_len) through the training-identical layer math,
         k/v written straight into its allocated blocks (padding tokens
         route to the scratch block). Positions below `shared_len` (a
         traced (1,) int32 — prefix-cache hits share ONE compiled
         prefill with cold prompts) also sink to scratch: their cache
-        content is already resident in adopted shared blocks.
+        content is already resident in adopted shared blocks. A net
+        with RECURRENT layers (models/decoder.py) takes one operand
+        more, `slot` (1,) int32: such a layer's entry of `pages` is a
+        state pool {name: (batch_slots, ...)} and the prefill writes
+        row `slot` of it whole, with the state as it stands after
+        `valid_len` positions.
 
     copy_block(pages, src, dst) -> pages: device-side block copy for
         prefix-cache copy-on-write (src/dst traced scalars, so every
@@ -232,7 +242,9 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
         per-row sampling of the PREVIOUS logits, one decode step for
         all batch slots, paged cache write, per-row PRNG advance.
         Inactive slots compute against the scratch block and their
-        outputs are discarded by the scheduler.
+        outputs are discarded by the scheduler. A RECURRENT layer has
+        no scratch: its step is masked by `active`, and an inactive
+        row's state comes back as it went in.
 
     prefill_chunk(params, pages, bt_row, ids, chunk_start, chunk_len)
         -> (pages, last_logits)  [when prefill_chunk > 0]: ONE
@@ -278,7 +290,7 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
     if ent is not None:
         return ent
 
-    from ..models.decoder import SLIDING
+    from ..models.decoder import RECURRENT, SLIDING
     from ..models.llama_math import final_logits, rms
     from ..kernels.flash_decode import (
         flash_decode_paged, flash_decode_paged_quantized,
@@ -353,9 +365,17 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
         return {"k": pg["k"].at[blk_ids, :, offs, :].set(k_rows),
                 "v": pg["v"].at[blk_ids, :, offs, :].set(v_rows)}
 
+    def write_state(pg, state, slot):
+        """Row `slot` of a recurrent layer's state pool, whole."""
+        return {name: lax.dynamic_update_slice_in_dim(
+            pg[name], state[name].astype(pg[name].dtype), slot, axis=0)
+            for name in pg}
+
     def prefill(params, pages, bt_row, ids, valid_len, shared_len,
                 *lo):
         B, T = ids.shape                       # B == 1
+        if dec.recurrent:
+            slot, lo = lo[0][0], lo[1:]
         la = gather_lora(lo)
         x = dec.embed(params, ids)
         positions = jnp.arange(T)
@@ -370,6 +390,12 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
         new_pages = []
         counts = None
         for li, (lp, pg) in enumerate(zip(params["layers"], pages)):
+            if dec.layer_kinds[li] == RECURRENT:
+                x, state, c = dec.prefill_recurrent(li, lp, x,
+                                                    valid_len)
+                counts = add_counts(counts, c)
+                new_pages.append(write_state(pg, state, slot))
+                continue
             x, k, v, c = dec.prefill_layer(li, lp, x, positions,
                                            valid_len, lora=la[li])
             counts = add_counts(counts, c)
@@ -396,6 +422,11 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
         new_pages = []
         counts = None
         for li, (lp, pg) in enumerate(zip(params["layers"], pages)):
+            if dec.layer_kinds[li] == RECURRENT:
+                x, npg, c = dec.decode_recurrent(li, lp, x, pg, active)
+                counts = add_counts(counts, c)
+                new_pages.append(npg)
+                continue
             q, k, v, carry = dec.layer_qkv(li, lp, x, pos[:, None],
                                            lora=la[li])
             bt = kind_of(block_tables, li)
@@ -530,8 +561,9 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
 
     ent = {"prefill": Program("serving_prefill", prefill,
                               donate_argnums=(1,)),
-           "decode": Program("serving_decode", decode,
-                             donate_argnums=(1,)),
+           "decode": Program(
+               "serving_decode", decode, donate_argnums=(1,),
+               compiler_options=dec.decode_compiler_options),
            "copy_block": Program("serving_copy_block", copy_block,
                                  donate_argnums=(0,)),
            "spill_block": Program("serving_spill_block", spill_block),

@@ -53,6 +53,20 @@ writes for positions outside the window sink into scratch block 0 by
 the same zero entries. Admission, `ensure`, `free_slot` and `check()`
 count both kinds; prefix sharing, rewind and tiering know one kind
 only, and the server refuses them for such a net.
+
+Recurrent layers (layer_kinds with "recurrent" entries, `state_shapes`
+= {name: (shape, dtype)}): such a layer caches no rows, it keeps a
+fixed-size state a sequence. Its entry of `pages` is the STATE POOL
+{name: (batch_slots,) + shape}: row `slot` is the state of the
+sequence in batch slot `slot`, so the pool needs no table, and block
+pools exist for the attention layers only. `alloc` takes the slot's
+row, `free_slot` gives it back (a preemption is a `free_slot`: the
+state is not snapshotted, re-admission prefills it anew); the prefill
+program overwrites the whole row, so a reused row carries nothing
+over. A row is in use exactly while its slot owns blocks
+(`state_slots_used`); `check()` holds every layer's pool to its kind.
+A state cannot be shared by prefix nor rewound by a token, and the
+server refuses those features for such a net.
 """
 from __future__ import annotations
 
@@ -82,7 +96,8 @@ class PagedKVCache:
                  dtype=jnp.float32, quantized: bool = False,
                  prefix_cache: bool = False, device=None,
                  layer_kinds=None, window: Optional[int] = None,
-                 window_num_blocks: Optional[int] = None):
+                 window_num_blocks: Optional[int] = None,
+                 state_shapes=None):
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is the "
                              "reserved scratch block)")
@@ -92,6 +107,13 @@ class PagedKVCache:
             raise ValueError(f"layer_kinds names {len(kinds)} layers, "
                              f"num_layers={num_layers}")
         self.layer_kinds = kinds
+        self.state_shapes = dict(state_shapes or {}) \
+            if "recurrent" in kinds else {}
+        if "recurrent" in kinds and (quantized or prefix_cache
+                                     or not self.state_shapes):
+            raise NotImplementedError(
+                "a cache with recurrent layers needs their state_shapes"
+                " and has no int8 pool and no prefix sharing")
         self.window = int(window) if "sliding" in kinds else None
         if self.window is not None and (quantized or prefix_cache):
             raise NotImplementedError(
@@ -134,12 +156,23 @@ class PagedKVCache:
                                 jnp.float32)}, dev)
                           for _ in range(num_layers)]
         else:
-            self.pages = [jax.device_put(
-                {"k": jnp.zeros((n, K, bs, d), dtype),
-                 "v": jnp.zeros((n, K, bs, d), dtype)}, dev)
-                          for n in (self.window_num_blocks
-                                    if kind == "sliding" else N
-                                    for kind in kinds)]
+            def layer_pages(kind):
+                if kind == "recurrent":
+                    return {name: jnp.zeros((batch_slots,) + tuple(shape),
+                                            dt)
+                            for name, (shape, dt)
+                            in self.state_shapes.items()}
+                n = self.window_num_blocks if kind == "sliding" else N
+                return {"k": jnp.zeros((n, K, bs, d), dtype),
+                        "v": jnp.zeros((n, K, bs, d), dtype)}
+
+            self.pages = [jax.device_put(layer_pages(kind), dev)
+                          for kind in kinds]
+        #: bytes of the recurrent layers' state pool (0 without them)
+        self.state_pool_bytes = sum(
+            int(a.size) * a.dtype.itemsize
+            for kind, pg in zip(kinds, self.pages) if kind == "recurrent"
+            for a in pg.values())
 
         # host-side allocator state. Free list is LIFO (hot blocks get
         # reused first); block 0 never enters it.
@@ -219,6 +252,14 @@ class PagedKVCache:
     @property
     def window_blocks_capacity(self) -> int:
         return max(0, self.window_num_blocks - 1)
+
+    @property
+    def state_slots_used(self) -> int:
+        """Rows of the state pool a sequence holds: a slot's row goes
+        with its blocks, taken by `alloc` and given back by
+        `free_slot`, so there is no second book to keep."""
+        return sum(bool(b) for b in self._slot_blocks) \
+            if self.state_shapes else 0
 
     def blocks_for(self, num_tokens: int) -> int:
         return max(1, math.ceil(num_tokens / self.block_size))
@@ -304,6 +345,9 @@ class PagedKVCache:
                "cow_copies": self.cow_count,
                "fragmentation": self.fragmentation(),
                "parked_blocks": self.parked_blocks()}
+        if self.state_shapes:
+            out.update(state_pool_bytes=self.state_pool_bytes,
+                       state_slots_used=self.state_slots_used)
         if self.window is not None:
             out.update(
                 window_blocks_used=self.window_blocks_used,
@@ -723,6 +767,15 @@ class PagedKVCache:
             # tier invariants: one tier per content key, conservation
             # across spill/restore/adopt (KVTierManager.check)
             self.tier.check()
+        for kind, pg in zip(self.layer_kinds, self.pages or ()):
+            # a recurrent layer holds the state pool, a row a slot, and
+            # no block pool; an attention layer the reverse
+            want = {n: (self.batch_slots,) + tuple(shape)
+                    for n, (shape, _) in self.state_shapes.items()} \
+                if kind == "recurrent" else None
+            assert want is None and "k" in pg or \
+                {n: a.shape for n, a in pg.items()} == want, \
+                f"a {kind} layer's pool out of shape: {sorted(pg)}"
         if self.window is not None:
             wowned = [b for blks in self._wslot_blocks for b in blks]
             assert 0 not in wowned and 0 not in self._wfree, \

@@ -374,16 +374,24 @@ class InferenceServer:
         # executable — one silent extra compile per program
         self._device = dev = _params_device(params)
         self._params = jax.device_put(params, dev)
+        kinds = {}
+        if dec.mixed or dec.recurrent:
+            kinds["layer_kinds"] = dec.layer_kinds
+        if dec.mixed:
+            kinds.update(window=dec.window,
+                         window_num_blocks=window_num_blocks)
+        if dec.recurrent:
+            kinds["state_shapes"] = dec.state_shapes()
         self.cache = PagedKVCache(
             num_layers=cfg.num_layers, num_kv_heads=cfg.num_kv_heads,
             head_dim=cfg.head_dim, num_blocks=num_blocks,
             block_size=block_size, batch_slots=batch_slots,
             max_blocks_per_seq=max_blocks, dtype=model_dtype,
             quantized=kv_cache_dtype == "int8",
-            prefix_cache=prefix_cache, device=dev,
-            **({"layer_kinds": dec.layer_kinds, "window": dec.window,
-                "window_num_blocks": window_num_blocks}
-               if dec.mixed else {}))
+            prefix_cache=prefix_cache, device=dev, **kinds)
+        #: a net with recurrent layers: prefill takes the slot, and the
+        #: spans carry the counts its kernels' rooflines read
+        self._recurrent = dec.recurrent
         #: cached positions the decode ticks attended, summed over
         #: active slots and ticks: every one (`context_tokens`) and the
         #: last `window` of them (`window_context_tokens`, what a
@@ -432,10 +440,11 @@ class InferenceServer:
         from ..kernels.flash_decode import (paged_kernel_mode,
                                             paged_gather_bytes)
         q8 = kv_cache_dtype == "int8"
-        pool_k = self.cache.pages[0]["k"]
+        pool_k = next(pg["k"] for pg in self.cache.pages if "k" in pg)
         self._kernel_paged = paged_kernel_mode(pool_k,
                                                quantized=q8) is not None
-        self._gather_bytes_per_tick = cfg.num_layers * paged_gather_bytes(
+        self._gather_bytes_per_tick = sum(
+            "k" in pg for pg in self.cache.pages) * paged_gather_bytes(
             pool_k.shape, (batch_slots, max_blocks),
             pool_k.dtype.itemsize, quantized=q8)
 
@@ -831,12 +840,17 @@ class InferenceServer:
         ids[0, :T] = req.prompt
         bt_row = self._tables(slot)
         t_pf = time.perf_counter()
+        # recurrent layers: the slot's row of the state pool is this
+        # prefill's to write, and the scan's roofline reads the tokens
+        slot_arg, scanned = ((jnp.asarray([slot], jnp.int32),),
+                             {"scan_tokens": T}) \
+            if self._recurrent else ((), {})
         with telemetry.phase("serve_prefill", tokens=T,
-                             padded=self.max_prompt_len):
+                             padded=self.max_prompt_len, **scanned):
             self.cache.pages, last, *counts = self.programs["prefill"](
                 self._params, self.cache.pages, bt_row,
                 jnp.asarray(ids), jnp.asarray([T], jnp.int32),
-                jnp.asarray([shared_len], jnp.int32),
+                jnp.asarray([shared_len], jnp.int32), *slot_arg,
                 *self._lora_args([req.adapter_idx]))
         # the decoder's counts of this prefill stay on the device
         # until the tick's one sync reads them
@@ -1418,10 +1432,14 @@ class InferenceServer:
         """Count the cached positions the rows `send` of a decode tick
         attend. With sliding-window layers the two sums also go on
         `mx.serve_dispatch` (`ctx`, `window_ctx`): what the full and
-        the sliding layers' sweeps read, for their roofline."""
+        the sliding layers' sweeps read, for their roofline; with
+        recurrent layers `ctx` and `ssm_rows`, the rows whose state the
+        tick steps."""
         vl = self._pos[send].astype(np.int64) + 1
         ctx = int(vl.sum())
         self.context_tokens += ctx
+        if self._recurrent:
+            return {"ctx": ctx, "ssm_rows": int(send.sum())}
         if self.decoder.window is None:
             return {}
         wctx = int(np.minimum(vl, self.decoder.window).sum())
@@ -1933,6 +1951,8 @@ class InferenceServer:
                 global_blocks_capacity=kv.global_blocks_capacity,
                 context_tokens=self.context_tokens,
                 window_context_tokens=self.window_context_tokens)
+        if self._recurrent:
+            out.update(context_tokens=self.context_tokens)
         out.update(self.decoder_counts)
         v = self.programs.get("verify")
         if v is not None:
@@ -1966,6 +1986,9 @@ class InferenceServer:
             extra["adapters"] = self.lora.stats()
         if self._wfs is not None:
             extra["tenant_passes"] = self._wfs.snapshot()
+        if self._recurrent:
+            extra.update(state_pool_bytes=self.cache.state_pool_bytes,
+                         state_slots_used=self.cache.state_slots_used)
         return {"ticks": self.ticks,
                 "ticks_ahead": self.ticks_ahead,
                 **extra,

@@ -47,7 +47,7 @@ TINY = chip_smoke.Size(
 def interpret(monkeypatch):
     """The kernels' CPU switch: trace the real Pallas kernels and run
     them under the interpreter."""
-    for fam in ("FLASH", "NORM", "CE", "MOE"):
+    for fam in ("FLASH", "NORM", "CE", "MOE", "SCAN"):
         monkeypatch.setenv(f"MXNET_TPU_{fam}_INTERPRET", "1")
 
 

@@ -509,3 +509,44 @@ def test_paged_window_quantized_matches_fp32_loosely():
     assert out.dtype == qw.dtype
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=0.08, atol=0.08)
+
+
+# -- 20 query heads on ONE kv head (jamba2_3b): a group that is no
+# multiple of 8 sublanes, a page of one kv head -----------------------------
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5),
+                                       ("bfloat16", 3e-2)])
+@pytest.mark.parametrize("vl", [None, [1, 16, 77]])
+def test_paged_sweep_at_a_group_of_twenty_on_one_kv_head(dtype, tol, vl):
+    from mxnet_tpu.kernels.flash_decode import _flash_decode_paged_pallas
+    q, kc, vc, kp, vp, bt, vl = _paged_data(B=3, H=20, K=1, S=128, d=128,
+                                            bs=16, seed=21, vl=vl)
+    dt = jnp.dtype(dtype)
+    out = _flash_decode_paged_pallas(
+        q.astype(dt), kp.astype(dt), vp.astype(dt), bt, vl, 128 ** -0.5,
+        interpret=True)
+    ref = reference_decode_attention(q.astype(dt), kc.astype(dt),
+                                     vc.astype(dt), vl, 128 ** -0.5)
+    assert out.shape == (3, 20, 128)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("lengths", [None, [256, 100]])
+def test_flash_attention_at_a_group_of_twenty_on_one_kv_head(
+        lengths, monkeypatch):
+    from mxnet_tpu.kernels.flash_attention import (flash_attention_raw,
+                                                   reference_attention)
+    monkeypatch.setenv("MXNET_TPU_FLASH_INTERPRET", "1")
+    rs = np.random.RandomState(4)
+    q = jnp.asarray(rs.randn(2, 256, 20, 128), jnp.float32)
+    k = jnp.asarray(rs.randn(2, 256, 1, 128), jnp.float32)
+    v = jnp.asarray(rs.randn(2, 256, 1, 128), jnp.float32)
+    n = None if lengths is None else jnp.asarray(lengths, jnp.int32)
+    out = flash_attention_raw(q, k, v, causal=True, lengths=n)
+    ref = reference_attention(q, k, v, causal=True, lengths=n)
+    rows = slice(None) if lengths is None else slice(0, 100)
+    np.testing.assert_allclose(np.asarray(out)[:, rows],
+                               np.asarray(ref)[:, rows],
+                               rtol=2e-4, atol=2e-4)

@@ -255,11 +255,50 @@ def test_a_decoder_with_counts_puts_them_on_its_spans(tmp_path):
     assert all(s.stats["touched"] <= 4 * 8 for s in emit)
 
 
+def test_a_recurrent_decoder_counts_its_rows_and_scanned_tokens(
+        tmp_path):
+    """Jamba: `ssm_rows` (rows whose state the tick steps) and `ctx` on
+    mx.serve_dispatch, `scan_tokens` (valid prompt tokens) beside
+    `tokens` / `padded` on mx.serve_prefill; they add up to what the
+    server counted. No `window_ctx`: there is no sliding layer."""
+    net = mx.models.get_model("jamba_tiny")
+    net.initialize()
+    server = InferenceServer(net, batch_slots=2, max_len=64,
+                             block_size=8, max_prompt_len=48)
+    rs = np.random.RandomState(3)
+    prompts = (40, 6, 9)
+
+    def body():
+        for n in prompts:
+            server.submit(rs.randint(0, 256, n).astype(np.int32),
+                          max_new_tokens=5)
+        server.run()
+
+    spans = record(body, tmp_path)
+    disp = named(spans, "mx.serve_dispatch")
+    pre = named(spans, "mx.serve_prefill")
+    assert len(disp) == server.ticks and len(pre) == 3
+    assert all(set(s.stats) == {"active", "ahead", "ctx", "ssm_rows"}
+               for s in disp)
+    assert all(s.stats["ssm_rows"] == s.stats["active"] for s in disp)
+    assert sum(s.stats["ssm_rows"] for s in disp) == 3 * 5
+    stats = server.compile_stats()
+    assert sum(s.stats["ctx"] for s in disp) \
+        == stats["context_tokens"] > 0
+    assert [s.stats["scan_tokens"] for s in pre] == list(prompts)
+    assert all(s.stats["scan_tokens"] == s.stats["tokens"]
+               and s.stats["padded"] == 48 for s in pre)
+    assert server.stats()["state_pool_bytes"] \
+        == server.cache.stats()["state_pool_bytes"] > 0
+
+
 def test_the_llama_block_adds_no_count(serve_trace):
     spans, _, _, _ = serve_trace
     assert all(set(s.stats) == {"active", "ahead"}
                for s in named(spans, "mx.serve_dispatch"))
     assert all(s.stats == {} for s in named(spans, "mx.serve_emit"))
+    assert all(set(s.stats) == {"tokens", "padded"}
+               for s in named(spans, "mx.serve_prefill"))
 
 
 def test_early_return_ticks_close_serve_tick(net, tmp_path):

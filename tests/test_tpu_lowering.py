@@ -188,6 +188,92 @@ def test_afmoe_kernels_compile_for_v5e(what, one_chip):
     assert name in text
 
 
+# -- the Jamba cell's kernels at its shapes (jamba2_3b.reason256: 256
+# slots of max_len 10,240, 20 query heads on ONE kv head of 128, d_inner
+# 5,120, d_state 16, prompts up to 2,048) ---------------------------------
+
+def _scan(x, dt, a, b, c, h):
+    from mxnet_tpu.kernels.selective_scan import selective_scan_fwd
+    return selective_scan_fwd(x, dt, a, b, c, h, chunk=256,
+                              interpret=False)
+
+
+def _state_step(h, x, dt, a, b, c, live):
+    from mxnet_tpu.kernels.selective_scan import _state_update
+    return _state_update(h, x, dt, a, b, c, live, rows_per_step=8,
+                         interpret=False)
+
+
+def _jamba_args(what, sharding=None):
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=sharding)
+    if what == "scan":
+        return (sds((1, 2048, 5120)), sds((1, 2048, 5120)),
+                sds((16, 5120)), sds((1, 2048, 16)), sds((1, 2048, 16)),
+                sds((1, 16, 40, 128)))
+    if what == "state update":
+        return (sds((256, 16, 40, 128)), sds((256, 5120)),
+                sds((256, 5120)), sds((16, 5120)), sds((256, 16)),
+                sds((256, 16)), sds((256,), jnp.bool_))
+    if what == "paged sweep":
+        return _paged_args(256, 640, 65537, K=1, H=20, sharding=sharding)
+    bf = jnp.bfloat16
+    return (sds((1, 2048, 20, 128), bf), sds((1, 2048, 1, 128), bf),
+            sds((1, 2048, 1, 128), bf), sds((1,), jnp.int32))
+
+
+def _group20_prefill(q, k, v, n):
+    from mxnet_tpu.kernels.flash_attention import _pallas_forward
+    return _pallas_forward(q, k, v, True, 128 ** -0.5, lengths=n)
+
+
+_JAMBA_KERNELS = {
+    "scan": (_scan, "selective_scan_fwd"),
+    "state update": (_state_step, "ssm_state_update"),
+    "paged sweep": (_paged, "flash_decode_paged"),
+    "flash forward": (_group20_prefill, "flash_attention_fwd")}
+
+
+@pytest.mark.parametrize("what", list(_JAMBA_KERNELS))
+def test_jamba_kernels_lower_at_the_cells_shapes(what):
+    _lowers(_JAMBA_KERNELS[what][0], *_jamba_args(what))
+
+
+@pytest.mark.parametrize("what", list(_JAMBA_KERNELS))
+def test_jamba_kernels_compile_for_v5e(what, one_chip):
+    """Mosaic's VMEM limit and tiling at the real sizes: the state
+    (16 x 8 x 128 float32 a channel block) across 8 time chunks, 8 rows
+    of state in and out a step with the pool aliased, a group of 20
+    query heads (no multiple of 8 sublanes) on 4 KB pages with a
+    655 KB block table in SMEM."""
+    fn, name = _JAMBA_KERNELS[what]
+    donate = (0,) if what == "state update" else ()
+    compiled = jax.jit(fn, donate_argnums=donate).lower(
+        *_jamba_args(what, one_chip)).compile()
+    text = compiled.as_text()
+    assert name in text
+    if what == "state update":
+        # the pool is updated in place: no copy of it, no second one
+        state = 256 * 16 * 5120 * 4
+        assert compiled.memory_analysis().alias_size_in_bytes == state
+        assert not re.search(r"f32\[256,16,40,128\]\S* copy\(", text)
+
+
+def test_the_sweeps_vmem_reckoning_takes_the_real_group():
+    from mxnet_tpu.kernels.flash_decode import _paged_sweep_pages
+    mistral, trinity, jamba = (5633, 8, 16, 128), (32001, 8, 16, 128), \
+        (65537, 1, 16, 128)
+    # groups up to 16 (one bf16 tile of rows) reckon as they always did
+    assert _paged_sweep_pages(mistral, 2, 528, 4) \
+        == _paged_sweep_pages(mistral, 2, 528) == 48
+    assert _paged_sweep_pages(trinity, 2, 1408, 6) \
+        == _paged_sweep_pages(trinity, 2, 1408, 16)
+    # a group of 20 takes two tiles: fewer bytes left for pages
+    free = lambda g: _paged_sweep_pages(jamba, 2, None, g)  # noqa: E731
+    assert free(20) == free(32) <= free(16)
+    assert _paged_sweep_pages(jamba, 2, 640, 20) >= 128
+
+
 @pytest.mark.parametrize("what", ["train cell, forward",
                                   "train cell, backward",
                                   "prefill of 2,048", "prefill of 6,144"])
@@ -366,3 +452,40 @@ def test_resnet_fused_train_step_lowers():
         assert exp.mlir_module()  # lowered for TPU without error
     finally:
         amp._STATE.update(saved_amp)
+
+
+def test_a_descriptions_decode_options_reach_the_tpu_compiler(
+        one_chip, monkeypatch):
+    """A recurrent net's tick is compiled without XLA's prefetches of
+    its operands into VMEM (1,094 asynchronous pairs a tick at the
+    cell's sizes, which a profiler trace pays for one by one). The
+    option has to be one libtpu knows — an unknown name fails the
+    compile — and the descriptions without recurrent layers give
+    none, so their programs are compiled as they were."""
+    from mxnet_tpu.models.afmoe import AfmoeDecoder
+    from mxnet_tpu.models.jamba import JambaDecoder
+    from mxnet_tpu.models.llama_infer import LlamaDecoder
+    from mxnet_tpu.serving.executables import Program
+
+    assert LlamaDecoder.decode_compiler_options is None
+    assert AfmoeDecoder.decode_compiler_options is None
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def tick(ws, x):
+        for w in ws:
+            x = jnp.tanh(x @ w)
+        return x
+
+    bf16 = jnp.bfloat16
+    args = ([jax.ShapeDtypeStruct((2560, 2560), bf16, sharding=one_chip)
+             for _ in range(8)],
+            jax.ShapeDtypeStruct((256, 2560), bf16, sharding=one_chip))
+
+    def starts(options):
+        text = Program("probe", tick, compiler_options=options) \
+            ._jit.lower(*args).compile().as_text()
+        return len(re.findall(r"(?:copy|slice)-start\(", text))
+
+    assert starts(JambaDecoder.decode_compiler_options) < starts(None)
+    with pytest.raises(Exception, match="xla_no_such_option"):
+        starts({"xla_no_such_option": 1})
