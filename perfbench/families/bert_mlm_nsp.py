@@ -2,12 +2,12 @@
 ``BERTForPretraining`` + AMP bf16 + AdamW lowered by
 ``ParallelPlan.lower`` into one ``FusedTrainStep``.
 
-The build/loss recipe is copied from ``bench.py::_bert_phase`` (model
-build, ``amp.convert_block``, MLM + NSP loss, AdamW, fused step) so
-that deleting ``bench.py`` later moves nothing here. What changes: the
-weights are planted from the benchmark's own seeded generator (the
-reference is given the same values), and the shapes come from the
-configuration and the job file.
+The build/loss recipe (model build, ``amp.convert_block``, MLM + NSP
+loss, AdamW, fused step) was copied from the repo's first benchmark
+script, which PR 29 deleted; this is its one copy. The weights are
+planted from the benchmark's own seeded generator (the reference is
+given the same values), and the shapes come from the configuration and
+the job file.
 """
 from perfbench.reference import bert_mlm_nsp as ref
 

@@ -72,8 +72,9 @@ def run(cell, seed, seconds, tracer, meter, devices, t_start):
                  served.kernel_fallbacks() - fallbacks0, 0)
     checks.equal("preemptions", counters["preemptions"], 0)
     sample = serving.sample_for_check(finished, seed, traffic)
-    serving.check_outputs(checks, cfg, traffic, seed, served, sample,
-                          devices)
+    ref = serving.check_outputs(checks, cfg, traffic, seed, served, sample,
+                                devices)
+    tracer.phases.add("reference", ref["reference_s"])
 
     return {
         "quantities": {

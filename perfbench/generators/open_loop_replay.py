@@ -138,8 +138,9 @@ def run(cell, seed, seconds, tracer, meter, devices, t_start):
     checks.at_least("requests_with_tpot", len(m["tpot"]),
                     traffic["limits"]["min_requests"])
     sample = serving.sample_for_check(m["finished"], seed, traffic)
-    serving.check_outputs(checks, cfg, traffic, seed, served, sample,
-                          devices)
+    ref = serving.check_outputs(checks, cfg, traffic, seed, served, sample,
+                                devices)
+    tracer.phases.add("reference", ref["reference_s"])
 
     return {
         "quantities": {

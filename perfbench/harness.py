@@ -144,6 +144,50 @@ def device_block(devices, peak_bytes):
             "memory_peak_bytes": peak_bytes}
 
 
+# -- where a run's seconds go ------------------------------------------------
+
+class Phases:
+    """Seconds of a run by part, for the one line a traced run writes
+    at its exit: ``timed(name)`` around a part, ``add(name, seconds)``
+    for one that was timed elsewhere, outside any open part. A part
+    timed inside another is taken out of the outer one, so the parts
+    add up to the run."""
+
+    def __init__(self):
+        self.rows = []      # [name, seconds], in the order they ended
+        self._inner = []    # seconds timed inside each open part
+
+    def add(self, name, seconds):
+        self.rows.append([name, seconds])
+
+    @contextlib.contextmanager
+    def timed(self, name):
+        t0 = time.perf_counter()
+        self._inner.append(0.0)
+        try:
+            yield
+        finally:
+            whole = time.perf_counter() - t0
+            own = whole - self._inner.pop()
+            self.rows.append([name, own])
+            if self._inner:
+                self._inner[-1] += whole
+
+    def line(self, total_s):
+        """The parts in the order they ended, those of the reduction
+        (``read.*``) under a second summed into one, and what no part
+        covers as ``other``."""
+        out, small = [("total", total_s)], 0.0
+        for name, v in self.rows:
+            if name.startswith("read.") and v < 1.0:
+                small += v
+            else:
+                out.append((name, v))
+        out.append(("read.rest", small))
+        out.append(("other", total_s - sum(v for _, v in self.rows)))
+        return "[phases] " + ", ".join(f"{n}_s: {v:.2f}" for n, v in out)
+
+
 # -- spans and the traced window --------------------------------------------
 
 class Tracer:
@@ -153,9 +197,10 @@ class Tracer:
     each idle gap of the device to what the host was doing. With it off
     a span costs one ``nullcontext``."""
 
-    def __init__(self, on, trace_dir):
+    def __init__(self, on, trace_dir, phases):
         self.on = bool(on)
         self.dir = trace_dir
+        self.phases = phases                # Phases: the run's seconds
         self._started = False
 
     def span(self, name):
@@ -193,7 +238,8 @@ class Tracer:
             with jax.profiler.TraceAnnotation("pb.window"):
                 yield
         finally:
-            jax.profiler.stop_trace()
+            with self.phases.timed("stop_trace"):
+                jax.profiler.stop_trace()
             self._started = False
 
 
@@ -225,11 +271,16 @@ class Checks:
     def ok(self):
         return bool(self.rows) and all(r[-1] for r in self.rows)
 
-    def print(self):
+    def print(self, file=None):
         for name, value, op, limit, ok in self.rows:
             v = f"{value:.6g}" if isinstance(value, float) else str(value)
             print(f"[check] {name}: {v} {op} {limit} "
-                  f"{'ok' if ok else 'FAILED'}", flush=True)
+                  f"{'ok' if ok else 'FAILED'}", file=file, flush=True)
+
+    def as_dict(self):
+        """{name: [number, its limit]} for the result line."""
+        return {name: [value, f"{op} {limit}"]
+                for name, value, op, limit, _ in self.rows}
 
 
 def say(tag, **facts):
@@ -268,7 +319,8 @@ def per_layer_values(cell, ctx):
         spec = load_json(HERE, "metrics", m["name"] + ".json")
         reader = importlib.import_module(
             "perfbench.readers." + spec["reader"])
-        value = reader.read(spec, ctx)
+        with ctx.phases.timed("read." + m["name"]):
+            value = reader.read(spec, ctx)
         if value is not None:
             out[m["name"]] = {"value": value, "unit": m["unit"]}
     return out
@@ -277,7 +329,8 @@ def per_layer_values(cell, ctx):
 class ReadContext:
     """What a per-layer reader may look at."""
 
-    def __init__(self, cell, outcome, trace, peaks):
+    def __init__(self, cell, outcome, trace, peaks, phases):
+        self.phases = phases                 # Phases: readers time parts
         self.config = cell.config
         self.traffic = cell.traffic
         self.chips = cell.chips
@@ -290,14 +343,20 @@ class ReadContext:
 
 
 def run_cell(cell, seed, seconds, trace, t_start, devices, on_chip=True):
-    """Set up, measure, check; returns the result object."""
+    """Set up, measure, check; returns the result object, the numbers
+    compared beside their limits as its last key."""
     from perfbench import peaks as peak_table, xtrace
 
     meter = CompileMeter()
-    tracer = Tracer(trace and on_chip, os.path.join(ROOT, ".pb_trace"))
+    phases = Phases()
+    tracer = Tracer(trace and on_chip, os.path.join(ROOT, ".pb_trace"),
+                    phases)
     gen = cell.generator()
     outcome = gen.run(cell, seed=seed, seconds=seconds, tracer=tracer,
                       meter=meter, devices=devices, t_start=t_start)
+    # the generator timed these two itself; they come first in the line
+    phases.rows[:0] = [["setup", outcome["setup_s"]],
+                       ["window", outcome["window_s"]]]
     checks = outcome["checks"]
     checks.equal("compiles_in_window", outcome["window_builds"], 0)
     checks.print()
@@ -307,18 +366,27 @@ def run_cell(cell, seed, seconds, trace, t_start, devices, on_chip=True):
     if not on_chip:
         # the rehearsal prints counts only: no device metric from a CPU
         result["metrics"] = {}
-        result["device"] = dev
-        return result
-    if tracer.on:
-        tr = xtrace.load(tracer.dir).windowed()
+    elif tracer.on:
+        with phases.timed("xtrace.load"):
+            tr = xtrace.load(tracer.dir).windowed()
         t0, t1 = tr.window()
-        dev["busy_s"] = tr.busy_s()
+        with phases.timed("read.busy_s"):
+            dev["busy_s"] = tr.busy_s()
         dev["window_s"] = (t1 - t0) * 1e-9
         ctx = ReadContext(cell, outcome, tr,
-                          peak_table.peaks_for(devices[0].device_kind))
+                          peak_table.peaks_for(devices[0].device_kind),
+                          phases)
         result["metrics"] = per_layer_values(cell, ctx)
-        result["breakdown"] = tr.breakdown()
+        with phases.timed("read.idle_gaps"):
+            tr.idle_gaps()          # kept: the breakdown asks again
+        with phases.timed("read.device_ops"):
+            result["breakdown"] = tr.breakdown()
     else:
         result["metrics"] = end_to_end_values(cell, outcome)
     result["device"] = dev
+    result["checks"] = checks.as_dict()
+    if tracer.on:
+        print(phases.line(time.perf_counter() - t_start), file=sys.stderr)
+    # the last lines on standard error: each number beside its limit
+    checks.print(sys.stderr)
     return result

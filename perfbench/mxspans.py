@@ -120,8 +120,7 @@ def device_idle(trace):
     devs = trace.devices
     if not devs or t1 <= t0:
         return None
-    busy = xtrace.union((s, s + d) for _, s, d in trace.device_ops[devs[0]])
-    return xtrace.subtract([[t0, t1]], busy)
+    return trace.idle(devs[0])
 
 
 def build(threads, trace):
@@ -164,10 +163,11 @@ def of(ctx):
     directory the harness wrote and kept on the context."""
     got = getattr(ctx, "_mxspans", None)
     if got is None:
-        try:
-            threads = read_threads(xtrace.find_xplane(
-                os.path.join(harness.ROOT, ".pb_trace")))
-        except FileNotFoundError:
-            threads = []
-        got = ctx._mxspans = build(threads, ctx.trace)
+        with ctx.phases.timed("read.mxspans"):
+            try:
+                threads = read_threads(xtrace.find_xplane(
+                    os.path.join(harness.ROOT, ".pb_trace")))
+            except FileNotFoundError:
+                threads = []
+            got = ctx._mxspans = build(threads, ctx.trace)
     return got

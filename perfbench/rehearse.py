@@ -1,7 +1,10 @@
 #!/usr/bin/env python3
-"""CPU rehearsal: every cell of ``BENCHMARK.json`` end to end at the
-tiny presets of ``perfbench/rehearsal.json``, Pallas kernels
-interpreted, four-chip cells on four virtual devices.
+"""CPU rehearsal: every cell of ``BENCHMARK.json`` end to end at its
+tiny presets, Pallas kernels interpreted, four-chip cells on four
+virtual devices. The first cells' presets are in
+``perfbench/rehearsal.json`` by configuration and traffic; a cell added
+since brings a file ``perfbench/rehearsal.<any name>.json`` of its own
+that names it (``"workload"``, ``"config"``, ``"traffic"``).
 
     python3 perfbench/rehearse.py [--workload <name>] [--seconds <s>]
 
@@ -10,6 +13,7 @@ is spent. It prints counts only (steps, requests, tokens, checks) and
 never a device metric: a time taken here says how fast the CPU backend
 is. The real entry, ``perfbench/run.py``, refuses to run here.
 """
+import glob
 import os
 import sys
 import time
@@ -18,7 +22,7 @@ _T_START = time.perf_counter()
 
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=4")
-for _k in ("FLASH", "NORM", "CE", "DECODE"):
+for _k in ("FLASH", "NORM", "CE", "DECODE", "MOE", "SCAN"):
     os.environ.setdefault(f"MXNET_TPU_{_k}_INTERPRET", "1")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
@@ -37,10 +41,18 @@ def tiny_cell(name, benchmark=None):
     """The cell ``name`` with the tiny presets laid over its files."""
     from perfbench import harness
 
-    tiny = harness.load_json(harness.HERE, "rehearsal.json")
     cell = harness.Cell(name, benchmark)
-    cell.config = merge(cell.config, tiny["configs"][cell.config_name])
-    cell.traffic = merge(cell.traffic, tiny["traffic"][cell.traffic_name])
+    own = next((p for p in map(harness.load_json, sorted(glob.glob(
+        os.path.join(harness.HERE, "rehearsal.*.json"))))
+        if p.get("workload") == name), None)
+    if own:
+        config, traffic = own["config"], own["traffic"]
+    else:
+        tiny = harness.load_json(harness.HERE, "rehearsal.json")
+        config = tiny["configs"][cell.config_name]
+        traffic = tiny["traffic"][cell.traffic_name]
+    cell.config = merge(cell.config, config)
+    cell.traffic = merge(cell.traffic, traffic)
     return cell
 
 

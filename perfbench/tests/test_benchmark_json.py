@@ -137,3 +137,61 @@ def test_file_names_use_only_name_characters():
         for f in files:
             rel = os.path.relpath(os.path.join(root, f), harness.ROOT)
             assert re.match(r"^[A-Za-z0-9_.\-/]+$", rel), rel
+
+
+def test_every_cell_has_a_rehearsal_preset(bm):
+    """``rehearse.py`` without ``--workload`` reaches every cell: the
+    first four by configuration and traffic in ``rehearsal.json``, a
+    later one by the file of its own that names it."""
+    from perfbench import rehearse
+
+    for w in bm["workloads"]:
+        real = harness.Cell(w["name"], bm)
+        tiny = rehearse.tiny_cell(w["name"], bm)
+        assert tiny.config["hidden_size"] < real.config["hidden_size"]
+        assert tiny.traffic != real.traffic
+    own = {harness.load_json(harness.HERE, f)["workload"]
+           for f in os.listdir(harness.HERE)
+           if re.match(r"^rehearsal\..+\.json$", f)}
+    assert own <= {w["name"] for w in bm["workloads"]}
+
+
+# -- what a run says beside its metrics ---------------------------------------
+
+def test_phases_take_an_inner_part_out_of_the_outer_one():
+    import time
+
+    ph = harness.Phases()
+    t0 = time.perf_counter()
+    with ph.timed("read.outer"):
+        with ph.timed("read.inner"):
+            time.sleep(0.05)
+        with ph.timed("read.mxspans"):
+            time.sleep(0.02)
+    whole = time.perf_counter() - t0
+    ph.add("reference", 2.0)
+    rows = dict(ph.rows)
+    assert list(rows) == ["read.inner", "read.mxspans", "read.outer",
+                          "reference"]
+    # what ran inside is not counted twice: the parts add up
+    assert rows["read.inner"] >= 0.05 and 0 <= rows["read.outer"] < 0.03
+    assert sum(rows.values()) - 2.0 == pytest.approx(whole, abs=0.01)
+    ph.rows[:0] = [["setup", 90.0], ["window", 51.0]]
+    ph.add("read.idle_gaps", 1.25)
+    line = ph.line(150.0)
+    assert line.startswith("[phases] total_s: 150.00, setup_s: 90.00, "
+                           "window_s: 51.00, reference_s: 2.00, "
+                           "read.idle_gaps_s: 1.25, read.rest_s: 0.")
+    # a reduction under a second is summed, never a part of the run
+    assert "read.inner" not in line and "other_s: 5.6" in line
+
+
+def test_checks_go_into_the_result_line_each_beside_its_limit():
+    checks = harness.Checks()
+    checks.at_most("mean_gap", 0.004, 0.0075)
+    checks.equal("preemptions", 0, 0)
+    checks.at_least("tokens", 3, 4)
+    assert not checks.ok
+    assert json.loads(json.dumps(checks.as_dict())) == {
+        "mean_gap": [0.004, "<= 0.0075"], "preemptions": [0, "== 0"],
+        "tokens": [3, ">= 4"]}

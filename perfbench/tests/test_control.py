@@ -79,6 +79,28 @@ def test_training_step_that_returns_its_state_unchanged_is_not_correct(
     assert result["correct"] is False
 
 
+def test_training_window_whose_loss_is_no_number_is_not_correct(
+        monkeypatch):
+    """The window's losses are reported and not held to a limit (a
+    loss that has to fall has no reference); one that is no number
+    still fails the run."""
+    cell = rehearse.tiny_cell("bert_base.pretrain512")
+    fam = cell.family()
+    real = fam.Trainer.__call__
+    calls = []
+
+    def overflowing(self, batch):
+        calls.append(1)
+        loss = real(self, batch)
+        # past the followed steps and the warm-up: inside the window
+        return loss if len(calls) <= 8 else loss * float("nan")
+
+    monkeypatch.setattr(fam.Trainer, "__call__", overflowing)
+    result = rehearse.run_tiny(cell, SEED, 1.0)
+    assert result["checks"]["nonfinite_losses_in_window"][0] > 0
+    assert result["correct"] is False
+
+
 def test_sound_tiny_serving_run_is_correct():
     cell = rehearse.tiny_cell("mistral_7b.chat")
     assert rehearse.run_tiny(cell, SEED, 2.0)["correct"] is True
@@ -114,7 +136,7 @@ def _gaps(cell, control, seed=SEED):
     served = fam.build(cfg, traffic["server"], seed, jax.devices()[:1],
                        control=control)
     drv = serving.Driver(served, cfg, traffic, seed,
-                         harness.Tracer(False, ""))
+                         harness.Tracer(False, "", harness.Phases()))
     for n in (20, 28, 12, 30):
         drv.submit(n, 24, False)
     drv.drain()
@@ -134,7 +156,7 @@ def test_serving_control_fp8_reference_fails_the_comparison():
     fam = cell.family()
     served = fam.build(cfg, traffic["server"], SEED, jax.devices()[:1])
     drv = serving.Driver(served, cfg, traffic, SEED,
-                         harness.Tracer(False, ""))
+                         harness.Tracer(False, "", harness.Phases()))
     for n in (20, 28, 12, 30):
         drv.submit(n, 24, False)
     drv.drain()

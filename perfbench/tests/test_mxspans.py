@@ -163,13 +163,29 @@ def test_a_count_shortfall_skips_spans_that_lack_either_count():
     assert metric("prefill_padding_share", Ctx(trace, none)) is None
 
 
+def reads_the_programs_spans(name):
+    """Whether the metric's reader takes anything from ``mxspans``: a
+    reader that imports the module is one that does."""
+    spec = harness.load_json(harness.HERE, "metrics", name + ".json")
+    reader = importlib.import_module("perfbench.readers." + spec["reader"])
+    return getattr(reader, "mxspans", None) is mxspans
+
+
 def test_a_program_without_the_spans_reads_as_nothing():
     trace, _ = synthetic()
     ctx = Ctx(trace, mxspans.build([], trace), {"slots": 2})
     bm = harness.load_json(harness.ROOT, "BENCHMARK.json")
-    new = [m["name"] for m in bm["per_layer"][28:]]
-    assert len(new) == 23
-    for name in new:
+    names = [m["name"] for m in bm["per_layer"]
+             if reads_the_programs_spans(m["name"])]
+    # whatever the list has grown to, it holds a metric of every kind
+    # of span reader and none of the host clock's or the device's own
+    assert {"tick_host_ms_p50.serve", "idle_ms_per_tick.wait.tpot",
+            "modules_per_step.train", "prefill_padding_share",
+            "moe_experts_roofline.afmoe",
+            "ssm_state_update_roofline.jamba"} <= set(names)
+    assert not {"gen_late_p99_ms", "device_idle_share.serve",
+                "step_ms_p50", "mfu.train"} & set(names)
+    for name in names:
         assert metric(name, ctx) is None, name
 
 

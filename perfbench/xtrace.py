@@ -107,16 +107,17 @@ def leaf_events(events):
     return [ev for ev in out if ev is not None]
 
 
-def time_by_name(events):
-    """{base name: seconds} over leaf events."""
+def time_by_name(events, leaves=None):
+    """{base name: seconds} over leaf events (``leaves``: the events'
+    leaves where the caller holds them already)."""
     acc = {}
-    for name, _, d in leaf_events(events):
+    for name, _, d in leaf_events(events) if leaves is None else leaves:
         k = base_name(name)
         acc[k] = acc.get(k, 0.0) + d * 1e-9
     return acc
 
 
-def kernel_events(events, kernel):
+def kernel_events(events, kernel, leaves=None):
     """Events of one Pallas kernel: the custom call's name carries the
     kernel's ``name=`` (PR 21 gave every pallas_call one). Inside a
     differentiated step JAX decorates it (``jvp_flash_attention_fwd_``,
@@ -124,19 +125,59 @@ def kernel_events(events, kernel):
     and trailing underscores are allowed, a longer kernel name
     (``flash_decode_paged_q8``) is not."""
     pat = re.compile(r"(^|_)" + re.escape(kernel) + r"_*$")
-    return [ev for ev in leaf_events(events)
+    return [ev for ev in (leaf_events(events) if leaves is None
+                          else leaves)
             if pat.search(base_name(ev[0]))]
 
 
-def exposed_collective_s(events):
+def exposed_collective_s(events, leaves=None):
     """Seconds in which a collective ran on the device and nothing else
     did: union(collectives) minus union(everything else)."""
-    leaves = leaf_events(events)
+    if leaves is None:
+        leaves = leaf_events(events)
     coll = union((s, s + d) for n, s, d in leaves
                  if COLLECTIVE.match(base_name(n)))
     rest = union((s, s + d) for n, s, d in leaves
                  if not COLLECTIVE.match(base_name(n)))
     return total(subtract(coll, rest)) * 1e-9, total(coll) * 1e-9
+
+
+def host_spans_by_start(host_spans):
+    """The spans an idle gap may be charged to, by start: all but the
+    one the harness lays over the whole window."""
+    return sorted((e for e in host_spans if e[0] != WINDOW_SPAN),
+                  key=lambda e: e[1])
+
+
+def charge_gaps(gaps, spans):
+    """``({span name: idle seconds}, span visits)``: each gap of the
+    sorted, disjoint ``gaps`` is charged to the span of ``spans``
+    (sorted by start) that overlaps it most, the earliest-starting one
+    on a tie, or to ``pb.unattributed`` where no overlap is positive.
+
+    ONE sweep: ``live`` holds, in start order, the spans that start
+    before the gap's end and have not ended by its start, which are
+    exactly the spans a walk from the first span would find overlapping
+    it. A span enters once and leaves once, so the cost is gaps + spans
+    + overlaps (the visits returned), not gaps x spans; a span over the
+    whole window costs one visit a gap and hides no later one."""
+    acc = {}
+    live = []
+    nxt = visits = 0
+    for gs, ge in gaps:
+        while nxt < len(spans) and spans[nxt][1] < ge:
+            live.append(spans[nxt])
+            nxt += 1
+            visits += 1
+        live = [sp for sp in live if sp[1] + sp[2] > gs]
+        visits += len(live)
+        best, best_ov = "pb.unattributed", 0
+        for name, s, d in live:
+            ov = min(ge, s + d) - max(gs, s)
+            if ov > best_ov:
+                best, best_ov = name, ov
+        acc[best] = acc.get(best, 0.0) + (ge - gs) * 1e-9
+    return acc, visits
 
 
 def idle_gaps(events, host_spans, t0, t1):
@@ -145,20 +186,8 @@ def idle_gaps(events, host_spans, t0, t1):
     host span (``pb.*``) that overlaps it most, or to
     ``pb.unattributed``."""
     busy = union((s, s + d) for _, s, d in events)
-    gaps = subtract([[t0, t1]], busy)
-    spans = sorted((e for e in host_spans if e[0] != WINDOW_SPAN),
-                   key=lambda e: e[1])
-    acc = {}
-    for gs, ge in gaps:
-        best, best_ov = "pb.unattributed", 0
-        for name, s, d in spans:
-            if s >= ge:
-                break
-            ov = min(ge, s + d) - max(gs, s)
-            if ov > best_ov:
-                best, best_ov = name, ov
-        acc[best] = acc.get(best, 0.0) + (ge - gs) * 1e-9
-    return acc
+    return charge_gaps(subtract([[t0, t1]], busy),
+                       host_spans_by_start(host_spans))[0]
 
 
 class Trace:
@@ -169,6 +198,30 @@ class Trace:
         self.device_ops = device_ops          # {device id: [events]}
         self.device_modules = device_modules  # {device id: [events]}
         self.host_spans = host_spans          # [events], names pb.*
+        # what several readers ask for again, worked out once a device:
+        # the events are never changed after loading
+        self._busy, self._leaves, self._kernel = {}, {}, {}
+        self._gaps = None
+
+    def busy(self, dev):
+        """Merged intervals in which an operation ran on ``dev``."""
+        if dev not in self._busy:
+            self._busy[dev] = union((s, s + d)
+                                    for _, s, d in self.device_ops[dev])
+        return self._busy[dev]
+
+    def leaves(self, dev):
+        """:func:`leaf_events` of ``dev``'s operations."""
+        if dev not in self._leaves:
+            self._leaves[dev] = leaf_events(self.device_ops[dev])
+        return self._leaves[dev]
+
+    def kernel(self, dev, kernel):
+        """:func:`kernel_events` of one kernel on ``dev``."""
+        if (dev, kernel) not in self._kernel:
+            self._kernel[dev, kernel] = kernel_events(
+                self.device_ops[dev], kernel, self.leaves(dev))
+        return self._kernel[dev, kernel]
 
     @property
     def devices(self):
@@ -200,16 +253,15 @@ class Trace:
         devs = self.devices
         if not devs:
             return 0.0
-        return sum(total(union((s, s + d)
-                               for _, s, d in self.device_ops[k]))
-                   for k in devs) * 1e-9 / len(devs)
+        return sum(total(self.busy(k)) for k in devs) * 1e-9 / len(devs)
 
     def op_seconds(self):
         """{base op name: seconds}, averaged over devices."""
         devs = self.devices
         acc = {}
         for k in devs:
-            for n, v in time_by_name(self.device_ops[k]).items():
+            for n, v in time_by_name(self.device_ops[k],
+                                  self.leaves(k)).items():
                 acc[n] = acc.get(n, 0.0) + v / len(devs)
         return acc
 
@@ -218,7 +270,7 @@ class Trace:
         devs = self.devices
         if not devs:
             return 0.0, 0
-        evs = [kernel_events(self.device_ops[k], kernel) for k in devs]
+        evs = [self.kernel(k, kernel) for k in devs]
         return (sum(d for ev in evs for _, _, d in ev) * 1e-9 / len(devs),
                 sum(len(ev) for ev in evs) // len(devs))
 
@@ -237,8 +289,7 @@ class Trace:
                 if pat.search(n)]
         if contains is None:
             return [(e - s) * 1e-9 for s, e in mods]
-        marks = sorted(s for _, s, _ in
-                       kernel_events(self.device_ops[devs[0]], contains))
+        marks = sorted(s for _, s, _ in self.kernel(devs[0], contains))
         out = []
         for s, e in mods:
             i = bisect.bisect_left(marks, s)
@@ -250,7 +301,8 @@ class Trace:
         devs = self.devices
         if not devs:
             return 0.0, 0.0
-        pairs = [exposed_collective_s(self.device_ops[k]) for k in devs]
+        pairs = [exposed_collective_s(self.device_ops[k], self.leaves(k))
+                 for k in devs]
         return (sum(p[0] for p in pairs) / len(devs),
                 sum(p[1] for p in pairs) / len(devs))
 
@@ -258,9 +310,16 @@ class Trace:
         devs = self.devices
         if not devs:
             return {}
+        if self._gaps is None:
+            self._gaps = charge_gaps(self.idle(devs[0]),
+                                     host_spans_by_start(self.host_spans))[0]
+        return self._gaps
+
+    def idle(self, dev):
+        """Merged intervals of the window in which no operation ran on
+        ``dev``."""
         t0, t1 = self.window()
-        return idle_gaps(self.device_ops[devs[0]], self.host_spans,
-                         t0, t1)
+        return subtract([[t0, t1]], self.busy(dev))
 
     def breakdown(self, top=10):
         ops = sorted(self.op_seconds().items(), key=lambda kv: -kv[1])
