@@ -205,6 +205,37 @@ def _quant_rows(rows):
     return q8, scale
 
 
+def _put_rows(pool, blk_ids, offs, rows):
+    """rows (T, K, d) to `pool[blk_ids, :, offs, :]` of a pool
+    (N, K, bs, d), written on the pool's (N, K*bs, d) view. A scatter
+    over dims 0 and 2 of the pool itself makes XLA:TPU re-lay the
+    WHOLE pool out with those dims major and back again, twice a pool
+    a call (docs/serving.md); on the view the scattered dims are the
+    major ones of the row-major pool as it stands, the reshapes are
+    bitcasts and the rows land in place."""
+    N, K, bs, d = pool.shape
+    at = jnp.arange(K) * bs + offs[:, None]                  # (T, K)
+    return pool.reshape(N, K * bs, d) \
+        .at[blk_ids[:, None], at].set(rows).reshape(pool.shape)
+
+
+def write_rows(pg, blk_ids, offs, k_rows, v_rows):
+    """Scatter per-token rows into a layer's pools. blk_ids/offs (T,),
+    rows (T, K, d); a pool with scales ("ks", "vs") stores int8. The
+    scale pools (N, K, bs, 1) keep the indexed write: advanced indices
+    around the K slice put the token axis first, so the value shape
+    (T, K, 1) matches the scales."""
+    if "ks" in pg:
+        k8, ks = _quant_rows(k_rows)
+        v8, vs = _quant_rows(v_rows)
+        return {"k": _put_rows(pg["k"], blk_ids, offs, k8),
+                "ks": pg["ks"].at[blk_ids, :, offs, :].set(ks),
+                "v": _put_rows(pg["v"], blk_ids, offs, v8),
+                "vs": pg["vs"].at[blk_ids, :, offs, :].set(vs)}
+    return {"k": _put_rows(pg["k"], blk_ids, offs, k_rows),
+            "v": _put_rows(pg["v"], blk_ids, offs, v_rows)}
+
+
 def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
                    block_size: int, max_prompt_len: int,
                    kv_cache_dtype: str = "model",
@@ -350,20 +381,6 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
         adapters, aids = lo
         return [{t: (tab["a"][aids], tab["b"][aids])
                  for t, tab in layer.items()} for layer in adapters]
-
-    def write_rows(pg, blk_ids, offs, k_rows, v_rows):
-        """Scatter per-token rows into the pool. blk_ids/offs (T,),
-        rows (T, K, d). Advanced indices around the K slice put the
-        token axis first — value shape (T, K, d) matches the rows."""
-        if q8:
-            k8, ks = _quant_rows(k_rows)
-            v8, vs = _quant_rows(v_rows)
-            return {"k": pg["k"].at[blk_ids, :, offs, :].set(k8),
-                    "ks": pg["ks"].at[blk_ids, :, offs, :].set(ks),
-                    "v": pg["v"].at[blk_ids, :, offs, :].set(v8),
-                    "vs": pg["vs"].at[blk_ids, :, offs, :].set(vs)}
-        return {"k": pg["k"].at[blk_ids, :, offs, :].set(k_rows),
-                "v": pg["v"].at[blk_ids, :, offs, :].set(v_rows)}
 
     def write_state(pg, state, slot):
         """Row `slot` of a recurrent layer's state pool, whole."""

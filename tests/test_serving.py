@@ -115,6 +115,61 @@ def test_quantized_cache_page_shapes():
     assert pg["ks"].dtype == jnp.float32
 
 
+# -- the paged row write ----------------------------------------------------
+
+@pytest.mark.parametrize("quantized", [False, True],
+                         ids=["model", "int8"])
+@pytest.mark.parametrize("T", [1, 20, 2048])
+@pytest.mark.parametrize("K", [1, 4, 8])
+def test_write_rows_equals_the_indexed_write(K, T, quantized):
+    """`write_rows` stores through the pool's (N, K*bs, d) view; the
+    pool it returns is, bit for bit, what the plain indexed write
+    `pool.at[blk, :, offs, :].set(rows)` gives — rows masked to the
+    scratch block 0 (several to one position) among them."""
+    rs = np.random.RandomState(K * 10007 + T)
+    bs, d = 16, 32
+    N = max(T // bs, 1) + 12
+    shape = (N, K, bs, d)
+    if quantized:
+        pg = {"k": rs.randint(-127, 128, shape).astype(np.int8),
+              "ks": rs.rand(N, K, bs, 1).astype(np.float32),
+              "v": rs.randint(-127, 128, shape).astype(np.int8),
+              "vs": rs.rand(N, K, bs, 1).astype(np.float32)}
+    else:
+        pg = {f: rs.randn(*shape).astype(jnp.bfloat16)
+              for f in ("k", "v")}
+    pg = {f: jnp.asarray(a) for f, a in pg.items()}
+    # a prompt's positions through a shuffled block table, as prefill
+    # writes them: the head (a shared prefix) and the tail (padding)
+    # sink into scratch; a decode tick's inactive rows do the same
+    t = np.arange(T)
+    table = 1 + rs.permutation(N - 1)
+    keep = (t >= T // 8) & (t < T - T // 5) if T > 1 else t >= 0
+    blk = jnp.asarray(np.where(keep, table[t // bs], 0), jnp.int32)
+    offs = jnp.asarray(np.where(keep, t % bs, t % 2), jnp.int32)
+    k_rows = jnp.asarray(rs.randn(T, K, d), jnp.bfloat16)
+    v_rows = jnp.asarray(rs.randn(T, K, d), jnp.bfloat16)
+
+    def indexed(pg, blk, offs, k_rows, v_rows):
+        rows = {"k": k_rows, "v": v_rows}
+        if quantized:
+            rows["k"], rows["ks"] = exe._quant_rows(k_rows)
+            rows["v"], rows["vs"] = exe._quant_rows(v_rows)
+        return {f: pg[f].at[blk, :, offs, :].set(rows[f]) for f in pg}
+
+    got = jax.jit(exe.write_rows)(pg, blk, offs, k_rows, v_rows)
+    want = jax.jit(indexed)(pg, blk, offs, k_rows, v_rows)
+    assert set(got) == set(pg)
+    for f in pg:
+        assert got[f].shape == pg[f].shape and got[f].dtype == pg[f].dtype
+        assert np.array_equal(np.asarray(got[f]), np.asarray(want[f])), f
+    # and it did write: a kept row is in its place
+    if not quantized:
+        b, o = int(blk[T // 2]), int(offs[T // 2])
+        assert np.array_equal(np.asarray(got["k"][b, :, o]),
+                              np.asarray(k_rows[T // 2]))
+
+
 # -- block-table gather path ------------------------------------------------
 
 def test_flash_decode_paged_matches_contiguous():

@@ -190,11 +190,15 @@ def test_dispatch_and_wait_tile_decode(serve_trace):
         both += 1
         disp, wait = kids
         assert disp.stats["ahead"] == 1
-        assert disp.end <= wait.start
-        # nothing of the program's runs between them: the two cover
-        # the phase but for the annotations' own entry and exit
-        covered = (disp.end - disp.start) + (wait.end - wait.start)
-        assert covered >= 0.5 * (d.end - d.start)
+        # in this order inside the phase, and no other span of the
+        # program's between or around them (`children` lists every
+        # span directly inside). Their share of the phase's wall time
+        # is the host's to decide and is not held: a stall between
+        # two annotations of a 2-6 ms phase failed this test on a
+        # busy machine
+        assert d.start <= disp.start <= disp.end <= wait.start \
+            <= wait.end <= d.end
+        assert not children(spans, disp) and not children(spans, wait)
     assert both == 6
 
 

@@ -7,6 +7,7 @@ hardware, which interpret-mode tests cannot give (the interpreter
 ignores tiling constraints; round 2 shipped kernels that passed
 interpret tests but could never have compiled on-chip)."""
 import re
+import types
 
 import numpy as np
 import pytest
@@ -489,3 +490,99 @@ def test_a_descriptions_decode_options_reach_the_tpu_compiler(
     assert starts(JambaDecoder.decode_compiler_options) < starts(None)
     with pytest.raises(Exception, match="xla_no_such_option"):
         starts({"xla_no_such_option": 1})
+
+
+# -- the serving programs whole: the paged cache's row write ---------------
+
+def _described_net(name, sharding, **sizes):
+    """A net of the cell's widths whose weights are shapes on the
+    described chip: nothing is allocated, `params_tree` reads them as
+    it reads arrays."""
+    import mxnet_tpu as mx
+    net = mx.models.get_model(name, **sizes)
+    for p in net.collect_params().values():
+        p._data = types.SimpleNamespace(_data=jax.ShapeDtypeStruct(
+            tuple(p.shape), jnp.dtype(p.dtype), sharding=sharding))
+        p._deferred = None
+    return net
+
+
+@pytest.mark.parametrize("what", [
+    "mistral_7b.reason decode", "mistral_7b.reason prefill",
+    "trinity_large.longctx decode", "trinity_large.longctx prefill",
+    "mistral_7b.reason decode, int8"])
+def test_the_paged_row_write_re_lays_no_pool_out(what, one_chip,
+                                                 monkeypatch):
+    """The real `decode` / `prefill` of `paged_programs` at two layers
+    of a cell's widths, with the cell's slots, blocks and `max_len`,
+    compiled for the described v5e: no `copy` in the compiled program
+    has a result of a pool's shape. The indexed write
+    `pool.at[blk, :, offs, :].set(rows)` held FOUR a layer (k and v,
+    each re-laid out with the scattered dims major for the scatter
+    and back for the kernel: 64 copies of 185 MB a Mistral tick, two
+    thirds of it); `write_rows` scatters on the pool's (N, K*bs, d)
+    view, in place. The int8 twin's scale pools (N, K, bs, 1) keep
+    the indexed write and are not held to this."""
+    from mxnet_tpu.serving.executables import paged_programs
+
+    # the kernels' gates ask the backend and the environment: the
+    # chip's answers, whatever another test module of this process set
+    # (perfbench/rehearse.py turns the interpreter on when imported)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for family in ("FLASH", "NORM", "CE", "MOE", "SCAN"):
+        monkeypatch.delenv(f"MXNET_TPU_{family}_INTERPRET", raising=False)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.dtype(dt), sharding=one_chip)
+    cell, program = what.split(",")[0].split()
+    q8 = what.endswith("int8")
+    if cell == "mistral_7b.reason":
+        net = _described_net("llama_3_8b", one_chip, num_layers=2,
+                             vocab_size=32000, rope_base=1e6)
+        slots, max_len, max_prompt, blocks = 20, 8448, 6144, [5633] * 2
+    else:       # one sliding layer (dense) and one full (sparse)
+        net = _described_net(
+            "afmoe", one_chip, vocab_size=25024, num_layers=2,
+            num_dense_layers=1, layer_types=["sliding", "full"],
+            held_experts=(0, 32), max_seq_len=22528)
+        slots, max_len, max_prompt = 48, 22528, 14336
+        blocks = [12337, 32001]
+    dec = net.decoder()
+    cfg, bs = dec.cfg, 16
+    nb = max_len // bs
+    programs = paged_programs(
+        net, batch_slots=slots, max_blocks_per_seq=nb, block_size=bs,
+        max_prompt_len=max_prompt,
+        kv_cache_dtype="int8" if q8 else "model")
+    kv = (cfg.num_kv_heads, bs, cfg.head_dim)
+    pages = [{f: sds((n,) + kv, "int8" if q8 else cfg.dtype)
+              for f in ("k", "v")} for n in blocks]
+    if q8:
+        for pg, n in zip(pages, blocks):
+            pg.update({f: sds((n,) + kv[:2] + (1,), "float32")
+                       for f in ("ks", "vs")})
+    params = dec.params_tree(net)
+    table, row = sds((slots, nb), "int32"), sds((nb,), "int32")
+    if dec.mixed:
+        table, row = (table, table), (row, row)
+    if program == "prefill":
+        args = (params, pages, row, sds((1, max_prompt), "int32"),
+                sds((1,), "int32"), sds((1,), "int32"))
+    else:
+        args = (params, pages, table, sds((slots,), "int32"),
+                sds((slots, cfg.vocab_size), cfg.dtype),
+                sds((slots, 2), "uint32"), sds((slots,), "float32"),
+                sds((slots,), "int32"), sds((slots,), "float32"),
+                sds((slots,), "bool"))
+    text = programs[program]._jit.lower(*args).compile().as_text()
+    copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+    assert copied, "the pattern finds no copy at all in this program"
+    pools = {",".join(map(str, (n,) + kv)) for n in blocks}
+    whole = [c for c in copied if c in pools]
+    assert not whole, f"{len(whole)} whole-pool copies"
+    kernel = "flash_attention_fwd" if program == "prefill" \
+        else "flash_decode_paged"
+    assert re.search(rf"%{kernel}[\w.]* = [^\n]* custom-call\(", text), \
+        f"no Mosaic call {kernel} in the compiled {program}"
+    # in place: every pool comes back in the buffer it came in
+    assert text.count("may-alias") + text.count("must-alias") \
+        >= len(jax.tree_util.tree_leaves(pages))
