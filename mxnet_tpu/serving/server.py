@@ -145,11 +145,14 @@ class _Flight:
     """One decode tick launched and not yet read: its outputs still on
     the device, and what the host knew of each row when it launched."""
 
-    __slots__ = ("tok", "n_acc", "counts", "prefill_counts", "admit",
-                 "warm", "dlens")
+    __slots__ = ("seq", "tok", "n_acc", "counts", "prefill_counts",
+                 "admit", "warm", "dlens")
 
-    def __init__(self, tok, n_acc, counts, prefill_counts, admit, warm,
-                 dlens):
+    def __init__(self, seq, tok, n_acc, counts, prefill_counts, admit,
+                 warm, dlens):
+        #: ordinal of the launch: `tick` on the `mx.serve_dispatch`
+        #: that launched it and on the `mx.serve_wait` that reads it
+        self.seq = seq
         self.tok, self.n_acc = tok, n_acc
         self.counts, self.prefill_counts = counts, prefill_counts
         #: admission stamp of each row's request, -2 where the tick
@@ -476,7 +479,9 @@ class InferenceServer:
         # tokens each slot has still to ask the device for
         self._flights: deque = deque()
         self._left = np.zeros(B, np.int32)
+        self._launches = 0
         self.ticks_ahead = 0
+        self.ticks_late = 0
         # chunked-prefill / speculative per-slot state: a prefilling
         # slot holds blocks + request but isn't decode-active yet; a
         # warm slot's next tick re-feeds the last prompt token (full
@@ -1301,12 +1306,27 @@ class InferenceServer:
         early returns included, holding `mx.serve_admit` (with one
         `mx.serve_prefill` a prompt), `mx.serve_blocks`,
         `mx.serve_decode` (`mx.serve_dispatch`: the uploads and launch
-        of the tick being queued, count `ahead` 1 when another was in
-        flight; then `mx.serve_wait`: the read of the tick handed
-        over) and `mx.serve_emit` (the counts of the tick read). From
-        an idle server a first `mx.serve_blocks` + `mx.serve_dispatch`
-        precede those; a `step()` with nothing left to launch has no
-        `mx.serve_blocks` and no `mx.serve_dispatch`."""
+        of the tick being queued, counts `tick` (the ordinal of the
+        launch), `active`, `ahead` 1 when another was in flight and
+        `late` 1 when that other's tokens were already there; then
+        `mx.serve_wait`: the read of the tick handed over, count
+        `tick` the ordinal ITS launch carried) and `mx.serve_emit`
+        (the counts of the tick read). From an idle server a first
+        `mx.serve_blocks` + `mx.serve_dispatch` precede those; a
+        `step()` with nothing left to launch has no `mx.serve_blocks`
+        and no `mx.serve_dispatch`.
+
+        With one tick queued ahead a wait carries the `tick` that the
+        dispatch of the `step()` before it carried; with
+        `speculative=` both carry the same one. `late` says the
+        tick in flight had ended before the host launched the next
+        (`stats()["ticks_late"]` sums it, with or without a profiler
+        session): the device had run out of decode work and stood
+        idle, unless a prompt this `step()` admitted kept it busy —
+        a `step()` that prefilled reads late too when the tick in
+        flight ended inside the prefill. The flag is read before the
+        launch's own uploads, so a launch that itself stalled there
+        is not counted."""
         with telemetry.span("serve_tick"):
             return self._tick()
 
@@ -1345,7 +1365,7 @@ class InferenceServer:
             # the host blocked on the tick before it
             if plan is not None:
                 self._dispatch(*plan)
-            with telemetry.span("serve_wait"):
+            with telemetry.span("serve_wait", tick=self._flights[0].seq):
                 flight = self._flights.popleft()
                 if flight.n_acc is not None:
                     wtok_np = np.asarray(flight.tok)   # (B, k+1)
@@ -1396,10 +1416,19 @@ class InferenceServer:
         read."""
         ahead = int(bool(self._flights))
         self.ticks_ahead += ahead
+        # the tick in flight has its tokens already: the device had
+        # finished all it had been given before the host launched more.
+        # Read before this launch's own uploads: one that stalls there
+        # is not counted
+        late = int(ahead and self._flights[0].tok.is_ready())
+        self.ticks_late += late
+        seq = self._launches
+        self._launches += 1
         counts = ()
         n_acc = None
-        with telemetry.span("serve_dispatch", active=int(send.sum()),
-                            ahead=ahead, **self._note_context(send)):
+        with telemetry.span("serve_dispatch", tick=seq,
+                            active=int(send.sum()), ahead=ahead,
+                            late=late, **self._note_context(send)):
             args = (self._params, self.cache.pages, self._tables(),
                     _upload(self._pos), self._last_logits, self._keys,
                     _upload(self._temps), _upload(self._top_ks),
@@ -1415,7 +1444,7 @@ class InferenceServer:
                     *args, *self._lora_args(self._adapter_ids))
             warm = send & self._warm
             self._flights.append(_Flight(
-                tok, n_acc, counts, self._prefill_counts,
+                seq, tok, n_acc, counts, self._prefill_counts,
                 np.where(send, self._slot_admit, -2), warm, dlens))
             self._prefill_counts = []
             self._pos[send] += 1
@@ -1991,6 +2020,7 @@ class InferenceServer:
                          state_slots_used=self.cache.state_slots_used)
         return {"ticks": self.ticks,
                 "ticks_ahead": self.ticks_ahead,
+                "ticks_late": self.ticks_late,
                 **extra,
                 "queue_age_p50_s": age_p50,
                 "queue_age_p95_s": age_p95,

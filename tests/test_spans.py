@@ -222,6 +222,121 @@ def test_dispatch_counts_the_slots_the_server_batched(serve_trace):
     assert sum(ahead) == stats["ticks_ahead"]
 
 
+def test_a_wait_carries_the_tick_its_dispatch_carried(serve_trace):
+    """One tick queued ahead: a `step()` launches tick n and reads
+    tick n - 1, the one the `step()` before it launched."""
+    spans, _, seen, stats = serve_trace
+    disp = named(spans, "mx.serve_dispatch")
+    waits = named(spans, "mx.serve_wait")
+    # every launch numbered in order, from idle (two launches in one
+    # step) and across the idle gap between the two waves alike
+    assert [d.stats["tick"] for d in disp] == list(range(len(seen)))
+    # every tick launched is read once, in the order launched
+    assert [w.stats["tick"] for w in waits] == list(range(len(seen)))
+    for d in named(spans, "mx.serve_decode"):
+        kids = children(spans, d)
+        if len(kids) == 2:
+            assert kids[1].stats["tick"] == kids[0].stats["tick"] - 1
+    # each wait comes after the dispatch of its own number
+    launched = {d.stats["tick"]: d for d in disp}
+    assert all(launched[w.stats["tick"]].end <= w.start for w in waits)
+    # `late` is the device's to decide here (a tiny tick may be over
+    # before the next launch); what the server counted is what it wrote
+    late = [d.stats["late"] for d in disp]
+    assert set(late) <= {0, 1} and sum(late) == stats["ticks_late"]
+    assert all(a >= b for a, b in zip(
+        (d.stats["ahead"] for d in disp), late))
+
+
+def test_speculation_reads_the_tick_it_just_launched(net, tmp_path):
+    server = InferenceServer(net, batch_slots=2, max_len=32,
+                             block_size=8, max_prompt_len=8,
+                             speculative=2)
+    server.submit(np.arange(5, dtype=np.int32), max_new_tokens=6)
+    spans = record(server.run, tmp_path)
+    decodes = named(spans, "mx.serve_decode")
+    assert decodes
+    for i, d in enumerate(decodes):
+        disp, wait = children(spans, d)
+        assert disp.stats["tick"] == wait.stats["tick"] == i
+        assert disp.stats["ahead"] == disp.stats["late"] == 0
+    assert server.stats()["ticks_late"] == 0
+
+
+class _StillRunning:
+    """A tick's tokens that say they are not there yet."""
+
+    def __init__(self, tok):
+        self.tok = tok
+
+    def is_ready(self):
+        return False
+
+    def __array__(self, *args, **kwargs):
+        return np.asarray(self.tok)
+
+
+@pytest.mark.parametrize("traced", [True, False])
+def test_late_is_a_launch_behind_a_tick_already_done(net, tmp_path,
+                                                     traced):
+    """`late` on the span and `stats()["ticks_late"]`, which is all an
+    untraced run (every judged one) has. The flag is read BEFORE the
+    tick's own uploads: it says the tick in flight was done when
+    `_dispatch` began, not that the launch itself was prompt."""
+    server = InferenceServer(net, batch_slots=2, max_len=32,
+                             block_size=8, max_prompt_len=8)
+    req = server.submit(np.arange(5, dtype=np.int32), max_new_tokens=6)
+    server.step()                       # from idle: two launches
+    before = server.stats()
+
+    def body():
+        # behind a tick that is still running
+        server._flights[0].tok = _StillRunning(server._flights[0].tok)
+        server.step()
+        assert server.stats()["ticks_late"] == before["ticks_late"]
+        # behind one whose tokens are on the host's side already
+        server._flights[0].tok.block_until_ready()
+        server.step()
+
+    if traced:
+        spans = record(body, tmp_path)
+        disp = named(spans, "mx.serve_dispatch")
+        assert [d.stats["late"] for d in disp] == [0, 1]
+        assert [d.stats["ahead"] for d in disp] == [1, 1]
+        assert [d.stats["tick"] for d in disp] == [2, 3]
+    else:
+        body()
+    after = server.stats()
+    assert after["ticks_late"] == before["ticks_late"] + 1
+    assert after["ticks_ahead"] == before["ticks_ahead"] + 2
+    server.run()
+    assert req.status == "ok" and len(req.output_tokens) == 6
+
+
+def test_tick_numbers_run_on_across_early_returns(net, tmp_path):
+    server = InferenceServer(net, batch_slots=2, max_len=32,
+                             block_size=8, max_prompt_len=8,
+                             watchdog_ticks=50)
+
+    def body():
+        server.submit(np.arange(4, dtype=np.int32), max_new_tokens=3)
+        server.run()
+        server.step()                   # idle: early return
+        faults.inject("serving.stall")
+        try:
+            server.step()               # injected dead tick
+        finally:
+            faults.clear()
+        server.submit(np.arange(6, dtype=np.int32), max_new_tokens=3)
+        server.run()
+
+    spans = record(body, tmp_path)
+    disp = [d.stats["tick"] for d in named(spans, "mx.serve_dispatch")]
+    assert disp == list(range(server.ticks)) and len(disp) == 6
+    assert [w.stats["tick"] for w in named(spans, "mx.serve_wait")] \
+        == disp
+
+
 def test_a_decoder_with_counts_puts_them_on_its_spans(tmp_path):
     """afmoe: `ctx` / `window_ctx` on mx.serve_dispatch (the positions
     the full and the sliding layers' sweeps read), the expert layers'
@@ -282,8 +397,8 @@ def test_a_recurrent_decoder_counts_its_rows_and_scanned_tokens(
     disp = named(spans, "mx.serve_dispatch")
     pre = named(spans, "mx.serve_prefill")
     assert len(disp) == server.ticks and len(pre) == 3
-    assert all(set(s.stats) == {"active", "ahead", "ctx", "ssm_rows"}
-               for s in disp)
+    assert all(set(s.stats) == {"tick", "active", "ahead", "late",
+                                "ctx", "ssm_rows"} for s in disp)
     assert all(s.stats["ssm_rows"] == s.stats["active"] for s in disp)
     assert sum(s.stats["ssm_rows"] for s in disp) == 3 * 5
     stats = server.compile_stats()
@@ -298,8 +413,10 @@ def test_a_recurrent_decoder_counts_its_rows_and_scanned_tokens(
 
 def test_the_llama_block_adds_no_count(serve_trace):
     spans, _, _, _ = serve_trace
-    assert all(set(s.stats) == {"active", "ahead"}
+    assert all(set(s.stats) == {"tick", "active", "ahead", "late"}
                for s in named(spans, "mx.serve_dispatch"))
+    assert all(set(s.stats) == {"tick"}
+               for s in named(spans, "mx.serve_wait"))
     assert all(s.stats == {} for s in named(spans, "mx.serve_emit"))
     assert all(set(s.stats) == {"tokens", "padded"}
                for s in named(spans, "mx.serve_prefill"))
