@@ -507,22 +507,15 @@ def _described_net(name, sharding, **sizes):
     return net
 
 
-@pytest.mark.parametrize("what", [
-    "mistral_7b.reason decode", "mistral_7b.reason prefill",
-    "trinity_large.longctx decode", "trinity_large.longctx prefill",
-    "mistral_7b.reason decode, int8"])
-def test_the_paged_row_write_re_lays_no_pool_out(what, one_chip,
-                                                 monkeypatch):
+_COMPILED = {}      # a cell's program compiles once for every test below
+
+
+def _compiled_serving_program(what, one_chip, monkeypatch):
     """The real `decode` / `prefill` of `paged_programs` at two layers
     of a cell's widths, with the cell's slots, blocks and `max_len`,
-    compiled for the described v5e: no `copy` in the compiled program
-    has a result of a pool's shape. The indexed write
-    `pool.at[blk, :, offs, :].set(rows)` held FOUR a layer (k and v,
-    each re-laid out with the scattered dims major for the scatter
-    and back for the kernel: 64 copies of 185 MB a Mistral tick, two
-    thirds of it); `write_rows` scatters on the pool's (N, K*bs, d)
-    view, in place. The int8 twin's scale pools (N, K, bs, 1) keep
-    the indexed write and are not held to this."""
+    compiled for the described v5e. Returns the compiled text, the
+    pools' shapes as a `copy`'s result prints them, and the number of
+    pool arrays."""
     from mxnet_tpu.serving.executables import paged_programs
 
     # the kernels' gates ask the backend and the environment: the
@@ -531,6 +524,8 @@ def test_the_paged_row_write_re_lays_no_pool_out(what, one_chip,
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for family in ("FLASH", "NORM", "CE", "MOE", "SCAN"):
         monkeypatch.delenv(f"MXNET_TPU_{family}_INTERPRET", raising=False)
+    if what in _COMPILED:
+        return _COMPILED[what]
     sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, jnp.dtype(dt), sharding=one_chip)
     cell, program = what.split(",")[0].split()
@@ -539,6 +534,10 @@ def test_the_paged_row_write_re_lays_no_pool_out(what, one_chip,
         net = _described_net("llama_3_8b", one_chip, num_layers=2,
                              vocab_size=32000, rope_base=1e6)
         slots, max_len, max_prompt, blocks = 20, 8448, 6144, [5633] * 2
+    elif cell == "jamba2_3b.reason256":     # one Mamba layer, one attention
+        net = _described_net("jamba", one_chip, num_layers=2,
+                             attn_layer_period=2, attn_layer_offset=1)
+        slots, max_len, max_prompt, blocks = 256, 10240, 2048, [0, 65537]
     else:       # one sliding layer (dense) and one full (sparse)
         net = _described_net(
             "afmoe", one_chip, vocab_size=25024, num_layers=2,
@@ -555,7 +554,10 @@ def test_the_paged_row_write_re_lays_no_pool_out(what, one_chip,
         kv_cache_dtype="int8" if q8 else "model")
     kv = (cfg.num_kv_heads, bs, cfg.head_dim)
     pages = [{f: sds((n,) + kv, "int8" if q8 else cfg.dtype)
-              for f in ("k", "v")} for n in blocks]
+              for f in ("k", "v")} if n else
+             {name: sds((slots,) + tuple(shape), dt)
+              for name, (shape, dt) in dec.state_shapes().items()}
+             for n in blocks]
     if q8:
         for pg, n in zip(pages, blocks):
             pg.update({f: sds((n,) + kv[:2] + (1,), "float32")
@@ -574,9 +576,30 @@ def test_the_paged_row_write_re_lays_no_pool_out(what, one_chip,
                 sds((slots,), "int32"), sds((slots,), "float32"),
                 sds((slots,), "bool"))
     text = programs[program]._jit.lower(*args).compile().as_text()
+    pools = {",".join(map(str, (n,) + kv)) for n in blocks if n}
+    _COMPILED[what] = text, pools, len(jax.tree_util.tree_leaves(pages))
+    return _COMPILED[what]
+
+
+@pytest.mark.parametrize("what", [
+    "mistral_7b.reason decode", "mistral_7b.reason prefill",
+    "trinity_large.longctx decode", "trinity_large.longctx prefill",
+    "mistral_7b.reason decode, int8"])
+def test_the_paged_row_write_re_lays_no_pool_out(what, one_chip,
+                                                 monkeypatch):
+    """No `copy` in the compiled program has a result of a pool's
+    shape. The indexed write
+    `pool.at[blk, :, offs, :].set(rows)` held FOUR a layer (k and v,
+    each re-laid out with the scattered dims major for the scatter
+    and back for the kernel: 64 copies of 185 MB a Mistral tick, two
+    thirds of it); `write_rows` scatters on the pool's (N, K*bs, d)
+    view, in place. The int8 twin's scale pools (N, K, bs, 1) keep
+    the indexed write and are not held to this."""
+    text, pools, n_pools = _compiled_serving_program(what, one_chip,
+                                                     monkeypatch)
+    program = what.split(",")[0].split()[1]
     copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
     assert copied, "the pattern finds no copy at all in this program"
-    pools = {",".join(map(str, (n,) + kv)) for n in blocks}
     whole = [c for c in copied if c in pools]
     assert not whole, f"{len(whole)} whole-pool copies"
     kernel = "flash_attention_fwd" if program == "prefill" \
@@ -584,5 +607,75 @@ def test_the_paged_row_write_re_lays_no_pool_out(what, one_chip,
     assert re.search(rf"%{kernel}[\w.]* = [^\n]* custom-call\(", text), \
         f"no Mosaic call {kernel} in the compiled {program}"
     # in place: every pool comes back in the buffer it came in
-    assert text.count("may-alias") + text.count("must-alias") \
-        >= len(jax.tree_util.tree_leaves(pages))
+    assert text.count("may-alias") + text.count("must-alias") >= n_pools
+
+
+# -- the sampler: thresholds by selection, no sort (PR 40) -----------------
+
+def _sampler_args(B, V, sharding=None):
+    """`sample_tokens`' operands as shapes: bf16 logits, row keys,
+    temperature, top_k, top_p."""
+    return [jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=sharding)
+            for shape, dt in (((B, V), "bfloat16"), ((B, 2), "uint32"),
+                              ((B,), "float32"), ((B,), "int32"),
+                              ((B,), "float32"))]
+
+
+def _sorts(text):
+    """[(operand dims, ...)] of every `sort` of a compiled program."""
+    return [tuple(tuple(map(int, dims.split(",")))
+                  for dims in re.findall(r"\w+\[([\d,]+)\]", operands))
+            for operands in re.findall(r" sort\(([^)]*)\)", text)]
+
+
+@pytest.mark.parametrize("what", ["jamba2_3b.reason256 decode",
+                                  "mistral_7b.reason decode",
+                                  "trinity_large.longctx decode"])
+def test_the_decode_program_sorts_no_logits(what, one_chip, monkeypatch):
+    """Until PR 40 every tick sorted its (slots, vocabulary) logits
+    twice (61% of jamba's tick). The compiled decode programs of the
+    Jamba and Mistral descriptions hold no `sort` at all; afmoe's
+    holds the expert layer's alone, over slots x top-k expert ids."""
+    text, _, _ = _compiled_serving_program(what, one_chip, monkeypatch)
+    assert " fusion(" in text and "reduce(" in text   # a compiled text
+    sorts = _sorts(text)
+    if what.startswith("trinity_large"):
+        assert sorts, "the expert layer's argsort has left the program"
+        assert all(dims == (48 * 4,) for op in sorts for dims in op), sorts
+    else:
+        assert not sorts, sorts
+
+
+def test_the_sampler_lowers_to_no_sort():
+    """Without a topology: the lowered text of `sample_tokens` holds
+    no sort (the parent's held two), whatever the backend makes of
+    the rest."""
+    from mxnet_tpu.serving.sampling import sample_tokens
+    text = jax.jit(sample_tokens).lower(*_sampler_args(8, 4099)).as_text()
+    assert "reduce" in text and "sort" not in text
+
+
+def test_a_selection_round_is_one_fusion_over_the_row(one_chip):
+    """What the tick pays for the two thresholds, counted from the
+    sampler compiled for the described v5e at jamba's 256 x 65,536:
+    each of the 32 rounds is ONE multi-output fusion over the bf16
+    logits (its 3 reductions are siblings) and ONE (256,) fusion that
+    settles the digit — no float32 copy of the row is kept, and the
+    device operations are a dozen more than the 66 the sorted sampler
+    compiled to (a profiler trace pays 36-46 us for every one, every
+    tick: PERF.md)."""
+    from mxnet_tpu.serving.sampling import sample_tokens
+    B, V = 256, 65536
+    compiled = jax.jit(sample_tokens).lower(
+        *_sampler_args(B, V, one_chip)).compile()
+    entry = compiled.as_text()
+    entry = entry[entry.index("\nENTRY"):]
+    ops = re.findall(r"^\s+(?:ROOT )?%[\w.\-]+ = (?:\(.*?\)|\S+) "
+                     r"([\w\-]+)\(", entry, re.M)
+    events = [op for op in ops if op not in (
+        "parameter", "get-tuple-element", "bitcast", "tuple", "constant",
+        "copy-start", "slice-start")]
+    assert "sort" not in events
+    assert 64 <= events.count("fusion") and len(events) <= 84, \
+        (len(events), sorted(set(events)))
+    assert compiled.memory_analysis().temp_size_in_bytes < B * V * 4
