@@ -551,6 +551,18 @@ def phase_kernels(size):
          jnp.asarray(bts), jnp.asarray(vls)),
         ("flash_decode_paged",), (TOL_DECODE,))
 
+    # the latent (MLA) sweep at the serving table: ONE pool of rows that
+    # all 64 heads share, read whole as keys and by their first 512 as
+    # values (sarvam_105b's widths: 512 latent + 64 rotated key + 64 zeros)
+    run("flash_decode paged latent, serving table",
+        lambda q, p, bt, n: fd.flash_decode_paged_latent(
+            q, p, bt, n, latent=512, scale=0.1352),
+        lambda q, p, bt, n: fd.reference_paged_latent_attention(
+            q.astype(f32), p.astype(f32), bt, n, 512, 0.1352),
+        (randn((Bs, 64, 640)), randn((Ns, 1, bs, 640), scale=0.5),
+         jnp.asarray(bts), jnp.asarray(vls)),
+        ("flash_decode_paged_latent",), (TOL_DECODE,))
+
     # the held experts' grouped matmul: a decode tick's few rows (most
     # held experts empty) and a prefill's many, routed a chunk at a time
     E, n, width, top_k = size.experts

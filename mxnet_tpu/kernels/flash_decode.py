@@ -30,6 +30,8 @@ __all__ = ["flash_decode", "flash_decode_quantized",
            "reference_decode_attention",
            "gather_kv_pages", "flash_decode_paged",
            "flash_decode_paged_quantized",
+           "flash_decode_paged_latent", "paged_latent_mode",
+           "reference_paged_latent_attention",
            "paged_kernel_mode", "paged_gather_bytes",
            "reference_paged_window_attention",
            "flash_decode_paged_window",
@@ -647,6 +649,222 @@ def flash_decode_paged_quantized(q, k8_pages, ks_pages, v8_pages,
     vs = gather_kv_pages(vs_pages, block_tables)
     return flash_decode_quantized(q, k8, ks, v8, vs, valid_len,
                                   scale=scale, use_flash=use_flash)
+
+
+# -- a paged pool of latents (MLA) -------------------------------------------
+# A LATENT layer (models/decoder.py) caches ONE row a position,
+# `[latent | rotated key | 0]`, that all H query heads share: its pool is
+# (N, 1, bs, row) and there is no second pool. The absorbed query is as
+# wide as the row, the scores read the row whole and the values are its
+# first `latent` entries, so a row is fetched from HBM once and used
+# twice. H query rows to one cached row is 2 * H * (row + latent) FLOPs
+# for 2 * row bytes, ~115 FLOP/byte at 64 heads: the one decode sweep
+# here where the MXU's time is not free beside the copies'.
+
+def reference_paged_latent_attention(q, pages, block_tables, valid_len,
+                                     latent, scale):
+    """jnp twin of the latent sweep: q (B, H, row), pages
+    (N, 1, bs, row), -> (B, H, latent). Gathers the contiguous view."""
+    rows = gather_kv_pages(pages, block_tables)[:, 0] \
+        .astype(jnp.float32)                            # (B, S, row)
+    s = jnp.einsum("bhr,bsr->bhs", q.astype(jnp.float32), rows) * scale
+    mask = jnp.arange(rows.shape[1])[None, :] < valid_len[:, None]
+    p = jax.nn.softmax(jnp.where(mask[:, None, :], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhs,bsl->bhl", p, rows[..., :latent]) \
+        .astype(q.dtype)
+
+
+def _latent_sweep_pages(bs, nb):
+    """Pages a step of the latent sweep moves: the tuned count, no
+    more than a table holds, in whole 128-lane tiles of scores where
+    it can be."""
+    from . import tuning
+
+    fit = max(1, min(tuning.get("flash_decode_paged_latent", "pages"),
+                     nb))
+    lane = max(1, 128 // bs)
+    return int(fit - fit % lane if fit > lane else fit)
+
+
+def paged_latent_mode(pool_operand, latent):
+    """Dispatch gate of the latent sweep, from static shapes alone:
+    None (the jnp twin), "interpret" or "compiled". One row a position
+    (K == 1), a block of whole sublane tiles; compiled, the row and the
+    latent in whole 128-lane tiles (Mosaic slices the values out of the
+    row at a tile's edge)."""
+    N, K, bs, row = pool_operand.shape
+    if K != 1 or bs % 8 != 0 or not 0 < latent <= row:
+        return None
+    if os.environ.get("MXNET_TPU_FLASH_INTERPRET", "0") == "1":
+        return "interpret"
+    if jax.default_backend() not in ("cpu",):
+        from .dispatch import operand_on_cpu
+
+        if operand_on_cpu(pool_operand) or row % 128 or latent % 128:
+            return None
+        return "compiled"
+    return None
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "latent", "scale", "pages", "interpret"))
+def _paged_latent_sweep(q, pool, block_tables, valid_len, *, latent,
+                        scale, pages, interpret):
+    """`_paged_sweep`'s schedule over ONE pool: grid (B,), a cell a
+    sequence, `pages` pages a step copied into one half of a VMEM
+    scratch while the arithmetic runs on the other, the last step of a
+    sequence starting the first of the next; online softmax in fp32
+    scratch. A step's rows (T, row) feed the MXU twice as stored:
+    scores q (H, row) x rows^T, then the probabilities x rows[:, :latent].
+    Against a bf16 pool the probabilities enter that product rounded
+    to bf16, ONE term and not `_paged_sweep`'s exact three: this sweep
+    is bound by the MXU and the vector unit, not by the copies alone,
+    and each term more cost the cell 6% of its tokens a second for
+    0.0002-0.0004 of mean logit gap (measured, docs/serving.md)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, R = q.shape
+    bs = pool.shape[2]
+    nb = block_tables.shape[1]
+    P, T = pages, pages * bs
+    native = q.dtype == pool.dtype == jnp.bfloat16
+    nt = (((1,), (1,)), ((), ()))                  # (h, r) x (t, r)
+
+    def kernel(bt_ref, vl_ref, q_ref, hbm, o_ref, buf, sem, slot_ref,
+               m_ref, l_ref, acc_ref):
+        b = pl.program_id(0)
+        vl = vl_ref[b]
+
+        def copy_step(row, i, slot, act):
+            """Start (or wait for) the pages of `row`'s step `i` that
+            hold a token, into (out of) half `slot`."""
+            def one(j, _):
+                page = bt_ref[row * nb + i * P + j]
+                act(pltpu.make_async_copy(
+                    hbm.at[page], buf.at[slot, j], sem.at[slot]))
+            held = (vl_ref[row] + bs - 1) // bs
+            jax.lax.fori_loop(0, jnp.minimum(P, held - i * P), one,
+                              None)
+
+        def start(row, i, slot):
+            copy_step(row, i, slot, lambda c: c.start())
+
+        @pl.when(b == 0)
+        def _first():
+            # a row no copy ever wrote must be finite under its 0
+            buf[...] = jnp.zeros_like(buf)
+            slot_ref[0] = 0
+            start(0, 0, 0)
+
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        # at least one step a sequence: the halves' hand-over below
+        # never skips a row (valid_len 0: all masked)
+        n = jnp.maximum((vl + T - 1) // T, 1)
+        slot0 = slot_ref[0]
+
+        def step(i, _):
+            slot = jax.lax.rem(slot0 + i, 2)
+
+            @pl.when(i + 1 < n)
+            def _next():
+                start(b, i + 1, 1 - slot)
+
+            @pl.when(jnp.logical_and(i + 1 == n, b + 1 < B))
+            def _next_row():
+                start(b + 1, 0, 1 - slot)
+
+            copy_step(b, i, slot, lambda c: c.wait())
+            live = i * T + jax.lax.broadcasted_iota(
+                jnp.int32, (H, T), 1) < vl
+            rows = buf[slot, :, 0].reshape(T, R)
+            vals = rows[:, :latent]
+            if native:
+                s = jax.lax.dot_general(
+                    q_ref[...], rows, nt,
+                    preferred_element_type=jnp.float32) * scale
+            else:
+                s = jax.lax.dot_general(
+                    q_ref[...].astype(jnp.float32) * scale,
+                    rows.astype(jnp.float32), nt,
+                    preferred_element_type=jnp.float32)
+            s = jnp.where(live, s, -jnp.inf)                 # (H, T)
+            m_prev = m_ref[...]                              # (H, 1)
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=-1, keepdims=True))
+            # `live`, a comparison: Mosaic has no is_finite lowering
+            p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+            corr = jnp.where(m_prev > -jnp.inf,
+                             jnp.exp(m_prev - m_new), 0.0)
+            m_ref[...] = m_new
+            l_ref[...] = corr * l_ref[...] \
+                + jnp.sum(p, axis=-1, keepdims=True)
+            if native:
+                pv = jnp.dot(p.astype(jnp.bfloat16), vals,
+                             preferred_element_type=jnp.float32)
+            else:
+                pv = jnp.dot(p, vals.astype(jnp.float32),
+                             preferred_element_type=jnp.float32)
+            acc_ref[...] = corr * acc_ref[...] + pv
+
+        jax.lax.fori_loop(0, n, step, None)
+        slot_ref[0] = jax.lax.rem(slot0 + n, 2)
+        l = l_ref[...]
+        o_ref[...] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)) \
+            .astype(o_ref.dtype)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((None, H, R), lambda b, bt, vl: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, H, latent),
+                               lambda b, bt, vl: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, P, 1, bs, R), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),           # a half each
+            pltpu.SMEM((1,), jnp.int32),             # half of step 0
+            pltpu.VMEM((H, 1), jnp.float32),         # m
+            pltpu.VMEM((H, 1), jnp.float32),         # l
+            pltpu.VMEM((H, latent), jnp.float32)])   # acc
+    # the sequences hand the scratch's halves and the copy in flight
+    # from one to the next: in order, on one core
+    params = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))}
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, latent), q.dtype),
+        interpret=interpret,
+        name="flash_decode_paged_latent",
+        **params,
+    )(block_tables.astype(jnp.int32).reshape(-1),
+      valid_len.astype(jnp.int32), q, pool)
+
+
+def flash_decode_paged_latent(q, pages, block_tables, valid_len, *,
+                              latent, scale, use_flash=True):
+    """Decode attention straight off a pool of latents: q (B, H, row)
+    the absorbed queries, pages (N, 1, bs, row) the cached rows, ->
+    (B, H, latent), the probabilities' mix of the rows' first `latent`
+    entries. The in-kernel sweep when the gate admits it, else the jnp
+    twin on the gathered view (counted at "flash-decode-paged")."""
+    mode = paged_latent_mode(pages, latent) if use_flash else None
+    if mode is not None:
+        try:
+            return _paged_latent_sweep(
+                q, pages, block_tables, valid_len, latent=int(latent),
+                scale=float(scale),
+                pages=_latent_sweep_pages(pages.shape[2],
+                                          block_tables.shape[1]),
+                interpret=mode == "interpret")
+        except Exception as e:
+            _paged_fallback.note(e)
+    return reference_paged_latent_attention(q, pages, block_tables,
+                                            valid_len, latent, scale)
 
 
 # -- multi-position window attention off the page pool ----------------------

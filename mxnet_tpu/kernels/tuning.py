@@ -42,6 +42,10 @@ DEFAULTS = {
     # blocks land on Mosaic's (8, 128) tiling
     "flash_decode_paged": {"vmem_budget_bytes": 8 << 20,
                            "preferred_block_size": 16},
+    # the latent (MLA) sweep: pages a step of ONE pool (a page is a
+    # (bs, row) block: 20 KB at 16 x 640 bf16, two steps of 128 in
+    # VMEM are 5.2 MB; timed on a v5e, tuned.json's note)
+    "flash_decode_paged_latent": {"pages": 128},
     # the expert layer's grouped matmul: a (block_k, block_n) tile of
     # an expert's matrix a step (1 MB in bf16, two of them when gate
     # and up share a pass), and how many tokens of a long prefill the

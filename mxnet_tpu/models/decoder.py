@@ -9,20 +9,27 @@ nothing a token), the parameter tree, and the layer in the forms the
 programs use: an attention layer whole (prefill) and split around the
 paged attention call (decode); a recurrent layer whole, handing back
 the state at each row's length (prefill), and for one token of every
-row, taking and returning the rows' states (decode). The Llama block is
-the first description (`llama_infer.LlamaDecoder`), afmoe the second
-(`afmoe.AfmoeDecoder`), Jamba the third (`jamba.JambaDecoder`).
+row, taking and returning the rows' states (decode). A LATENT layer
+caches every position like a FULL one, but ONE row a position that all
+its heads share and read twice, as keys whole and as values by its
+first `latent` entries (`latent_shapes`): its `prefill_layer` and
+`layer_qkv` hand back that row as `k` and None as `v`, and its queries
+enter the paged call as wide as the row. The Llama block is the first
+description (`llama_infer.LlamaDecoder`), afmoe the second
+(`afmoe.AfmoeDecoder`), Jamba the third (`jamba.JambaDecoder`), Sarvam's
+latent attention the fourth (`sarvam.SarvamDecoder`).
 """
 from __future__ import annotations
 
-FULL, SLIDING, RECURRENT = "full", "sliding", "recurrent"
+FULL, SLIDING, RECURRENT, LATENT = "full", "sliding", "recurrent", \
+    "latent"
 
 
 class DecoderDescription:
     """Base of the descriptions. `cfg` carries num_layers, num_heads,
     num_kv_heads, head_dim, vocab_size, rms_eps, dtype.
 
-    layer_kinds  one of FULL / SLIDING / RECURRENT a layer
+    layer_kinds  one of FULL / SLIDING / RECURRENT / LATENT a layer
     window       positions a SLIDING layer attends, else None
     counts       names of the int32 counts `layer_finish` /
                  `prefill_layer` return a layer (summed over layers
@@ -55,6 +62,11 @@ class DecoderDescription:
         """True where some layer keeps a state a sequence."""
         return RECURRENT in self.layer_kinds
 
+    @property
+    def latent(self):
+        """True where some layer caches one shared row a position."""
+        return LATENT in self.layer_kinds
+
     def layer_window(self, li):
         return self.window if self.layer_kinds[li] == SLIDING else None
 
@@ -63,7 +75,7 @@ class DecoderDescription:
             raise NotImplementedError(
                 f"{what} is not implemented for {type(self).__name__} "
                 f"(layer kinds {sorted(set(self.layer_kinds))} of "
-                f"{[FULL, SLIDING, RECURRENT]}, counts "
+                f"{[FULL, SLIDING, RECURRENT, LATENT]}, counts "
                 f"{list(self.counts)}; it implements "
                 f"{sorted(self.supports) or 'plain prefill and decode'}"
                 f"): serve this net without it")
@@ -99,9 +111,19 @@ class DecoderDescription:
         False hands its state back untouched."""
         raise NotImplementedError
 
+    # -- a LATENT layer's cache ----------------------------------------------
+    def latent_shapes(self):
+        """What the paged call of a LATENT layer takes: {"latent": the
+        entries of a cached row read as values (its first ones; the
+        scores read the whole row, `cfg.head_dim` wide), "scale": the
+        softmax scale}."""
+        raise NotImplementedError
+
     def layer_qkv(self, li, lp, x, positions, lora=None):
         """-> (q, k, v, carry): k, v as the cache stores them, `carry`
-        whatever `layer_finish` needs beside the attention output."""
+        whatever `layer_finish` needs beside the attention output. A
+        LATENT layer: q (B, T, H, row), k (B, T, 1, row), v None, and
+        `layer_finish` is given att (B, T, H, latent)."""
         raise NotImplementedError
 
     def layer_finish(self, li, lp, x, att, carry, lora=None,

@@ -55,12 +55,15 @@ def rms(x, g, eps):
     return fused_rmsnorm(x, g, eps=eps)
 
 
-def rope_at(x, positions, base):
+def rope_at(x, positions, base, inv=None):
     """Rotary embedding for (B, T, H, d) at absolute `positions`
-    ((T,) or (B, T)); fp32 rotation, output in x.dtype."""
+    ((T,) or (B, T)); fp32 rotation, output in x.dtype. `inv` (d / 2,)
+    gives the frequencies themselves where they are not the plain
+    `base` ** (-i / half) (YaRN: `mla_math.yarn_inv_freq`)."""
     d = x.shape[-1]
     half = d // 2
-    inv = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if inv is None:
+        inv = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     pos = jnp.asarray(positions, jnp.float32)
     if pos.ndim == 1:
         pos = pos[None, :]

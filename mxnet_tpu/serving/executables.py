@@ -224,7 +224,10 @@ def write_rows(pg, blk_ids, offs, k_rows, v_rows):
     rows (T, K, d); a pool with scales ("ks", "vs") stores int8. The
     scale pools (N, K, bs, 1) keep the indexed write: advanced indices
     around the K slice put the token axis first, so the value shape
-    (T, K, 1) matches the scales."""
+    (T, K, 1) matches the scales. A LATENT layer has the one pool,
+    "k", of the rows its heads share, and hands None as `v_rows`."""
+    if v_rows is None:
+        return {"k": _put_rows(pg["k"], blk_ids, offs, k_rows)}
     if "ks" in pg:
         k8, ks = _quant_rows(k_rows)
         v8, vs = _quant_rows(v_rows)
@@ -273,7 +276,10 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
         per-row sampling of the PREVIOUS logits, one decode step for
         all batch slots, paged cache write, per-row PRNG advance.
         Inactive slots compute against the scratch block and their
-        outputs are discarded by the scheduler. A RECURRENT layer has
+        outputs are discarded by the scheduler. A LATENT layer's entry
+        of `pages` is the one pool {"k": (N, 1, bs, row)} of the rows
+        its heads share; the tick writes the new row in place and the
+        latent sweep reads keys and values off it. A RECURRENT layer has
         no scratch: its step is masked by `active`, and an inactive
         row's state comes back as it went in.
 
@@ -321,11 +327,12 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
     if ent is not None:
         return ent
 
-    from ..models.decoder import RECURRENT, SLIDING
+    from ..models.decoder import LATENT, RECURRENT, SLIDING
     from ..models.llama_math import final_logits, rms
     from ..kernels.flash_decode import (
-        flash_decode_paged, flash_decode_paged_quantized,
-        flash_decode_paged_window, flash_decode_paged_window_quantized)
+        flash_decode_paged, flash_decode_paged_latent,
+        flash_decode_paged_quantized, flash_decode_paged_window,
+        flash_decode_paged_window_quantized)
     from .sampling import sample_tokens
 
     # the net's own description of its decoder (models/decoder.py):
@@ -334,6 +341,8 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
     cfg = dec.cfg
     K, d = cfg.num_kv_heads, cfg.head_dim
     q8 = kv_cache_dtype == "int8"
+    # a LATENT layer's paged call takes the values' width and the scale
+    latent = dec.latent_shapes() if dec.latent else None
     for feature, wanted in (("int8", q8), ("lora", lora),
                             ("prefill_chunk", prefill_chunk),
                             ("speculative", spec_k)):
@@ -417,7 +426,8 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
                                            valid_len, lora=la[li])
             counts = add_counts(counts, c)
             blk = jnp.where(keep, kind_of(bt_row, li)[t // bs], 0)
-            new_pages.append(write_rows(pg, blk, offs, k[0], v[0]))
+            new_pages.append(write_rows(pg, blk, offs, k[0],
+                                        None if v is None else v[0]))
         x = rms(x, params["norm"], cfg.rms_eps)
         idx = jnp.maximum(valid_len - 1, 0)
         last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
@@ -448,8 +458,13 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
                                            lora=la[li])
             bt = kind_of(block_tables, li)
             blk = jnp.where(active, bt[rows, pos // bs], 0)
-            npg = write_rows(pg, blk, offs, k[:, 0], v[:, 0])
-            if q8:
+            npg = write_rows(pg, blk, offs, k[:, 0],
+                             None if v is None else v[:, 0])
+            if dec.layer_kinds[li] == LATENT:
+                att = flash_decode_paged_latent(
+                    q[:, 0], npg["k"], bt, vl, latent=latent["latent"],
+                    scale=latent["scale"])[:, None]
+            elif q8:
                 att = flash_decode_paged_quantized(
                     q[:, 0], npg["k"], npg["ks"], npg["v"], npg["vs"],
                     bt, vl)[:, None]

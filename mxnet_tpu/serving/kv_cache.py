@@ -67,6 +67,15 @@ over. A row is in use exactly while its slot owns blocks
 (`state_slots_used`); `check()` holds every layer's pool to its kind.
 A state cannot be shared by prefix nor rewound by a token, and the
 server refuses those features for such a net.
+
+Latent layers (layer_kinds with "latent" entries): such a layer caches
+every position, like a FULL one and under the same allocator, table
+and `check()`, but ONE row a position that all its heads share and
+that is read as keys and as values both: its entry of `pages` is
+{"k": (N, 1, bs, row)} alone, `num_kv_heads` 1 and `head_dim` the
+row's width. `latent_pool_bytes` is what those pools hold. No int8
+pool and no prefix sharing (the server refuses them by the
+description).
 """
 from __future__ import annotations
 
@@ -114,6 +123,12 @@ class PagedKVCache:
             raise NotImplementedError(
                 "a cache with recurrent layers needs their state_shapes"
                 " and has no int8 pool and no prefix sharing")
+        if "latent" in kinds and (quantized or prefix_cache
+                                  or num_kv_heads != 1):
+            raise NotImplementedError(
+                "a cache with latent layers holds one row a position "
+                "(num_kv_heads 1) and has no int8 pool and no prefix "
+                "sharing")
         self.window = int(window) if "sliding" in kinds else None
         if self.window is not None and (quantized or prefix_cache):
             raise NotImplementedError(
@@ -163,6 +178,8 @@ class PagedKVCache:
                             for name, (shape, dt)
                             in self.state_shapes.items()}
                 n = self.window_num_blocks if kind == "sliding" else N
+                if kind == "latent":
+                    return {"k": jnp.zeros((n, K, bs, d), dtype)}
                 return {"k": jnp.zeros((n, K, bs, d), dtype),
                         "v": jnp.zeros((n, K, bs, d), dtype)}
 
@@ -173,6 +190,14 @@ class PagedKVCache:
             int(a.size) * a.dtype.itemsize
             for kind, pg in zip(kinds, self.pages) if kind == "recurrent"
             for a in pg.values())
+
+        #: bytes of the latent layers' pools (0 without them)
+        self.latent_pool_bytes = sum(
+            int(pg["k"].size) * pg["k"].dtype.itemsize
+            for kind, pg in zip(kinds, self.pages) if kind == "latent")
+        #: positions those pools hold (block 0, the scratch, apart)
+        self.latent_pool_tokens = (num_blocks - 1) * block_size \
+            if "latent" in kinds else 0
 
         # host-side allocator state. Free list is LIFO (hot blocks get
         # reused first); block 0 never enters it.
@@ -348,6 +373,9 @@ class PagedKVCache:
         if self.state_shapes:
             out.update(state_pool_bytes=self.state_pool_bytes,
                        state_slots_used=self.state_slots_used)
+        if self.latent_pool_bytes:
+            out.update(latent_pool_bytes=self.latent_pool_bytes,
+                       latent_pool_tokens=self.latent_pool_tokens)
         if self.window is not None:
             out.update(
                 window_blocks_used=self.window_blocks_used,
@@ -769,13 +797,16 @@ class PagedKVCache:
             self.tier.check()
         for kind, pg in zip(self.layer_kinds, self.pages or ()):
             # a recurrent layer holds the state pool, a row a slot, and
-            # no block pool; an attention layer the reverse
+            # no block pool; an attention layer the reverse, a latent
+            # one its rows' pool alone
             want = {n: (self.batch_slots,) + tuple(shape)
                     for n, (shape, _) in self.state_shapes.items()} \
                 if kind == "recurrent" else None
             assert want is None and "k" in pg or \
                 {n: a.shape for n, a in pg.items()} == want, \
                 f"a {kind} layer's pool out of shape: {sorted(pg)}"
+            assert (kind == "latent") == (sorted(pg) == ["k"]), \
+                f"a {kind} layer's pools: {sorted(pg)}"
         if self.window is not None:
             wowned = [b for blks in self._wslot_blocks for b in blks]
             assert 0 not in wowned and 0 not in self._wfree, \

@@ -378,7 +378,7 @@ class InferenceServer:
         self._device = dev = _params_device(params)
         self._params = jax.device_put(params, dev)
         kinds = {}
-        if dec.mixed or dec.recurrent:
+        if dec.mixed or dec.recurrent or dec.latent:
             kinds["layer_kinds"] = dec.layer_kinds
         if dec.mixed:
             kinds.update(window=dec.window,
@@ -395,6 +395,9 @@ class InferenceServer:
         #: a net with recurrent layers: prefill takes the slot, and the
         #: spans carry the counts its kernels' rooflines read
         self._recurrent = dec.recurrent
+        #: a net with latent layers: the dispatch span carries `ctx`,
+        #: the cached rows its sweep reads
+        self._latent = dec.latent
         #: cached positions the decode ticks attended, summed over
         #: active slots and ticks: every one (`context_tokens`) and the
         #: last `window` of them (`window_context_tokens`, what a
@@ -1469,6 +1472,8 @@ class InferenceServer:
         self.context_tokens += ctx
         if self._recurrent:
             return {"ctx": ctx, "ssm_rows": int(send.sum())}
+        if self._latent:
+            return {"ctx": ctx}
         if self.decoder.window is None:
             return {}
         wctx = int(np.minimum(vl, self.decoder.window).sum())
@@ -1980,7 +1985,7 @@ class InferenceServer:
                 global_blocks_capacity=kv.global_blocks_capacity,
                 context_tokens=self.context_tokens,
                 window_context_tokens=self.window_context_tokens)
-        if self._recurrent:
+        if self._recurrent or self._latent:
             out.update(context_tokens=self.context_tokens)
         out.update(self.decoder_counts)
         v = self.programs.get("verify")
@@ -2018,6 +2023,10 @@ class InferenceServer:
         if self._recurrent:
             extra.update(state_pool_bytes=self.cache.state_pool_bytes,
                          state_slots_used=self.cache.state_slots_used)
+        if self._latent:
+            extra.update(
+                latent_pool_bytes=self.cache.latent_pool_bytes,
+                latent_pool_tokens=self.cache.latent_pool_tokens)
         return {"ticks": self.ticks,
                 "ticks_ahead": self.ticks_ahead,
                 "ticks_late": self.ticks_late,

@@ -550,3 +550,75 @@ def test_flash_attention_at_a_group_of_twenty_on_one_kv_head(
     np.testing.assert_allclose(np.asarray(out)[:, rows],
                                np.asarray(ref)[:, rows],
                                rtol=2e-4, atol=2e-4)
+
+
+# -- the latent (MLA) sweep: one pool, every row read once, used twice ------
+
+from mxnet_tpu.kernels import flash_decode as fd  # noqa: E402
+
+def _latent_pool(dtype, B=5, H=8, R=256, bs=8, nb=6, N=40, seed=0):
+    """Ragged lengths: an idle slot on the scratch block (length 1,
+    table all 0), a sequence shorter than one page, one that ends on a
+    page's edge, two ragged ones."""
+    rng = np.random.default_rng(seed)
+    pool = jnp.asarray(rng.normal(0, 1, (N, 1, bs, R)), dtype)
+    q = jnp.asarray(rng.normal(0, 1, (B, H, R)), dtype)
+    vl = np.array([1, 5, 17, 48, 33], np.int32)
+    bt = np.zeros((B, nb), np.int32)
+    free = list(rng.permutation(np.arange(1, N)))
+    for b in range(1, B):
+        for j in range(-(-int(vl[b]) // bs)):
+            bt[b, j] = free.pop()
+    return q, pool, jnp.asarray(bt), jnp.asarray(vl)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
+                                       ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("pages", [1, 2, 3, 4, 6, 8])
+def test_flash_decode_paged_latent_matches_its_twin(dtype, tol, pages,
+                                                    monkeypatch):
+    """Over the counts of pages a step: one (a step a block), a last
+    step part full (sequences of 1 to 6 blocks), the table's own 6 and
+    more than it holds (one step a sequence)."""
+    from mxnet_tpu.kernels import tuning
+
+    monkeypatch.setenv("MXNET_TPU_FLASH_INTERPRET", "1")
+    q, pool, bt, vl = _latent_pool(jnp.dtype(dtype))
+    tuning.set_runtime("flash_decode_paged_latent", "pages", pages)
+    before = fd._paged_fallback.count
+    try:
+        assert fd.paged_latent_mode(pool, 128) == "interpret"
+        got = fd.flash_decode_paged_latent(q, pool, bt, vl, latent=128,
+                                           scale=0.2)
+    finally:
+        tuning.clear_runtime()
+    want = fd.reference_paged_latent_attention(q, pool, bt, vl, 128, 0.2)
+    assert got.shape == (5, 8, 128) and got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol)
+    assert fd._paged_fallback.count == before  # kernel path, no note()
+
+
+def test_the_latent_twin_reads_keys_and_values_off_one_row():
+    """By hand on one sequence: scores over the whole row, values its
+    first `latent` entries, positions past the length masked."""
+    q, pool, bt, vl = _latent_pool(jnp.float32)
+    b, n = 2, int(vl[2])
+    rows = np.concatenate([np.asarray(pool[p, 0]) for p in
+                           np.asarray(bt[b])])[:n]
+    s = np.asarray(q[b]) @ rows.T * 0.2
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = (p / p.sum(-1, keepdims=True)) @ rows[:, :128]
+    got = fd.reference_paged_latent_attention(q, pool, bt, vl, 128, 0.2)
+    np.testing.assert_allclose(got[b], want, atol=1e-5)
+
+
+def test_the_latent_gate_answers_from_static_shapes(monkeypatch):
+    monkeypatch.delenv("MXNET_TPU_FLASH_INTERPRET", raising=False)
+    pool = jnp.zeros((4, 1, 16, 640), jnp.bfloat16)
+    assert fd.paged_latent_mode(pool, 512) is None          # the CPU
+    monkeypatch.setenv("MXNET_TPU_FLASH_INTERPRET", "1")
+    assert fd.paged_latent_mode(pool, 512) == "interpret"
+    assert fd.paged_latent_mode(jnp.zeros((4, 2, 16, 640)), 512) is None
+    assert fd.paged_latent_mode(jnp.zeros((4, 1, 12, 640)), 512) is None
+    assert fd.paged_latent_mode(pool, 768) is None

@@ -411,6 +411,70 @@ def test_a_recurrent_decoder_counts_its_rows_and_scanned_tokens(
         == server.cache.stats()["state_pool_bytes"] > 0
 
 
+#: what each description's spans carry beside `tick`, `active`, `ahead`
+#: and `late` (mx.serve_dispatch), on mx.serve_emit, and beside `tokens`
+#: and `padded` (mx.serve_prefill): a new description adds its own and
+#: none to another's tick
+DESCRIPTION_COUNTS = {
+    "llama_tiny": ({}, set(), set(), set()),
+    "afmoe_tiny": ({"held_experts": (0, 8)}, {"ctx", "window_ctx"},
+                   {"pairs", "touched"}, set()),
+    "jamba_tiny": ({}, {"ctx", "ssm_rows"}, set(), {"scan_tokens"}),
+    "sarvam_mla_tiny": ({"held_experts": (0, 8)}, {"ctx"},
+                        {"pairs", "touched"}, set()),
+}
+
+
+@pytest.mark.parametrize("model", list(DESCRIPTION_COUNTS))
+def test_every_description_carries_its_own_counts_and_no_others(
+        model, tmp_path):
+    """Sarvam (latent layers): `ctx` on mx.serve_dispatch, the cached
+    rows the latent sweep reads, summed over slots, and the expert
+    layers' `pairs` / `touched` (a prefill's as `prefill_*`) on
+    mx.serve_emit. A Llama, an afmoe and a Jamba tick carry exactly what
+    they carried before it came."""
+    kw, on_dispatch, on_emit, on_prefill = DESCRIPTION_COUNTS[model]
+    net = mx.models.get_model(model, **kw)
+    net.initialize()
+    server = InferenceServer(net, batch_slots=2, max_len=64,
+                             block_size=8, max_prompt_len=48)
+    rs = np.random.RandomState(3)
+
+    def body():
+        for n in (40, 6, 9):
+            server.submit(rs.randint(0, 256, n).astype(np.int32),
+                          max_new_tokens=5)
+        server.run()
+
+    spans = record(body, tmp_path)
+    disp = named(spans, "mx.serve_dispatch")
+    emit = named(spans, "mx.serve_emit")
+    assert len(disp) == len(emit) == server.ticks
+    assert all(set(s.stats) == {"tick", "active", "ahead", "late"}
+               | on_dispatch for s in disp)
+    assert all(set(s.stats) - {"prefill_" + k for k in on_emit}
+               == on_emit for s in emit)
+    assert all(set(s.stats) == {"tokens", "padded"} | on_prefill
+               for s in named(spans, "mx.serve_prefill"))
+    assert all(set(s.stats) == {"tick"}
+               for s in named(spans, "mx.serve_wait"))
+    stats = server.compile_stats()
+    if on_dispatch:
+        assert sum(s.stats["ctx"] for s in disp) \
+            == stats["context_tokens"] > 0
+    for key in on_emit:
+        assert sum(s.stats[key] for s in emit) == stats[key] > 0
+        assert sum(s.stats.get("prefill_" + key, 0) for s in emit) \
+            == stats["prefill_" + key] > 0
+    latent = model == "sarvam_mla_tiny"
+    assert ("latent_pool_bytes" in server.stats()) == latent
+    if latent:
+        # 3 layers x 17 blocks x 8 positions x a row of 128 float32
+        assert server.stats()["latent_pool_bytes"] \
+            == 3 * 17 * 8 * 128 * 4
+        assert server.stats()["latent_pool_tokens"] == 16 * 8
+
+
 def test_the_llama_block_adds_no_count(serve_trace):
     spans, _, _, _ = serve_trace
     assert all(set(s.stats) == {"tick", "active", "ahead", "late"}

@@ -123,6 +123,47 @@ def test_flash_decode_paged_compiles_for_v5e(B, nb, N, one_chip):
     assert "flash_decode_paged" in text
 
 
+# -- the latent sweep at its cell's shapes (sarvam_105b.longctx64: 64
+# slots of max_len 26,624, 64 heads on one cached row of 640 = 512
+# latent + 64 rotated key + 64 zeros, a pool of 57,501 blocks) ----------
+
+def _latent_args(B=64, nb=1664, N=57501, sharding=None):
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=sharding)
+    return (sds((B, 64, 640), jnp.bfloat16),
+            sds((N, 1, 16, 640), jnp.bfloat16), sds((B, nb), jnp.int32),
+            sds((B,), jnp.int32))
+
+
+def _latent(q, pool, bt, vl):
+    from mxnet_tpu.kernels.flash_decode import _paged_latent_sweep
+    return _paged_latent_sweep(q, pool, bt, vl, latent=512, scale=0.1352,
+                               pages=128, interpret=False)
+
+
+def test_flash_decode_paged_latent_lowers_at_the_cells_shapes():
+    _lowers(_latent, *_latent_args())
+
+
+def test_flash_decode_paged_latent_compiles_for_v5e(one_chip, monkeypatch):
+    """Through the dispatch, so the gate's answer for this pool is part
+    of what is held: compiled, never the gathered twin. A pool of rows
+    576 wide is what the gate turns away (Mosaic slices the values out
+    of the row at a 128-lane tile's edge)."""
+    from mxnet_tpu.kernels import flash_decode as fd
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("MXNET_TPU_FLASH_INTERPRET", raising=False)
+    args = _latent_args(sharding=one_chip)
+    assert fd.paged_latent_mode(args[1], 512) == "compiled"
+    assert fd.paged_latent_mode(jax.ShapeDtypeStruct(
+        (9, 1, 16, 576), jnp.bfloat16, sharding=one_chip), 512) is None
+    text = jax.jit(lambda *a: fd.flash_decode_paged_latent(
+        *a, latent=512, scale=0.1352)).lower(*args).compile().as_text()
+    assert "flash_decode_paged_latent" in text
+    assert "gather" not in text
+
+
 # -- the afmoe cell's kernels at its shapes (trinity_large.longctx:
 # 48 slots of max_len 22,528, window 4,096, 48 / 8 heads of 128, 32 held
 # experts of 3,072 x 3,072, prompts up to 14,336) -------------------------
@@ -534,6 +575,11 @@ def _compiled_serving_program(what, one_chip, monkeypatch):
         net = _described_net("llama_3_8b", one_chip, num_layers=2,
                              vocab_size=32000, rope_base=1e6)
         slots, max_len, max_prompt, blocks = 20, 8448, 6144, [5633] * 2
+    elif cell == "sarvam_105b.longctx64":   # the dense layer, one sparse
+        net = _described_net("sarvam_mla", one_chip, num_layers=2,
+                             vocab_size=32768, held_experts=(0, 16),
+                             max_seq_len=26624)
+        slots, max_len, max_prompt, blocks = 64, 26624, 14336, [57501] * 2
     elif cell == "jamba2_3b.reason256":     # one Mamba layer, one attention
         net = _described_net("jamba", one_chip, num_layers=2,
                              attn_layer_period=2, attn_layer_offset=1)
@@ -554,7 +600,7 @@ def _compiled_serving_program(what, one_chip, monkeypatch):
         kv_cache_dtype="int8" if q8 else "model")
     kv = (cfg.num_kv_heads, bs, cfg.head_dim)
     pages = [{f: sds((n,) + kv, "int8" if q8 else cfg.dtype)
-              for f in ("k", "v")} if n else
+              for f in (("k",) if dec.latent else ("k", "v"))} if n else
              {name: sds((slots,) + tuple(shape), dt)
               for name, (shape, dt) in dec.state_shapes().items()}
              for n in blocks]
@@ -584,7 +630,8 @@ def _compiled_serving_program(what, one_chip, monkeypatch):
 @pytest.mark.parametrize("what", [
     "mistral_7b.reason decode", "mistral_7b.reason prefill",
     "trinity_large.longctx decode", "trinity_large.longctx prefill",
-    "mistral_7b.reason decode, int8"])
+    "mistral_7b.reason decode, int8",
+    "sarvam_105b.longctx64 decode", "sarvam_105b.longctx64 prefill"])
 def test_the_paged_row_write_re_lays_no_pool_out(what, one_chip,
                                                  monkeypatch):
     """No `copy` in the compiled program has a result of a pool's
@@ -594,7 +641,9 @@ def test_the_paged_row_write_re_lays_no_pool_out(what, one_chip,
     and back for the kernel: 64 copies of 185 MB a Mistral tick, two
     thirds of it); `write_rows` scatters on the pool's (N, K*bs, d)
     view, in place. The int8 twin's scale pools (N, K, bs, 1) keep
-    the indexed write and are not held to this."""
+    the indexed write and are not held to this. A LATENT layer's one
+    pool (N, 1, bs, 640) is written the same way and read by the
+    latent sweep (PR 41)."""
     text, pools, n_pools = _compiled_serving_program(what, one_chip,
                                                      monkeypatch)
     program = what.split(",")[0].split()[1]
@@ -603,6 +652,7 @@ def test_the_paged_row_write_re_lays_no_pool_out(what, one_chip,
     whole = [c for c in copied if c in pools]
     assert not whole, f"{len(whole)} whole-pool copies"
     kernel = "flash_attention_fwd" if program == "prefill" \
+        else "flash_decode_paged_latent" if what.startswith("sarvam") \
         else "flash_decode_paged"
     assert re.search(rf"%{kernel}[\w.]* = [^\n]* custom-call\(", text), \
         f"no Mosaic call {kernel} in the compiled {program}"
