@@ -659,7 +659,13 @@ def flash_decode_paged_quantized(q, k8_pages, ks_pages, v8_pages,
 # first `latent` entries, so a row is fetched from HBM once and used
 # twice. H query rows to one cached row is 2 * H * (row + latent) FLOPs
 # for 2 * row bytes, ~115 FLOP/byte at 64 heads: the one decode sweep
-# here where the MXU's time is not free beside the copies'.
+# here where the MXU's time is not free beside the copies'. Measured
+# on a v5e at 596k rows of 640 in 16-row pages (PERF.md section 6,
+# PR 42): the arithmetic alone 0.68 ms a call, the copies alone (their
+# starts and waits in loops of their own) 1.24, the two in series 1.88;
+# with the starts spread between the pieces of the arithmetic the
+# copies alone take 1.08 (the DMA engine's pace, 28 ns a 20 KB page)
+# and the whole 1.13.
 
 def reference_paged_latent_attention(q, pages, block_tables, valid_len,
                                      latent, scale):
@@ -674,16 +680,19 @@ def reference_paged_latent_attention(q, pages, block_tables, valid_len,
         .astype(q.dtype)
 
 
-def _latent_sweep_pages(bs, nb):
-    """Pages a step of the latent sweep moves: the tuned count, no
-    more than a table holds, in whole 128-lane tiles of scores where
-    it can be."""
+def _latent_sweep_sizes(bs, nb):
+    """(pages a step, keys a sub-chunk) of the latent sweep: the tuned
+    counts, a step no longer than a table and in whole 128-lane tiles
+    of scores where it can be, a sub-chunk in whole pages."""
     from . import tuning
 
     fit = max(1, min(tuning.get("flash_decode_paged_latent", "pages"),
                      nb))
     lane = max(1, 128 // bs)
-    return int(fit - fit % lane if fit > lane else fit)
+    pages = int(fit - fit % lane if fit > lane else fit)
+    chunk = max(bs, int(tuning.get("flash_decode_paged_latent",
+                                   "chunk")) // bs * bs)
+    return pages, chunk
 
 
 def paged_latent_mode(pool_operand, latent):
@@ -707,19 +716,34 @@ def paged_latent_mode(pool_operand, latent):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "latent", "scale", "pages", "interpret"))
+    "latent", "scale", "pages", "chunk", "interpret"))
 def _paged_latent_sweep(q, pool, block_tables, valid_len, *, latent,
-                        scale, pages, interpret):
-    """`_paged_sweep`'s schedule over ONE pool: grid (B,), a cell a
-    sequence, `pages` pages a step copied into one half of a VMEM
-    scratch while the arithmetic runs on the other, the last step of a
-    sequence starting the first of the next; online softmax in fp32
-    scratch. A step's rows (T, row) feed the MXU twice as stored:
-    scores q (H, row) x rows^T, then the probabilities x rows[:, :latent].
-    Against a bf16 pool the probabilities enter that product rounded
-    to bf16, ONE term and not `_paged_sweep`'s exact three: this sweep
-    is bound by the MXU and the vector unit, not by the copies alone,
-    and each term more cost the cell 6% of its tokens a second for
+                        scale, pages, chunk, interpret):
+    """Grid (B,), a cell a sequence; a step is `pages` pages of the ONE
+    pool in one half of a VMEM scratch while the copies of the step
+    after it in the global order (the sequence's next, or the next
+    sequence's first) land in the other. A step's rows feed the MXU
+    twice as stored: scores q (H, row) x rows^T, then the probabilities
+    x rows[:, :latent]; online softmax in fp32 scratch.
+
+    The schedule (PR 42; measured, docs/serving.md). A step's keys are
+    worked in sub-chunks of `chunk` keys, each one update of the carry
+    (m, l, acc), and both products of a sub-chunk in PIECES of a
+    128-lane tile of scores. After every piece go the next few of the
+    next step's page copies, each start under its own predicate (a page
+    that holds no token is not fetched): the copies' bookkeeping is
+    scalar work between the pieces of one basic block, not a loop in
+    front of the arithmetic, and the DMA queue is fed evenly. A
+    sub-chunk's pages signal a semaphore of their own and are waited
+    for by the bits of their count, a full sub-chunk in ONE wait (the
+    semaphore counts bytes), so the arithmetic starts when the first
+    sub-chunk has landed. Every step of a sequence, its last too, runs
+    the one body: whose copies it starts, how many pages it waits for
+    and where its mask falls are scalars.
+
+    Against a bf16 pool the probabilities enter the value product
+    rounded to bf16, ONE term and not `_paged_sweep`'s exact three:
+    each term more cost the cell 6% of its tokens a second for
     0.0002-0.0004 of mean logit gap (measured, docs/serving.md)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -728,6 +752,15 @@ def _paged_latent_sweep(q, pool, block_tables, valid_len, *, latent,
     bs = pool.shape[2]
     nb = block_tables.shape[1]
     P, T = pages, pages * bs
+    G = max(1, min(chunk // bs, P))                # pages a sub-chunk
+    groups = [(a, min(a + G, P)) for a in range(0, P, G)]
+    Q = max(1, min(128 // bs, G))                  # pages a piece
+    pieces = [list(range(a, z, Q)) for a, z in groups]
+    # a sub-chunk's pieces, scores then values, each followed by its
+    # share of the next step's P starts
+    first_call = [2 * sum(map(len, pieces[:g]))
+                  for g in range(len(groups) + 1)]
+    n_calls = first_call[-1]
     native = q.dtype == pool.dtype == jnp.bfloat16
     nt = (((1,), (1,)), ((), ()))                  # (h, r) x (t, r)
 
@@ -736,83 +769,135 @@ def _paged_latent_sweep(q, pool, block_tables, valid_len, *, latent,
         b = pl.program_id(0)
         vl = vl_ref[b]
 
-        def copy_step(row, i, slot, act):
-            """Start (or wait for) the pages of `row`'s step `i` that
-            hold a token, into (out of) half `slot`."""
-            def one(j, _):
-                page = bt_ref[row * nb + i * P + j]
-                act(pltpu.make_async_copy(
-                    hbm.at[page], buf.at[slot, j], sem.at[slot]))
-            held = (vl_ref[row] + bs - 1) // bs
-            jax.lax.fori_loop(0, jnp.minimum(P, held - i * P), one,
-                              None)
+        def held(row, i):
+            """Pages of `row`'s step `i` that hold a token."""
+            return jnp.minimum((vl_ref[row] + bs - 1) // bs - i * P, P)
 
-        def start(row, i, slot):
-            copy_step(row, i, slot, lambda c: c.start())
+        def wait_pages(slot, a, k):
+            """ONE wait for `k` pages of the sub-chunk at page `a` of
+            half `slot`: the semaphore counts bytes."""
+            pltpu.make_async_copy(
+                hbm.at[pl.ds(0, k)], buf.at[slot, pl.ds(a, k)],
+                sem.at[slot, a // G]).wait()
+
+        def starter(row, i, slot, count):
+            """-> feed(c): the c-th of a step's n_calls calls starts its
+            share of the pages of `row`'s step `i` into half `slot`,
+            those of them under `count`. (lax on int32 scalars: a jnp
+            operator a start is most of this body's tracing time.)"""
+            first = row * nb + i * P
+            last = jnp.int32(B * nb - 1)     # a short table's end
+            into, sems = buf.at[slot], sem.at[slot]
+
+            def feed(c):
+                for j in range(P * c // n_calls, P * (c + 1) // n_calls):
+                    at = jax.lax.min(jax.lax.add(first, jnp.int32(j)), last)
+
+                    @pl.when(jax.lax.gt(count, jnp.int32(j)))
+                    def _start(j=j, at=at):
+                        pltpu.make_async_copy(
+                            hbm.at[bt_ref[at]], into.at[j],
+                            sems.at[j // G]).start()
+            return feed
+
+        def mix(p, vals):
+            if native:
+                return jnp.dot(p.astype(jnp.bfloat16), vals,
+                               preferred_element_type=jnp.float32)
+            return jnp.dot(p, vals.astype(jnp.float32),
+                           preferred_element_type=jnp.float32)
+
+        def sub_chunk(slot, g, carry, left, feed):
+            """Sub-chunk `g` of half `slot` into the carry, the keys at
+            and past `left` masked. A step's first key is live, so m is
+            finite from its first sub-chunk on and exp(-inf - m) is 0:
+            no select but the mask's own."""
+            a, z = groups[g]
+            cuts, c0 = pieces[g], first_call[g]
+
+            def rows(k):
+                return buf[slot, k:min(k + Q, z), 0].reshape(-1, R)
+
+            def scores(k):
+                if native:
+                    return jax.lax.dot_general(
+                        q_ref[...], rows(k), nt,
+                        preferred_element_type=jnp.float32) * scale
+                return jax.lax.dot_general(
+                    q_ref[...].astype(jnp.float32) * scale,
+                    rows(k).astype(jnp.float32), nt,
+                    preferred_element_type=jnp.float32)
+
+            parts = []
+            for t, k in enumerate(cuts):
+                parts.append(scores(k))
+                feed(c0 + t)
+            s = jnp.concatenate(parts, axis=1)               # (H, chunk)
+            live = a * bs + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1) < left
+            s = jnp.where(live, s, -jnp.inf)
+            m, l, acc = carry
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+            l = corr * l + jnp.sum(p, axis=-1, keepdims=True)
+            acc = corr * acc
+            for t, k in enumerate(cuts):
+                vals = rows(k)[:, :latent]
+                at = (k - a) * bs
+                acc = acc + mix(p[:, at:at + vals.shape[0]], vals)
+                feed(c0 + len(cuts) + t)
+            return m_new, l, acc
 
         @pl.when(b == 0)
         def _first():
             # a row no copy ever wrote must be finite under its 0
             buf[...] = jnp.zeros_like(buf)
             slot_ref[0] = 0
-            start(0, 0, 0)
+            jax.lax.fori_loop(
+                0, held(0, 0), lambda j, _: pltpu.make_async_copy(
+                    hbm.at[bt_ref[j]], buf.at[0, j],
+                    sem.at[0, j // G]).start(), None)
 
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        # at least one step a sequence: the halves' hand-over below
-        # never skips a row (valid_len 0: all masked)
+        # at least one step a sequence: the halves' hand-over never
+        # skips a row
         n = jnp.maximum((vl + T - 1) // T, 1)
         slot0 = slot_ref[0]
+        nxt = jnp.minimum(b + 1, B - 1)
 
         def step(i, _):
+            """ONE body for every step: what differs (whose copies it
+            starts and how many, how many of its own pages it waits
+            for, where its mask falls) is scalars."""
             slot = jax.lax.rem(slot0 + i, 2)
-
-            @pl.when(i + 1 < n)
-            def _next():
-                start(b, i + 1, 1 - slot)
-
-            @pl.when(jnp.logical_and(i + 1 == n, b + 1 < B))
-            def _next_row():
-                start(b + 1, 0, 1 - slot)
-
-            copy_step(b, i, slot, lambda c: c.wait())
-            live = i * T + jax.lax.broadcasted_iota(
-                jnp.int32, (H, T), 1) < vl
-            rows = buf[slot, :, 0].reshape(T, R)
-            vals = rows[:, :latent]
-            if native:
-                s = jax.lax.dot_general(
-                    q_ref[...], rows, nt,
-                    preferred_element_type=jnp.float32) * scale
-            else:
-                s = jax.lax.dot_general(
-                    q_ref[...].astype(jnp.float32) * scale,
-                    rows.astype(jnp.float32), nt,
-                    preferred_element_type=jnp.float32)
-            s = jnp.where(live, s, -jnp.inf)                 # (H, T)
-            m_prev = m_ref[...]                              # (H, 1)
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(s, axis=-1, keepdims=True))
-            # `live`, a comparison: Mosaic has no is_finite lowering
-            p = jnp.where(live, jnp.exp(s - m_new), 0.0)
-            corr = jnp.where(m_prev > -jnp.inf,
-                             jnp.exp(m_prev - m_new), 0.0)
-            m_ref[...] = m_new
-            l_ref[...] = corr * l_ref[...] \
-                + jnp.sum(p, axis=-1, keepdims=True)
-            if native:
-                pv = jnp.dot(p.astype(jnp.bfloat16), vals,
-                             preferred_element_type=jnp.float32)
-            else:
-                pv = jnp.dot(p, vals.astype(jnp.float32),
-                             preferred_element_type=jnp.float32)
-            acc_ref[...] = corr * acc_ref[...] + pv
+            last = i + 1 == n
+            row, ahead = jnp.where(last, nxt, b), jnp.where(last, 0, i + 1)
+            feed = starter(row, ahead, 1 - slot, jnp.where(
+                jnp.logical_and(last, b + 1 == B), 0, held(row, ahead)))
+            # valid_len 0: one key of a stale row, and a 0 below
+            left = jnp.maximum(vl - i * T, 1)
+            carry = m_ref[...], l_ref[...], acc_ref[...]
+            for g, (a, z) in enumerate(groups):
+                # the sub-chunk's pages that were started, waited for
+                # by the bits of their count: a full one in ONE wait
+                here = jnp.clip(held(b, i) - a, 0, z - a)
+                k = 1 << ((z - a).bit_length() - 1)
+                while k:
+                    @pl.when((here & k) != 0)
+                    def _wait(a=a, k=k):
+                        wait_pages(slot, a, k)
+                    k >>= 1
+                carry = sub_chunk(slot, g, carry, left, feed)
+            m_ref[...], l_ref[...], acc_ref[...] = carry
 
         jax.lax.fori_loop(0, n, step, None)
         slot_ref[0] = jax.lax.rem(slot0 + n, 2)
         l = l_ref[...]
-        o_ref[...] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)) \
+        o_ref[...] = jnp.where(
+            vl > 0, acc_ref[...] / jnp.where(l > 0, l, 1.0), 0.0) \
             .astype(o_ref.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -824,12 +909,12 @@ def _paged_latent_sweep(q, pool, block_tables, valid_len, *, latent,
                                lambda b, bt, vl: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, P, 1, bs, R), pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),           # a half each
+            pltpu.SemaphoreType.DMA((2, len(groups))),  # a sub-chunk each
             pltpu.SMEM((1,), jnp.int32),             # half of step 0
             pltpu.VMEM((H, 1), jnp.float32),         # m
             pltpu.VMEM((H, 1), jnp.float32),         # l
             pltpu.VMEM((H, latent), jnp.float32)])   # acc
-    # the sequences hand the scratch's halves and the copy in flight
+    # the sequences hand the scratch's halves and the copies in flight
     # from one to the next: in order, on one core
     params = {} if interpret else {
         "compiler_params": pltpu.CompilerParams(
@@ -855,11 +940,11 @@ def flash_decode_paged_latent(q, pages, block_tables, valid_len, *,
     mode = paged_latent_mode(pages, latent) if use_flash else None
     if mode is not None:
         try:
+            step, chunk = _latent_sweep_sizes(pages.shape[2],
+                                              block_tables.shape[1])
             return _paged_latent_sweep(
                 q, pages, block_tables, valid_len, latent=int(latent),
-                scale=float(scale),
-                pages=_latent_sweep_pages(pages.shape[2],
-                                          block_tables.shape[1]),
+                scale=float(scale), pages=step, chunk=chunk,
                 interpret=mode == "interpret")
         except Exception as e:
             _paged_fallback.note(e)

@@ -44,8 +44,9 @@ DEFAULTS = {
                            "preferred_block_size": 16},
     # the latent (MLA) sweep: pages a step of ONE pool (a page is a
     # (bs, row) block: 20 KB at 16 x 640 bf16, two steps of 128 in
-    # VMEM are 5.2 MB; timed on a v5e, tuned.json's note)
-    "flash_decode_paged_latent": {"pages": 128},
+    # VMEM are 5.2 MB) and keys a sub-chunk, one update of the online
+    # softmax's carry (timed on a v5e, tuned.json's note)
+    "flash_decode_paged_latent": {"pages": 128, "chunk": 1024},
     # the expert layer's grouped matmul: a (block_k, block_n) tile of
     # an expert's matrix a step (1 MB in bf16, two of them when gate
     # and up share a pass), and how many tokens of a long prefill the

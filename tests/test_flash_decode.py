@@ -556,35 +556,62 @@ def test_flash_attention_at_a_group_of_twenty_on_one_kv_head(
 
 from mxnet_tpu.kernels import flash_decode as fd  # noqa: E402
 
-def _latent_pool(dtype, B=5, H=8, R=256, bs=8, nb=6, N=40, seed=0):
-    """Ragged lengths: an idle slot on the scratch block (length 1,
-    table all 0), a sequence shorter than one page, one that ends on a
-    page's edge, two ragged ones."""
+def _latent_pool(dtype, vl=None, H=8, R=256, bs=8, N=80, seed=0):
+    """Ragged lengths by default: an idle slot on the scratch block
+    (length 1, table all 0, as every length of 1 here), a sequence
+    shorter than one page, one that ends on a page's edge, two ragged
+    ones. The table is as wide as the longest needs."""
     rng = np.random.default_rng(seed)
+    vl = vl or (1, 5, 17, 48, 33)
+    B, nb = len(vl), max(6, -(-max(vl) // bs))
     pool = jnp.asarray(rng.normal(0, 1, (N, 1, bs, R)), dtype)
     q = jnp.asarray(rng.normal(0, 1, (B, H, R)), dtype)
-    vl = np.array([1, 5, 17, 48, 33], np.int32)
+    vl = np.array(vl, np.int32)
     bt = np.zeros((B, nb), np.int32)
     free = list(rng.permutation(np.arange(1, N)))
-    for b in range(1, B):
-        for j in range(-(-int(vl[b]) // bs)):
+    for b in range(B):
+        for j in range(-(-int(vl[b]) // bs) if vl[b] > 1 else 0):
             bt[b, j] = free.pop()
     return q, pool, jnp.asarray(bt), jnp.asarray(vl)
 
 
+# (pages a step, keys a sub-chunk, lengths): blocks of 8 positions
+_LATENT_CASES = [
+    # over the counts of pages a step, the step ONE sub-chunk: one (a
+    # step a block), a last step part full (sequences of 1 to 6
+    # blocks), the table's own 6 and more than it holds
+    *[(pages, None, None) for pages in (1, 2, 3, 4, 6, 8)],
+    # steps of 32 in sub-chunks of 16: a length that ends on a
+    # sub-chunk's edge, on a step's edge, one position into a new
+    # sub-chunk, two whole steps, one position into a new step, idle
+    (4, 16, (16, 32, 17, 64, 33, 1)),
+    # the halves' hand-over under the single wait: a many-step sequence,
+    # then a one-step one, an idle slot, and many steps again
+    (4, 16, (90, 5, 1, 70, 96)),
+    # sub-chunks that do not divide the step: 4 + 2 pages, 2 + 1,
+    # 3 + 3 + 2
+    (6, 32, (32, 48, 33, 49, 96, 1)),
+    (3, 16, (16, 24, 17, 25, 72)),
+    (8, 24, (24, 48, 25, 64, 65, 90)),
+    # a page a sub-chunk, and one step a sequence in 8 + 4 pages
+    (2, 8, (8, 16, 9, 90, 3, 1)),
+    (12, 64, (64, 96, 65, 1, 95)),
+]
+
+
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
                                        ("bfloat16", 2e-2)])
-@pytest.mark.parametrize("pages", [1, 2, 3, 4, 6, 8])
+@pytest.mark.parametrize("pages,chunk,vl", _LATENT_CASES)
 def test_flash_decode_paged_latent_matches_its_twin(dtype, tol, pages,
+                                                    chunk, vl,
                                                     monkeypatch):
-    """Over the counts of pages a step: one (a step a block), a last
-    step part full (sequences of 1 to 6 blocks), the table's own 6 and
-    more than it holds (one step a sequence)."""
     from mxnet_tpu.kernels import tuning
 
     monkeypatch.setenv("MXNET_TPU_FLASH_INTERPRET", "1")
-    q, pool, bt, vl = _latent_pool(jnp.dtype(dtype))
+    q, pool, bt, vl = _latent_pool(jnp.dtype(dtype), vl)
     tuning.set_runtime("flash_decode_paged_latent", "pages", pages)
+    if chunk is not None:
+        tuning.set_runtime("flash_decode_paged_latent", "chunk", chunk)
     before = fd._paged_fallback.count
     try:
         assert fd.paged_latent_mode(pool, 128) == "interpret"
@@ -593,7 +620,7 @@ def test_flash_decode_paged_latent_matches_its_twin(dtype, tol, pages,
     finally:
         tuning.clear_runtime()
     want = fd.reference_paged_latent_attention(q, pool, bt, vl, 128, 0.2)
-    assert got.shape == (5, 8, 128) and got.dtype == q.dtype
+    assert got.shape == (len(vl), 8, 128) and got.dtype == q.dtype
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=tol)
     assert fd._paged_fallback.count == before  # kernel path, no note()

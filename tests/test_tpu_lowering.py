@@ -6,6 +6,7 @@ are the proof that the 'compiled' kernel paths are actually viable on
 hardware, which interpret-mode tests cannot give (the interpreter
 ignores tiling constraints; round 2 shipped kernels that passed
 interpret tests but could never have compiled on-chip)."""
+import functools
 import re
 import types
 
@@ -135,19 +136,26 @@ def _latent_args(B=64, nb=1664, N=57501, sharding=None):
             sds((B,), jnp.int32))
 
 
-def _latent(q, pool, bt, vl):
+def _latent(q, pool, bt, vl, pages=128, chunk=1024):
     from mxnet_tpu.kernels.flash_decode import _paged_latent_sweep
     return _paged_latent_sweep(q, pool, bt, vl, latent=512, scale=0.1352,
-                               pages=128, interpret=False)
+                               pages=pages, chunk=chunk, interpret=False)
 
 
 def test_flash_decode_paged_latent_lowers_at_the_cells_shapes():
     _lowers(_latent, *_latent_args())
 
 
+def _custom_calls(text):
+    """Names of the Mosaic custom calls of a compiled program."""
+    return re.findall(
+        r"%(\S+) = [^\n]*custom-call\([^\n]*tpu_custom_call", text)
+
+
 def test_flash_decode_paged_latent_compiles_for_v5e(one_chip, monkeypatch):
     """Through the dispatch, so the gate's answer for this pool is part
-    of what is held: compiled, never the gathered twin. A pool of rows
+    of what is held: compiled, never the gathered twin, and ONE kernel
+    under the name the benchmark's readers look for. A pool of rows
     576 wide is what the gate turns away (Mosaic slices the values out
     of the row at a 128-lane tile's edge)."""
     from mxnet_tpu.kernels import flash_decode as fd
@@ -160,8 +168,22 @@ def test_flash_decode_paged_latent_compiles_for_v5e(one_chip, monkeypatch):
         (9, 1, 16, 576), jnp.bfloat16, sharding=one_chip), 512) is None
     text = jax.jit(lambda *a: fd.flash_decode_paged_latent(
         *a, latent=512, scale=0.1352)).lower(*args).compile().as_text()
-    assert "flash_decode_paged_latent" in text
+    calls = _custom_calls(text)
+    assert len(calls) == 1 and calls[0].startswith(
+        "flash_decode_paged_latent"), calls
     assert "gather" not in text
+
+
+@pytest.mark.parametrize("pages,chunk", [
+    (128, 512), (128, 2048), (64, 512), (192, 1536), (256, 2048)])
+def test_the_latent_sweeps_swept_sizes_fit_the_chip(pages, chunk,
+                                                    one_chip):
+    """Every (pages a step, keys a sub-chunk) `tuned.json` may take (its
+    note's sweep; 128 / 1,024, the values taken, compile above): the
+    two halves of the scratch and the unrolled step fit the VMEM."""
+    text = jax.jit(functools.partial(_latent, pages=pages, chunk=chunk)) \
+        .lower(*_latent_args(sharding=one_chip)).compile().as_text()
+    assert len(_custom_calls(text)) == 1
 
 
 # -- the afmoe cell's kernels at its shapes (trinity_large.longctx:
