@@ -310,6 +310,7 @@ def phase_kernels(size):
                                    flash_decode as fd, fused_ce as ce,
                                    fused_norm as fnorm,
                                    grouped_matmul as _gmm,  # noqa: F401
+                                   power_retention as pret,
                                    selective_scan as ssm)
     from mxnet_tpu.parallel import moe
 
@@ -612,6 +613,39 @@ def phase_kernels(size):
         lambda *a: ssm.ssm_state_update_ref(*a, live),
         (hr, xs[0, :B], dts[0, :B], a_log, bs_[0, :B], cs[0, :B]),
         ("ssm_state_update",), (TOL_SCAN, TOL_SCAN))
+
+    # the power-retention kernels (models/brumby.py's layer, 5 query
+    # heads a kv head): the chunked form over a prompt of three chunks
+    # with a ragged tail (zero keys and a gate of 1 on the padding),
+    # q and k normed as the model's, gates of a 64-8,192 horizon; then
+    # one decode step of every row from that state, a row in four idle
+    def unit(x):
+        return (x / jnp.sqrt(jnp.mean(jnp.square(x), -1, keepdims=True))
+                ).astype(dt)
+
+    Tr, Kr = 3 * 256 - 40, 2
+    on = (jnp.arange(Tr) < Tr - 9)[None, :, None]
+    qr, kr = unit(randn((1, Tr, 5 * Kr, d), f32)), \
+        unit(randn((1, Tr, Kr, d), f32)) * on[..., None].astype(dt)
+    vr = randn((1, Tr, Kr, d))
+    horizon = jnp.exp(jax.random.uniform(next(key), (1, 1, Kr), f32,
+                                         math.log(64), math.log(8192)))
+    lgr = jnp.where(on, jnp.log1p(-1.0 / horizon), 0.0)
+    run("power_retention chunked prompt", pret.power_retention_chunked,
+        lambda *a: pret.power_retention_chunked_ref(*a),
+        (qr, kr, vr, lgr), ("power_retention_chunked",),
+        (TOL_ATTN, TOL_ATTN, TOL_ATTN))
+    st = pret.power_retention_chunked_ref(qr, kr, vr, lgr)[1]
+    Sr, zr = (jnp.broadcast_to(st[n_], (B,) + st[n_].shape[1:])
+              for n_ in ("S", "z"))
+    run("power_retention step decode rows",
+        lambda S_, z_, *a: pret.power_retention_step(S_, z_, *a, live),
+        lambda S_, z_, *a: pret.power_retention_step_ref(S_, z_, *a,
+                                                         live),
+        (Sr, zr, unit(randn((B, 5 * Kr, d), f32)),
+         unit(randn((B, Kr, d), f32)), randn((B, Kr, d)),
+         jnp.broadcast_to(lgr[0, 0], (B, Kr))),
+        ("power_retention_step",), (TOL_SCAN, TOL_SCAN, TOL_ATTN))
 
     # decode and prefill attention at 20 query heads on ONE kv head: a
     # group that is no multiple of 8 sublanes, pages of 4 KB

@@ -61,6 +61,12 @@ DEFAULTS = {
     # 8 rows of 16 x 5120 fp32). Hand-chosen.
     "selective_scan": {"time_chunk": 256},
     "ssm_state_update": {"rows": 8},
+    # the power-retention prompt form: positions a grid step works (its
+    # five (heads x chunk, 128) float32 scratches are 6.5 MB at 512
+    # beside the head's 4.3 MB state). Swept on a v5e (tuned.json's
+    # note; the decode step has no constant: a grid step moves a kv
+    # head's whole state, every smaller block was slower there)
+    "power_retention_chunked": {"chunk": 512},
 }
 
 _cache: Optional[dict] = None

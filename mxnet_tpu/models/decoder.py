@@ -9,7 +9,10 @@ nothing a token), the parameter tree, and the layer in the forms the
 programs use: an attention layer whole (prefill) and split around the
 paged attention call (decode); a recurrent layer whole, handing back
 the state at each row's length (prefill), and for one token of every
-row, taking and returning the rows' states (decode). A LATENT layer
+row, taking and returning the rows' states (decode), both with the
+positions (a state-space layer ignores them, a retention layer rotates
+its queries and keys by them). A decoder whose layers are ALL recurrent
+is served with no block pool and no table. A LATENT layer
 caches every position like a FULL one, but ONE row a position that all
 its heads share and read twice, as keys whole and as values by its
 first `latent` entries (`latent_shapes`): its `prefill_layer` and
@@ -17,7 +20,9 @@ first `latent` entries (`latent_shapes`): its `prefill_layer` and
 enter the paged call as wide as the row. The Llama block is the first
 description (`llama_infer.LlamaDecoder`), afmoe the second
 (`afmoe.AfmoeDecoder`), Jamba the third (`jamba.JambaDecoder`), Sarvam's
-latent attention the fourth (`sarvam.SarvamDecoder`).
+latent attention the fourth (`sarvam.SarvamDecoder`), power retention
+the fifth (`brumby.BrumbyDecoder`: every layer RECURRENT, a
+matrix-valued state).
 """
 from __future__ import annotations
 
@@ -98,17 +103,17 @@ class DecoderDescription:
         state: what the cache keeps a slot a layer."""
         raise NotImplementedError
 
-    def prefill_recurrent(self, li, lp, x, lengths):
-        """The whole layer on (B, T, D) from a zero state -> (x, state,
-        counts | None): `state` {name: (B,) + shape} as it stands after
-        each row's `lengths` positions (right padding must not advance
-        it)."""
+    def prefill_recurrent(self, li, lp, x, positions, lengths):
+        """The whole layer on (B, T, D) at `positions` (T,) from a zero
+        state -> (x, state, counts | None): `state` {name: (B,) + shape}
+        as it stands after each row's `lengths` positions (right padding
+        must not advance it)."""
         raise NotImplementedError
 
-    def decode_recurrent(self, li, lp, x, state, active):
-        """One token of every row, x (B, 1, D), `state` the rows'
-        states -> (x, state, counts | None). A row whose `active` is
-        False hands its state back untouched."""
+    def decode_recurrent(self, li, lp, x, positions, state, active):
+        """One token of every row, x (B, 1, D) at `positions` (B,),
+        `state` the rows' states -> (x, state, counts | None). A row
+        whose `active` is False hands its state back untouched."""
         raise NotImplementedError
 
     # -- a LATENT layer's cache ----------------------------------------------

@@ -182,10 +182,11 @@ class JambaDecoder(DecoderDescription):
         zero = jamba_math.zero_state(self.cfg, 1)
         return {k: (v.shape[1:], v.dtype) for k, v in zero.items()}
 
-    def prefill_recurrent(self, li, lp, x, lengths):
+    # a state-space layer carries the order itself: no positions
+    def prefill_recurrent(self, li, lp, x, positions, lengths):
         return jamba_math.mamba_layer(lp, x, self.cfg, lengths) + (None,)
 
-    def decode_recurrent(self, li, lp, x, state, active):
+    def decode_recurrent(self, li, lp, x, positions, state, active):
         return jamba_math.mamba_layer_step(lp, x, self.cfg, state,
                                            active) + (None,)
 

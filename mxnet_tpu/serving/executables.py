@@ -258,7 +258,11 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
         more, `slot` (1,) int32: such a layer's entry of `pages` is a
         state pool {name: (batch_slots, ...)} and the prefill writes
         row `slot` of it whole, with the state as it stands after
-        `valid_len` positions.
+        `valid_len` positions; the layer is handed the positions as an
+        attention layer is (a retention layer rotates by them, a
+        state-space layer ignores them). A net ALL of whose layers are
+        recurrent has no block pool: `bt_row` here and `block_tables`
+        in `decode` are `()`.
 
     copy_block(pages, src, dst) -> pages: device-side block copy for
         prefix-cache copy-on-write (src/dst traced scalars, so every
@@ -417,8 +421,8 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
         counts = None
         for li, (lp, pg) in enumerate(zip(params["layers"], pages)):
             if dec.layer_kinds[li] == RECURRENT:
-                x, state, c = dec.prefill_recurrent(li, lp, x,
-                                                    valid_len)
+                x, state, c = dec.prefill_recurrent(
+                    li, lp, x, positions, valid_len)
                 counts = add_counts(counts, c)
                 new_pages.append(write_state(pg, state, slot))
                 continue
@@ -450,7 +454,8 @@ def paged_programs(net, *, batch_slots: int, max_blocks_per_seq: int,
         counts = None
         for li, (lp, pg) in enumerate(zip(params["layers"], pages)):
             if dec.layer_kinds[li] == RECURRENT:
-                x, npg, c = dec.decode_recurrent(li, lp, x, pg, active)
+                x, npg, c = dec.decode_recurrent(
+                    li, lp, x, pos, pg, active)
                 counts = add_counts(counts, c)
                 new_pages.append(npg)
                 continue

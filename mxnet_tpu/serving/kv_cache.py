@@ -68,6 +68,15 @@ over. A row is in use exactly while its slot owns blocks
 A state cannot be shared by prefix nor rewound by a token, and the
 server refuses those features for such a net.
 
+A cache ALL of whose layers are recurrent (`paged` False) holds the
+state pool and nothing else: no block pool on the device, not even the
+scratch block (nothing is written a token, so nothing needs a sink), no
+table, an empty free list. A sequence costs one row of the pool and
+nothing a token: `alloc` takes the slot's row whatever the length,
+`can_alloc` and `ensure` always hold, `blocks_for` is 0, so admission
+is by free slot alone, nothing is ever preempted for room, and the
+server does no block work a tick. `num_blocks` is ignored.
+
 Latent layers (layer_kinds with "latent" entries): such a layer caches
 every position, like a FULL one and under the same allocator, table
 and `check()`, but ONE row a position that all its heads share and
@@ -107,11 +116,16 @@ class PagedKVCache:
                  layer_kinds=None, window: Optional[int] = None,
                  window_num_blocks: Optional[int] = None,
                  state_shapes=None):
-        if num_blocks < 2:
-            raise ValueError("num_blocks must be >= 2 (block 0 is the "
-                             "reserved scratch block)")
         kinds = tuple(layer_kinds) if layer_kinds is not None \
             else ("full",) * num_layers
+        #: False where every layer is recurrent: the state pool alone,
+        #: no block pool, no table, nothing to allocate a token
+        self.paged = any(kind != "recurrent" for kind in kinds)
+        if not self.paged:
+            num_blocks, max_blocks_per_seq = 1, 0
+        elif num_blocks < 2:
+            raise ValueError("num_blocks must be >= 2 (block 0 is the "
+                             "reserved scratch block)")
         if len(kinds) != num_layers:
             raise ValueError(f"layer_kinds names {len(kinds)} layers, "
                              f"num_layers={num_layers}")
@@ -211,6 +225,9 @@ class PagedKVCache:
         self._slot_blocks: List[List[int]] = [[] for _ in
                                               range(batch_slots)]
         self._slot_len = np.zeros(batch_slots, np.int64)
+        #: slots a sequence holds (with their blocks, where there are
+        #: blocks; the one book an all-recurrent cache keeps)
+        self._slot_held = np.zeros(batch_slots, bool)
         self.alloc_count = 0
         self.free_count = 0
         # the sliding layers' pool: its own free list and table; a
@@ -281,12 +298,13 @@ class PagedKVCache:
     @property
     def state_slots_used(self) -> int:
         """Rows of the state pool a sequence holds: a slot's row goes
-        with its blocks, taken by `alloc` and given back by
-        `free_slot`, so there is no second book to keep."""
-        return sum(bool(b) for b in self._slot_blocks) \
-            if self.state_shapes else 0
+        with the slot, taken by `alloc` and given back by
+        `free_slot`."""
+        return int(self._slot_held.sum()) if self.state_shapes else 0
 
     def blocks_for(self, num_tokens: int) -> int:
+        if not self.paged:
+            return 0
         return max(1, math.ceil(num_tokens / self.block_size))
 
     def _window_span(self, num_tokens: int):
@@ -425,7 +443,7 @@ class PagedKVCache:
         """Allocate blocks for a fresh sequence of `num_tokens` in
         `slot`. Returns False (and allocates nothing) if the pool
         cannot cover it; the slot must be empty."""
-        if self._slot_blocks[slot]:
+        if self._slot_held[slot]:
             raise ValueError(f"slot {slot} already holds "
                              f"{len(self._slot_blocks[slot])} blocks")
         need = self.blocks_for(num_tokens)
@@ -439,6 +457,7 @@ class PagedKVCache:
         self._slot_blocks[slot] = blocks
         self.block_tables[slot, :need] = blocks
         self._slot_len[slot] = num_tokens
+        self._slot_held[slot] = True
         self.alloc_count += need
         if self.window is not None:
             first, count = self._window_span(num_tokens)
@@ -478,6 +497,8 @@ class PagedKVCache:
         slot's next write position). Allocates at most one block.
         Returns False if the pool is exhausted — the scheduler then
         preempts another sequence and retries."""
+        if not self.paged:
+            return True
         if self.window is not None \
                 and not self._window_ensure(slot, pos):
             return False
@@ -496,6 +517,7 @@ class PagedKVCache:
         self._slot_blocks[slot].append(blk)
         self.block_tables[slot, held] = blk
         self._slot_len[slot] = pos + 1
+        self._slot_held[slot] = True
         self.alloc_count += 1
         return True
 
@@ -554,6 +576,7 @@ class PagedKVCache:
         self._slot_blocks[slot] = []
         self.block_tables[slot, :] = 0
         self._slot_len[slot] = 0
+        self._slot_held[slot] = False
         if self.window is not None:
             self._wfree.extend(reversed(self._wslot_blocks[slot]))
             self._wslot_blocks[slot] = []
@@ -622,7 +645,7 @@ class PagedKVCache:
         prompt ENDS inside a shared block (T == shared_len), the block
         is adopted as-is and the first decode write triggers
         copy-on-write via prepare_write()."""
-        if self._slot_blocks[slot]:
+        if self._slot_held[slot]:
             raise ValueError(f"slot {slot} already holds "
                              f"{len(self._slot_blocks[slot])} blocks")
         T = len(tokens)
@@ -673,6 +696,7 @@ class PagedKVCache:
         self._slot_blocks[slot] = blocks
         self.block_tables[slot, :len(blocks)] = blocks
         self._slot_len[slot] = T
+        self._slot_held[slot] = True
         self.alloc_count += need
         if shared_len:
             self.prefix_hits += 1
@@ -762,6 +786,14 @@ class PagedKVCache:
         ownership exactly, scratch never handed out or shared,
         conservation of blocks, content index consistent."""
         owned = [b for blks in self._slot_blocks for b in blks]
+        assert all(bool(b) <= bool(h) for b, h
+                   in zip(self._slot_blocks, self._slot_held)), \
+            "blocks on a slot no sequence holds"
+        assert self.paged or (
+            self.num_blocks == 1 and not owned and not self._free
+            and self.block_tables.size == 0
+            and not any("k" in pg for pg in self.pages or ())), \
+            "an all-recurrent cache holds a block pool or a table"
         assert 0 not in owned, "scratch block allocated"
         assert 0 not in self._free, "scratch block in free list"
         counts: dict = {}

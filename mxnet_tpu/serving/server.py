@@ -446,10 +446,12 @@ class InferenceServer:
         from ..kernels.flash_decode import (paged_kernel_mode,
                                             paged_gather_bytes)
         q8 = kv_cache_dtype == "int8"
-        pool_k = next(pg["k"] for pg in self.cache.pages if "k" in pg)
-        self._kernel_paged = paged_kernel_mode(pool_k,
-                                               quantized=q8) is not None
-        self._gather_bytes_per_tick = sum(
+        # (a cache with no block pool has no paged call to probe)
+        pool_k = next((pg["k"] for pg in self.cache.pages if "k" in pg),
+                      None)
+        self._kernel_paged = pool_k is not None and paged_kernel_mode(
+            pool_k, quantized=q8) is not None
+        self._gather_bytes_per_tick = 0 if pool_k is None else sum(
             "k" in pg for pg in self.cache.pages) * paged_gather_bytes(
             pool_k.shape, (batch_slots, max_blocks),
             pool_k.dtype.itemsize, quantized=q8)
@@ -555,9 +557,12 @@ class InferenceServer:
 
     def _tables(self, slot=None):
         """The block table(s) as the executables take them: the one
-        array, or with two kinds of layer the pair (full, sliding);
-        `slot` picks that sequence's row."""
+        array, or with two kinds of layer the pair (full, sliding), or
+        nothing at all for a cache with no block pool; `slot` picks
+        that sequence's row."""
         c = self.cache
+        if not c.paged:
+            return ()
         tabs = (c.block_tables,) if c.window_tables is None \
             else (c.block_tables, c.window_tables)
         out = tuple(_upload(t if slot is None else t[slot])
@@ -1406,7 +1411,8 @@ class InferenceServer:
             return None
         drafts = dlens = None
         with telemetry.span("serve_blocks"):
-            self._ensure_blocks(send)
+            if self.cache.paged:    # no pool: a token costs no block
+                self._ensure_blocks(send)
             if self._spec is not None:
                 drafts, dlens = self._propose_drafts()
         send &= self._active        # less the slots a preemption emptied
@@ -1563,7 +1569,8 @@ class InferenceServer:
         self.ticks += 1
         self.tokens_generated += net_new
         self._tok_window.append((now, net_new))
-        self._forecaster.add(now, self.cache.num_free_blocks)
+        if self.cache.paged:
+            self._forecaster.add(now, self.cache.num_free_blocks)
         if self.tier is not None \
                 and self.tier.spill_exhaust_s is not None:
             # the forecaster's exhaust signal is the spill TRIGGER:

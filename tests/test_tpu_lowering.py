@@ -323,6 +323,75 @@ def test_jamba_kernels_compile_for_v5e(what, one_chip):
         assert not re.search(r"f32\[256,16,40,128\]\S* copy\(", text)
 
 
+# -- the Brumby cell's kernels at its shapes (brumby_14b.longctx20: 20
+# slots, 40 query heads on 8 kv heads of 128, a state of 65 offsets x
+# 128 x 128 float32 a kv head, prompts padded to 12,288) -------------------
+
+def _retention_step(S, z, q, k, v, g, live):
+    from mxnet_tpu.kernels.power_retention import EPS, _step
+    return _step(S, z, q, k, v, g, live, eps=EPS, interpret=False)
+
+
+def _retention_chunked(q, k, v, g, chunk=256):
+    from mxnet_tpu.kernels.power_retention import \
+        EPS, power_retention_chunked_fwd
+    return power_retention_chunked_fwd(q, k, v, g, chunk=chunk, eps=EPS,
+                                       interpret=False)
+
+
+def _retention_args(what, sharding=None, rows=20):
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=sharding)
+    bf = jnp.bfloat16
+    if what == "step":
+        return (sds((rows, 8, 65, 128, 128)), sds((rows, 8, 72, 128)),
+                sds((rows, 40, 128), bf), sds((rows, 8, 128), bf),
+                sds((rows, 8, 128), bf), sds((rows, 8)),
+                sds((rows,), jnp.bool_))
+    return (sds((1, 12288, 40, 128), bf), sds((1, 12288, 8, 128), bf),
+            sds((1, 12288, 8, 128), bf), sds((1, 12288, 8)))
+
+
+_RETENTION_KERNELS = {
+    "step": (_retention_step, "power_retention_step"),
+    "chunked": (_retention_chunked, "power_retention_chunked")}
+
+
+@pytest.mark.parametrize("what", list(_RETENTION_KERNELS))
+def test_retention_kernels_lower_at_the_cells_shapes(what):
+    assert _lowers(_RETENTION_KERNELS[what][0],
+                   *_retention_args(what)) == 1
+
+
+@pytest.mark.parametrize("what,size", [
+    ("step", 20), ("step", 16), ("step", 1),
+    ("chunked", 128), ("chunked", 256), ("chunked", 512)])
+def test_retention_kernels_compile_for_v5e(what, size, one_chip):
+    """Mosaic's VMEM limit and tiling at the real sizes: a kv head's
+    whole state (4.3 MB) in and out a step with the pool aliased, at
+    the cell's 20 rows, at 16 and at 1; a head's state resident across
+    96 / 48 / 24 chunks of a padded prompt beside five (heads x chunk,
+    128) scratches, at every value of the swept chunk."""
+    fn, name = _RETENTION_KERNELS[what]
+    if what == "step":
+        args = _retention_args(what, one_chip, rows=size)
+    else:
+        fn, args = functools.partial(fn, chunk=size), \
+            _retention_args(what, one_chip)
+    compiled = jax.jit(
+        fn, donate_argnums=(0, 1) if what == "step" else ()).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(rf"%{name}[\w.]* = [^\n]* custom-call\(",
+                          text)) == 1
+    if what == "step":
+        # the pool is updated in place: no copy of it, no second one
+        state = size * 8 * (65 * 128 * 128 + 72 * 128) * 4
+        assert compiled.memory_analysis().alias_size_in_bytes == state
+        assert not re.search(
+            rf"f32\[{size},8,(65,128,128|72,128)\]\S* copy\(", text)
+
+
 def test_the_sweeps_vmem_reckoning_takes_the_real_group():
     from mxnet_tpu.kernels.flash_decode import _paged_sweep_pages
     mistral, trinity, jamba = (5633, 8, 16, 128), (32001, 8, 16, 128), \
@@ -602,6 +671,10 @@ def _compiled_serving_program(what, one_chip, monkeypatch):
                              vocab_size=32768, held_experts=(0, 16),
                              max_seq_len=26624)
         slots, max_len, max_prompt, blocks = 64, 26624, 14336, [57501] * 2
+    elif cell == "brumby_14b.longctx20":    # two retention layers, no pool
+        net = _described_net("brumby", one_chip, num_layers=2,
+                             max_seq_len=20480)
+        slots, max_len, max_prompt, blocks = 20, 20480, 12288, [0, 0]
     elif cell == "jamba2_3b.reason256":     # one Mamba layer, one attention
         net = _described_net("jamba", one_chip, num_layers=2,
                              attn_layer_period=2, attn_layer_offset=1)
@@ -634,9 +707,12 @@ def _compiled_serving_program(what, one_chip, monkeypatch):
     table, row = sds((slots, nb), "int32"), sds((nb,), "int32")
     if dec.mixed:
         table, row = (table, table), (row, row)
+    if not any(blocks):             # no pool: no table goes in
+        table = row = ()
     if program == "prefill":
         args = (params, pages, row, sds((1, max_prompt), "int32"),
-                sds((1,), "int32"), sds((1,), "int32"))
+                sds((1,), "int32"), sds((1,), "int32")) \
+            + ((sds((1,), "int32"),) if dec.recurrent else ())
     else:
         args = (params, pages, table, sds((slots,), "int32"),
                 sds((slots, cfg.vocab_size), cfg.dtype),
@@ -679,6 +755,31 @@ def test_the_paged_row_write_re_lays_no_pool_out(what, one_chip,
     assert re.search(rf"%{kernel}[\w.]* = [^\n]* custom-call\(", text), \
         f"no Mosaic call {kernel} in the compiled {program}"
     # in place: every pool comes back in the buffer it came in
+    assert text.count("may-alias") + text.count("must-alias") >= n_pools
+
+
+@pytest.mark.parametrize("program,kernel", [
+    ("decode", "power_retention_step"),
+    ("prefill", "power_retention_chunked")])
+def test_the_retention_programs_hold_the_state_pool_in_place(
+        program, kernel, one_chip, monkeypatch):
+    """The whole `decode` / `prefill` of a net with no attention layer
+    at the cell's sizes: one Mosaic call a layer, no block pool and no
+    table among the operands, and no `copy` whose result has the shape
+    of the state pool (PR 38's lesson: a pool re-laid out round its
+    kernel costs more than the kernel). In the tick every pool comes
+    back in the buffer it came in."""
+    text, pools, n_pools = _compiled_serving_program(
+        f"brumby_14b.longctx20 {program}", one_chip, monkeypatch)
+    assert pools == set() and n_pools == 4          # S and z, two layers
+    assert len(re.findall(rf"%{kernel}[\w.]* = [^\n]* custom-call\(",
+                          text)) == 2
+    assert "flash_decode_paged" not in text
+    copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+    assert copied, "the pattern finds no copy at all in this program"
+    whole = [c for c in copied
+             if c in ("20,8,65,128,128", "20,8,72,128")]
+    assert not whole, f"{len(whole)} whole-pool copies"
     assert text.count("may-alias") + text.count("must-alias") >= n_pools
 
 
