@@ -507,6 +507,23 @@ def phase_kernels(size):
                     jnp.asarray(vls)),
         ("flash_decode_paged",), (TOL_DECODE,))
 
+    # ... and with idle rows, the first, every third and the last, their
+    # table all sink: of length 1 (what the server hands the kernel for
+    # an idle slot: one token of the sink page, a successor that skips
+    # the branch round the lead's shares) and of length 0 (fetches no
+    # page, hands the scratch's halves on and writes a finite 0)
+    idle = np.arange(Bs) % 3 == 0
+    idle[-1] = True
+    short = np.where(np.arange(Bs) % 2 == 0, 1, 0)
+    run("flash_decode paged, serving table, empty rows",
+        fd.flash_decode_paged,
+        lambda q, kp, vp, bt, n: jnp.where(
+            (n > 0)[:, None, None], paged_ref(q, kp, vp, bt, n), 0),
+        (randn((Bs, H, d)), randn((Ns, K, bs, d)), randn((Ns, K, bs, d)),
+         jnp.asarray(np.where(idle[:, None], 0, bts)),
+         jnp.asarray(np.where(idle, short, vls))),
+        ("flash_decode_paged",), (TOL_DECODE,))
+
     pools8 = [to_pool(c) for c in (k8, ks, v8, vs)]
     run("flash_decode paged int8", fd.flash_decode_paged_quantized,
         lambda q, k8, ks, v8, vs, bt, n: fd.reference_decode_attention(

@@ -291,6 +291,27 @@ def _flash_decode_paged_pallas(q, k_pages, v_pages, block_tables,
     stepped; the ragged tail of the last step is masked (its v rows
     are what an earlier step left there: finite, under an exact 0).
 
+    The schedule of the copies (PR 46; measured, docs/serving.md). A
+    step starts the 2 * P copies of the step after it in the global
+    order (this sequence's next, or the next sequence's first) as
+    straight-line code, each page under a predicate of its own, dealt
+    out in 2 * K shares (_dealt_starts): half of them in front of the
+    step's waits (the half they fill was freed by the step before, and
+    the DMA engine else stands still while the step waits), the others
+    between the score and value products of the kv heads; the shares
+    in front past the first sit behind one branch, which a successor
+    that fits the first share skips (an idle slot's row of one token:
+    nothing co-issues with a start's scalar work in front of the
+    waits). A share of
+    more than _UNROLLED_SHARE pages (one kv head) goes in a loop. The
+    step waits for its own pages by the bits of their
+    count (_wait_by_bits), k and v each on the semaphore of their half:
+    a full step in a wait a set bit of P, not one a page. Every step of
+    a sequence, plain or windowed, runs the ONE body: whose copies it
+    starts and how many, how many pages it waits for and where its
+    mask falls are scalars. A row of valid_len 0 takes one step,
+    fetches nothing and writes a finite 0.
+
     The online softmax (m, l, acc per kv head and query row) lives in
     VMEM scratch across the steps, fp32 throughout. bf16 q, k and v
     feed the MXU as stored (bf16 x bf16 accumulated in fp32 is exact)
@@ -312,14 +333,69 @@ def _flash_decode_paged_pallas(q, k_pages, v_pages, block_tables,
                         window=None if window is None else int(window))
 
 
+#: pages of a share that _dealt_starts writes out under predicates; a
+#: larger share goes in a loop. Measured on a v5e (PR 46, PERF.md
+#: section 6): shares of 3 pages (8 kv heads, 48 pages a step) gain by
+#: being written out, one of 124 (Jamba's one kv head, 248 a step)
+#: takes 113 ns a page where the loop takes 67: every start's scalar
+#: work runs whether its predicate holds or not.
+_UNROLLED_SHARE = 8
+
+
+def _dealt_starts(pl, pages, calls, first, end, count, start):
+    """-> feed(c): the c-th of a step's `calls` calls starts its share
+    of the step's `pages` page copies, `start(j, at)` for page j whose
+    table entry is `at` = first + j, those of them under `count`. Each
+    start is straight-line code under a predicate of its own (a page
+    that holds no token is not fetched; the compiler if-converts it),
+    so dealt out between the pieces of a step's arithmetic the copies'
+    bookkeeping is scalar work inside one basic block and the DMA queue
+    is fed evenly. `at` is clamped to `end`, the table's last entry: a
+    start whose predicate is false still reads its index. (lax on int32
+    scalars: a jnp operator a start is most of the body's tracing
+    time.) A share of more than _UNROLLED_SHARE pages is a loop as
+    long as its pages under `count`."""
+    end = jnp.int32(end)
+
+    def feed(c):
+        lo, hi = pages * c // calls, pages * (c + 1) // calls
+        if hi - lo > _UNROLLED_SHARE:
+            def one(j, _):
+                start(j, jax.lax.min(first + j, end))
+            jax.lax.fori_loop(lo, jnp.clip(count, lo, hi), one, None)
+            return
+        for j in range(lo, hi):
+            at = jax.lax.min(jax.lax.add(first, jnp.int32(j)), end)
+
+            @pl.when(jax.lax.gt(count, jnp.int32(j)))
+            def _start(j=j, at=at):
+                start(j, at)
+    return feed
+
+
+def _wait_by_bits(pl, count, most, wait):
+    """Wait for `count` (0 .. `most`) pages that signal one semaphore:
+    `wait(k)` waits for k pages' bytes (the semaphore counts bytes, so
+    one descriptor of k pages does), under a predicate for every bit of
+    the count: a full step in a wait a set bit of `most`, none in a
+    loop."""
+    k = 1 << (most.bit_length() - 1)
+    while k:
+        @pl.when((count & k) != 0)
+        def _wait(k=k):
+            wait(k)
+        k >>= 1
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "pages",
                                              "interpret", "window"))
 def _paged_sweep(q, k_pages, v_pages, block_tables, valid_len, *,
                  scale, pages, interpret, window=None):
     """_flash_decode_paged_pallas's kernel at `pages` a step. A jit of
     its own, so that the layers of a decode program share one trace
-    and one Mosaic lowering of the body (its kv heads are unrolled:
-    traced a layer it cost a 16-layer program 2 s of every start)."""
+    and one Mosaic lowering of the body (its kv heads and its page
+    starts are unrolled: traced a layer it cost a 16-layer program 2 s
+    of every start)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -343,60 +419,90 @@ def _paged_sweep(q, k_pages, v_pages, block_tables, valid_len, *,
                 return 0
             return jnp.maximum(vl_ref[row] - window, 0) // bs
 
-        def copy_step(row, i, slot, act):
-            """Start (or wait for) the pages of `row`'s step `i` that
-            hold a token, into (out of) half `slot`."""
-            def one(j, _):
-                page = bt_ref[row * nb + first(row) + i * P + j]
-                act(pltpu.make_async_copy(
-                    k_hbm.at[page], kbuf.at[slot, j], sem.at[0, slot]))
-                act(pltpu.make_async_copy(
-                    v_hbm.at[page], vbuf.at[slot, j], sem.at[1, slot]))
-            held = (vl_ref[row] + bs - 1) // bs - first(row)
-            jax.lax.fori_loop(0, jnp.minimum(P, held - i * P), one,
-                              None)
+        def held(row, i):
+            """Pages of `row`'s step `i` that hold a token (none: <= 0)."""
+            return jnp.minimum(
+                (vl_ref[row] + bs - 1) // bs - first(row) - i * P, P)
 
-        def start(row, i, slot):
-            copy_step(row, i, slot, lambda c: c.start())
+        def start_page(page, slot, j):
+            """k and v of pool page `page` into place j of half `slot`."""
+            pltpu.make_async_copy(k_hbm.at[page], kbuf.at[slot, j],
+                                  sem.at[0, slot]).start()
+            pltpu.make_async_copy(v_hbm.at[page], vbuf.at[slot, j],
+                                  sem.at[1, slot]).start()
+
+        def starter(row, i, slot, count):
+            """-> feed(c): the c-th of a step's 2 * K calls starts its
+            share of the pages of `row`'s step `i` into half `slot`,
+            those of them under `count`."""
+            return _dealt_starts(
+                pl, P, 2 * K, row * nb + first(row) + i * P,
+                B * nb - 1, count,
+                lambda j, at: start_page(bt_ref[at], slot, j))
 
         @pl.when(b == 0)
         def _first():
             # a v row no copy ever wrote must be finite under its 0
             vbuf[...] = jnp.zeros_like(vbuf)
             slot_ref[0] = 0
-            start(0, 0, 0)
+            # the one step no arithmetic hides: a loop will do
+            jax.lax.fori_loop(
+                0, held(0, 0), lambda j, _: start_page(
+                    bt_ref[first(0) + j], 0, j), None)
 
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         # a sequence takes at least one step, so that the hand-over of
         # the halves below never skips a row (valid_len 0: all masked)
-        if window is None:
-            n = jnp.maximum((vl + T - 1) // T, 1)
-        else:
-            n = jnp.maximum(
-                ((vl + bs - 1) // bs - first(b) + P - 1) // P, 1)
+        n = jnp.maximum(
+            ((vl + bs - 1) // bs - first(b) + P - 1) // P, 1)
         slot0 = slot_ref[0]
+        nxt = jnp.minimum(b + 1, B - 1)
 
         def step(i, _):
+            """ONE body for every step, plain or windowed: whose copies
+            it starts and how many, how many of its own pages it waits
+            for and where its mask falls are scalars."""
             slot = jax.lax.rem(slot0 + i, 2)
+            last = i + 1 == n
+            row, ahead = jnp.where(last, nxt, b), jnp.where(last, 0, i + 1)
+            count = jnp.where(jnp.logical_and(last, b + 1 == B), 0,
+                              held(row, ahead))
+            feed = starter(row, ahead, 1 - slot, count)
 
-            @pl.when(i + 1 < n)
-            def _next():
-                start(b, i + 1, 1 - slot)
+            def wait(k):
+                for hbm, buf, done in ((k_hbm, kbuf, sem.at[0, slot]),
+                                       (v_hbm, vbuf, sem.at[1, slot])):
+                    pltpu.make_async_copy(
+                        hbm.at[pl.ds(0, k)], buf.at[slot, pl.ds(0, k)],
+                        done).wait()
+            # half of the shares (two at least) go in front of the
+            # waits: the half they fill was freed by the step before,
+            # and the DMA engine then never runs dry while this step
+            # waits; the others after every other piece (measured,
+            # docs/serving.md: all in front is the parent's order).
+            # In front of the waits nothing co-issues with a start's
+            # scalar work, so the shares there past the first sit
+            # behind ONE branch: a successor that fits the first share
+            # (an idle slot's row of one token) skips them
+            calls = 2 * K
+            lead = max(calls // 2, 2)
+            after = [[] for _ in range(calls)]
+            for c in range(lead, calls):
+                after[(c - lead) * calls // (calls - lead)].append(c)
+            feed(0)
 
-            @pl.when(jnp.logical_and(i + 1 == n, b + 1 < B))
-            def _next_row():
-                start(b + 1, 0, 1 - slot)
-
-            copy_step(b, i, slot, lambda c: c.wait())
-            if window is None:
-                live = i * T + jax.lax.broadcasted_iota(
-                    jnp.int32, (rep, T), 1) < vl
-            else:
-                at = first(b) * bs + i * T + jax.lax.broadcasted_iota(
-                    jnp.int32, (rep, T), 1)
-                live = jnp.logical_and(at < vl, at >= vl - window)
+            @pl.when(count > P // calls)
+            def _rest_of_the_lead():
+                for c in range(1, lead):
+                    feed(c)
+            _wait_by_bits(pl, jnp.maximum(held(b, i), 0), P, wait)
+            at = first(b) * bs + i * T + jax.lax.broadcasted_iota(
+                jnp.int32, (rep, T), 1)
+            live = at < vl
+            if window is not None:
+                live = jnp.logical_and(live, at >= vl - window)
             for h in range(K):
                 qh = q_ref[h]                                # (rep, d)
                 kh = kbuf[slot, :, h].reshape(T, d)
@@ -410,6 +516,8 @@ def _paged_sweep(q, k_pages, v_pages, block_tables, valid_len, *,
                         qh.astype(jnp.float32) * scale,
                         kh.astype(jnp.float32), nt,
                         preferred_element_type=jnp.float32)
+                for c in after[2 * h]:
+                    feed(c)
                 s = jnp.where(live, s, -jnp.inf)             # (rep, T)
                 m_prev = m_ref[h]                            # (rep, 1)
                 m_new = jnp.maximum(
@@ -436,6 +544,8 @@ def _paged_sweep(q, k_pages, v_pages, block_tables, valid_len, *,
                     pv = jnp.dot(p, vh.astype(jnp.float32),
                                  preferred_element_type=jnp.float32)
                 acc_ref[h] = corr * acc_ref[h] + pv
+                for c in after[2 * h + 1]:
+                    feed(c)
 
         jax.lax.fori_loop(0, n, step, None)
         slot_ref[0] = jax.lax.rem(slot0 + n, 2)
@@ -783,22 +893,14 @@ def _paged_latent_sweep(q, pool, block_tables, valid_len, *, latent,
         def starter(row, i, slot, count):
             """-> feed(c): the c-th of a step's n_calls calls starts its
             share of the pages of `row`'s step `i` into half `slot`,
-            those of them under `count`. (lax on int32 scalars: a jnp
-            operator a start is most of this body's tracing time.)"""
-            first = row * nb + i * P
-            last = jnp.int32(B * nb - 1)     # a short table's end
+            those of them under `count`."""
             into, sems = buf.at[slot], sem.at[slot]
 
-            def feed(c):
-                for j in range(P * c // n_calls, P * (c + 1) // n_calls):
-                    at = jax.lax.min(jax.lax.add(first, jnp.int32(j)), last)
-
-                    @pl.when(jax.lax.gt(count, jnp.int32(j)))
-                    def _start(j=j, at=at):
-                        pltpu.make_async_copy(
-                            hbm.at[bt_ref[at]], into.at[j],
-                            sems.at[j // G]).start()
-            return feed
+            def start(j, at):
+                pltpu.make_async_copy(hbm.at[bt_ref[at]], into.at[j],
+                                      sems.at[j // G]).start()
+            return _dealt_starts(pl, P, n_calls, row * nb + i * P,
+                                 B * nb - 1, count, start)
 
         def mix(p, vals):
             if native:
@@ -875,21 +977,17 @@ def _paged_latent_sweep(q, pool, block_tables, valid_len, *, latent,
             slot = jax.lax.rem(slot0 + i, 2)
             last = i + 1 == n
             row, ahead = jnp.where(last, nxt, b), jnp.where(last, 0, i + 1)
-            feed = starter(row, ahead, 1 - slot, jnp.where(
-                jnp.logical_and(last, b + 1 == B), 0, held(row, ahead)))
+            count = jnp.where(jnp.logical_and(last, b + 1 == B), 0,
+                              held(row, ahead))
+            feed = starter(row, ahead, 1 - slot, count)
             # valid_len 0: one key of a stale row, and a 0 below
             left = jnp.maximum(vl - i * T, 1)
             carry = m_ref[...], l_ref[...], acc_ref[...]
             for g, (a, z) in enumerate(groups):
                 # the sub-chunk's pages that were started, waited for
                 # by the bits of their count: a full one in ONE wait
-                here = jnp.clip(held(b, i) - a, 0, z - a)
-                k = 1 << ((z - a).bit_length() - 1)
-                while k:
-                    @pl.when((here & k) != 0)
-                    def _wait(a=a, k=k):
-                        wait_pages(slot, a, k)
-                    k >>= 1
+                _wait_by_bits(pl, jnp.clip(held(b, i) - a, 0, z - a), z - a,
+                              functools.partial(wait_pages, slot, a))
                 carry = sub_chunk(slot, g, carry, left, feed)
             m_ref[...], l_ref[...], acc_ref[...] = carry
 

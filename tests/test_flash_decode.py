@@ -321,6 +321,73 @@ def test_paged_sweep_bf16_splits_probabilities_exactly(pages_per_step):
                                np.asarray(ref), rtol=8e-3, atol=8e-3)
 
 
+# the schedule of PR 46 (starts dealt between the heads' products, each
+# under its predicate; waits by the bits of the count; ONE step body,
+# plain or windowed), against the reference on the GATHERED view:
+# (P, data kwargs, valid lengths, window). S=128 at bs=8 is nb=16 pages,
+# a step of P=3 pages is 24 tokens.
+_DEALT_CASES = {
+    "all_rows_empty": (3, {}, [0, 0, 0, 0, 0], None),
+    "len_0_1_step_step_plus_1": (3, {}, [0, 1, 24, 25, 48], None),
+    "ragged_last_page": (3, {}, [21, 77, 128, 3, 127], None),
+    # the halves are handed over rows that take one step and fetch
+    # nothing
+    "empty_first_middle_last": (3, {}, [0, 61, 0, 47, 0], None),
+    "empty_between_long_rows": (3, {}, [128, 0, 0, 128, 1], None),
+    # nb = 2 < P = 4: a start past the table's end reads a clamped index
+    "table_shorter_than_a_step": (4, {"S": 16}, [16, 0, 9, 1, 16], None),
+    "one_step_holds_the_table": (16, {}, [128, 0, 77, 1, 24], None),
+    # an idle slot is a row of ONE token: a successor that fits the
+    # first share skips the branch round the lead's other shares
+    "idle_rows_of_one_token": (8, {}, [1, 128, 1, 1, 90], None),
+    "idle_rows_of_one_token_window": (8, {}, [1, 128, 1, 1, 90], 30),
+    # first(row) > 0; 21 and 43 end inside a page, 40 on a page's edge
+    "window_ends_inside_a_page": (3, {}, [100, 128, 5, 61, 0], 21),
+    "window_on_a_page_edge": (3, {}, [100, 128, 5, 61, 0], 40),
+    "window_over_two_steps": (3, {}, [128, 0, 44, 90, 43], 43),
+    "window_short_table": (4, {"S": 16}, [16, 0, 9, 1, 13], 5),
+    "rep_20_two_bf16_tiles": (3, {"H": 20, "K": 1}, [0, 128, 25, 1, 90],
+                              None),
+    "rep_20_window": (3, {"H": 20, "K": 1}, [0, 128, 25, 1, 90], 30),
+    # one kv head: two shares of 12 pages a step, each in a loop
+    "large_shares_loop": (24, {"S": 256, "H": 4, "K": 1},
+                          [256, 0, 77, 1, 200], None),
+    "large_shares_loop_window": (24, {"S": 256, "H": 4, "K": 1},
+                                 [256, 0, 77, 1, 200], 50),
+}
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5),
+                                       ("bfloat16", 8e-3)])
+@pytest.mark.parametrize("case", sorted(_DEALT_CASES))
+def test_paged_sweep_dealt_starts_and_bit_waits(case, dtype, tol):
+    # bf16 at the tolerance of the exact three-term value product (the
+    # test above), against fp32 arithmetic on the bf16 operands
+    from mxnet_tpu.kernels.flash_decode import (_paged_sweep,
+                                                gather_kv_pages)
+    P, kw, vls, window = _DEALT_CASES[case]
+    q, _, _, kp, vp, bt, vl = _paged_data(seed=29, vl=vls,
+                                          **{"B": 5, **kw})
+    bs = kp.shape[2]
+    cut = np.array(bt)
+    if window is not None:      # as the cache leaves a sliding layer's
+        for b, n in enumerate(vls):
+            cut[b, :max(n - window, 0) // bs] = 0
+    dt = jnp.dtype(dtype)
+    q, kp, vp = (x.astype(dt) for x in (q, kp, vp))
+    out = _paged_sweep(q, kp, vp, jnp.asarray(cut), vl, scale=0.25,
+                       pages=P, interpret=True, window=window)
+    assert out.dtype == dt
+    out = np.asarray(out, np.float32)
+    f32 = jnp.float32
+    ref = np.asarray(reference_decode_attention(
+        q.astype(f32), gather_kv_pages(kp, bt).astype(f32),
+        gather_kv_pages(vp, bt).astype(f32), vl, 0.25, window))
+    held = np.asarray(vls) > 0
+    np.testing.assert_allclose(out[held], ref[held], rtol=tol, atol=tol)
+    assert (out[~held] == 0).all()       # a finite 0, not 0 / 0
+
+
 class _Pool:
     """A pool's static face: all the step chooser and the gate read."""
 

@@ -758,6 +758,25 @@ def test_the_paged_row_write_re_lays_no_pool_out(what, one_chip,
     assert text.count("may-alias") + text.count("must-alias") >= n_pools
 
 
+@pytest.mark.parametrize("what,calls", [
+    ("mistral_7b.reason decode", 2),        # two full layers: plain
+    ("trinity_large.longctx decode", 2),    # one sliding (window), one full
+    ("jamba2_3b.reason256 decode", 1)])     # one attention layer of two
+def test_the_dealt_sweep_is_one_call_a_layer_round_no_pool_copy(
+        what, calls, one_chip, monkeypatch):
+    """PR 46's schedule (a step's starts unrolled under predicates, one
+    step body) still compiles, in the cells' real decode programs, to
+    ONE `flash_decode_paged` Mosaic call an attention layer, plain and
+    windowed, and XLA lays no pool out anew to feed it (PR 38)."""
+    text, pools, _ = _compiled_serving_program(what, one_chip,
+                                               monkeypatch)
+    found = re.findall(
+        r"%flash_decode_paged(?:\.\d+)? = [^\n]* custom-call\(", text)
+    assert len(found) == calls, found
+    copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+    assert not [c for c in copied if c in pools]
+
+
 @pytest.mark.parametrize("program,kernel", [
     ("decode", "power_retention_step"),
     ("prefill", "power_retention_chunked")])
