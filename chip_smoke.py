@@ -316,7 +316,7 @@ def phase_kernels(size):
 
     fallbacks0 = dispatch.fallback_counts()
     dt = jnp.dtype(size.dtype)
-    key = iter(jax.random.split(jax.random.PRNGKey(1), 64))
+    key = iter(jax.random.split(jax.random.PRNGKey(1), 96))
 
     def randn(shape, dtype=dt, scale=1.0):
         return (jax.random.normal(next(key), shape, jnp.float32)
@@ -623,13 +623,29 @@ def phase_kernels(size):
     run("selective_scan prompt", ssm.selective_scan,
         ssm.selective_scan_ref, (xs, dts, a_log, bs_, cs, h0),
         ("selective_scan_fwd",), (TOL_SCAN, TOL_SCAN))
+    # one decode step of every row, a row in four idle: the whole of a
+    # recurrent layer between in_proj and out_proj in one call (the
+    # convolution, x_proj, Jamba's three norms, dt_proj, softplus, the
+    # recurrence, the gate), both pools in place, against its jnp twin,
+    # which rounds x_proj's and dt_proj's products, B and C to bf16
+    # where the call keeps float32: g and h' to the matmuls' tolerance,
+    # the tail to the bit
+    Kc, Rk = 4, Dn // 32
+    lw = {"conv_w": randn((Kc, Dn), f32, 0.5), "conv_b": randn((Dn,), f32, 0.1),
+          "x_proj": randn((Rk + 2 * Ns_, Dn), scale=Dn ** -0.5),
+          "dt_norm": randn((Rk,), scale=0.1) + 1,
+          "b_norm": randn((Ns_,), scale=0.1) + 1,
+          "c_norm": randn((Ns_,), scale=0.1) + 1,
+          "dt_proj": randn((Dn, Rk), scale=Rk ** -0.5),
+          "dt_bias": randn((Dn,), f32) - 3.0, "A_log": a_log,
+          "D": randn((Dn,), f32)}
     hr = randn((B,) + ssm.state_shape(Ns_, Dn), f32)
     live = jnp.arange(B) % 4 != 1
     run("ssm_state_update decode rows",
-        lambda *a: ssm.ssm_state_update(*a, live),
-        lambda *a: ssm.ssm_state_update_ref(*a, live),
-        (hr, xs[0, :B], dts[0, :B], a_log, bs_[0, :B], cs[0, :B]),
-        ("ssm_state_update",), (TOL_SCAN, TOL_SCAN))
+        lambda h, t, xz: ssm.ssm_state_update(h, t, xz, live, lw, 1e-6),
+        lambda h, t, xz: ssm.ssm_state_update_ref(h, t, xz, live, lw, 1e-6),
+        (hr, randn((B,) + ssm.tail_shape(Kc, Dn)), randn((B, 2 * Dn))),
+        ("ssm_state_update",), (TOL_ATTN, TOL_ATTN, 0.0))
 
     # the power-retention kernels (models/brumby.py's layer, 5 query
     # heads a kv head): the chunked form over a prompt of three chunks

@@ -22,11 +22,27 @@ on full vregs. `(T, d_inner, N)` is never formed, in VMEM or in HBM.
   caller zeroes `dt` on right padding, and the final state is the one
   at `valid_len`. VPU / EUP bound (N exp and ~6 N vector operations a
   channel a position); HBM needs a twentieth of its time.
-- `ssm_state_update` (decode): one call a layer for every row of the
-  tick. The state pool goes in and comes out ALIASED
-  (`input_output_aliases`), a block of rows a grid step; a row whose
-  `active` is 0 is written back as it was. HBM bound: the state is
-  read and written once, 2 x N x d_inner x 4 bytes a row.
+- `ssm_state_update` (decode): ONE call a layer for every row of the
+  tick, and everything of the layer that lies between its two big
+  matmuls (`in_proj` before, `out_proj` after). In: the state pool
+  `(R,) + state_shape` float32 and the tail pool `(R,) + tail_shape`
+  in the model's dtype, both ALIASED in and out
+  (`input_output_aliases`); `xz` `(R, 2 Dn)` as `in_proj` leaves it,
+  its x and z halves taken by two `BlockSpec`s over the one array; a
+  layer's small weights (`STEP_WEIGHTS`, 3.6 MB bf16 + 0.5 MB float32
+  at Jamba2-3B's sizes) whole and resident; `active` in SMEM. Out: `g`
+  `(R, Dn)` in the model's dtype, ready for `out_proj`. A block of
+  rows a grid step: the convolution's taps and silu, x_proj and
+  dt_proj on the MXU over the block's rows, Jamba's three norms, bias
+  and softplus run with the ROWS on the sublanes, as `xz`, the tail
+  and `g` lie in HBM; dt and dt * x are then re-laid to a row's
+  `(Dn / 128, 128)` tile through a VMEM scratch (a strided store of
+  every 128-channel chunk), B and C go to SMEM by a DMA, and the
+  recurrence runs a row at a time as it always did; y comes back the
+  same way for the gate. A row whose `active` is 0 gets state and tail
+  written back as they were. HBM bound: the state is read and written
+  once, 2 x N x Dn x 4 bytes a row, beside `xz`, both tails and `g`
+  (18 Dn bytes in bf16); x, dt and y never leave VMEM.
 
 There is no backward: nothing trains through these kernels.
 Each has a jnp twin (`*_ref`) that is the CPU path and the tests'
@@ -36,6 +52,7 @@ Pallas interpreter.
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -45,7 +62,8 @@ from . import tuning
 from .dispatch import KernelFallback, operand_on_cpu
 
 __all__ = ["selective_scan", "selective_scan_ref", "ssm_state_update",
-           "ssm_state_update_ref", "state_shape"]
+           "ssm_state_update_ref", "ssm_inputs", "gate", "state_shape",
+           "tail_shape", "STEP_WEIGHTS"]
 
 _scan_fallback = KernelFallback("selective-scan",
                                 strict_envs=("MXNET_TPU_STRICT_SCAN",))
@@ -73,6 +91,16 @@ def state_shape(n, dn):
         raise ValueError(f"d_inner {dn} is no whole number of "
                          f"{_LANES}-lane rows")
     return (n, dn // _LANES, _LANES)
+
+
+def tail_shape(k, dn):
+    """One sequence's convolution tail, the last d_conv - 1 inputs, as
+    the step holds it and a pool stores it: `((d_conv - 1) * d_inner,)`,
+    tap after tap along the lanes, so that a block of rows is the rows
+    of `xz` beside it. (A pool kept `(d_conv - 1, d_inner)` a row is
+    tiled over its 3 x 5,120 minor dimensions and is re-laid out on its
+    way into every call.)"""
+    return ((k - 1) * dn,)
 
 
 def _channel_rows(dn):
@@ -107,19 +135,57 @@ def selective_scan_ref(x, dt, a_log, b, c, h0):
     return jnp.moveaxis(y, 0, 1), h.reshape(shape)
 
 
-def ssm_state_update_ref(h, x, dt, a_log, b, c, active):
-    """h (R,) + state_shape f32; x, dt (R, Dn) f32; b, c (R, N);
-    active (R,) bool. Returns (h', y (R, Dn)); an inactive row keeps
-    its state."""
-    a = -jnp.exp(a_log.astype(jnp.float32))
-    shape = h.shape
-    h = h.reshape(shape[0], shape[1], -1)
-    new = jnp.exp(dt[:, None, :] * a) * h \
-        + (dt * x)[:, None, :] * b[:, :, None]
-    y = jnp.sum(new * c[:, :, None], axis=1)
+STEP_WEIGHTS = ("conv_w", "conv_b", "x_proj", "dt_norm", "b_norm", "c_norm",
+                "dt_proj", "dt_bias", "A_log", "D")
+
+
+def ssm_inputs(w, xc, eps):
+    """dt (float32, after softplus), B, C of the recurrence from the
+    convolved input xc (..., Dn): x_proj, Jamba's three norms (on dt, B
+    and C), dt_proj."""
+    from .fused_norm import fused_rmsnorm as rms
+
+    R, N = w["dt_proj"].shape[1], w["b_norm"].shape[0]
+    p = xc @ w["x_proj"].T
+    dt_r = rms(p[..., :R], w["dt_norm"], eps)
+    b = rms(p[..., R:R + N], w["b_norm"], eps)
+    c = rms(p[..., R + N:], w["c_norm"], eps)
+    dt = jax.nn.softplus((dt_r @ w["dt_proj"].T).astype(jnp.float32)
+                         + w["dt_bias"])
+    return dt, b, c
+
+
+def gate(w, y, xc, z):
+    """(y + D x) * silu(z), the product in float32, in z's dtype."""
+    g = (y + w["D"] * xc.astype(jnp.float32)) \
+        * jax.nn.silu(z.astype(jnp.float32))
+    return g.astype(z.dtype)
+
+
+def ssm_state_update_ref(h, tail, xz, active, w, eps):
+    """One token of every row between in_proj and out_proj: h (R,) +
+    state_shape f32; tail (R,) + tail_shape and xz (R, 2 Dn) in the
+    model's dtype; active (R,) bool; `w` a layer's STEP_WEIGHTS.
+    Returns (g (R, Dn), h', tail'); an inactive row keeps its state and
+    its tail."""
+    f32 = jnp.float32
+    R, Dn = xz.shape[0], xz.shape[-1] // 2
+    xr, z = xz[:, :Dn], xz[:, Dn:]
+    win = jnp.concatenate([tail.reshape(R, -1, Dn).astype(xr.dtype),
+                           xr[:, None]], axis=1)
+    acc = jnp.sum(win.astype(f32) * w["conv_w"], axis=1) + w["conv_b"]
+    xc = jax.nn.silu(acc).astype(xz.dtype)
+    dt, b, c = ssm_inputs(w, xc, eps)
+    a = -jnp.exp(w["A_log"].astype(f32))
+    hf = h.reshape(R, h.shape[1], -1).astype(f32)
+    new = jnp.exp(dt[:, None, :] * a) * hf \
+        + (dt * xc.astype(f32))[:, None, :] * b.astype(f32)[:, :, None]
+    y = jnp.sum(new * c.astype(f32)[:, :, None], axis=1)
     keep = active[:, None, None]
-    return jnp.where(keep, new, h).reshape(shape), \
-        jnp.where(active[:, None], y, 0.0)
+    return gate(w, jnp.where(active[:, None], y, 0.0), xc, z), \
+        jnp.where(keep, new, hf).reshape(h.shape).astype(h.dtype), \
+        jnp.where(active[:, None],
+                  win[:, 1:].reshape(R, -1).astype(tail.dtype), tail)
 
 
 # -- the scan over a prompt ----------------------------------------------------
@@ -218,95 +284,194 @@ def selective_scan(x, dt, a_log, b, c, h0, use_kernel=True):
 # -- one step for every row of a decode tick -----------------------------------
 
 def _rows_per_step(R, want):
-    """The largest divisor of R up to `want`: the state pool is updated
-    in place, so its rows cannot be padded to a block."""
+    """The largest divisor of R up to `want`: the pools are updated in
+    place, so their rows cannot be padded to a block."""
     return max(r for r in range(1, min(R, want) + 1) if R % r == 0)
 
 
-@functools.partial(jax.jit, static_argnames=("rows_per_step",
+@functools.partial(jax.jit, static_argnames=("eps", "rows_per_step",
                                              "interpret"))
-def _state_update(h, x, dt, a_log, b, c, active, *, rows_per_step,
+def _state_update(h, tail, xz, active, w, *, eps, rows_per_step,
                   interpret):
+    """The Pallas step: `w` the STEP_WEIGHTS of one layer. A jit of
+    its own, so the layers of a decode program share one trace and one
+    Mosaic lowering of the body."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    h4 = h
+    f32 = jnp.float32
     R, N, rows, _ = h.shape
     Dn = rows * _LANES
+    K = w["conv_w"].shape[0]
+    Rk = w["dt_proj"].shape[1]
     Rb = rows_per_step
-    x3 = x.reshape(R, rows, _LANES)
-    dt3 = dt.reshape(R, rows, _LANES)
-    a3 = a_log.astype(jnp.float32).reshape(N, rows, _LANES)
+    dtype = xz.dtype
+    G = 16 if Rb % 16 == 0 else 8 if Rb % 8 == 0 else Rb   # rows a group
+    Cw = next(c for c in (1024, 640, 512, 384, 256, 128) if Dn % c == 0)
+    nt = (((1,), (1,)), ((), ()))           # a @ b.T
+    act = active.astype(jnp.int32)
+    row = lambda v, dt: v.astype(dt).reshape(1, -1)  # noqa: E731
 
-    def kernel(h_ref, x_ref, dt_ref, a_ref, b_ref, c_ref, act_ref,
-               ho_ref, y_ref, a_scr):
-        a_scr[...] = -jnp.exp(a_ref[...])
+    def kernel(h_ref, tail_ref, xr_ref, z_ref, act_ref, live_ref, cw_ref,
+               cb_ref, xp_ref, dtn_ref, bn_ref, cn_ref, dtp_ref, dtb_ref,
+               a_ref, d_ref, ho_ref, tailo_ref, g_ref, a_scr, dt_scr,
+               dtx_scr, y_scr, xc_scr, b_vm, c_vm, b_sm, c_sm, sem):
         base = pl.program_id(0) * Rb
+        lane = lambda c, n=Cw: slice(c, c + n)  # noqa: E731
 
-        def row(r, _):
+        # (1) the convolution's K taps and silu, rows on the sublanes;
+        # the tail moves up by one tap where the row is live
+        def conv(gi, _):
+            sl = pl.ds(pl.multiple_of(gi * G, G), G)
+            live = live_ref[sl, :] != 0                      # (G, 1)
+            for c in range(0, Dn, Cw):
+                taps = [tail_ref[sl, lane(j * Dn + c)].astype(f32)
+                        for j in range(K - 1)]
+                taps.append(xr_ref[sl, lane(c)].astype(f32))
+                acc = cb_ref[:, lane(c)]
+                for j in range(K):
+                    acc = acc + taps[j] * cw_ref[j:j + 1, lane(c)]
+                xc_scr[sl, lane(c)] = jax.nn.silu(acc).astype(dtype)
+                for j in range(K - 1):
+                    tailo_ref[sl, lane(j * Dn + c)] = jnp.where(
+                        live, taps[j + 1], taps[j]).astype(dtype)
+
+        jax.lax.fori_loop(0, Rb // G, conv, None)
+
+        # (2) x_proj on the MXU over the block's rows, Jamba's three
+        # norms; B and C go to SMEM, where the step splats them from
+        xc = xc_scr[...]
+        p = jax.lax.dot_general(xc, xp_ref[...], nt,
+                                preferred_element_type=f32)
+
+        def norm(v, g_ref):
+            ms = jnp.mean(v * v, axis=-1, keepdims=True)
+            return v * jax.lax.rsqrt(ms + eps) * g_ref[...].astype(f32)
+
+        dt_r = norm(p[:, :Rk], dtn_ref).astype(dtype)
+        b_vm[...] = norm(p[:, Rk:Rk + N], bn_ref)
+        c_vm[...] = norm(p[:, Rk + N:], cn_ref)
+        to_smem = [pltpu.make_async_copy(b_vm, b_sm, sem.at[0]),
+                   pltpu.make_async_copy(c_vm, c_sm, sem.at[1])]
+        for cp in to_smem:
+            cp.start()
+
+        # (3) dt_proj, bias, softplus; dt and dt * x re-laid from rows
+        # on the sublanes to a row's (rows, 128) tile, where the state
+        # lives: a chunk of 128 channels of every row lands `rows`
+        # sublanes apart
+        for c in range(0, Dn, Cw):
+            dt = jax.nn.softplus(
+                jnp.dot(dt_r, dtp_ref[:, lane(c)],
+                        preferred_element_type=f32)
+                + dtb_ref[:, lane(c)])
+            dtx = dt * xc_scr[:, lane(c)].astype(f32)
+            for j in range(c // _LANES, (c + Cw) // _LANES):
+                at = lane(j * _LANES - c, _LANES)
+                dt_scr[pl.ds(j, Rb, stride=rows), :] = dt[:, at]
+                dtx_scr[pl.ds(j, Rb, stride=rows), :] = dtx[:, at]
+        a = -jnp.exp(a_ref[...])                             # (N, Dn)
+        for j in range(rows):
+            a_scr[pl.ds(j, N, stride=rows), :] = a[:, lane(j * _LANES,
+                                                           _LANES)]
+        for cp in to_smem:
+            cp.wait()
+
+        # (4) the recurrence, a row at a time on its own tile
+        def step(r, _):
             live = act_ref[base + r] != 0
+            at = pl.ds(pl.multiple_of(r * rows, math.gcd(rows, 8)), rows)
 
             @pl.when(live)
             def _step():
-                dt_r = dt_ref[r]                         # (rows, 128)
-                dtx = dt_r * x_ref[r]
+                dt_r = dt_scr[at, :]                         # (rows, 128)
+                dtx = dtx_scr[at, :]
                 y = jnp.zeros_like(dt_r)
                 for n in range(N):
-                    hn = jnp.exp(dt_r * a_scr[n]) * h_ref[r, n] \
-                        + dtx * b_ref[base + r, n]
-                    y = y + hn * c_ref[base + r, n]
+                    hn = jnp.exp(dt_r * a_scr[n * rows:(n + 1) * rows]) \
+                        * h_ref[r, n] + dtx * b_sm[r, n]
+                    y = y + hn * c_sm[r, n]
                     ho_ref[r, n] = hn
-                y_ref[r] = y
+                y_scr[at, :] = y
 
             @pl.when(jnp.logical_not(live))
             def _keep():
                 ho_ref[r] = h_ref[r]
-                y_ref[r] = jnp.zeros_like(y_ref[r])
+                y_scr[at, :] = jnp.zeros((rows, _LANES), f32)
 
-        jax.lax.fori_loop(0, Rb, row, None)
+        jax.lax.fori_loop(0, Rb, step, None)
+
+        # (5) the gate, y back on rows-on-sublanes
+        for j in range(rows):
+            at = lane(j * _LANES, _LANES)
+            y = y_scr[pl.ds(j, Rb, stride=rows), :]
+            g = (y + d_ref[:, at] * xc_scr[:, at].astype(f32)) \
+                * jax.nn.silu(z_ref[:, at].astype(f32))
+            g_ref[:, at] = g.astype(dtype)
 
     state = pl.BlockSpec((Rb, N, rows, _LANES), lambda i: (i, 0, 0, 0))
-    vec = pl.BlockSpec((Rb, rows, _LANES), lambda i: (i, 0, 0))
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    taps = pl.BlockSpec((Rb, (K - 1) * Dn), lambda i: (i, 0))
+    half = lambda k: pl.BlockSpec((Rb, Dn), lambda i: (i, k))  # noqa: E731
+    whole = lambda v: pl.BlockSpec(v.shape, lambda i: (0,) * v.ndim)  # noqa: E731
+    # dt_proj goes in as (dt_rank, Dn): on the chip a (Dn, dt_rank)
+    # array lies with Dn minor (XLA's layout where the last dimension
+    # is no multiple of 128), so the transpose is a bitcast and the
+    # array as it is would be copied row-major for every call
+    weights = [w["conv_w"].astype(f32), row(w["conv_b"], f32),
+               w["x_proj"].astype(dtype), row(w["dt_norm"], dtype),
+               row(w["b_norm"], dtype), row(w["c_norm"], dtype),
+               w["dt_proj"].T.astype(dtype), row(w["dt_bias"], f32),
+               w["A_log"].astype(f32), row(w["D"], f32)]
+    item = jnp.dtype(dtype).itemsize
+    blocks = Rb * (2 * N * Dn * 4 + (2 * (K - 1) + 3) * Dn * item)
+    held = sum(v.size * v.dtype.itemsize for v in weights)
+    scratch = (N + 3 * Rb) * Dn * 4 + Rb * Dn * item
     params = {} if interpret else {
         "compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel",),
-            vmem_limit_bytes=int(
-                4 * Rb * (N + 3) * Dn * 4 + 3 * N * Dn * 4 + (4 << 20)))}
-    ho, y = pl.pallas_call(
+            vmem_limit_bytes=int(2 * blocks + 2 * held + scratch
+                                 + (8 << 20)))}
+    ho, tail_o, g = pl.pallas_call(
         kernel,
         grid=(R // Rb,),
-        in_specs=[state, vec, vec,
-                  pl.BlockSpec((N, rows, _LANES), lambda i: (0, 0, 0)),
-                  smem, smem, smem],
-        out_specs=[state, vec],
-        out_shape=[jax.ShapeDtypeStruct(h4.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(x3.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((N, rows, _LANES), jnp.float32)],
-        input_output_aliases={0: 0},
+        in_specs=[state, taps, half(0), half(1),
+                  pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec((Rb, 1), lambda i: (i, 0))]
+        + [whole(v) for v in weights],
+        out_specs=[state, taps, half(0)],
+        out_shape=[jax.ShapeDtypeStruct(h.shape, f32),
+                   jax.ShapeDtypeStruct(tail.shape, tail.dtype),
+                   jax.ShapeDtypeStruct((R, Dn), dtype)],
+        scratch_shapes=[pltpu.VMEM((N * rows, _LANES), f32),
+                        pltpu.VMEM((Rb * rows, _LANES), f32),
+                        pltpu.VMEM((Rb * rows, _LANES), f32),
+                        pltpu.VMEM((Rb * rows, _LANES), f32),
+                        pltpu.VMEM((Rb, Dn), dtype),
+                        pltpu.VMEM((Rb, N), f32), pltpu.VMEM((Rb, N), f32),
+                        pltpu.SMEM((Rb, N), f32), pltpu.SMEM((Rb, N), f32),
+                        pltpu.SemaphoreType.DMA((2,))],
+        input_output_aliases={0: 0, 1: 1},
         interpret=interpret,
         name="ssm_state_update",
         **params,
-    )(h4, x3, dt3, a3, b, c, active.astype(jnp.int32))
-    return ho, y.reshape(R, Dn)
+    )(h, tail, xz, xz, act, act[:, None], *weights)
+    return g, ho, tail_o
 
 
-def ssm_state_update(h, x, dt, a_log, b, c, active, use_kernel=True):
-    """(h', y) of one step for the rows `active`; the others keep their
-    state and read y = 0. `h` (R,) + state_shape, float32, is updated
-    in place where the caller donates it."""
-    f32 = jnp.float32
-    x, dt, b, c = (v.astype(f32) for v in (x, dt, b, c))
+def ssm_state_update(h, tail, xz, active, w, eps, use_kernel=True):
+    """(g, h', tail') of one token for the rows `active`, everything a
+    recurrent layer does between in_proj and out_proj; the other rows
+    keep state and tail, and their g is nobody's. `h` (R,) + state_shape
+    float32 and `tail` (R,) + tail_shape are updated in place where
+    the caller donates them; `w` holds a layer's STEP_WEIGHTS."""
     mode = _pallas_mode(h) if use_kernel else None
-    if mode is not None and h.dtype == f32:
+    if mode is not None and h.dtype == jnp.float32:
         try:
             return _state_update(
-                h, x, dt, a_log, b, c, active,
-                rows_per_step=_rows_per_step(
+                h, tail, xz, active, {k: w[k] for k in STEP_WEIGHTS},
+                eps=eps, rows_per_step=_rows_per_step(
                     h.shape[0], tuning.get("ssm_state_update", "rows")),
                 interpret=mode == "interpret")
         except Exception as e:
             _step_fallback.note(e)
-    hn, y = ssm_state_update_ref(h.astype(f32), x, dt, a_log, b, c,
-                                 active)
-    return hn.astype(h.dtype), y
+    return ssm_state_update_ref(h, tail, xz, active, w, eps)

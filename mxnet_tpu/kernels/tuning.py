@@ -56,11 +56,12 @@ DEFAULTS = {
                            "chunk_tokens": 2048},
     # the state-space kernels: positions a grid step of the prompt scan
     # holds in VMEM (x, dt and y blocks of 8 x 128 channels, fp32,
-    # double-buffered: 6 MB at 256), and rows of the state pool a grid
-    # step of the decode update moves in and out (2.6 MB each way at
-    # 8 rows of 16 x 5120 fp32). Hand-chosen.
+    # double-buffered: 6 MB at 256; hand-chosen), and rows of the state
+    # and tail pools a grid step of the one-call decode step moves in
+    # and out (10.5 MB of state each way at 32 rows of 16 x 5120 fp32;
+    # swept on a v5e, flat from 8 to 32: tuned.json's note).
     "selective_scan": {"time_chunk": 256},
-    "ssm_state_update": {"rows": 8},
+    "ssm_state_update": {"rows": 32},
     # the power-retention prompt form: positions a grid step works (its
     # five (heads x chunk, 128) float32 scratches are 6.5 MB at 512
     # beside the head's 4.3 MB state). Swept on a v5e (tuned.json's

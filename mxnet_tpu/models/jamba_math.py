@@ -25,9 +25,13 @@ An attention layer's: ln_in, wq, wk, wv, wo, ln_ff, gate, up, down.
 
 The state of one sequence in one layer: `h` (N, Dn / 128, 128) float32
 (kernels/selective_scan.py::state_shape) and `tail`, the last
-d_conv - 1 inputs of the convolution, (d_conv - 1, Dn) in the model's
-dtype. No function here has a backward through the scan kernel: the
-net is for inference.
+d_conv - 1 inputs of the convolution, ((d_conv - 1) * Dn,) in the
+model's dtype, tap after tap (`tail_shape`: the layout the decode step
+reads and writes in place beside `xz`; `mixer` views it
+(d_conv - 1, Dn)). `mixer_step` is three operations: in_proj, ONE call
+that does the rest of the layer with both pools in place
+(`ssm_state_update`), out_proj. No function here has a backward
+through the scan kernel: the net is for inference.
 """
 from __future__ import annotations
 
@@ -44,34 +48,14 @@ __all__ = ["mamba_layer", "mamba_layer_step", "attention_qkv",
 
 
 def zero_state(cfg, batch):
-    from ..kernels.selective_scan import state_shape
+    from ..kernels.selective_scan import state_shape, tail_shape
 
     return {"h": jnp.zeros((batch,) + state_shape(cfg.d_state,
                                                   cfg.d_inner),
                            jnp.float32),
-            "tail": jnp.zeros((batch, cfg.d_conv - 1, cfg.d_inner),
+            "tail": jnp.zeros((batch,) + tail_shape(cfg.d_conv,
+                                                    cfg.d_inner),
                               jnp.dtype(cfg.dtype))}
-
-
-def _ssm_inputs(lp, xc, cfg):
-    """dt (float32, after softplus), B, C of the recurrence from the
-    convolved input xc (..., Dn): x_proj, Jamba's three norms,
-    dt_proj."""
-    R, N, eps = cfg.dt_rank, cfg.d_state, cfg.rms_eps
-    p = xc @ lp["x_proj"].T
-    dt_r = rms(p[..., :R], lp["dt_norm"], eps)
-    b = rms(p[..., R:R + N], lp["b_norm"], eps)
-    c = rms(p[..., R + N:], lp["c_norm"], eps)
-    dt = jax.nn.softplus((dt_r @ lp["dt_proj"].T).astype(jnp.float32)
-                         + lp["dt_bias"])
-    return dt, b, c
-
-
-def _gate_out(lp, y, xc, z):
-    """out_proj((y + D x) * silu(z)), the product in float32."""
-    g = (y + lp["D"] * xc.astype(jnp.float32)) \
-        * jax.nn.silu(z.astype(jnp.float32))
-    return g.astype(z.dtype) @ lp["out_proj"].T
 
 
 def mixer(lp, u, cfg, lengths=None, state=None):
@@ -79,7 +63,7 @@ def mixer(lp, u, cfg, lengths=None, state=None):
     None). Returns (out, state'): with `lengths` (B,) the state is the
     one after each row's last valid position (dt = 0 on the right
     padding holds h still; the tail is cut at the length)."""
-    from ..kernels.selective_scan import selective_scan
+    from ..kernels.selective_scan import gate, selective_scan, ssm_inputs
 
     B, T, _ = u.shape
     Dn, k = cfg.d_inner, cfg.d_conv
@@ -87,12 +71,13 @@ def mixer(lp, u, cfg, lengths=None, state=None):
         state = zero_state(cfg, B)
     xz = u @ lp["in_proj"].T
     xr, z = xz[..., :Dn], xz[..., Dn:]
-    xp = jnp.concatenate([state["tail"].astype(xr.dtype), xr], axis=1)
+    xp = jnp.concatenate([state["tail"].reshape(B, k - 1, Dn)
+                          .astype(xr.dtype), xr], axis=1)
     acc = lp["conv_b"]
     for j in range(k):
         acc = acc + xp[:, j:j + T].astype(jnp.float32) * lp["conv_w"][j]
     xc = jax.nn.silu(acc).astype(u.dtype)
-    dt, b, c = _ssm_inputs(lp, xc, cfg)
+    dt, b, c = ssm_inputs(lp, xc, cfg.rms_eps)
     if lengths is None:
         tail = xp[:, T:]
     else:
@@ -101,30 +86,23 @@ def mixer(lp, u, cfg, lengths=None, state=None):
         tail = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(
             row, n, k - 1, axis=0))(xp, lengths)
     y, h = selective_scan(xc, dt, lp["A_log"], b, c, state["h"])
-    return _gate_out(lp, y, xc, z), {"h": h,
-                                     "tail": tail.astype(u.dtype)}
+    return gate(lp, y, xc, z) @ lp["out_proj"].T, {
+        "h": h, "tail": tail.reshape(B, -1).astype(u.dtype)}
 
 
 def mixer_step(lp, u, cfg, state, active):
     """One token of every row: u (B, 1, D), `state` the rows' states.
-    A row whose `active` is False keeps its state (and its output is
-    never read)."""
+    Three operations: in_proj, the step (kernels/selective_scan.py::
+    ssm_state_update: convolution, x_proj, the norms, dt_proj, the
+    recurrence and the gate in one call, `h` and `tail` in place),
+    out_proj. A row whose `active` is False keeps its state (and its
+    output is never read)."""
     from ..kernels.selective_scan import ssm_state_update
 
-    Dn = cfg.d_inner
-    xz = u[:, 0] @ lp["in_proj"].T
-    xr, z = xz[..., :Dn], xz[..., Dn:]
-    tail = state["tail"]
-    win = jnp.concatenate([tail.astype(xr.dtype), xr[:, None]], axis=1)
-    acc = jnp.sum(win.astype(jnp.float32) * lp["conv_w"], axis=1) \
-        + lp["conv_b"]
-    xc = jax.nn.silu(acc).astype(u.dtype)
-    dt, b, c = _ssm_inputs(lp, xc, cfg)
-    h, y = ssm_state_update(state["h"], xc, dt, lp["A_log"], b, c,
-                            active)
-    new_tail = jnp.where(active[:, None, None],
-                         win[:, 1:].astype(tail.dtype), tail)
-    return _gate_out(lp, y, xc, z)[:, None], {"h": h, "tail": new_tail}
+    g, h, tail = ssm_state_update(
+        state["h"], state["tail"], u[:, 0] @ lp["in_proj"].T, active, lp,
+        cfg.rms_eps)
+    return (g @ lp["out_proj"].T)[:, None], {"h": h, "tail": tail}
 
 
 def _feed_forward(lp, x, cfg):

@@ -108,7 +108,7 @@ def test_the_layer_order_and_the_tied_head():
     assert dec.recurrent and not dec.mixed and dec.supports == frozenset()
     st = dec.state_shapes()
     assert st["h"] == ((16, 40, 128), jnp.float32)
-    assert st["tail"] == ((3, 5120), jnp.bfloat16)
+    assert st["tail"] == ((3 * 5120,), jnp.bfloat16)  # tap after tap
 
 
 # -- (2) served through the state pool and the paged cache ---------------------
@@ -431,6 +431,33 @@ def test_the_step_and_the_scan_are_one_layer():
     np.testing.assert_allclose(jnp.concatenate([first, second], 1),
                                whole, atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(end["h"], st["h"], atol=2e-5, rtol=2e-5)
+
+
+def test_the_step_of_a_recurrent_layer_is_three_operations(interpret):
+    """`mixer_step` since PR 47: in_proj, ONE call that takes `xz` and
+    both pools and gives `g` (the convolution, x_proj, the norms,
+    dt_proj, the recurrence and the gate inside it), out_proj; the rest
+    of its jaxpr only views operands."""
+    net = mx.models.get_model("jamba_tiny")
+    net.initialize()
+    cfg = net.model.cfg
+    lp = net.decoder().params_tree(net)["layers"][0]
+    state = jamba_math.zero_state(cfg, 2)
+    assert state["tail"].shape == (2, (cfg.d_conv - 1) * cfg.d_inner)
+    jaxpr = jax.make_jaxpr(lambda u, s: jamba_math.mixer_step(
+        lp, u, cfg, s, jnp.ones((2,), bool)))(jnp.zeros((2, 1, 64)),
+                                              state)
+    views = {"slice", "squeeze", "transpose", "reshape",
+             "broadcast_in_dim"}
+    work = [e for e in jaxpr.eqns if e.primitive.name not in views]
+    assert [e.primitive.name for e in work] \
+        == ["dot_general", "jit", "dot_general"]
+    assert work[1].params["name"] == "_state_update"
+    # the call takes in_proj's product whole and the pools as they are
+    xz, (_, h, tail) = work[0].outvars[0], jaxpr.jaxpr.invars
+    assert xz in work[1].invars and h in work[1].invars \
+        and tail in work[1].invars
+    assert work[1].outvars[0] in work[2].invars
 
 
 # -- (6) the benchmark's Jamba cell, tiny ---------------------------------------------

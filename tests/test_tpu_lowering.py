@@ -262,10 +262,12 @@ def _scan(x, dt, a, b, c, h):
                               interpret=False)
 
 
-def _state_step(h, x, dt, a, b, c, live):
+def _state_step(h, tail, xz, live, w):
+    from mxnet_tpu.kernels import tuning
     from mxnet_tpu.kernels.selective_scan import _state_update
-    return _state_update(h, x, dt, a, b, c, live, rows_per_step=8,
-                         interpret=False)
+    return _state_update(
+        h, tail, xz, live, w, eps=1e-6, interpret=False,
+        rows_per_step=tuning.get("ssm_state_update", "rows", "tpu"))
 
 
 def _jamba_args(what, sharding=None):
@@ -276,9 +278,15 @@ def _jamba_args(what, sharding=None):
                 sds((16, 5120)), sds((1, 2048, 16)), sds((1, 2048, 16)),
                 sds((1, 16, 40, 128)))
     if what == "state update":
-        return (sds((256, 16, 40, 128)), sds((256, 5120)),
-                sds((256, 5120)), sds((16, 5120)), sds((256, 16)),
-                sds((256, 16)), sds((256,), jnp.bool_))
+        # both pools, xz as in_proj leaves it, a layer's small weights
+        bf = jnp.bfloat16
+        return (sds((256, 16, 40, 128)), sds((256, 3 * 5120), bf),
+                sds((256, 10240), bf), sds((256,), jnp.bool_),
+                {"conv_w": sds((4, 5120)), "conv_b": sds((5120,)),
+                 "x_proj": sds((192, 5120), bf), "dt_norm": sds((160,), bf),
+                 "b_norm": sds((16,), bf), "c_norm": sds((16,), bf),
+                 "dt_proj": sds((5120, 160), bf), "dt_bias": sds((5120,)),
+                 "A_log": sds((16, 5120)), "D": sds((5120,))})
     if what == "paged sweep":
         return _paged_args(256, 640, 65537, K=1, H=20, sharding=sharding)
     bf = jnp.bfloat16
@@ -306,21 +314,33 @@ def test_jamba_kernels_lower_at_the_cells_shapes(what):
 @pytest.mark.parametrize("what", list(_JAMBA_KERNELS))
 def test_jamba_kernels_compile_for_v5e(what, one_chip):
     """Mosaic's VMEM limit and tiling at the real sizes: the state
-    (16 x 8 x 128 float32 a channel block) across 8 time chunks, 8 rows
-    of state in and out a step with the pool aliased, a group of 20
-    query heads (no multiple of 8 sublanes) on 4 KB pages with a
-    655 KB block table in SMEM."""
+    (16 x 8 x 128 float32 a channel block) across 8 time chunks, a
+    block of rows of state and tail in and out a step with both pools
+    aliased and a layer's small weights resident, a group of 20 query
+    heads (no multiple of 8 sublanes) on 4 KB pages with a 655 KB
+    block table in SMEM."""
     fn, name = _JAMBA_KERNELS[what]
-    donate = (0,) if what == "state update" else ()
+    donate = (0, 1) if what == "state update" else ()
     compiled = jax.jit(fn, donate_argnums=donate).lower(
         *_jamba_args(what, one_chip)).compile()
     text = compiled.as_text()
     assert name in text
     if what == "state update":
-        # the pool is updated in place: no copy of it, no second one
-        state = 256 * 16 * 5120 * 4
-        assert compiled.memory_analysis().alias_size_in_bytes == state
-        assert not re.search(r"f32\[256,16,40,128\]\S* copy\(", text)
+        # both pools are updated in place: no copy of either, no second
+        # one (the tail is a pool of (3 x 5120,) rows, tap after tap;
+        # held (3, 5120) a row it was re-laid out twice a layer)
+        state, tail = 256 * 16 * 5120 * 4, 256 * 3 * 5120 * 2
+        assert compiled.memory_analysis().alias_size_in_bytes \
+            == state + tail
+        assert not re.search(r"(f32\[256,16,40,128\]|bf16\[256,3,5120\]"
+                             r"|bf16\[256,15360\])\S* copy\(", text)
+        # x, dt and y no longer travel between XLA and the call as
+        # float32: it takes xz and gives g, both in the model's dtype
+        # (the line names its results and, under
+        # operand_layout_constraints, its operands)
+        call = re.search(r"%ssm_state_update[\w.]* = [^\n]* custom-call\("
+                         r"[^\n]*", text).group(0)
+        assert "bf16[256,10240]" in call and "f32[256,5120]" not in call
 
 
 # -- the Brumby cell's kernels at its shapes (brumby_14b.longctx20: 20
@@ -775,6 +795,30 @@ def test_the_dealt_sweep_is_one_call_a_layer_round_no_pool_copy(
     assert len(found) == calls, found
     copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
     assert not [c for c in copied if c in pools]
+
+
+def test_the_recurrent_step_is_one_call_a_layer_both_pools_in_place(
+        one_chip, monkeypatch):
+    """Jamba's whole `decode` at the cell's sizes (one recurrent layer,
+    one attention layer): ONE `ssm_state_update` Mosaic call for
+    everything between in_proj and out_proj, no `copy` whose result has
+    the shape of the state pool or of the tail pool (held (3, 5120) a
+    row the tail was copied on its way in and out, PR 35's finding),
+    nothing float32 of a row's width between XLA and the call, and
+    every pool back in the buffer it came in."""
+    text, _, n_pools = _compiled_serving_program(
+        "jamba2_3b.reason256 decode", one_chip, monkeypatch)
+    assert n_pools == 4                             # h, tail, k, v
+    calls = re.findall(r"%ssm_state_update[\w.]* = [^\n]* custom-call\(",
+                       text)
+    assert len(calls) == 1 and "f32[256,5120]" not in calls[0]
+    assert "bf16[256,15360]" in calls[0] and "bf16[256,5120]" in calls[0]
+    copied = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+    assert copied, "the pattern finds no copy at all in this program"
+    whole = [c for c in copied
+             if c in ("256,16,40,128", "256,15360", "256,3,5120")]
+    assert not whole, f"{len(whole)} whole-pool copies"
+    assert text.count("may-alias") + text.count("must-alias") >= n_pools
 
 
 @pytest.mark.parametrize("program,kernel", [
