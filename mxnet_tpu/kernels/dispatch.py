@@ -1,18 +1,29 @@
-"""Shared kernel-dispatch policy. Every Pallas kernel family routes a
-failure of a kernel its gate admitted through one KernelFallback. On a
-TPU backend that failure RAISES — a kernel that does not trace, lower
-or compile on the chip is a bug, and a silent return to the jnp
-reference would let a chip run pass without the kernel. Off the chip
-(the Pallas interpreter under tests) it is a warn-once, counted
-fallback that MXNET_TPU_STRICT_KERNELS=1 (or the family-specific env)
-makes fatal."""
+"""Where the choice of kernel is made. Whether a call runs its Mosaic
+kernel, the Pallas interpreter or its jax.numpy twin is ONE decision,
+`kernel_mode` (the family's switch in the environment, the backend,
+where a concrete operand lives; a kernel file adds only its own shape
+preconditions), followed by ONE pattern, `KernelFallback.run`: try the
+kernel; a failure RAISES on a TPU backend — a kernel that does not
+trace, lower or compile on the chip is a bug, and a silent return to
+the jnp twin would let a chip run pass without the kernel. Off the
+chip (the Pallas interpreter under tests) it is a warn-once, counted
+fallback that MXNET_TPU_STRICT_KERNELS=1 (or the family's
+MXNET_TPU_STRICT_<family>=1) makes fatal.
+
+The families and their switches (docs/MIGRATION.md has the table):
+FLASH, NORM, CE, MOE, SCAN -> MXNET_TPU_<family>_INTERPRET=1 runs the
+family's kernels under the Pallas interpreter on any backend."""
 from __future__ import annotations
 
 import os
 import warnings
 
-__all__ = ["KernelFallback", "fallback_counts", "operand_on_cpu",
-           "pick_rows", "pad_rows", "per_shard"]
+__all__ = ["KernelFallback", "fallback_counts", "kernel_mode",
+           "operand_on_cpu", "pick_rows", "pad_rows", "per_shard"]
+
+
+def _on(name: str) -> bool:
+    return os.environ.get(name, "0") == "1"
 
 
 def operand_on_cpu(x) -> bool:
@@ -28,6 +39,24 @@ def operand_on_cpu(x) -> bool:
         return bool(devs) and all(d.platform == "cpu" for d in devs)
     except Exception:
         return False
+
+
+def kernel_mode(family, operand=None, *, ok=True, ok_compiled=True):
+    """None (the jnp twin), "interpret" or "compiled" for one call of a
+    kernel of `family`. `ok`: the kernel's own preconditions in either
+    mode; `ok_compiled`: those Mosaic alone has (the interpreter wins
+    over them). Compiled needs a backend that is not the CPU and an
+    `operand` that, where concrete, is not committed to CPU devices."""
+    if not ok:
+        return None
+    if _on(f"MXNET_TPU_{family}_INTERPRET"):
+        return "interpret"
+    import jax
+
+    if jax.default_backend() != "cpu" and ok_compiled \
+            and not operand_on_cpu(operand):    # False for None too
+        return "compiled"
+    return None
 
 
 def per_shard(fn, args, in_specs, out_like=0):
@@ -113,9 +142,9 @@ def fallback_counts():
 
 
 class KernelFallback:
-    def __init__(self, kernel_name: str, strict_envs=()):
+    def __init__(self, kernel_name: str, family: str):
         self.kernel_name = kernel_name
-        self.strict_envs = tuple(strict_envs) + ("MXNET_TPU_STRICT_KERNELS",)
+        self.family = family
         self.count = 0
         self._warned = False
         _REGISTRY[kernel_name] = self
@@ -123,8 +152,20 @@ class KernelFallback:
     def strict(self) -> bool:
         import jax
 
-        return jax.default_backend() == "tpu" or any(
-            os.environ.get(e, "0") == "1" for e in self.strict_envs)
+        return jax.default_backend() == "tpu" \
+            or _on(f"MXNET_TPU_STRICT_{self.family}") \
+            or _on("MXNET_TPU_STRICT_KERNELS")
+
+    def run(self, mode, kernel, twin):
+        """`kernel(interpret)` where `mode` (kernel_mode's answer) is
+        not None; `twin()` without a mode or after a failure that
+        `note` let pass."""
+        if mode is not None:
+            try:
+                return kernel(mode == "interpret")
+            except Exception as e:
+                self.note(e)
+        return twin()
 
     def note(self, e: BaseException):
         """Record a fallback; re-raises first on a TPU backend and in
@@ -134,7 +175,9 @@ class KernelFallback:
         self.count += 1
         if not self._warned:
             self._warned = True
+            # note <- run <- the kernel's entry <- its caller <- the
+            # caller's: the model's line, not the op wrapper's
             warnings.warn(
                 f"Pallas {self.kernel_name} kernel failed; falling back "
                 f"to the jnp path: {type(e).__name__}: {e}",
-                RuntimeWarning, stacklevel=4)
+                RuntimeWarning, stacklevel=5)

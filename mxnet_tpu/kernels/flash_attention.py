@@ -18,25 +18,17 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from . import tuning
-from .dispatch import KernelFallback
+from .dispatch import KernelFallback, kernel_mode, per_shard
 
 __all__ = ["flash_attention_raw", "reference_attention"]
 
-#: fallback bookkeeping (FALLBACK_COUNT exposed via __getattr__ below)
-_fallback = KernelFallback("flash-attention",
-                           strict_envs=("MXNET_TPU_STRICT_FLASH",))
-
-
-def __getattr__(name):
-    if name == "FALLBACK_COUNT":
-        return _fallback.count
-    raise AttributeError(name)
+_fallback = KernelFallback("flash-attention", "FLASH")
 
 
 def reference_attention(q, k, v, causal=True, scale=None,
@@ -482,21 +474,22 @@ def _flash_pallas_bwd(causal, scale, interpret, window, res, g):
             "forward serves prefill only")
     q, k, v, lengths, out, lse = res
     delta = _rowsum_per_head(g, out)                 # (B, H, T)
-    try:
-        dq, dk, dv = _pallas_backward(q, k, v, lse, delta,
-                                      g.astype(q.dtype), causal, scale,
-                                      interpret=interpret,
-                                      lengths=lengths)
-        return dq, dk, dv, _len_cotangent(lengths)
-    except Exception as e:
-        # same contract as the forward: never let a kernel regression
-        # crash training unless the user opted into strict mode
-        _fallback.note(e)
+
+    def ref():
         _, vjp = jax.vjp(lambda q_, k_, v_:
                          reference_attention(q_, k_, v_, causal, scale,
                                              lengths),
                          q, k, v)
-        return vjp(g) + (_len_cotangent(lengths),)
+        return vjp(g)
+
+    # same contract as the forward: never let a kernel regression
+    # crash training unless the user opted into strict mode
+    return _fallback.run(
+        "interpret" if interpret else "compiled",
+        lambda interp: _pallas_backward(
+            q, k, v, lse, delta, g.astype(q.dtype), causal, scale,
+            interpret=interp, lengths=lengths),
+        ref) + (_len_cotangent(lengths),)
 
 
 _flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
@@ -527,20 +520,8 @@ def _flash_ref_bwd(causal, scale, window, res, g):
 _flash_ref.defvjp(_flash_ref_fwd, _flash_ref_bwd)
 
 
-def _pallas_mode(T):
-    """None (use reference), 'compiled', or 'interpret' (CPU testing of
-    the real kernels, enabled via MXNET_TPU_FLASH_INTERPRET=1)."""
-    if T % 128 != 0:
-        return None
-    if os.environ.get("MXNET_TPU_FLASH_INTERPRET", "0") == "1":
-        return "interpret"
-    if jax.default_backend() not in ("cpu",):
-        return "compiled"
-    return None
-
-
-def flash_attention_raw(q, k, v, causal=True, scale=None,
-                        use_flash=True, lengths=None, window=None):
+def flash_attention_raw(q, k, v, causal=True, scale=None, lengths=None,
+                        window=None):
     """lengths (B,) optionally masks key positions >= lengths[b]
     (BERT-style key padding); composes with causal. `window` (causal
     only) is a sliding window: query i sees keys i - window < j <= i;
@@ -554,31 +535,16 @@ def flash_attention_raw(q, k, v, causal=True, scale=None,
         scale = 1.0 / math.sqrt(q.shape[-1])
     if lengths is not None:
         lengths = jnp.asarray(lengths, jnp.int32)
-    mode = _pallas_mode(q.shape[1]) if use_flash else None
-    if mode == "compiled":
-        from .dispatch import operand_on_cpu
-
-        if operand_on_cpu(q):
-            mode = None  # eager call on CPU-committed data: no Mosaic
-    if mode is not None:
-        from jax.sharding import PartitionSpec as P
-
-        from .dispatch import per_shard
-
-        interp = mode == "interpret"
-        qkv = P("dp", None, "tp", None)     # batch over dp, heads over tp
-        has_len = lengths is not None
-        try:
-            return per_shard(
-                lambda q_, k_, v_, *l_: _flash_pallas(
-                    q_, k_, v_, l_[0] if l_ else None, causal, scale,
-                    interp, window),
-                (q, k, v) + ((lengths,) if has_len else ()),
-                (qkv, qkv, qkv) + ((P("dp"),) if has_len else ()))
-        except Exception as e:
-            # fail loudly: a silently-degraded flash path hides O(T^2)
-            # perf regressions. MXNET_TPU_STRICT_FLASH=1 (or
-            # MXNET_TPU_STRICT_KERNELS=1) turns the fallback into an
-            # error; otherwise warn once and count.
-            _fallback.note(e)
-    return _flash_ref(q, k, v, lengths, causal, scale, window)
+    qkv = P("dp", None, "tp", None)     # batch over dp, heads over tp
+    has_len = lengths is not None
+    # fail loudly: a silently-degraded flash path hides O(T^2) perf
+    # regressions (the Mosaic blocks need T in whole 128-row tiles)
+    return _fallback.run(
+        kernel_mode("FLASH", q, ok=q.shape[1] % 128 == 0),
+        lambda interp: per_shard(
+            lambda q_, k_, v_, *l_: _flash_pallas(
+                q_, k_, v_, l_[0] if l_ else None, causal, scale,
+                interp, window),
+            (q, k, v) + ((lengths,) if has_len else ()),
+            (qkv, qkv, qkv) + ((P("dp"),) if has_len else ())),
+        lambda: _flash_ref(q, k, v, lengths, causal, scale, window))

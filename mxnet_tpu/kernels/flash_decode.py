@@ -18,12 +18,12 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 
-from .dispatch import KernelFallback
+from . import tuning
+from .dispatch import KernelFallback, kernel_mode
 
 __all__ = ["flash_decode", "flash_decode_quantized",
            "quantize_kv", "dequantize_kv",
@@ -38,20 +38,12 @@ __all__ = ["flash_decode", "flash_decode_quantized",
            "flash_decode_paged_window_quantized",
            "paged_window_mode"]
 
-_fallback = KernelFallback("flash-decode",
-                           strict_envs=("MXNET_TPU_STRICT_FLASH",))
+_fallback = KernelFallback("flash-decode", "FLASH")
 
 #: distinct fallback site for the in-kernel paged path, so a paged
 #: regression is visible separately from the contiguous kernel in
 #: telemetry's kernel_fallbacks provider
-_paged_fallback = KernelFallback("flash-decode-paged",
-                                 strict_envs=("MXNET_TPU_STRICT_FLASH",))
-
-
-def __getattr__(name):
-    if name == "FALLBACK_COUNT":
-        return _fallback.count
-    raise AttributeError(name)
+_paged_fallback = KernelFallback("flash-decode-paged", "FLASH")
 
 
 def reference_decode_attention(q, k_cache, v_cache, valid_len,
@@ -158,21 +150,32 @@ def _flash_decode_pallas(q, k_cache, v_cache, valid_len, scale,
     return out.reshape(B, H, d)
 
 
-def flash_decode(q, k_cache, v_cache, valid_len, scale=None,
-                 use_flash=True):
+def _pallas_mode(cache, scale_bytes=0):
+    """Gate of the contiguous kernels, from static shapes: Mosaic
+    tiling needs S % 128 == 0, and one kv head's K+V (with
+    `scale_bytes` of per-token scale a row beside an int8 cache) must
+    fit VMEM (~16 MiB/core) next to the working blocks — beyond the
+    budget (kernels/tuning.py: flash_decode.vmem_cache_budget_bytes)
+    the (B, K)-grid kernel would fail at Mosaic compile time INSIDE the
+    caller's jit, where no try/except can catch it."""
+    S, d = cache.shape[2], cache.shape[3]
+    cache_bytes = 2 * S * (d * cache.dtype.itemsize + scale_bytes)
+    return kernel_mode("FLASH", cache, ok=S % 128 == 0 and cache_bytes
+                       <= tuning.get("flash_decode",
+                                     "vmem_cache_budget_bytes"))
+
+
+def flash_decode(q, k_cache, v_cache, valid_len, scale=None):
     """Single-position attention against the cache; Pallas on TPU, the
     no-repeat jnp formulation elsewhere."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    mode = _pallas_mode(k_cache) if use_flash else None
-    if mode is not None:
-        try:
-            return _flash_decode_pallas(q, k_cache, v_cache, valid_len,
-                                        scale, mode == "interpret")
-        except Exception as e:
-            _fallback.note(e)
-    return reference_decode_attention(q, k_cache, v_cache, valid_len,
-                                      scale)
+    return _fallback.run(
+        _pallas_mode(k_cache),
+        lambda interpret: _flash_decode_pallas(
+            q, k_cache, v_cache, valid_len, scale, interpret),
+        lambda: reference_decode_attention(q, k_cache, v_cache,
+                                           valid_len, scale))
 
 
 # -- paged (block-allocated) KV cache ---------------------------------------
@@ -193,7 +196,7 @@ def flash_decode(q, k_cache, v_cache, valid_len, scale=None,
 #   PR 25); the int8 and the window twins below still do.
 # - GATHER (fallback): `gather_kv_pages` materializes the contiguous
 #   view with jnp.take, then the contiguous flash sweep runs on it.
-#   Correct everywhere (interpret off, odd shapes, use_flash=False)
+#   Correct everywhere (interpret off, odd shapes)
 #   but re-creates exactly the pool-sized HBM traffic paging exists
 #   to avoid; every fall-through is counted at the
 #   "flash-decode-paged" site.
@@ -263,8 +266,6 @@ def _paged_sweep_pages(pool_shape, itemsize, nb=None, group=1):
     rows (a group of 20 takes two). On a v5e the kernel's time falls
     with every page added up to the budget (tuned.json has the
     sweep)."""
-    from . import tuning
-
     _, K, bs, d = pool_shape
     page = K * bs * d * itemsize
     rows = 16 * -(-group // 16)
@@ -671,29 +672,16 @@ def paged_kernel_mode(pool_operand, quantized=False):
     Compiled, the sweep also needs head_dim in whole 128-lane rows:
     Mosaic cannot slice a page out of a pool of narrower ones."""
     N, K, bs, d = pool_operand.shape
-    if bs % 8 != 0:
-        return None
     if quantized:
-        from . import tuning
-
         per_block = bs * d * pool_operand.dtype.itemsize + bs * 4
         # 2 operands (k, v) x 2 pipeline buffers + q block + scratch
-        cell_bytes = 4 * per_block + 2 * d * 4 + (d + 2) * 4 * 8
-        if cell_bytes > tuning.get("flash_decode_paged",
-                                   "vmem_budget_bytes"):
-            return None
-    elif _paged_sweep_pages(pool_operand.shape,
-                            pool_operand.dtype.itemsize) < 1:
-        return None
-    if os.environ.get("MXNET_TPU_FLASH_INTERPRET", "0") == "1":
-        return "interpret"
-    if jax.default_backend() not in ("cpu",):
-        from .dispatch import operand_on_cpu
-
-        if operand_on_cpu(pool_operand) or (not quantized and d % 128):
-            return None
-        return "compiled"
-    return None
+        fits = 4 * per_block + 2 * d * 4 + (d + 2) * 4 * 8 \
+            <= tuning.get("flash_decode_paged", "vmem_budget_bytes")
+    else:
+        fits = _paged_sweep_pages(pool_operand.shape,
+                                  pool_operand.dtype.itemsize) >= 1
+    return kernel_mode("FLASH", pool_operand, ok=bs % 8 == 0 and fits,
+                       ok_compiled=quantized or d % 128 == 0)
 
 
 def paged_gather_bytes(pool_shape, table_shape, itemsize,
@@ -711,7 +699,7 @@ def paged_gather_bytes(pool_shape, table_shape, itemsize,
 
 
 def flash_decode_paged(q, k_pages, v_pages, block_tables, valid_len,
-                       scale=None, use_flash=True, window=None):
+                       scale=None, window=None):
     """Block-table decode attention straight off the page pool: the
     in-kernel Pallas path when the gate admits it, else gather the
     contiguous view and run the standard flash sweep. Both paths are
@@ -720,45 +708,39 @@ def flash_decode_paged(q, k_pages, v_pages, block_tables, valid_len,
     table's entries before the window may be stale or zero."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    mode = paged_kernel_mode(k_pages) if use_flash else None
-    if mode is not None:
-        try:
-            return _flash_decode_paged_pallas(
-                q, k_pages, v_pages, block_tables, valid_len, scale,
-                mode == "interpret", window)
-        except Exception as e:
-            _paged_fallback.note(e)
-    k = gather_kv_pages(k_pages, block_tables)
-    v = gather_kv_pages(v_pages, block_tables)
-    if window is not None:
-        return reference_decode_attention(q, k, v, valid_len, scale,
-                                          window)
-    return flash_decode(q, k, v, valid_len, scale=scale,
-                        use_flash=use_flash)
+
+    def gathered():
+        k = gather_kv_pages(k_pages, block_tables)
+        v = gather_kv_pages(v_pages, block_tables)
+        if window is not None:
+            return reference_decode_attention(q, k, v, valid_len, scale,
+                                              window)
+        return flash_decode(q, k, v, valid_len, scale=scale)
+
+    return _paged_fallback.run(
+        paged_kernel_mode(k_pages),
+        lambda interpret: _flash_decode_paged_pallas(
+            q, k_pages, v_pages, block_tables, valid_len, scale,
+            interpret, window),
+        gathered)
 
 
 def flash_decode_paged_quantized(q, k8_pages, ks_pages, v8_pages,
                                  vs_pages, block_tables, valid_len,
-                                 scale=None, use_flash=True):
+                                 scale=None):
     """Paged variant of flash_decode_quantized: int8 data + per-token
     scale blocks, in-kernel when the gate admits, gathered otherwise."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    mode = paged_kernel_mode(k8_pages, quantized=True) if use_flash \
-        else None
-    if mode is not None:
-        try:
-            return _flash_decode_paged_pallas_q8(
-                q, k8_pages, ks_pages, v8_pages, vs_pages,
-                block_tables, valid_len, scale, mode == "interpret")
-        except Exception as e:
-            _paged_fallback.note(e)
-    k8 = gather_kv_pages(k8_pages, block_tables)
-    ks = gather_kv_pages(ks_pages, block_tables)
-    v8 = gather_kv_pages(v8_pages, block_tables)
-    vs = gather_kv_pages(vs_pages, block_tables)
-    return flash_decode_quantized(q, k8, ks, v8, vs, valid_len,
-                                  scale=scale, use_flash=use_flash)
+    return _paged_fallback.run(
+        paged_kernel_mode(k8_pages, quantized=True),
+        lambda interpret: _flash_decode_paged_pallas_q8(
+            q, k8_pages, ks_pages, v8_pages, vs_pages, block_tables,
+            valid_len, scale, interpret),
+        lambda: flash_decode_quantized(
+            q, *(gather_kv_pages(p, block_tables) for p in
+                 (k8_pages, ks_pages, v8_pages, vs_pages)),
+            valid_len, scale=scale))
 
 
 # -- a paged pool of latents (MLA) -------------------------------------------
@@ -794,8 +776,6 @@ def _latent_sweep_sizes(bs, nb):
     """(pages a step, keys a sub-chunk) of the latent sweep: the tuned
     counts, a step no longer than a table and in whole 128-lane tiles
     of scores where it can be, a sub-chunk in whole pages."""
-    from . import tuning
-
     fit = max(1, min(tuning.get("flash_decode_paged_latent", "pages"),
                      nb))
     lane = max(1, 128 // bs)
@@ -812,17 +792,10 @@ def paged_latent_mode(pool_operand, latent):
     latent in whole 128-lane tiles (Mosaic slices the values out of the
     row at a tile's edge)."""
     N, K, bs, row = pool_operand.shape
-    if K != 1 or bs % 8 != 0 or not 0 < latent <= row:
-        return None
-    if os.environ.get("MXNET_TPU_FLASH_INTERPRET", "0") == "1":
-        return "interpret"
-    if jax.default_backend() not in ("cpu",):
-        from .dispatch import operand_on_cpu
-
-        if operand_on_cpu(pool_operand) or row % 128 or latent % 128:
-            return None
-        return "compiled"
-    return None
+    return kernel_mode(
+        "FLASH", pool_operand,
+        ok=K == 1 and bs % 8 == 0 and 0 < latent <= row,
+        ok_compiled=row % 128 == 0 and latent % 128 == 0)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -1029,25 +1002,24 @@ def _paged_latent_sweep(q, pool, block_tables, valid_len, *, latent,
 
 
 def flash_decode_paged_latent(q, pages, block_tables, valid_len, *,
-                              latent, scale, use_flash=True):
+                              latent, scale):
     """Decode attention straight off a pool of latents: q (B, H, row)
     the absorbed queries, pages (N, 1, bs, row) the cached rows, ->
     (B, H, latent), the probabilities' mix of the rows' first `latent`
     entries. The in-kernel sweep when the gate admits it, else the jnp
     twin on the gathered view (counted at "flash-decode-paged")."""
-    mode = paged_latent_mode(pages, latent) if use_flash else None
-    if mode is not None:
-        try:
-            step, chunk = _latent_sweep_sizes(pages.shape[2],
-                                              block_tables.shape[1])
-            return _paged_latent_sweep(
-                q, pages, block_tables, valid_len, latent=int(latent),
-                scale=float(scale), pages=step, chunk=chunk,
-                interpret=mode == "interpret")
-        except Exception as e:
-            _paged_fallback.note(e)
-    return reference_paged_latent_attention(q, pages, block_tables,
-                                            valid_len, latent, scale)
+    def sweep(interpret):
+        step, chunk = _latent_sweep_sizes(pages.shape[2],
+                                          block_tables.shape[1])
+        return _paged_latent_sweep(
+            q, pages, block_tables, valid_len, latent=int(latent),
+            scale=float(scale), pages=step, chunk=chunk,
+            interpret=interpret)
+
+    return _paged_fallback.run(
+        paged_latent_mode(pages, latent), sweep,
+        lambda: reference_paged_latent_attention(
+            q, pages, block_tables, valid_len, latent, scale))
 
 
 # -- multi-position window attention off the page pool ----------------------
@@ -1090,26 +1062,14 @@ def paged_window_mode(pool_operand, window, quantized=False):
     window width; the int8 window path always takes the gathered
     dequantize reference (in-kernel q8 window is a chip-window
     follow-up), so quantized=True returns None."""
-    if quantized:
-        return None
     N, K, bs, d = pool_operand.shape
-    if bs % 8 != 0:
-        return None
-    from . import tuning
-
     per_block = bs * d * pool_operand.dtype.itemsize
     cell_bytes = 4 * per_block \
         + int(window) * (2 * d * 4 + (d + 2) * 4 * 8)
-    if cell_bytes > tuning.get("flash_decode_paged",
-                               "vmem_budget_bytes"):
-        return None
-    if os.environ.get("MXNET_TPU_FLASH_INTERPRET", "0") == "1":
-        return "interpret"
-    if jax.default_backend() not in ("cpu",):
-        from .dispatch import operand_on_cpu
-
-        return None if operand_on_cpu(pool_operand) else "compiled"
-    return None
+    return kernel_mode(
+        "FLASH", pool_operand,
+        ok=not quantized and bs % 8 == 0 and cell_bytes
+        <= tuning.get("flash_decode_paged", "vmem_budget_bytes"))
 
 
 def _flash_decode_paged_window_pallas(q, k_pages, v_pages,
@@ -1201,7 +1161,7 @@ def _flash_decode_paged_window_pallas(q, k_pages, v_pages,
 
 
 def flash_decode_paged_window(q, k_pages, v_pages, block_tables,
-                              valid_lens, scale=None, use_flash=True):
+                              valid_lens, scale=None):
     """W-position window attention straight off the page pool
     (chunked prefill / speculative verify): in-kernel Pallas when the
     gate admits it, else gather the contiguous view and run the window
@@ -1209,25 +1169,20 @@ def flash_decode_paged_window(q, k_pages, v_pages, block_tables,
     calls at matching valid lengths."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    mode = paged_window_mode(k_pages, q.shape[1]) if use_flash \
-        else None
-    if mode is not None:
-        try:
-            return _flash_decode_paged_window_pallas(
-                q, k_pages, v_pages, block_tables, valid_lens, scale,
-                mode == "interpret")
-        except Exception as e:
-            _paged_fallback.note(e)
-    k = gather_kv_pages(k_pages, block_tables)
-    v = gather_kv_pages(v_pages, block_tables)
-    return reference_paged_window_attention(q, k, v, valid_lens,
-                                            scale)
+    return _paged_fallback.run(
+        paged_window_mode(k_pages, q.shape[1]),
+        lambda interpret: _flash_decode_paged_window_pallas(
+            q, k_pages, v_pages, block_tables, valid_lens, scale,
+            interpret),
+        lambda: reference_paged_window_attention(
+            q, gather_kv_pages(k_pages, block_tables),
+            gather_kv_pages(v_pages, block_tables), valid_lens, scale))
 
 
 def flash_decode_paged_window_quantized(q, k8_pages, ks_pages,
                                         v8_pages, vs_pages,
                                         block_tables, valid_lens,
-                                        scale=None, use_flash=True):
+                                        scale=None):
     """Window attention against the int8 pool: gather + dequantize to
     fp32, then the window reference (paged_window_mode gates the
     in-kernel path off for quantized pools). Cast back to q.dtype so
@@ -1347,67 +1302,20 @@ def _flash_decode_pallas_q8(q, k8, ks, v8, vs, valid_len, scale,
     return out.reshape(B, H, d)
 
 
-def flash_decode_quantized(q, k8, ks, v8, vs, valid_len, scale=None,
-                           use_flash=True):
+def flash_decode_quantized(q, k8, ks, v8, vs, valid_len, scale=None):
     """Single-position attention against an int8 cache with per-token
     scales (see quantize_kv). Pallas on TPU; dequantize + the
     no-repeat jnp formulation elsewhere."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    mode = _pallas_mode_q8(k8) if use_flash else None
-    if mode is not None:
-        try:
-            return _flash_decode_pallas_q8(q, k8, ks, v8, vs,
-                                           valid_len, scale,
-                                           mode == "interpret")
-        except Exception as e:
-            _fallback.note(e)
-    # cast to q.dtype so both dispatch paths agree (the Pallas kernel's
-    # out_shape is q.dtype; the fp32-dequantized reference would
-    # otherwise leak fp32 into the bf16 decode step)
-    return reference_decode_attention(
-        q, dequantize_kv(k8, ks, jnp.float32),
-        dequantize_kv(v8, vs, jnp.float32), valid_len,
-        scale).astype(q.dtype)
-
-
-def _pallas_mode_q8(k8):
-    # int8 halves the cache bytes; fp32 scales add 4 per token
-    S, d = k8.shape[2], k8.shape[3]
-    return _gate(k8, cache_bytes=2 * S * (d + 4))
-
-
-# one kv head's K+V must fit VMEM (~16 MiB/core) next to the working
-# blocks; beyond this the (B, K)-grid kernel would fail at Mosaic
-# compile time INSIDE the caller's jit — where the try/except above
-# cannot catch it — so gate on static shapes instead. The byte budget
-# is tunable (kernels/tuning.py: flash_decode.vmem_cache_budget_bytes)
-
-
-def _vmem_cache_budget():
-    from . import tuning
-
-    return tuning.get("flash_decode", "vmem_cache_budget_bytes")
-
-
-def _pallas_mode(k_cache):
-    S, d = k_cache.shape[2], k_cache.shape[3]
-    return _gate(k_cache,
-                 cache_bytes=2 * S * d * k_cache.dtype.itemsize)
-
-
-def _gate(cache_operand, cache_bytes):
-    """Shared dispatch gate for both cache dtypes: Mosaic tiling needs
-    S % 128 == 0, one kv head's cache must fit the VMEM budget, and an
-    eager call on CPU-committed data must never attempt Mosaic."""
-    if cache_operand.shape[2] % 128 != 0:
-        return None
-    if cache_bytes > _vmem_cache_budget():
-        return None
-    if os.environ.get("MXNET_TPU_FLASH_INTERPRET", "0") == "1":
-        return "interpret"
-    if jax.default_backend() not in ("cpu",):
-        from .dispatch import operand_on_cpu
-
-        return None if operand_on_cpu(cache_operand) else "compiled"
-    return None
+    # the twin casts to q.dtype so both dispatch paths agree (the
+    # Pallas kernel's out_shape is q.dtype; the fp32-dequantized
+    # reference would otherwise leak fp32 into the bf16 decode step)
+    return _fallback.run(
+        _pallas_mode(k8, scale_bytes=4),    # an fp32 scale a token
+        lambda interpret: _flash_decode_pallas_q8(
+            q, k8, ks, v8, vs, valid_len, scale, interpret),
+        lambda: reference_decode_attention(
+            q, dequantize_kv(k8, ks, jnp.float32),
+            dequantize_kv(v8, vs, jnp.float32), valid_len,
+            scale).astype(q.dtype))

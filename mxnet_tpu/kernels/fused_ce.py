@@ -19,35 +19,19 @@ rows are padded to the 8-sublane multiple and sliced off the outputs.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
-from .dispatch import (KernelFallback, operand_on_cpu, pad_rows,
-                       per_shard, pick_rows)
+from . import tuning
+from .dispatch import (KernelFallback, kernel_mode, pad_rows, per_shard,
+                       pick_rows)
 
 __all__ = ["fused_softmax_ce_raw", "reference_softmax_ce", "eligible"]
 
-#: fallback bookkeeping (FALLBACK_COUNT exposed via __getattr__ below)
-_fallback = KernelFallback("fused-ce",
-                           strict_envs=("MXNET_TPU_STRICT_CE",))
-
-
-def __getattr__(name):
-    if name == "FALLBACK_COUNT":
-        return _fallback.count
-    raise AttributeError(name)
-
-
-def _pallas_mode():
-    if os.environ.get("MXNET_TPU_CE_INTERPRET", "0") == "1":
-        return "interpret"
-    if jax.default_backend() not in ("cpu",):
-        return "compiled"
-    return None
+_fallback = KernelFallback("fused-ce", "CE")
 
 
 #: Mosaic's default scoped-VMEM limit is 16 MiB per kernel. The backward
@@ -62,15 +46,20 @@ def _row_bytes(vocab, itemsize):
     return vocab * (4 * itemsize + 8)
 
 
-def eligible(vocab: int, itemsize: int = 4) -> bool:
+def _worth_it(vocab, itemsize):
     """The kernel only pays off once the vocab is large enough that
-    the jnp path's extra HBM round trips dominate (threshold
-    overridable via MXNET_TPU_CE_MIN_VOCAB, read per call so tests can
-    lower it), and it needs the 8-row minimum block of `itemsize`-byte
-    logits to fit the working set (fp32: 64k columns, bf16: 96k)."""
-    min_vocab = int(os.environ.get("MXNET_TPU_CE_MIN_VOCAB", "1024"))
-    return (_pallas_mode() is not None and vocab >= min_vocab
+    the jnp path's extra HBM round trips dominate (kernels/tuning.py:
+    fused_ce.min_vocab), and it needs the 8-row minimum block of
+    `itemsize`-byte logits to fit the working set (fp32: 64k columns,
+    bf16: 96k)."""
+    return (vocab >= tuning.get("fused_ce", "min_vocab")
             and 8 * _row_bytes(vocab, itemsize) <= _VMEM_WORKING_SET_BYTES)
+
+
+def eligible(vocab: int, itemsize: int = 4) -> bool:
+    """Whether a loss over `vocab` columns should take this module's
+    entry at all (gluon.loss asks before it reshapes)."""
+    return kernel_mode("CE", ok=_worth_it(vocab, itemsize)) is not None
 
 
 def reference_softmax_ce(x2, lbl):
@@ -80,8 +69,6 @@ def reference_softmax_ce(x2, lbl):
 
 
 def _pick_rows(n, v, itemsize):
-    from . import tuning
-
     return pick_rows(n, _row_bytes(v, itemsize),
                      want=tuning.get("fused_ce", "row_block_want"),
                      budget_bytes=_VMEM_WORKING_SET_BYTES)
@@ -197,18 +184,14 @@ def fused_softmax_ce_raw(x2, lbl, use_fused=True):
     """Per-row sparse softmax cross-entropy: x2 (N, V) logits, lbl (N,)
     int labels -> (N,) fp32 loss. Pallas on TPU (vocab padded to lane
     multiples), jnp reference elsewhere; falls back loudly, never
-    silently (MXNET_TPU_STRICT_CE=1 / MXNET_TPU_STRICT_KERNELS=1)."""
+    silently (dispatch.KernelFallback)."""
     lbl = lbl.astype(jnp.int32)
-    mode = _pallas_mode() if use_fused else None
-    if mode == "compiled" and operand_on_cpu(x2):
-        mode = None  # eager call on CPU-committed data: no Mosaic
-    if mode is not None and eligible(x2.shape[1], x2.dtype.itemsize):
-        try:
-            # rows over dp; the vocab axis stays whole (the softmax
-            # reduces over it), so tp-sharded logits gather first
-            return per_shard(
-                lambda x_, l_: _ce_pallas(x_, l_, mode == "interpret"),
-                (x2, lbl), (P("dp"), P("dp")), out_like=1)
-        except Exception as e:
-            _fallback.note(e)
-    return reference_softmax_ce(x2, lbl)
+    # rows over dp; the vocab axis stays whole (the softmax reduces
+    # over it), so tp-sharded logits gather first
+    return _fallback.run(
+        kernel_mode("CE", x2, ok=use_fused and _worth_it(
+            x2.shape[1], x2.dtype.itemsize)),
+        lambda interpret: per_shard(
+            lambda x_, l_: _ce_pallas(x_, l_, interpret),
+            (x2, lbl), (P("dp"), P("dp")), out_like=1),
+        lambda: reference_softmax_ce(x2, lbl))

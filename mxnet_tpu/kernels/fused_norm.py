@@ -14,14 +14,13 @@ over row blocks with the full feature dim resident in VMEM.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
-from .dispatch import KernelFallback, per_shard
+from .dispatch import KernelFallback, kernel_mode, per_shard
 
 __all__ = ["fused_rmsnorm", "fused_layernorm"]
 
@@ -29,23 +28,7 @@ __all__ = ["fused_rmsnorm", "fused_layernorm"]
 #: stays whole — the kernels reduce over it
 _ROWS = P("dp")
 
-#: fallback bookkeeping (FALLBACK_COUNT exposed via __getattr__ below)
-_fallback = KernelFallback("fused-norm",
-                           strict_envs=("MXNET_TPU_STRICT_NORM",))
-
-
-def __getattr__(name):
-    if name == "FALLBACK_COUNT":
-        return _fallback.count
-    raise AttributeError(name)
-
-
-def _pallas_mode():
-    if os.environ.get("MXNET_TPU_NORM_INTERPRET", "0") == "1":
-        return "interpret"
-    if jax.default_backend() not in ("cpu",):
-        return "compiled"
-    return None
+_fallback = KernelFallback("fused-norm", "NORM")
 
 
 # block sizing/padding shared across kernel families (dispatch.py):
@@ -168,26 +151,19 @@ _rms.defvjp(_rms_fwd, _rms_bwd)
 
 def fused_rmsnorm(x, gamma, eps=1e-6):
     """RMSNorm over the trailing axis; Pallas on TPU, jnp elsewhere."""
-    mode = _pallas_mode()
-    if mode == "compiled":
-        from .dispatch import operand_on_cpu
+    def twin():
+        xs = x.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(xs), axis=-1, keepdims=True)
+        return (xs * jax.lax.rsqrt(ms + eps) *
+                gamma.astype(jnp.float32)).astype(x.dtype)
 
-        if operand_on_cpu(x):
-            mode = None  # eager call on CPU-committed data: no Mosaic
-    if mode is not None:
-        def rms(x_, g_):
-            x2 = x_.reshape(-1, x_.shape[-1])
-            return _rms(x2, g_, eps, mode == "interpret") \
-                .reshape(x_.shape)
-
-        try:
-            return per_shard(rms, (x, gamma), (_ROWS, P()))
-        except Exception as e:
-            _fallback.note(e)
-    xs = x.astype(jnp.float32)
-    ms = jnp.mean(jnp.square(xs), axis=-1, keepdims=True)
-    return (xs * jax.lax.rsqrt(ms + eps) *
-            gamma.astype(jnp.float32)).astype(x.dtype)
+    return _fallback.run(
+        kernel_mode("NORM", x),
+        lambda interpret: per_shard(
+            lambda x_, g_: _rms(x_.reshape(-1, x_.shape[-1]), g_, eps,
+                                interpret).reshape(x_.shape),
+            (x, gamma), (_ROWS, P())),
+        twin)
 
 
 # -------------------------------------------------------------- LayerNorm
@@ -296,25 +272,18 @@ _ln.defvjp(_ln_fwd, _ln_bwd)
 
 def fused_layernorm(x, gamma, beta, eps=1e-5):
     """LayerNorm over the trailing axis; Pallas on TPU, jnp elsewhere."""
-    mode = _pallas_mode()
-    if mode == "compiled":
-        from .dispatch import operand_on_cpu
+    def twin():
+        xs = x.astype(jnp.float32)
+        mean = jnp.mean(xs, axis=-1, keepdims=True)
+        var = jnp.var(xs, axis=-1, keepdims=True)
+        return ((xs - mean) * jax.lax.rsqrt(var + eps)
+                * gamma.astype(jnp.float32)
+                + beta.astype(jnp.float32)).astype(x.dtype)
 
-        if operand_on_cpu(x):
-            mode = None  # eager call on CPU-committed data: no Mosaic
-    if mode is not None:
-        def ln(x_, g_, b_):
-            x2 = x_.reshape(-1, x_.shape[-1])
-            return _ln(x2, g_, b_, eps, mode == "interpret") \
-                .reshape(x_.shape)
-
-        try:
-            return per_shard(ln, (x, gamma, beta), (_ROWS, P(), P()))
-        except Exception as e:
-            _fallback.note(e)
-    xs = x.astype(jnp.float32)
-    mean = jnp.mean(xs, axis=-1, keepdims=True)
-    var = jnp.var(xs, axis=-1, keepdims=True)
-    return ((xs - mean) * jax.lax.rsqrt(var + eps)
-            * gamma.astype(jnp.float32)
-            + beta.astype(jnp.float32)).astype(x.dtype)
+    return _fallback.run(
+        kernel_mode("NORM", x),
+        lambda interpret: per_shard(
+            lambda x_, g_, b_: _ln(x_.reshape(-1, x_.shape[-1]), g_, b_,
+                                   eps, interpret).reshape(x_.shape),
+            (x, gamma, beta), (_ROWS, P(), P())),
+        twin)

@@ -22,19 +22,17 @@ The Pallas path runs compiled on a TPU and interpreted under
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
 from . import tuning
-from .dispatch import KernelFallback, operand_on_cpu
+from .dispatch import KernelFallback, kernel_mode
 
 __all__ = ["grouped_matmul", "reference_grouped_matmul",
            "grouped_matmul_mode"]
 
-_fallback = KernelFallback("moe-grouped-matmul",
-                           strict_envs=("MXNET_TPU_STRICT_MOE",))
+_fallback = KernelFallback("moe-grouped-matmul", "MOE")
 
 
 def _tile(n, want):
@@ -50,11 +48,7 @@ def _tile(n, want):
 
 def grouped_matmul_mode(operand):
     """None (ragged_dot), 'interpret' or 'compiled'."""
-    if os.environ.get("MXNET_TPU_MOE_INTERPRET", "0") == "1":
-        return "interpret"
-    if jax.default_backend() != "cpu" and not operand_on_cpu(operand):
-        return "compiled"
-    return None
+    return kernel_mode("MOE", operand)
 
 
 def reference_grouped_matmul(lhs, rhs, tile_group, n_tiles, tm,
@@ -169,13 +163,10 @@ def grouped_matmul(lhs, rhs, tile_group, n_tiles, tm, rhs2=None,
     """lhs (M, K) with M a multiple of `tm`; rhs, rhs2 (G, K, N);
     tile_group (M // tm,) int32; n_tiles () int32. Rows of tiles past
     `n_tiles` come back unwritten: the caller masks them."""
-    mode = grouped_matmul_mode(lhs) if use_kernel else None
-    if mode is not None:
-        try:
-            return _grouped_matmul_pallas(
-                lhs, rhs, rhs2, tile_group, n_tiles, tm=tm,
-                interpret=mode == "interpret")
-        except Exception as e:
-            _fallback.note(e)
-    return reference_grouped_matmul(lhs, rhs, tile_group, n_tiles, tm,
-                                    rhs2)
+    return _fallback.run(
+        grouped_matmul_mode(lhs) if use_kernel else None,
+        lambda interpret: _grouped_matmul_pallas(
+            lhs, rhs, rhs2, tile_group, n_tiles, tm=tm,
+            interpret=interpret),
+        lambda: reference_grouped_matmul(lhs, rhs, tile_group, n_tiles,
+                                         tm, rhs2))

@@ -59,37 +59,25 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 
 from . import tuning
-from .dispatch import KernelFallback, operand_on_cpu
+from .dispatch import KernelFallback, kernel_mode
 
 __all__ = ["power_retention_chunked", "power_retention_chunked_ref",
            "power_retention_step", "power_retention_step_ref",
            "state_shapes", "feature_map", "EPS"]
 
-_chunked_fallback = KernelFallback(
-    "power-retention-chunked", strict_envs=("MXNET_TPU_STRICT_SCAN",))
-_step_fallback = KernelFallback(
-    "power-retention-step", strict_envs=("MXNET_TPU_STRICT_SCAN",))
+_chunked_fallback = KernelFallback("power-retention-chunked", "SCAN")
+_step_fallback = KernelFallback("power-retention-step", "SCAN")
 
 #: the normaliser's floor where the caller gives none (a model passes its
 #: configuration's `retention_eps`)
 EPS = 1e-6
 _LANES = 128
 _SQRT2 = math.sqrt(2.0)
-
-
-def _pallas_mode(operand):
-    if os.environ.get("MXNET_TPU_SCAN_INTERPRET", "0") == "1":
-        return "interpret"
-    if jax.default_backend() not in ("cpu",) \
-            and not operand_on_cpu(operand):
-        return "compiled"
-    return None
 
 
 def _offsets(d):
@@ -357,18 +345,19 @@ def power_retention_chunked(q, k, v, log_g, use_kernel=True, eps=EPS):
     MXU take their operands in q's dtype (bfloat16 at the served model:
     phi and the state are rounded once where they enter a product, the
     state itself is kept and accumulated in float32)."""
-    mode = _pallas_mode(q) if use_kernel else None
-    d = q.shape[-1]
-    if mode == "interpret" or (mode and d % _LANES == 0):
-        try:
-            return power_retention_chunked_fwd(
-                q, k.astype(q.dtype), v.astype(q.dtype), log_g,
-                chunk=tuning.get("power_retention_chunked", "chunk"),
-                eps=eps, interpret=mode == "interpret")
-        except Exception as e:
-            _chunked_fallback.note(e)
-    y, state = power_retention_chunked_ref(q, k, v, log_g, eps)
-    return y.astype(q.dtype), state
+    def twin():
+        y, state = power_retention_chunked_ref(q, k, v, log_g, eps)
+        return y.astype(q.dtype), state
+
+    return _chunked_fallback.run(
+        # compiled, the kernels slice the state in whole lane rows
+        kernel_mode("SCAN", q, ok=use_kernel,
+                    ok_compiled=q.shape[-1] % _LANES == 0),
+        lambda interpret: power_retention_chunked_fwd(
+            q, k.astype(q.dtype), v.astype(q.dtype), log_g,
+            chunk=tuning.get("power_retention_chunked", "chunk"),
+            eps=eps, interpret=interpret),
+        twin)
 
 
 # -- one step for every row of a decode tick -----------------------------------
@@ -482,17 +471,19 @@ def power_retention_step(S, z, q, k, v, log_g, active, use_kernel=True,
     their state and read y = 0. `S`, `z` (R,) + state_shapes, float32,
     are updated in place where the caller donates them; y (R, H, d) in
     q's dtype."""
-    mode = _pallas_mode(S) if use_kernel else None
-    d = q.shape[-1]
-    if (mode == "interpret" or (mode and d % _LANES == 0)) \
-            and S.dtype == jnp.float32:
-        try:
-            Sn, zn, y = _step(S, z, q, k, v, log_g, active, eps=eps,
-                              interpret=mode == "interpret")
-            return Sn, zn, y.astype(q.dtype)
-        except Exception as e:
-            _step_fallback.note(e)
-    Sn, zn, y = power_retention_step_ref(
-        S.astype(jnp.float32), z.astype(jnp.float32), q, k, v, log_g,
-        active, eps)
-    return Sn.astype(S.dtype), zn.astype(z.dtype), y.astype(q.dtype)
+    def kernel(interpret):
+        Sn, zn, y = _step(S, z, q, k, v, log_g, active, eps=eps,
+                          interpret=interpret)
+        return Sn, zn, y.astype(q.dtype)
+
+    def twin():
+        Sn, zn, y = power_retention_step_ref(
+            S.astype(jnp.float32), z.astype(jnp.float32), q, k, v,
+            log_g, active, eps)
+        return Sn.astype(S.dtype), zn.astype(z.dtype), y.astype(q.dtype)
+
+    return _step_fallback.run(
+        kernel_mode("SCAN", S,
+                    ok=use_kernel and S.dtype == jnp.float32,
+                    ok_compiled=q.shape[-1] % _LANES == 0),
+        kernel, twin)

@@ -53,33 +53,21 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 
 from . import tuning
-from .dispatch import KernelFallback, operand_on_cpu
+from .dispatch import KernelFallback, kernel_mode
 
 __all__ = ["selective_scan", "selective_scan_ref", "ssm_state_update",
            "ssm_state_update_ref", "ssm_inputs", "gate", "state_shape",
            "tail_shape", "STEP_WEIGHTS"]
 
-_scan_fallback = KernelFallback("selective-scan",
-                                strict_envs=("MXNET_TPU_STRICT_SCAN",))
-_step_fallback = KernelFallback("ssm-state-update",
-                                strict_envs=("MXNET_TPU_STRICT_SCAN",))
+_scan_fallback = KernelFallback("selective-scan", "SCAN")
+_step_fallback = KernelFallback("ssm-state-update", "SCAN")
 
 _LANES = 128
-
-
-def _pallas_mode(operand):
-    if os.environ.get("MXNET_TPU_SCAN_INTERPRET", "0") == "1":
-        return "interpret"
-    if jax.default_backend() not in ("cpu",) \
-            and not operand_on_cpu(operand):
-        return "compiled"
-    return None
 
 
 def state_shape(n, dn):
@@ -269,16 +257,14 @@ def selective_scan(x, dt, a_log, b, c, h0, use_kernel=True):
     where the gate admits it, else the jnp twin. float32 in and out."""
     f32 = jnp.float32
     x, dt, b, c, h0 = (v.astype(f32) for v in (x, dt, b, c, h0))
-    mode = _pallas_mode(x) if use_kernel else None
-    if mode is not None and _channel_rows(x.shape[-1]):
-        try:
-            return selective_scan_fwd(
-                x, dt, a_log, b, c, h0,
-                chunk=tuning.get("selective_scan", "time_chunk"),
-                interpret=mode == "interpret")
-        except Exception as e:
-            _scan_fallback.note(e)
-    return selective_scan_ref(x, dt, a_log, b, c, h0)
+    return _scan_fallback.run(
+        kernel_mode("SCAN", x,
+                    ok=use_kernel and _channel_rows(x.shape[-1])),
+        lambda interpret: selective_scan_fwd(
+            x, dt, a_log, b, c, h0,
+            chunk=tuning.get("selective_scan", "time_chunk"),
+            interpret=interpret),
+        lambda: selective_scan_ref(x, dt, a_log, b, c, h0))
 
 
 # -- one step for every row of a decode tick -----------------------------------
@@ -464,14 +450,12 @@ def ssm_state_update(h, tail, xz, active, w, eps, use_kernel=True):
     keep state and tail, and their g is nobody's. `h` (R,) + state_shape
     float32 and `tail` (R,) + tail_shape are updated in place where
     the caller donates them; `w` holds a layer's STEP_WEIGHTS."""
-    mode = _pallas_mode(h) if use_kernel else None
-    if mode is not None and h.dtype == jnp.float32:
-        try:
-            return _state_update(
-                h, tail, xz, active, {k: w[k] for k in STEP_WEIGHTS},
-                eps=eps, rows_per_step=_rows_per_step(
-                    h.shape[0], tuning.get("ssm_state_update", "rows")),
-                interpret=mode == "interpret")
-        except Exception as e:
-            _step_fallback.note(e)
-    return ssm_state_update_ref(h, tail, xz, active, w, eps)
+    return _step_fallback.run(
+        kernel_mode("SCAN", h,
+                    ok=use_kernel and h.dtype == jnp.float32),
+        lambda interpret: _state_update(
+            h, tail, xz, active, {k: w[k] for k in STEP_WEIGHTS},
+            eps=eps, rows_per_step=_rows_per_step(
+                h.shape[0], tuning.get("ssm_state_update", "rows")),
+            interpret=interpret),
+        lambda: ssm_state_update_ref(h, tail, xz, active, w, eps))

@@ -31,7 +31,9 @@ DEFAULTS = {
     "flash_attention": {"block_q": 256, "block_k": 256},
     "fused_norm": {"row_block_want": 512,
                    "vmem_budget_bytes": 8 << 20},
-    "fused_ce": {"row_block_want": 256},
+    # min_vocab: below it the jnp path's extra round trips over the
+    # logits cost less than the kernel's launch (hand-chosen)
+    "fused_ce": {"row_block_want": 256, "min_vocab": 1024},
     "flash_decode": {"vmem_cache_budget_bytes": 10 << 20},
     # in-kernel paged decode: what one step of the sweep may hold in
     # VMEM — two steps of P pages of k and of v (a page is every kv

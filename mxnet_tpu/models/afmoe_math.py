@@ -89,8 +89,7 @@ def layer_finish(lp, x, att, gate, cfg, valid=None):
     return x + rms(f, lp["ln_post_mlp"], cfg.rms_eps), counts
 
 
-def decoder_layer(lp, x, positions, cfg, kind, lengths=None,
-                  use_flash=True):
+def decoder_layer(lp, x, positions, cfg, kind, lengths=None):
     """One whole layer on (B, T, D): the Gluon forward and the serving
     prefill. Ragged `lengths` (B,) mask the keys past each row's end
     and keep the padding out of the experts. Returns (x, k, v,
@@ -100,7 +99,7 @@ def decoder_layer(lp, x, positions, cfg, kind, lengths=None,
     q, k, v, gate = layer_qkv(lp, x, positions, cfg, kind)
     att = flash_attention_raw(
         q, k, v, causal=True, scale=1.0 / math.sqrt(cfg.head_dim),
-        use_flash=use_flash, lengths=lengths,
+        lengths=lengths,
         window=cfg.window if kind == SLIDING else None)
     valid = None if lengths is None else \
         jnp.arange(x.shape[1])[None, :] < lengths[:, None]

@@ -141,12 +141,12 @@ def attention_finish(lp, x, att, cfg):
                          cfg)
 
 
-def attention_layer(lp, x, cfg, lengths=None, use_flash=True):
+def attention_layer(lp, x, cfg, lengths=None):
     """One whole attention layer on (B, T, D) -> (x, k, v)."""
     from ..kernels.flash_attention import flash_attention_raw
 
     q, k, v = attention_qkv(lp, x, cfg)
     att = flash_attention_raw(q, k, v, causal=True,
                               scale=1.0 / math.sqrt(cfg.head_dim),
-                              use_flash=use_flash, lengths=lengths)
+                              lengths=lengths)
     return attention_finish(lp, x, att, cfg), k, v

@@ -118,7 +118,7 @@ def layer_finish(lp, x, att, eps, lora=None):
 
 
 def decoder_layer(lp, x, positions, eps, base, H, K, d, lengths=None,
-                  use_flash=True, return_kv=False, lora=None):
+                  return_kv=False, lora=None):
     """One full decoder layer on (B, T, D): the training forward and
     the prefill forward are THIS function (prefill passes ragged
     `lengths` and return_kv=True to harvest the cache rows).
@@ -129,8 +129,7 @@ def decoder_layer(lp, x, positions, eps, base, H, K, d, lengths=None,
     q, k, v = layer_qkv(lp, x, positions, eps, base, H, K, d,
                         lora=lora)
     att = flash_attention_raw(q, k, v, causal=True,
-                              scale=1.0 / math.sqrt(d),
-                              use_flash=use_flash, lengths=lengths)
+                              scale=1.0 / math.sqrt(d), lengths=lengths)
     out = layer_finish(lp, x, att, eps, lora=lora)
     return (out, k, v) if return_kv else out
 

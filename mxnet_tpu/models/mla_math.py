@@ -142,7 +142,7 @@ def layer_finish(lp, x, att, cfg, valid=None):
     return _finish(lp, x, att, cfg, valid)
 
 
-def decoder_layer(lp, x, positions, cfg, lengths=None, use_flash=True):
+def decoder_layer(lp, x, positions, cfg, lengths=None):
     """One whole layer on (B, T, D), keys and values materialised: the
     Gluon forward and the serving prefill. Returns (x, row, counts),
     `row` (B, T, 1, row) what the cache stores. The flash kernel takes
@@ -159,8 +159,7 @@ def decoder_layer(lp, x, positions, cfg, lengths=None, use_flash=True):
         [kv[..., :nope], jnp.broadcast_to(k_rope, q_rope.shape)], axis=-1)
     v = jnp.pad(kv[..., nope:], ((0, 0),) * 3 + ((0, d - dv),))
     att = flash_attention_raw(q, k, v, causal=True,
-                              scale=softmax_scale(cfg),
-                              use_flash=use_flash, lengths=lengths)
+                              scale=softmax_scale(cfg), lengths=lengths)
     valid = None if lengths is None else \
         jnp.arange(T)[None, :] < lengths[:, None]
     out, counts = _finish(lp, x, att[..., :dv], cfg, valid)

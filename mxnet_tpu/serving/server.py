@@ -437,12 +437,11 @@ class InferenceServer:
             if store is not None:
                 self.tier.load_store()
 
-        # host-side probe of the decode kernel's dispatch: traced code
-        # cannot bump counters, so the per-tick HBM bytes the in-kernel
-        # paged path avoids (vs the gather fallback's contiguous view)
-        # are computed here and counted after each decode tick. The
-        # probe is shape/env/backend-deterministic, so it matches the
-        # decision flash_decode_paged makes at trace time.
+        # traced code cannot bump counters, so the per-tick HBM bytes
+        # the in-kernel paged path avoids (vs the gather fallback's
+        # contiguous view) are computed here and counted after each
+        # decode tick. The probe and flash_decode_paged's trace-time
+        # choice are the same call: paged_kernel_mode.
         from ..kernels.flash_decode import (paged_kernel_mode,
                                             paged_gather_bytes)
         q8 = kv_cache_dtype == "int8"

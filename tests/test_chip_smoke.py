@@ -149,7 +149,7 @@ def test_kernel_failure_raises_on_a_tpu_backend(monkeypatch):
     from mxnet_tpu.kernels import dispatch
 
     monkeypatch.setattr(dispatch, "_REGISTRY", dict(dispatch._REGISTRY))
-    fb = dispatch.KernelFallback("test-family")
+    fb = dispatch.KernelFallback("test-family", "FLASH")
     with pytest.warns(RuntimeWarning, match="falling back"):
         fb.note(ValueError("interpreter quirk"))        # off the chip
     assert fb.count == 1

@@ -213,7 +213,7 @@ def test_packed_heads_match_reference(case, monkeypatch):
     q, k, v = _qkv(B=2, T=128, H=H, K=K, d=d, seed=21)
     lengths = jnp.asarray([128, 45], jnp.int32) if ragged else None
     kw = dict(causal=causal, lengths=lengths, window=window)
-    before = fa.FALLBACK_COUNT
+    before = fa._fallback.count
     out = fa.flash_attention_raw(q, k, v, **kw)
     ref = reference_attention(q, k, v, **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -228,7 +228,7 @@ def test_packed_heads_match_reference(case, monkeypatch):
         for a, b in zip(gk, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=3e-4, atol=3e-5)
-    assert fa.FALLBACK_COUNT == before, "the kernels fell back"
+    assert fa._fallback.count == before, "the kernels fell back"
 
 
 def test_row_with_every_key_masked(monkeypatch):

@@ -155,12 +155,15 @@ def test_quantized_decode_matches_fp32_reference():
 
 
 def test_quantized_decode_jnp_fallback_matches_kernel():
-    from mxnet_tpu.kernels.flash_decode import (flash_decode_quantized,
-                                                quantize_kv)
+    from mxnet_tpu.kernels.flash_decode import (
+        dequantize_kv, flash_decode_quantized, quantize_kv,
+        reference_decode_attention)
     q, kc, vc, vl = _data(seed=5)
     k8, ks, v8, vs = quantize_kv(kc, vc)
-    # fallback path (use_flash=False): dequantized exact softmax
-    a = flash_decode_quantized(q, k8, ks, v8, vs, vl, use_flash=False)
+    # the twin by its name: dequantized exact softmax
+    a = reference_decode_attention(
+        q, dequantize_kv(k8, ks, jnp.float32),
+        dequantize_kv(v8, vs, jnp.float32), vl).astype(q.dtype)
     # interpreter kernel path
     import os
     os.environ["MXNET_TPU_FLASH_INTERPRET"] = "1"
@@ -422,14 +425,16 @@ def test_paged_inkernel_quantized_matches_gather():
     # uses) and demand the in-kernel int8 path agree with the gathered
     # dequantize-exact fallback — the parity the dispatch gate promises
     from mxnet_tpu.kernels.flash_decode import (
-        _flash_decode_paged_pallas_q8, flash_decode_paged_quantized,
-        quantize_kv)
+        _flash_decode_paged_pallas_q8, dequantize_kv, gather_kv_pages,
+        quantize_kv, reference_decode_attention)
     q, kc, vc, kp, vp, bt, vl = _paged_data(seed=10)
     k8, ks, v8, vs = quantize_kv(kp, vp)
     out = _flash_decode_paged_pallas_q8(q, k8, ks, v8, vs, bt, vl,
                                         0.25, interpret=True)
-    ref = flash_decode_paged_quantized(q, k8, ks, v8, vs, bt, vl,
-                                       scale=0.25, use_flash=False)
+    ref = reference_decode_attention(
+        q, *(dequantize_kv(gather_kv_pages(p8, bt),
+                           gather_kv_pages(ps, bt), jnp.float32)
+             for p8, ps in ((k8, ks), (v8, vs))), vl, 0.25)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 
@@ -441,7 +446,8 @@ def test_paged_dispatch_interpret_matches_gather(monkeypatch):
     monkeypatch.setenv("MXNET_TPU_FLASH_INTERPRET", "1")
     assert fd.paged_kernel_mode(kp) == "interpret"
     a = fd.flash_decode_paged(q, kp, vp, bt, vl)
-    b = fd.flash_decode_paged(q, kp, vp, bt, vl, use_flash=False)
+    b = fd.reference_decode_attention(
+        q, fd.gather_kv_pages(kp, bt), fd.gather_kv_pages(vp, bt), vl)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=2e-5, atol=2e-5)
     assert fd._paged_fallback.count == before  # kernel path, no note()
@@ -557,8 +563,8 @@ def test_paged_window_dispatch_and_gate(monkeypatch):
     assert fd.paged_window_mode(odd, 4) is None
     before = fd._paged_fallback.count
     a = fd.flash_decode_paged_window(qw, kp, vp, bt, vls)
-    b = fd.flash_decode_paged_window(qw, kp, vp, bt, vls,
-                                     use_flash=False)
+    b = fd.reference_paged_window_attention(
+        qw, fd.gather_kv_pages(kp, bt), fd.gather_kv_pages(vp, bt), vls)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=2e-5, atol=2e-5)
     assert fd._paged_fallback.count == before

@@ -64,9 +64,9 @@ def test_fallback_counts_and_returns_reference(monkeypatch):
         raise RuntimeError("forced kernel failure")
 
     monkeypatch.setattr(fused_ce, "_run_fwd", boom)
-    before = fused_ce.FALLBACK_COUNT
+    before = fused_ce._fallback.count
     out = fused_softmax_ce_raw(x, lbl)
-    assert fused_ce.FALLBACK_COUNT == before + 1
+    assert fused_ce._fallback.count == before + 1
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(reference_softmax_ce(x, lbl)),
                                rtol=1e-5, atol=1e-5)
@@ -85,12 +85,14 @@ def test_strict_mode_raises(monkeypatch):
         fused_softmax_ce_raw(x, lbl)
 
 
-def test_loss_block_rides_kernel(monkeypatch):
+def test_loss_block_rides_kernel(monkeypatch, request):
     """SoftmaxCrossEntropyLoss routes large-vocab sparse CE through the
     fused kernel (interpret mode here) and matches the jnp path —
     values AND gradients, eager and 3-D (B, T, V)."""
     monkeypatch.setenv("MXNET_TPU_CE_INTERPRET", "1")
-    monkeypatch.setenv("MXNET_TPU_CE_MIN_VOCAB", "64")
+    from mxnet_tpu.kernels import tuning
+    tuning.set_runtime("fused_ce", "min_vocab", 64)
+    request.addfinalizer(tuning.clear_runtime)
     import mxnet_tpu as mx
 
     rs = np.random.RandomState(0)
@@ -105,7 +107,7 @@ def test_loss_block_rides_kernel(monkeypatch):
     l_fused.backward()
     g_fused = pred.grad.asnumpy()
 
-    monkeypatch.setenv("MXNET_TPU_CE_MIN_VOCAB", "100000")  # force jnp
+    tuning.set_runtime("fused_ce", "min_vocab", 100000)  # force jnp
     pred2 = mx.nd.array(pred.asnumpy())
     pred2.attach_grad()
     with mx.autograd.record():

@@ -460,14 +460,28 @@ def test_flash_attention_compiles_for_v5e_at_the_cells_shapes(
         (B, H // 2, 2, T) if d == 64 else (B, H, 1, T)), text), what
 
 
-def test_full_llama_step_lowers_with_kernels():
+def _gates_as_on_the_chip(monkeypatch):
+    """The one gate (kernels/dispatch.py) answers as on the chip: it
+    asks jax.default_backend() (cpu in tests) and the environment,
+    whatever another test module of this process set there
+    (perfbench/rehearse.py turns the interpreter on when imported)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for family in ("FLASH", "NORM", "CE", "MOE", "SCAN"):
+        monkeypatch.delenv(f"MXNET_TPU_{family}_INTERPRET", raising=False)
+
+
+@pytest.fixture
+def chip_gates(monkeypatch):
+    _gates_as_on_the_chip(monkeypatch)
+
+
+def test_full_llama_step_lowers_with_kernels(chip_gates):
     """The flagship model's jitted forward lowers for TPU with the
     fused-norm kernels actually inside (the _ops_nn dispatch routes
     trailing-axis norms to Pallas when the backend is not cpu — the
-    export targets TPU, so patch the mode check the way the TPU
-    runtime would see it)."""
+    export targets TPU, so patch the backend the gate asks for the way
+    the TPU runtime would see it)."""
     import mxnet_tpu as mx
-    from mxnet_tpu.kernels import fused_norm
     from mxnet_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
     mx.random.seed(0)
@@ -487,10 +501,7 @@ def test_full_llama_step_lowers_with_kernels():
         flat, _ = ent.raw_fn(tr, aux, key, ids_)
         return flat[0]
 
-    import unittest.mock as mock
-    with mock.patch.object(fused_norm, "_pallas_mode",
-                           lambda: "compiled"):
-        n = _lowers(fwd, jax.ShapeDtypeStruct((2, 256), jnp.int32))
+    n = _lowers(fwd, jax.ShapeDtypeStruct((2, 256), jnp.int32))
     assert n >= 2  # at least the norm kernels appear in the program
 
 
@@ -516,7 +527,7 @@ def test_flash_decode_quantized_lowers():
         q_, k_, ks_, v_, vs_, vl_, 0.088, False), q, k8, ks, k8, ks, vl)
 
 
-def test_bert_forward_with_flash_lengths_lowers():
+def test_bert_forward_with_flash_lengths_lowers(chip_gates):
     """The benchmark's BERT cells feed ragged valid_length so the
     flash kernel's key-padding path engages — prove THAT exact forward
     lowers for TPU before chip time is spent on it."""
@@ -541,19 +552,9 @@ def test_bert_forward_with_flash_lengths_lowers():
         flat, _ = ent.raw_fn(tr, aux, key, ids_, tok_, vlen_)
         return flat[0]
 
-    # the dispatch gates consult jax.default_backend() (cpu in tests);
-    # patch them the way the TPU runtime would resolve, same as the
-    # llama lowering test above
-    import unittest.mock as mock
-
-    from mxnet_tpu.kernels import flash_attention, fused_norm
-    with mock.patch.object(flash_attention, "_pallas_mode",
-                           lambda T: "compiled"), \
-            mock.patch.object(fused_norm, "_pallas_mode",
-                              lambda: "compiled"):
-        n = _lowers(fwd, jax.ShapeDtypeStruct((2, 128), jnp.int32),
-                    jax.ShapeDtypeStruct((2, 128), jnp.int32),
-                    jax.ShapeDtypeStruct((2,), jnp.int32))
+    n = _lowers(fwd, jax.ShapeDtypeStruct((2, 128), jnp.int32),
+                jax.ShapeDtypeStruct((2, 128), jnp.int32),
+                jax.ShapeDtypeStruct((2,), jnp.int32))
     assert n >= 2  # flash attention AND the fused norms engaged
 
 
@@ -670,12 +671,7 @@ def _compiled_serving_program(what, one_chip, monkeypatch):
     pool arrays."""
     from mxnet_tpu.serving.executables import paged_programs
 
-    # the kernels' gates ask the backend and the environment: the
-    # chip's answers, whatever another test module of this process set
-    # (perfbench/rehearse.py turns the interpreter on when imported)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    for family in ("FLASH", "NORM", "CE", "MOE", "SCAN"):
-        monkeypatch.delenv(f"MXNET_TPU_{family}_INTERPRET", raising=False)
+    _gates_as_on_the_chip(monkeypatch)
     if what in _COMPILED:
         return _COMPILED[what]
     sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
