@@ -605,7 +605,8 @@ def phase_kernels(size):
                          (2 * size.seq, "prefill rows")):
         run(f"moe_grouped_matmul held experts, {label}",
             lambda x, *w_: moe.held_expert_ffn(
-                x, *w_, lo=0, top_k=top_k, route_scale=2.448)[0],
+                x, *w_, lo=0, top_k=top_k, route=moe.route_top_k,
+                route_scale=2.448)[0],
             experts_ref, (randn((rows_, size.hidden)), rw, rb, eg, eu, ed),
             ("moe_grouped_matmul",), (TOL_ATTN,))
 

@@ -19,7 +19,8 @@ import os
 import warnings
 
 __all__ = ["KernelFallback", "fallback_counts", "kernel_mode",
-           "operand_on_cpu", "pick_rows", "pad_rows", "per_shard"]
+           "operand_on_cpu", "pick_rows", "pad_rows", "per_shard",
+           "float0_like"]
 
 
 def _on(name: str) -> bool:
@@ -100,6 +101,15 @@ def per_shard(fn, args, in_specs, out_like=0):
                                 for ax in sp]) for sp in in_specs)
     return shard_map(fn, mesh=mesh, in_specs=ins,
                      out_specs=ins[out_like], check_vma=False)(*args)
+
+
+def float0_like(a):
+    """The cotangent a `custom_vjp` hands back for an integer or bool
+    operand (jax's convention: zeros of dtype float0)."""
+    import jax
+    import numpy as np
+
+    return np.zeros(a.shape, jax.dtypes.float0)
 
 
 def pick_rows(n, row_bytes, want, budget_bytes):

@@ -4,9 +4,10 @@ Reference analogue: the fork's fused multi-head attention CUDA kernels
 (interleaved_matmul_selfatt*, fmha). TPU-first: Pallas kernels tile
 Q/K/V blocks through VMEM with an online-softmax accumulator, forward
 (`flash_attention_fwd`) and backward (`flash_attention_dkv`: dQ, dK and
-dV of one recomputation against the saved log-sum-exp). The jnp
-reference is the CPU path, the tests' yardstick and the differentiable
-path of a sliding window.
+dV of one recomputation against the saved log-sum-exp). Both take a
+sliding `window` and skip the blocks outside it, so a windowed layer
+trains at the window's cost. The jnp reference is the CPU path and the
+tests' yardstick; it differentiates too (`_flash_ref`).
 
 Layout convention: (B, T, H, d) for q, (B, T, K, d) for k/v with GQA
 (H % K == 0). Output (B, T, H, d). The kernels read and write the
@@ -24,7 +25,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from . import tuning
-from .dispatch import KernelFallback, kernel_mode, per_shard
+from .dispatch import (KernelFallback, float0_like, kernel_mode,
+                       per_shard)
 
 __all__ = ["flash_attention_raw", "reference_attention"]
 
@@ -294,10 +296,10 @@ def _pallas_forward(q, k, v, causal, scale, block_q=None, block_k=None,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "scale", "block_q", "block_k", "interpret"))
+    "causal", "scale", "block_q", "block_k", "interpret", "window"))
 def _pallas_backward(q, k, v, lse, delta, dout, causal, scale,
                      block_q=None, block_k=None, interpret=False,
-                     lengths=None):
+                     lengths=None, window=None):
     """O(T)-memory flash backward: dQ/dK/dV via block recomputation
     against the saved log-sum-exp — no (T, T) score matrix is ever
     materialized. lse and delta = rowsum(dO * O) are (B, H, T).
@@ -308,7 +310,11 @@ def _pallas_backward(q, k, v, lse, delta, dout, causal, scale,
     where a dq and a dkv call made 7). dK/dV come per *query* head; the
     GQA group-sum over the rep query heads per kv head happens
     outside. dQ of a query block gathers over the key blocks (the
-    grid's last axis) in a float32 scratch."""
+    grid's last axis) in a float32 scratch. With a `window` (static,
+    causal) a key block's sweep ends at the last query block that still
+    sees one of its keys, and the edge blocks are masked by
+    `_keep_mask` as the forward's are: the blocks behind the window
+    cost nothing. `window=None` traces what it always did."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -334,8 +340,15 @@ def _pallas_backward(q, k, v, lse, delta, dout, causal, scale,
         # rows beyond lengths still attend valid keys (only KEYS are
         # padded), so their cotangents legitimately reach dk/dv; a key
         # block wholly past lengths[b] is all zeros and sweeps nothing
-        upper = jnp.where(ki * block_k < len_b, n_q, 0) \
-            if has_len and n_k > 1 else n_q
+        upper = n_q
+        if window is not None:
+            # the block's last key, (ki + 1) * block_k - 1, is seen by
+            # the queries up to window - 1 past it: later query blocks
+            # lie wholly outside the window
+            upper = jnp.minimum(
+                n_q, ((ki + 1) * block_k + window - 2) // block_q + 1)
+        if has_len and n_k > 1:
+            upper = jnp.where(ki * block_k < len_b, upper, 0)
 
         if n_k > 1:
             @pl.when(ki == 0)
@@ -355,7 +368,7 @@ def _pallas_backward(q, k, v, lse, delta, dout, causal, scale,
                 doblk = do_ref[r, cols]
                 s = _dot(kblk, qh, _NT)                  # (block_k, bq)
                 keep = _keep_mask(qi, ki, block_q, block_k, causal,
-                                  None, len_b)
+                                  window, len_b)
                 if keep is not None:
                     s = jnp.where(keep, s, -jnp.inf)
                 p = jnp.exp(s - lse_ref[j:j + 1, r])     # 0 where masked
@@ -460,25 +473,17 @@ def _rowsum_per_head(g, out):
 def _len_cotangent(lengths):
     # integer primal -> float0 cotangent (jax's convention); None stays
     # None (the static no-padding case)
-    if lengths is None:
-        return None
-    import numpy as _np
-    return _np.zeros(lengths.shape, jax.dtypes.float0)
+    return None if lengths is None else float0_like(lengths)
 
 
 def _flash_pallas_bwd(causal, scale, interpret, window, res, g):
-    if window is not None:
-        raise NotImplementedError(
-            "flash attention with a sliding window has no backward "
-            "kernel (dkv knows no window mask): the windowed "
-            "forward serves prefill only")
     q, k, v, lengths, out, lse = res
     delta = _rowsum_per_head(g, out)                 # (B, H, T)
 
     def ref():
         _, vjp = jax.vjp(lambda q_, k_, v_:
                          reference_attention(q_, k_, v_, causal, scale,
-                                             lengths),
+                                             lengths, window),
                          q, k, v)
         return vjp(g)
 
@@ -488,7 +493,7 @@ def _flash_pallas_bwd(causal, scale, interpret, window, res, g):
         "interpret" if interpret else "compiled",
         lambda interp: _pallas_backward(
             q, k, v, lse, delta, g.astype(q.dtype), causal, scale,
-            interpret=interp, lengths=lengths),
+            interpret=interp, lengths=lengths, window=window),
         ref) + (_len_cotangent(lengths),)
 
 
@@ -525,8 +530,10 @@ def flash_attention_raw(q, k, v, causal=True, scale=None, lengths=None,
     """lengths (B,) optionally masks key positions >= lengths[b]
     (BERT-style key padding); composes with causal. `window` (causal
     only) is a sliding window: query i sees keys i - window < j <= i;
-    the forward kernel masks and skips the key blocks behind it, the
-    backward kernels refuse it (the jnp path differentiates)."""
+    the forward kernel masks and skips the key blocks behind it and
+    the backward kernel the query blocks past it, so both
+    differentiate at the window's cost (as the jnp path does at the
+    whole square's)."""
     if window is not None:
         if not causal:
             raise ValueError("a sliding window needs causal=True")

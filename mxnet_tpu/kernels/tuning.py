@@ -53,9 +53,21 @@ DEFAULTS = {
     # an expert's matrix a step (1 MB in bf16, two of them when gate
     # and up share a pass), and how many tokens of a long prefill the
     # layer routes at a time (its row buffers are sized for the worst
-    # case, every pair on a held expert). Hand-chosen.
+    # case, every pair on a held expert). Hand-chosen. A layer that
+    # lays out `large_rows` pairs or more (a training chunk) takes row
+    # tiles of `block_m_large`, so that an expert's matrices are read
+    # once for 512 rows (at 128 the product is bound by reading them:
+    # 128 FLOPs a byte under a ridge of 240), and with them blocks of
+    # up to 1024 either way (the backward's products contract over an
+    # expert's own width, 896 at Mellum's: one block). A chunk that a
+    # training step rebuilds in its backward is sized by its rows as
+    # the row tile is: `chunk_rows_remat` pairs (8,192 tokens at top-8;
+    # twice that is no faster on a v5e and asks for 0.8 GB more)
     "moe_grouped_matmul": {"block_k": 512, "block_n": 1024,
-                           "chunk_tokens": 2048},
+                           "chunk_tokens": 2048,
+                           "large_rows": 32768, "block_m_large": 512,
+                           "block_large": 1024,
+                           "chunk_rows_remat": 65536},
     # the state-space kernels: positions a grid step of the prompt scan
     # holds in VMEM (x, dt and y blocks of 8 x 128 channels, fp32,
     # double-buffered: 6 MB at 256; hand-chosen), and rows of the state

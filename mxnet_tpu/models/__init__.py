@@ -15,7 +15,7 @@ def register_model(name):
 def _ensure_registry():
     from . import (lenet, mlp, resnet, mobilenet, vgg, alexnet,  # noqa: F401
                    squeezenet, densenet, inception, bert, transformer,
-                   llama, afmoe, jamba, sarvam, brumby, fm, word_embedding, ssd)
+                   llama, afmoe, jamba, sarvam, brumby, mellum, fm, word_embedding, ssd)
     return _FACTORIES
 
 

@@ -29,6 +29,30 @@ __all__ = ["AfmoeConfig", "AfmoeForCausalLM", "AfmoeDecoder",
            "afmoe", "afmoe_tiny"]
 
 
+def layer_kinds(layer_types, num_layers):
+    """(SLIDING | FULL, ...) of a config's `layer_types` (None: the
+    published pattern, every 4th layer full)."""
+    if layer_types is None:
+        layer_types = [FULL if (i + 1) % 4 == 0 else SLIDING
+                       for i in range(num_layers)]
+    kinds = tuple(str(t).replace("_attention", "") for t in layer_types)
+    if len(kinds) != num_layers or set(kinds) - {FULL, SLIDING}:
+        raise ValueError(f"layer_types must name {num_layers} "
+                         f"layers as {FULL!r} or {SLIDING!r}: "
+                         f"{layer_types}")
+    return kinds
+
+
+def held_range(held_experts, num_experts):
+    """(lo, n) of the experts held here (None: all of them)."""
+    lo, n = held_experts if held_experts is not None \
+        else (0, num_experts)
+    if not (0 <= lo and n >= 1 and lo + n <= num_experts):
+        raise ValueError(f"held_experts {(lo, n)} is no range of "
+                         f"the {num_experts} experts")
+    return int(lo), int(n)
+
+
 class AfmoeConfig:
     def __init__(self, vocab_size=200192, hidden_size=3072,
                  intermediate_size=12288, moe_intermediate_size=3072,
@@ -44,26 +68,13 @@ class AfmoeConfig:
         self.moe_intermediate_size = moe_intermediate_size
         self.num_layers = num_layers
         self.num_dense_layers = num_dense_layers
-        if layer_types is None:     # published: every 4th layer full
-            layer_types = [FULL if (i + 1) % 4 == 0 else SLIDING
-                           for i in range(num_layers)]
-        kinds = tuple(str(t).replace("_attention", "")
-                      for t in layer_types)
-        if len(kinds) != num_layers or set(kinds) - {FULL, SLIDING}:
-            raise ValueError(f"layer_types must name {num_layers} "
-                             f"layers as {FULL!r} or {SLIDING!r}: "
-                             f"{layer_types}")
-        self.layer_kinds = kinds
+        self.layer_kinds = layer_kinds(layer_types, num_layers)
         self.num_heads = num_heads
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
         self.num_experts = num_experts
-        lo, n = held_experts if held_experts is not None \
-            else (0, num_experts)
-        if not (0 <= lo and n >= 1 and lo + n <= num_experts):
-            raise ValueError(f"held_experts {(lo, n)} is no range of "
-                             f"the {num_experts} experts")
-        self.held_lo, self.num_held = int(lo), int(n)
+        self.held_lo, self.num_held = held_range(held_experts,
+                                                 num_experts)
         self.top_k = top_k
         self.route_scale = route_scale
         self.window = window

@@ -65,13 +65,13 @@ def mlp(lp, m, cfg, valid=None):
     touched) of `parallel.moe.held_expert_ffn`."""
     if "router" not in lp:
         return swiglu(m, lp["gate"], lp["up"], lp["down"]), None
-    from ..parallel.moe import held_expert_ffn
+    from ..parallel.moe import held_expert_ffn, route_top_k
 
     B, T, D = m.shape
-    routed, pairs, touched = held_expert_ffn(
+    routed, pairs, touched, _ = held_expert_ffn(
         m.reshape(B * T, D), lp["router"], lp["bias"], lp["ex_gate"],
         lp["ex_up"], lp["ex_down"], lo=cfg.held_lo, top_k=cfg.top_k,
-        route_scale=cfg.route_scale,
+        route=route_top_k, route_scale=cfg.route_scale,
         valid=None if valid is None else valid.reshape(B * T))
     f = swiglu(m, lp["sh_gate"], lp["sh_up"], lp["sh_down"]) \
         + routed.reshape(B, T, D).astype(m.dtype)

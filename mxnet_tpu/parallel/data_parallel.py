@@ -10,6 +10,7 @@ and XLA inserts the gradient AllReduce over ICI during the backward pass.
 """
 from __future__ import annotations
 
+import collections
 import time as _time
 from typing import Callable, Optional, Sequence
 
@@ -219,10 +220,17 @@ class FusedTrainStep:
                  n_model_inputs: int = 1, grad_accum: int = 1,
                  compression=None, zero1: bool = False, zero=None,
                  pipeline=None, pp_axis: str = "pp", plan=None,
-                 virtual: int = 1):
+                 virtual: int = 1, counts=None):
         from ..gluon.trainer import Trainer
         self.net = net
         self.loss_fn = loss_fn
+        # counts=(names...): the net's LAST output is a small int32
+        # vector the step hands back beside the loss (`loss_fn` never
+        # sees it). A step's counts are read once its arrays are ready
+        # — never by waiting on the step just launched — and ride the
+        # `mx.train_step` span of the call that found them
+        self._count_names = tuple(counts or ())
+        self._counts_pending = collections.deque()
         # plan mode: a validated ParallelPlan drives the composition —
         # the legacy warn-once degrade matrices below are BYPASSED
         # (the plan already rejected every unfusable combination loudly)
@@ -303,6 +311,11 @@ class FusedTrainStep:
                              f"count; got {pipeline!r}")
         self.pipeline = int(pipeline) if pipeline is not None else None
         self.pp_axis = pp_axis
+        if self._count_names and (self.zero_stage or self.pipeline
+                                  or compression or grad_accum > 1):
+            raise ValueError(
+                "counts= rides the plain fused step only (no zero, "
+                "pipeline, compression or grad_accum)")
         # degrade matrix for the widened wire-compression config: each
         # unfusable combination warns ONCE (at construction) and runs
         # without the requested compression rather than failing the run.
@@ -573,6 +586,7 @@ class FusedTrainStep:
         treedef_box = entry
 
         accum = self.grad_accum
+        counted = bool(self._count_names)
 
         def loss_of(tr_, aux_, key_, batch_):
             flat, new_aux = entry.raw_fn(tr_, aux_, key_,
@@ -580,6 +594,11 @@ class FusedTrainStep:
             outs = jax.tree_util.tree_unflatten(
                 treedef_box.out_treedef,
                 [NDArray(f) for f in flat])
+            if counted:
+                # the counts ride out as aux data beside new_aux
+                *outs, cnt = outs
+                outs = outs[0] if len(outs) == 1 else tuple(outs)
+                new_aux = (new_aux, cnt._data)
             with autograd._mode(False, True), _random.trace_key(
                     jax.random.fold_in(key_, 7)):
                 labels = [NDArray(b) for b in batch_[n_in:]]
@@ -624,6 +643,9 @@ class FusedTrainStep:
             for n in tr_names:
                 new_tr[n], new_states[n] = opt._step(
                     tr[n], grads[n], states[n], hyper)
+            if counted:
+                new_aux, cnt = new_aux
+                return loss, new_tr, new_aux, new_states, cnt
             return loss, new_tr, new_aux, new_states
 
         # run_steps scans this same body; the extra global grad-norm
@@ -686,7 +708,8 @@ class FusedTrainStep:
                 step,
                 in_shardings=(tr_sh, aux_sh, st_sh, hyper_sh, repl,
                               *batch_sh),
-                out_shardings=(repl, tr_sh, aux_sh, st_sh),
+                out_shardings=(repl, tr_sh, aux_sh, st_sh)
+                + ((repl,) if counted else ()),
                 donate_argnums=(0, 2) if self.donate else ())
             # place initial state on the mesh (args arrive single-device)
             self._tr = {n: _global_put(v, tr_sh[n])
@@ -2020,8 +2043,20 @@ class FusedTrainStep:
     def __call__(self, *args) -> NDArray:
         # in a profiler trace: one `mx.train_step` a call, holding the
         # `mx.data` phase and `mx.train_dispatch` (the compiled call)
-        with _tm.span("train_step"):
+        with _tm.span("train_step", **self._ready_counts()):
             return self._step(args)
+
+    def _ready_counts(self):
+        """{name: sum} over the launched steps whose counts have
+        arrived since the last call (and `counted_steps`, how many).
+        Empty for a step that counts nothing."""
+        got = {}
+        pending = self._counts_pending
+        while pending and pending[0].is_ready():
+            for n, v in zip(self._count_names + ("counted_steps",),
+                            list(_np.asarray(pending.popleft())) + [1]):
+                got[n] = got.get(n, 0) + int(v)
+        return got
 
     def _step(self, args) -> NDArray:
         if self._params is None:
@@ -2096,8 +2131,12 @@ class FusedTrainStep:
                     self._tr, self._aux, self._states, hyper, key,
                     self._resid, *raw)
             else:
-                loss, self._tr, self._aux, self._states = self._compiled(
-                    self._tr, self._aux, self._states, hyper, key, *raw)
+                loss, self._tr, self._aux, self._states, *cnt = \
+                    self._compiled(self._tr, self._aux, self._states,
+                                   hyper, key, *raw)
+                if cnt:
+                    cnt[0].copy_to_host_async()
+                    self._counts_pending.append(cnt[0])
         if self._compiled._cache_size() > n_exe:
             _tracing.record_compile("fused_step", None)
             _tracing.record_compile_seconds(
@@ -2411,6 +2450,9 @@ class FusedTrainStep:
         k = len(batches)
         if k == 0:
             raise ValueError("run_steps needs at least one batch")
+        if self._count_names:
+            raise ValueError("counts= rides single dispatches only: a "
+                             "scanned window hands none back")
         if self._params is None:
             self._init_state(batches[0])
         if self._compiled is None:

@@ -23,10 +23,12 @@ from .. import nd
 from ..ndarray import NDArray
 from ..gluon.block import HybridBlock
 from ..gluon.parameter import Parameter
+from ..kernels.dispatch import float0_like
 from .mesh import current_manual_axes
 from .tensor_parallel import sharding_constraint
 
-__all__ = ["MoEMLP", "held_expert_ffn", "route_top_k"]
+__all__ = ["MoEMLP", "held_expert_ffn", "route_top_k",
+           "route_softmax_top_k"]
 
 
 class MoEMLP(HybridBlock):
@@ -162,6 +164,88 @@ def route_top_k(x, router_w, bias, top_k, route_scale):
     return sel.astype(jnp.int32), w
 
 
+def route_softmax_top_k(x, router_w, bias, top_k, route_scale):
+    """A softmax in float32 over ALL experts, its top-k, the picked
+    probabilities normalised to sum to one (`norm_topk_prob`) and
+    scaled; no bias (`bias` is None). Same shapes as `route_top_k`. The
+    weights differentiate into the router through the softmax and the
+    normalisation; the picks carry no gradient."""
+    del bias
+    # a true float32 product: the MXU's default single bf16 pass flips
+    # near-tied picks (T x E x D is small change)
+    p = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32).T,
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    picked, sel = jax.lax.top_k(p, top_k)
+    w = route_scale * picked / jnp.sum(picked, axis=1, keepdims=True)
+    return sel.astype(jnp.int32), w
+
+
+# The rows' way to the experts and back. Forward they are the two
+# gathers they always were; backward each is a gather too (the layout
+# knows both directions: `row_token` row -> token, `pair_row` pair ->
+# row), where the transposes XLA would derive are scatter-adds over
+# every row.
+
+def _picked(y, pair_row, held):
+    # a row no tile wrote is whatever the buffer held: where(), not a
+    # product with a zero weight
+    return jnp.where(held.reshape(-1, 1),
+                     y[pair_row].astype(jnp.float32), 0.0)
+
+
+@jax.custom_vjp
+def _to_rows(x, row_token, pair_row, held):
+    return x[row_token]
+
+
+def _to_rows_fwd(x, row_token, pair_row, held):
+    return x[row_token], (row_token, pair_row, held,
+                          jnp.zeros((0,), x.dtype))
+
+
+def _to_rows_bwd(res, drows):
+    row_token, pair_row, held, like = res
+    d = _picked(drows, pair_row, held).reshape(held.shape + (-1,))
+    return (d.sum(axis=1).astype(like.dtype), float0_like(row_token),
+            float0_like(pair_row), float0_like(held))
+
+
+_to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
+
+
+@jax.custom_vjp
+def _from_rows(y, w, pair_row, held, row_token, row_pair):
+    y = _picked(y, pair_row, held) * w.reshape(-1, 1)
+    return y.reshape(w.shape + (-1,)).sum(axis=1)
+
+
+def _from_rows_fwd(y, w, pair_row, held, row_token, row_pair):
+    return _from_rows(y, w, pair_row, held, row_token, row_pair), \
+        (y, w, pair_row, held, row_token, row_pair)
+
+
+def _from_rows_bwd(res, dout):
+    y, w, pair_row, held, row_token, row_pair = res
+    # row_pair: a row's pair, or T * k for a row no pair owns — those
+    # get a ZERO cotangent (the weights' gradient sums every row of a
+    # live tile)
+    row_w = jnp.concatenate(
+        [w.reshape(-1), jnp.zeros((1,), w.dtype)])[row_pair]
+    # the rows are gathered in y's type (half the bytes of float32;
+    # `held_expert_ffn`'s docstring says what that rounds) and
+    # weighted in float32
+    dy = (dout.astype(y.dtype)[row_token].astype(jnp.float32)
+          * row_w[:, None]).astype(y.dtype)
+    dw = jnp.sum(_picked(y, pair_row, held).reshape(w.shape + (-1,))
+                 * dout[:, None, :], axis=-1)
+    return (dy, dw, float0_like(pair_row), float0_like(held),
+            float0_like(row_token), float0_like(row_pair))
+
+
+_from_rows.defvjp(_from_rows_fwd, _from_rows_bwd)
+
+
 def _held_rows(x, g, w, ex_gate, ex_up, ex_down, use_kernel):
     """The held experts' weighted sum for the tokens of `x`; g (T, k)
     names each pair's held expert (0 .. n - 1), or n where the pair
@@ -169,12 +253,12 @@ def _held_rows(x, g, w, ex_gate, ex_up, ex_down, use_kernel):
     each expert's rows padded to whole tiles, a grouped SwiGLU runs
     over them and the rows go back to their tokens weighted. Every
     shape is the worst case's: all T * k pairs held."""
-    from ..kernels.grouped_matmul import grouped_matmul
+    from ..kernels.grouped_matmul import grouped_matmul, row_tile
 
     T, k = g.shape
     n = ex_gate.shape[0]
     P_ = T * k
-    tm = 16 if P_ <= 1024 else 128
+    tm = row_tile(P_)
     m_pad = -(-P_ // tm) * tm + n * tm
     held = g < n
     g = g.reshape(-1)
@@ -191,46 +275,66 @@ def _held_rows(x, g, w, ex_gate, ex_up, ex_down, use_kernel):
         (order // k).astype(jnp.int32), mode="drop")
     pair_row = jnp.zeros((P_,), jnp.int32).at[order].set(
         jnp.minimum(row, m_pad - 1).astype(jnp.int32))
+    # only the backward reads it (dead code in a forward program)
+    row_pair = jnp.full((m_pad,), P_, jnp.int32).at[row].set(
+        order.astype(jnp.int32), mode="drop")
     tiles = m_pad // tm
     tile_group = jnp.minimum(
         jnp.searchsorted(pend, jnp.arange(tiles) * tm, side="right"),
         n - 1).astype(jnp.int32)
     n_tiles = pend[-1] // tm
-    rows = x[row_token]
+    rows = _to_rows(x, row_token, pair_row, held)
     h = grouped_matmul(rows, ex_gate, tile_group, n_tiles, tm,
                        rhs2=ex_up, use_kernel=use_kernel)
     y = grouped_matmul(h, ex_down, tile_group, n_tiles, tm,
                        use_kernel=use_kernel)
-    # a row no tile wrote is whatever the buffer held: where(), not a
-    # product with a zero weight
-    y = jnp.where(held.reshape(-1, 1), y[pair_row].astype(jnp.float32),
-                  0.0) * w.reshape(-1, 1)
-    return y.reshape(T, k, -1).sum(axis=1)
+    return _from_rows(y, w, pair_row, held, row_token, row_pair)
 
 
 def held_expert_ffn(x, router_w, bias, ex_gate, ex_up, ex_down, *, lo,
-                    top_k, route_scale, valid=None, use_kernel=True):
+                    top_k, route, route_scale=1.0, valid=None,
+                    use_kernel=True, remat=False):
     """One chip's share of a token-choice expert layer, dropless.
 
     x (T, D); router_w (E, D) and bias (E,) at the PUBLISHED expert
     count; ex_gate, ex_up (n, D, I) and ex_down (n, I, D): the stacked
     SwiGLU weights of the experts [lo, lo + n) held here, input-major.
-    Every token is scored and its top-k picked over all E experts; the
-    layer returns the sum over picked AND held experts of
-    w_e * SwiGLU_e(x) in float32 — what the absent experts would add
-    lives on the chips that hold them, and on one chip the layer runs
-    without its exchange. `valid` (T,) bool keeps padding rows and idle
-    batch slots out of the experts. No capacity, nothing dropped:
-    shapes are the worst case's (T * k rows).
+    `route(x, router_w, bias, top_k, route_scale) -> (sel, w)` is the
+    model's routing rule (`route_top_k`: sigmoid scores and a bias that
+    picks; `route_softmax_top_k`: a softmax's top-k, normalised): every
+    token is scored and its top-k picked over all E experts; the layer
+    returns the sum over picked AND held experts of w_e * SwiGLU_e(x)
+    in float32 — what the absent experts would add lives on the chips
+    that hold them, and on one chip the layer runs without its
+    exchange. `valid` (T,) bool keeps padding rows and idle batch slots
+    out of the experts. No capacity, nothing dropped: shapes are the
+    worst case's (T * k rows).
 
-    Returns (y (T, D) float32, pairs, touched): the pairs that fell on
-    held experts and the held experts with at least one row, int32.
+    The layer differentiates end to end: x and the experts' matrices
+    through the grouped products (`kernels/grouped_matmul.py`: the
+    kernel again on the transposed matrices, and a wgrad kernel), the
+    rows' way there and back by gathers both ways, the router through
+    the weights `route` returns.
 
-    A long prefill is routed `moe_grouped_matmul.chunk_tokens` tokens
-    at a time, so the row buffers stay a chunk's worst case."""
+    Returns (y (T, D) float32, pairs, touched, pairs_max): the pairs
+    that fell on held experts, the held experts with at least one row
+    and the fullest held expert's rows, int32.
+
+    More than a chunk of tokens are routed a chunk at a time, so the
+    row buffers stay a chunk's worst case: the tuned
+    `moe_grouped_matmul.chunk_tokens` (a long prefill's), or with
+    `remat`, where a chunk's buffers are rebuilt in the backward and
+    not kept (a training step keeps one chunk's at a time),
+    `chunk_rows_remat` pairs — sized by its rows, as the row tile is.
+
+    The cotangent reaches the experts' rows rounded to their type
+    (`_from_rows_bwd` gathers it in y's type, half the bytes): the
+    gradients are exact to the type the experts compute in, not to the
+    float32 the layer returns. A caller that casts the output to that
+    type (the decoder blocks do) loses nothing by it."""
     from ..kernels import tuning
 
-    sel, w = route_top_k(x, router_w, bias, top_k, route_scale)
+    sel, w = route(x, router_w, bias, top_k, route_scale)
     n = ex_gate.shape[0]
     held = (sel >= lo) & (sel < lo + n)
     if valid is not None:
@@ -238,15 +342,18 @@ def held_expert_ffn(x, router_w, bias, ex_gate, ex_up, ex_down, *, lo,
     T = x.shape[0]
     g = jnp.where(held, sel - lo, n)        # n: on no expert held here
     hits = jnp.zeros((n + 1,), jnp.int32).at[g.reshape(-1)].add(1)[:n]
-    pairs, touched = jnp.sum(hits), jnp.sum(hits > 0)
-    chunk = tuning.get("moe_grouped_matmul", "chunk_tokens")
-    if T <= chunk or T % chunk:
-        return _held_rows(x, g, w, ex_gate, ex_up, ex_down,
-                          use_kernel), pairs, touched
+    counts = (jnp.sum(hits), jnp.sum(hits > 0), jnp.max(hits))
+    chunk = tuning.get("moe_grouped_matmul", "chunk_rows_remat") // top_k \
+        if remat else tuning.get("moe_grouped_matmul", "chunk_tokens")
 
     def one(c):
         return _held_rows(*c, ex_gate, ex_up, ex_down, use_kernel)
 
+    if remat:
+        one = jax.checkpoint(one)
+    if T <= chunk or T % chunk:
+        return (one((x, g, w)),) + counts
+
     split = lambda a: a.reshape((T // chunk, chunk) + a.shape[1:])
     out = jax.lax.map(one, (split(x), split(g), split(w)))
-    return out.reshape(T, -1), pairs, touched
+    return (out.reshape(T, -1),) + counts

@@ -149,9 +149,9 @@ def test_the_shares_add_up_to_the_uncut_layer():
                           for k in ("ex_gate", "ex_up", "ex_down")})
         sh, part = ref.ffn_parts(share, lps, m)
         np.testing.assert_allclose(sh, shared, atol=1e-6)
-        got, pairs, _ = held_expert_ffn(
+        got, pairs, *_ = held_expert_ffn(
             m, lps["router"], lps["bias"], lps["ex_gate"], lps["ex_up"],
-            lps["ex_down"], lo=lo, top_k=2,
+            lps["ex_down"], lo=lo, top_k=2, route=route_top_k,
             route_scale=share["route_scale"])
         np.testing.assert_allclose(got, part, atol=2e-5)
         total += np.asarray(part)
@@ -210,8 +210,9 @@ def test_held_expert_layer_is_dropless(case, kernel, monkeypatch):
     if case == "chunked":
         tuning.set_runtime("moe_grouped_matmul", "chunk_tokens", 16)
     try:
-        got, pairs, touched = held_expert_ffn(
-            x, rw, b, eg, eu, ed, lo=lo, top_k=k, route_scale=2.448,
+        got, pairs, touched, fullest = held_expert_ffn(
+            x, rw, b, eg, eu, ed, lo=lo, top_k=k, route=route_top_k,
+            route_scale=2.448,
             valid=None if valid is None else jnp.asarray(valid))
     finally:
         tuning.clear_runtime()
@@ -222,6 +223,8 @@ def test_held_expert_layer_is_dropless(case, kernel, monkeypatch):
         on &= valid[:, None]
     assert int(pairs) == int(on.sum())
     assert int(touched) == len(set(sel[on].tolist()))
+    assert int(fullest) == max(
+        [0] + [int((sel[on] == e).sum()) for e in range(lo, lo + 4)])
     if case == "skewed":
         assert (sel == 5).sum() > 0.8 * x.shape[0]
     if case == "empty":
@@ -258,10 +261,17 @@ def test_flash_attention_window_matches_the_reference(window, interpret):
         assert float(jnp.abs(full - want)[0].max()) > 1e-2
 
 
-def test_flash_attention_window_refuses_the_backward(interpret):
-    q = jnp.ones((1, 128, 2, 128))
-    with pytest.raises(NotImplementedError, match="sliding window"):
-        jax.grad(lambda a: flash_attention_raw(a, q, q, window=32).sum())(q)
+def test_flash_attention_window_differentiates_and_needs_causal(
+        interpret):
+    """The windowed kernel has its backward (it refused one until the
+    dkv kernel learnt the window); a window without `causal` is still
+    no attention this file knows."""
+    q = jax.random.normal(jax.random.PRNGKey(3), (1, 128, 2, 128))
+    got = jax.grad(
+        lambda a: flash_attention_raw(a, q, q, window=32).sum())(q)
+    want = jax.grad(
+        lambda a: reference_attention(a, q, q, window=32).sum())(q)
+    np.testing.assert_allclose(got, want, atol=2e-5)
     with pytest.raises(ValueError, match="causal"):
         flash_attention_raw(q, q, q, causal=False, window=32)
 

@@ -202,7 +202,7 @@ _PACKED = {
 def test_packed_heads_match_reference(case, monkeypatch):
     """Forward and custom_vjp gradients of the lane-dense kernels
     against reference_attention and its jax.vjp, through the dispatch
-    seam (the windowed forward has no backward: forward only)."""
+    seam (the windowed case too, since the dkv kernel takes one)."""
     from mxnet_tpu.kernels import flash_attention as fa
     monkeypatch.setenv("MXNET_TPU_FLASH_INTERPRET", "1")
     H, K, d, causal, window, ragged = _PACKED[case]
@@ -218,17 +218,51 @@ def test_packed_heads_match_reference(case, monkeypatch):
     ref = reference_attention(q, k, v, **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
-    if window is None:
-        g = jnp.asarray(np.random.RandomState(22)
-                        .randn(*q.shape).astype(np.float32) * 0.2)
-        gk = jax.grad(lambda *a: (fa.flash_attention_raw(*a, **kw)
-                                  * g).sum(), argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(lambda *a: (reference_attention(*a, **kw)
-                                  * g).sum(), argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gk, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=3e-4, atol=3e-5)
+    g = jnp.asarray(np.random.RandomState(22)
+                    .randn(*q.shape).astype(np.float32) * 0.2)
+    gk = jax.grad(lambda *a: (fa.flash_attention_raw(*a, **kw)
+                              * g).sum(), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: (reference_attention(*a, **kw)
+                              * g).sum(), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gk, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=3e-4, atol=3e-5)
     assert fa._fallback.count == before, "the kernels fell back"
+
+
+# the windowed backward: (window, ragged lengths) at T = 256 in blocks
+# of 64 — a window inside one block, of exactly a block, of two and a
+# half, and one no query reaches
+_WINDOWED_BWD = [(17, False), (64, False), (64, True), (160, True),
+                 (1000, False)]
+
+
+@pytest.mark.parametrize("window,ragged", _WINDOWED_BWD)
+def test_windowed_backward_matches_reference_vjp(window, ragged):
+    """dQ, dK and dV of the dkv kernel with a sliding window against
+    the VJP of reference_attention, 4 query heads on 2 kv heads; with
+    blocks of 64 the sweep of a key block ends where its window does."""
+    from mxnet_tpu.kernels.flash_attention import _pallas_backward
+    q, k, v = _qkv(B=2, T=256, H=4, K=2, d=16, seed=31)
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    lengths = jnp.asarray([256, 101], jnp.int32) if ragged else None
+    g = jnp.asarray(np.random.RandomState(32)
+                    .randn(*q.shape).astype(np.float32) * 0.2)
+    kw = dict(causal=True, scale=scale, lengths=lengths, window=window)
+    _, vjp = jax.vjp(lambda *a: reference_attention(*a, **kw), q, k, v)
+    want = vjp(g)
+    out, lse = _pallas_forward(q, k, v, block_q=64, block_k=64,
+                               interpret=True, return_lse=True, **kw)
+    delta = jnp.sum(g * out, axis=-1).transpose(0, 2, 1)
+    got = _pallas_backward(q, k, v, lse, delta, g, block_q=64,
+                           block_k=64, interpret=True, **kw)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+    if window < 256:
+        full = jax.vjp(lambda *a: reference_attention(
+            *a, causal=True, scale=scale, lengths=lengths), q, k, v)[1](g)
+        assert float(jnp.abs(full[0] - want[0]).max()) > 1e-3
 
 
 def test_row_with_every_key_masked(monkeypatch):

@@ -20,7 +20,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
 import mxnet_tpu as mx  # noqa: E402
 from mxnet_tpu.models import mla_math  # noqa: E402
 from mxnet_tpu.models.decoder import LATENT  # noqa: E402
-from mxnet_tpu.parallel.moe import held_expert_ffn  # noqa: E402
+from mxnet_tpu.parallel.moe import held_expert_ffn, route_top_k  # noqa: E402
 from mxnet_tpu.serving import InferenceServer  # noqa: E402
 from mxnet_tpu.serving.kv_cache import PagedKVCache  # noqa: E402
 from perfbench import harness, rehearse, schedule  # noqa: E402
@@ -221,9 +221,9 @@ def test_the_shares_add_up_to_the_uncut_layer():
                           for k in ("ex_gate", "ex_up", "ex_down")})
         sh, part = ref.ffn_parts(share, lps, m)
         np.testing.assert_allclose(sh, shared, atol=1e-6)
-        got, _, _ = held_expert_ffn(
+        got, *_ = held_expert_ffn(
             m, lps["router"], lps["bias"], lps["ex_gate"], lps["ex_up"],
-            lps["ex_down"], lo=lo, top_k=2,
+            lps["ex_down"], lo=lo, top_k=2, route=route_top_k,
             route_scale=share["routed_scaling_factor"])
         np.testing.assert_allclose(got, part, atol=2e-5)
         total += np.asarray(part)

@@ -252,6 +252,61 @@ def test_afmoe_kernels_compile_for_v5e(what, one_chip):
     assert name in text
 
 
+# -- the Mellum training cell's kernels at its shapes
+# (mellum2_12b.pretrain8k: 4 x 8,192 positions, 32 / 4 heads of 128,
+# window 1,024; 16 held experts of 2,304 x 896, a chunk of 8,192 tokens
+# x top-8 laid out in row tiles of 512) ------------------------------------
+
+_MELLUM = ["grouped swiglu, pre-activations kept", "grouped down",
+           "grouped dgrad, transposed sum of two", "grouped wgrad up",
+           "grouped wgrad down", "flash backward, window",
+           "flash backward, full"]
+
+
+@pytest.mark.parametrize("what", _MELLUM)
+def test_mellum_training_kernels_compile_for_v5e(what, one_chip):
+    from mxnet_tpu.kernels import flash_attention as fa
+    from mxnet_tpu.kernels import grouped_matmul as gm
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    tm = gm.row_tile(8192 * 8)
+    assert tm == 512
+    M, D, I, n = 8192 * 8 + 16 * tm, 2304, 896, 16
+    kw = dict(tm=tm, interpret=False)
+    tg, nt = sds((M // tm,), jnp.int32), sds((), jnp.int32)
+    name = "moe_grouped_matmul"
+    if what.startswith("grouped swiglu"):
+        fn = lambda x, a, b, tg, nt: gm._grouped_matmul_pallas(  # noqa: E731
+            x, a, b, tg, nt, save_pre=True, **kw)
+        args = (sds((M, D)), sds((n, D, I)), sds((n, D, I)), tg, nt)
+    elif what == "grouped down":
+        fn = lambda x, a, tg, nt: gm._grouped_matmul_pallas(  # noqa: E731
+            x, a, None, tg, nt, **kw)
+        args = (sds((M, I)), sds((n, I, D)), tg, nt)
+    elif what.startswith("grouped dgrad"):
+        fn = lambda x, a, y, b, tg, nt: gm._grouped_matmul_pallas(  # noqa: E731
+            x, a, b, tg, nt, lhs2=y, transpose_rhs=True, **kw)
+        args = (sds((M, I)), sds((n, D, I)), sds((M, I)), sds((n, D, I)),
+                tg, nt)
+    elif what.startswith("grouped wgrad"):
+        K, N = (D, I) if what.endswith("up") else (I, D)
+        fn = lambda x, dy, tg, nt: gm._grouped_wgrad_pallas(  # noqa: E731
+            x, dy, tg, nt, n, **kw)
+        args, name = (sds((M, K)), sds((M, N)), tg, nt), \
+            "moe_grouped_matmul_wgrad"
+    else:
+        window = 1024 if "window" in what else None
+        B, T, H, Kh, d = 4, 8192, 32, 4, 128
+        fn = lambda q, k, v, lse, dl, do: fa._pallas_backward(  # noqa: E731
+            q, k, v, lse, dl, do, True, d ** -0.5, window=window)
+        args = (sds((B, T, H, d)), sds((B, T, Kh, d)), sds((B, T, Kh, d)),
+                sds((B, H, T), jnp.float32), sds((B, H, T), jnp.float32),
+                sds((B, T, H, d)))
+        name = "flash_attention_dkv"
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert name in text
+
+
 # -- the Jamba cell's kernels at its shapes (jamba2_3b.reason256: 256
 # slots of max_len 10,240, 20 query heads on ONE kv head of 128, d_inner
 # 5,120, d_state 16, prompts up to 2,048) ---------------------------------
